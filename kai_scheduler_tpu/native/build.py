@@ -1,42 +1,58 @@
-"""Build + load the native state store (g++ -> shared lib, cached)."""
+"""Build + load the native state store (g++ -> shared lib, cached).
+
+The library is built from the committed ``statestore.cpp`` into
+``native/_build/`` inside the checkout (git-ignored), keyed by the
+source digest — a fixed place, so a fresh checkout builds it once and
+every later process of that checkout loads the same file."""
 
 from __future__ import annotations
 
 import ctypes
 import hashlib
 import os
+import shutil
 import subprocess
-import tempfile
+
+from ..utils.logging import LOG
 
 _SRC = os.path.join(os.path.dirname(__file__), "statestore.cpp")
+_BUILD_DIR = os.path.join(os.path.dirname(__file__), "_build")
 _LIB_CACHE: dict = {}
 
 
 def _lib_path() -> str:
     with open(_SRC, "rb") as f:
         digest = hashlib.sha256(f.read()).hexdigest()[:16]
-    cache_dir = os.environ.get(
-        "KAI_NATIVE_CACHE",
-        os.path.join(tempfile.gettempdir(), "kai_scheduler_tpu_native"))
-    os.makedirs(cache_dir, exist_ok=True)
-    return os.path.join(cache_dir, f"statestore-{digest}.so")
+    return os.path.join(_BUILD_DIR, f"statestore-{digest}.so")
 
 
 def load_statestore_lib():
-    """Compile (if needed) and dlopen the state store; None if no
-    toolchain."""
+    """Compile (if needed) and dlopen the state store.  Returns None —
+    and says so once in the log — only when the machine has no ``g++``;
+    a compiler that is present and fails is an error."""
     if "lib" in _LIB_CACHE:
         return _LIB_CACHE["lib"]
     path = _lib_path()
     if not os.path.exists(path):
+        if shutil.which("g++") is None:
+            LOG.warning("native state store unavailable: no g++ on PATH; "
+                        "sessions use the numpy node mirrors")
+            _LIB_CACHE["lib"] = None
+            return None
+        os.makedirs(_BUILD_DIR, exist_ok=True)
+        # Build under a private name, then rename: two processes of one
+        # checkout may start together and must never dlopen a half-
+        # written file.
+        tmp = f"{path}.{os.getpid()}.tmp"
         try:
             subprocess.run(
                 ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC,
-                 "-o", path],
+                 "-o", tmp],
                 check=True, capture_output=True, timeout=120)
-        except (OSError, subprocess.SubprocessError):
-            _LIB_CACHE["lib"] = None
-            return None
+            os.replace(tmp, path)
+        finally:
+            if os.path.exists(tmp):
+                os.unlink(tmp)
     lib = ctypes.CDLL(path)
     d = ctypes.POINTER(ctypes.c_double)
     lib.ss_create.restype = ctypes.c_void_p

@@ -98,6 +98,10 @@ class NativeNodeTable:
     # ~25% of per-task statement cost at 100k-node scale.
     def _view(self, ptr, shape):
         size = int(np.prod(shape))
+        if size == 0:
+            # A table of no nodes (the embedded daemon before any Node
+            # exists) has no buffer: its C vectors' data() is NULL.
+            return np.zeros(shape)
         buf = np.ctypeslib.as_array(ptr, shape=(size,))
         return buf.reshape(shape)
 

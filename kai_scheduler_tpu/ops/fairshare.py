@@ -195,11 +195,17 @@ def divide_groups_jax(spec: LevelSpec, group_total, group_of_queue,
         in_band = (band_of_queue == band)[:, None]  # [Q,1]
 
         def cond(carry):
-            fair, remaining, rem_frac, go, i = carry
-            return go & (i < spec.max_rounds)
+            fair, remaining, rem_frac, live, i = carry
+            return jnp.any(live) & (i < spec.max_rounds)
 
         def body(carry):
-            fair, remaining, rem_frac, _, i = carry
+            # ``live`` [G,R]: the (group, resource) pairs whose previous
+            # round asked for another.  The sequential reference loops
+            # per group and per resource, so a pair that is done must
+            # sit out the rounds another pair still needs — a further
+            # round would re-split its leftover and overwrite the
+            # remainders its largest-remainder pass ranks by.
+            fair, remaining, rem_frac, live, i = carry
             unsat = in_band & (requestable - fair > EPS)
             tw = _segment_sum(jnp.where(unsat, oqw, 0.0), seg, G)  # [G,R]
             n_w = jnp.where(unsat & (tw[seg] > 0), oqw / jnp.where(
@@ -208,7 +214,7 @@ def divide_groups_jax(spec: LevelSpec, group_total, group_of_queue,
                                 jnp.maximum(0.0, n_w + k_value * (n_w - usage)),
                                 0.0)
             sw = _segment_sum(share_w, seg, G)  # [G,R]
-            active = unsat & (share_w > 0) & (sw[seg] > 0)
+            active = unsat & (share_w > 0) & (sw[seg] > 0) & live[seg]
             fair_q = jnp.where(active,
                                remaining[seg] * share_w
                                / jnp.where(sw[seg] > 0, sw[seg], 1.0), 0.0)
@@ -225,12 +231,13 @@ def divide_groups_jax(spec: LevelSpec, group_total, group_of_queue,
             remaining = jnp.maximum(
                 remaining - _segment_sum(give, seg, G), 0.0)
             another = (active & (rem_req < fair_q)) & (remaining[seg] > EPS)
-            go = jnp.any(another)
-            return fair, remaining, new_frac, go, i + 1
+            live = _segment_sum(another.astype(fair.dtype), seg, G) > 0
+            return fair, remaining, new_frac, live, i + 1
 
         fair, remaining, rem_frac, _, _ = jax.lax.while_loop(
             cond, body,
-            (fair, remaining, rem_frac_all, jnp.array(True), jnp.array(0)))
+            (fair, remaining, rem_frac_all, jnp.ones((G, R), bool),
+             jnp.array(0)))
         return fair, remaining, rem_frac
 
     # Static unroll over priority bands (band ids are dense 0..num_bands-1,
@@ -493,11 +500,13 @@ def _divide_level_dense(spec: ForestSpec, bands: tuple, pool, band_q,
         in_band = (band_q == band)[:, :, None]  # [G,S,1]
 
         def cond(carry):
-            fair, remaining, rem_frac, go, i = carry
-            return go & (i < spec.max_rounds)
+            fair, remaining, rem_frac, live, i = carry
+            return jnp.any(live) & (i < spec.max_rounds)
 
         def body(carry):
-            fair, remaining, rem_frac, _, i = carry
+            # ``live`` [G,R] as in ``divide_groups_jax``: a (group,
+            # resource) pair runs a round only while it asked for one.
+            fair, remaining, rem_frac, live, i = carry
             unsat = in_band & (requestable - fair > EPS)
             tw = jnp.where(unsat, oqw, 0.0).sum(axis=1)  # [G,R]
             tw_b = tw[:, None, :]
@@ -508,7 +517,7 @@ def _divide_level_dense(spec: ForestSpec, bands: tuple, pool, band_q,
                                             n_w + k_value * (n_w - usage)),
                                 0.0)
             sw = share_w.sum(axis=1)[:, None, :]  # [G,1,R]
-            active = unsat & (share_w > 0) & (sw > 0)
+            active = unsat & (share_w > 0) & (sw > 0) & live[:, None, :]
             fair_q = jnp.where(active,
                                remaining[:, None, :] * share_w
                                / jnp.where(sw > 0, sw, 1.0), 0.0)
@@ -525,12 +534,12 @@ def _divide_level_dense(spec: ForestSpec, bands: tuple, pool, band_q,
             remaining = jnp.maximum(remaining - give.sum(axis=1), 0.0)
             another = (active & (rem_req < fair_q)) \
                 & (remaining[:, None, :] > EPS)
-            go = jnp.any(another)
-            return fair, remaining, new_frac, go, i + 1
+            return fair, remaining, new_frac, another.any(axis=1), i + 1
 
         fair, remaining, rem_frac, _, _ = jax.lax.while_loop(
             cond, body,
-            (fair, remaining, rem_frac0, jnp.array(True), jnp.array(0)))
+            (fair, remaining, rem_frac0, jnp.ones((G, R), bool),
+             jnp.array(0)))
         return fair, remaining, rem_frac
 
     # Band fold: a fori_loop over the band ids actually present at this

@@ -18,8 +18,8 @@ Two generations of kernels live here:
 
 Semantics match ops.predicates.feasibility_caps_row +
 ops.scoring.score_row_selected at f32 (parity-tested in interpret mode);
-the host wrapper's mode resolution falls back to the fused-jnp path on
-non-TPU backends or when the node bucket doesn't tile.
+the host wrapper's mode resolution picks the fused-jnp path on non-TPU
+backends or when the node bucket doesn't tile.
 """
 
 from __future__ import annotations
@@ -132,41 +132,61 @@ def task_row_pallas(req, sel, tol, node_idle, node_releasing, node_labels,
             cap_now[:, 0], cap_tot[:, 0])
 
 
-def _tile_row_terms(req, sel, tol, idle, rel, labels, taints, room,
-                    mask, releasing_empty: bool):
-    """Shared per-tile feasibility + capacity terms (f32, unrolled R).
+def _where_s(flag, if_true, if_false):
+    """``where`` on a SCALAR flag over [1, TILE] rows.  The flag rides as
+    an f32 0/1 scalar and is compared in vector land: Mosaic legalizes
+    neither a broadcast of an i1 scalar nor a select between i1 vectors
+    (the refusal the first v5e compile of this kernel hit)."""
+    shape = jnp.shape(if_true) or jnp.shape(if_false)
+    return jnp.where(jnp.full(shape, flag, jnp.float32) > 0.5,
+                     if_true, if_false)
 
-    Mirrors predicates.feasibility_caps_row on one VMEM-resident tile:
-    req/sel/tol are [1, X] rows, node state is [TILE, X].  Returns
-    (fit_now, fit_future, cap_now_f, cap_tot_f), each [TILE, 1]."""
+
+def _tile_row_terms(req_ref, sel_ref, tol_ref, idle_ref, rel_ref,
+                    labels_ref, taints_ref, room, mask,
+                    releasing_empty: bool):
+    """Shared per-tile feasibility + capacity terms (f32, every axis but
+    the node axis unrolled).
+
+    Mirrors predicates.feasibility_caps_row on one VMEM-resident tile in
+    the lane-dense layout: node state refs are [X, TILE] (node axis on
+    the lanes), req/sel/tol are SMEM scalars.  Returns (fit_now,
+    fit_future, cap_now_f, cap_tot_f), each [1, TILE]."""
     from .predicates import EPS, NO_LABEL, NO_TAINT
-    sel_ok = jnp.all((sel == NO_LABEL) | (sel == labels), axis=-1,
-                     keepdims=True)
-    tolerated = jnp.any(taints[:, :, None] == tol[0][None, None, :],
-                        axis=-1)
-    taint_ok = jnp.all((taints == NO_TAINT) | tolerated, axis=-1,
-                       keepdims=True)
-    hard = sel_ok & taint_ok & (room >= 1.0)
+    hard = room >= 1.0
+    one, zero = jnp.int32(1), jnp.int32(0)
+    for l in range(labels_ref.shape[0]):
+        want = sel_ref[l]
+        wild = jnp.where(want == NO_LABEL, one, zero)
+        match = jnp.where(labels_ref[l:l + 1, :] == want, one, zero)
+        hard = hard & ((match + wild) > 0)
+    for t in range(taints_ref.shape[0]):
+        taint = taints_ref[t:t + 1, :]
+        tolerated = taint == NO_TAINT
+        for k in range(tol_ref.shape[0]):
+            tolerated = tolerated | (taint == tol_ref[k])
+        hard = hard & tolerated
     if mask is not None:
         hard = hard & (mask > 0.5)
 
-    r_dims = idle.shape[1]
     fits_idle = hard
     fits_total = hard
     cap_now_f = None
     cap_tot_f = None
-    for r in range(r_dims):
-        rq = req[0, r]
+    for r in range(idle_ref.shape[0]):
+        rq = req_ref[r]
         safe = jnp.where(rq > 0, rq, 1.0)
-        col = idle[:, r:r + 1]
+        # +inf where the task does not ask for this resource, else +0.
+        unasked = jnp.where(rq > 0, 0.0, jnp.inf)
+        col = idle_ref[r:r + 1, :]
         fits_idle = fits_idle & (rq <= col + EPS)
-        ratio = jnp.where(rq > 0, jnp.floor(col / safe), jnp.inf)
+        ratio = jnp.floor(col / safe) + unasked
         cap_now_f = ratio if cap_now_f is None \
             else jnp.minimum(cap_now_f, ratio)
         if not releasing_empty:
-            tot = col + rel[:, r:r + 1]
+            tot = col + rel_ref[r:r + 1, :]
             fits_total = fits_total & (rq <= tot + EPS)
-            ratio_t = jnp.where(rq > 0, jnp.floor(tot / safe), jnp.inf)
+            ratio_t = jnp.floor(tot / safe) + unasked
             cap_tot_f = ratio_t if cap_tot_f is None \
                 else jnp.minimum(cap_tot_f, ratio_t)
     if releasing_empty:
@@ -175,11 +195,11 @@ def _tile_row_terms(req, sel, tol, idle, rel, labels, taints, room,
 
 
 def _f32_key(score):
-    """Order-preserving u32 key for an f32 score (per-lane form of
-    ops.allocate_grouped._score_keys' f32 branch)."""
-    bits = jax.lax.bitcast_convert_type(score, jnp.uint32)
-    return jnp.where(bits >> jnp.uint32(31) == 1, ~bits,
-                     bits | jnp.uint32(1 << 31))
+    """Order-preserving key for an f32 score, as the int32 bit pattern of
+    ops.allocate_grouped._score_keys' u32 key (the caller bitcasts)."""
+    bits = jax.lax.bitcast_convert_type(score, jnp.int32)
+    sign = jnp.int32(-2 ** 31)
+    return jnp.where(bits < 0, bits ^ jnp.int32(-1), bits | sign)
 
 
 def group_step_pallas(node_allocatable, idle, rel, node_labels,
@@ -192,12 +212,16 @@ def group_step_pallas(node_allocatable, idle, rel, node_labels,
     (key_now, key_pipe | None, cap_now, cap_tot | None, levels, utype)
     exactly like ops.allocate_grouped._fused_row, computed at f32.
 
+    Layout: every node tensor enters transposed, [X, N] with the node
+    axis on the lanes, and every output is a [1, N] row — the blocks are
+    lane-dense (X, TILE) slabs; req/sel/tol ride in SMEM as scalars.
     Grid (2, n_tiles): phase 0 reduces the selected resource column's
     valid min/max into SMEM scratch; phase 1 recomputes the tile terms
     from VMEM and writes keys + capacities.  ``interpret`` defaults to
     True off-TPU (the test suite's parity path); on TPU the kernel
     compiles to Mosaic."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -234,16 +258,10 @@ def group_step_pallas(node_allocatable, idle, rel, node_labels,
         phase = pl.program_id(0)
         j = pl.program_id(1)
 
-        reqv = req_ref[...]
-        idlev = idle_ref[...]
-        relv = rel_ref[...] if have_rel else None
         roomv = room_ref[...]
-        allocv = alloc_ref[...]
-        maskv = mask_ref[...] if have_mask else None
-
         fit_now, fit_future, cap_now_f, cap_tot_f = _tile_row_terms(
-            reqv, sel_ref[...], tol_ref[...], idlev, relv,
-            labels_ref[...], taints_ref[...], roomv, maskv,
+            req_ref, sel_ref, tol_ref, idle_ref, rel_ref, labels_ref,
+            taints_ref, roomv, mask_ref[...] if have_mask else None,
             releasing_empty)
         if pipeline_only:
             fit_now = jnp.zeros_like(fit_now)
@@ -251,11 +269,11 @@ def group_step_pallas(node_allocatable, idle, rel, node_labels,
                               if (allow_pipeline or pipeline_only)
                               else jnp.zeros_like(fit_future))
 
-        is_gpu_job = reqv[0, RES_GPU] > 0.0
-        free = jnp.where(is_gpu_job, idlev[:, RES_GPU:RES_GPU + 1],
-                         idlev[:, RES_CPU:RES_CPU + 1])
-        axcap = jnp.where(is_gpu_job, allocv[:, RES_GPU:RES_GPU + 1],
-                          allocv[:, RES_CPU:RES_CPU + 1])
+        gpu_job = jnp.where(req_ref[RES_GPU] > 0.0, 1.0, 0.0)
+        free = _where_s(gpu_job, idle_ref[RES_GPU:RES_GPU + 1, :],
+                        idle_ref[RES_CPU:RES_CPU + 1, :])
+        axcap = _where_s(gpu_job, alloc_ref[RES_GPU:RES_GPU + 1, :],
+                         alloc_ref[RES_CPU:RES_CPU + 1, :])
         has_res = axcap > 0.0
         valid = feasible & has_res
 
@@ -286,13 +304,13 @@ def group_step_pallas(node_allocatable, idle, rel, node_labels,
                 flat = span <= 0.0
                 placement = MAX_HIGH_DENSITY * (
                     1.0 - (free - min_free) / jnp.where(flat, 1.0, span))
-                placement = jnp.where(flat, MAX_HIGH_DENSITY, placement)
+                placement = _where_s(jnp.where(flat, 1.0, 0.0),
+                                     MAX_HIGH_DENSITY, placement)
                 placement = jnp.where(has_res, placement, 0.0)
-            node_has_gpu = allocv[:, RES_GPU:RES_GPU + 1] > 0.0
-            rtype = jnp.where(
-                jnp.where(is_gpu_job, node_has_gpu, ~node_has_gpu),
-                RESOURCE_TYPE, 0.0)
-            score = placement + rtype \
+            node_has_gpu = jnp.where(
+                alloc_ref[RES_GPU:RES_GPU + 1, :] > 0.0, 1.0, 0.0)
+            score = placement \
+                + jnp.where(node_has_gpu == gpu_job, RESOURCE_TYPE, 0.0) \
                 + jnp.where(fit_now, AVAILABILITY, 0.0)
             if have_extra:
                 score = score + extra_ref[...]
@@ -306,75 +324,58 @@ def group_step_pallas(node_allocatable, idle, rel, node_labels,
                 cap_tot_ref[...] = jnp.where(
                     feasible, jnp.minimum(cap_tot_f, roomv), 0.0)
 
-        # Phase 0 leaves the output blocks untouched; write zeros so the
-        # inter-visit flush is deterministic (phase 1 overwrites).
-        @pl.when(phase == 0)
-        def _zero_outputs():
-            key_now_ref[...] = jnp.zeros_like(key_now_ref)
-            cap_now_ref[...] = jnp.zeros_like(cap_now_ref)
-            if pipe_items:
-                key_pipe_ref[...] = jnp.zeros_like(key_pipe_ref)
-                cap_tot_ref[...] = jnp.zeros_like(cap_tot_ref)
+    def node_block(rows):
+        return pl.BlockSpec((rows, tile), lambda p, j: (0, j))
 
-    def node_block(cols):
-        return pl.BlockSpec((tile, cols), lambda p, j: (j, 0))
+    # Phase 0 writes no output: its steps all map to output block 0,
+    # which stays resident until phase 1 has filled it, so every block is
+    # flushed exactly once and with phase-1 values.
+    out_block = pl.BlockSpec((1, tile), lambda p, j: (0, j * p))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
-    def bcast_block(cols):
-        return pl.BlockSpec((1, cols), lambda p, j: (0, 0))
-
-    in_specs = [bcast_block(r), bcast_block(L), bcast_block(tol.shape[0]),
-                node_block(r), node_block(r)]
-    args = [req[None, :].astype(jnp.float32),
-            sel[None, :].astype(jnp.int32),
-            tol[None, :].astype(jnp.int32),
-            node_allocatable.astype(jnp.float32),
-            idle.astype(jnp.float32)]
+    in_specs = [smem, smem, smem, node_block(r), node_block(r)]
+    args = [req.astype(jnp.float32), sel.astype(jnp.int32),
+            tol.astype(jnp.int32),
+            node_allocatable.astype(jnp.float32).T,
+            idle.astype(jnp.float32).T]
     if have_rel:
         in_specs.append(node_block(r))
-        args.append(rel.astype(jnp.float32))
+        args.append(rel.astype(jnp.float32).T)
     in_specs += [node_block(L), node_block(tt), node_block(1)]
-    args += [node_labels.astype(jnp.int32), node_taints.astype(jnp.int32),
-             room.astype(jnp.float32)[:, None]]
+    args += [node_labels.astype(jnp.int32).T,
+             node_taints.astype(jnp.int32).T,
+             room.astype(jnp.float32)[None, :]]
     if have_extra:
         in_specs.append(node_block(1))
-        args.append(extra_row.astype(jnp.float32)[:, None])
+        args.append(extra_row.astype(jnp.float32)[None, :])
     if have_mask:
         in_specs.append(node_block(1))
-        args.append(mask_row.astype(jnp.float32)[:, None])
+        args.append(mask_row.astype(jnp.float32)[None, :])
 
     n_outs = 4 if pipe_items else 2
-    out_shape = ([jax.ShapeDtypeStruct((n, 1), jnp.uint32),
-                  jax.ShapeDtypeStruct((n, 1), jnp.float32)]
-                 + ([jax.ShapeDtypeStruct((n, 1), jnp.uint32),
-                     jax.ShapeDtypeStruct((n, 1), jnp.float32)]
-                    if pipe_items else []))
-    from jax.experimental.pallas import tpu as pltpu
-    scratch = [pltpu.SMEM((2,), jnp.float32)]
+    out_shape = [jax.ShapeDtypeStruct((1, n), jnp.int32),
+                 jax.ShapeDtypeStruct((1, n), jnp.float32)] * (n_outs // 2)
 
     outs = pl.pallas_call(
         kernel,
         grid=(2, n_tiles),
         in_specs=in_specs,
-        out_specs=[node_block(1)] * n_outs,
+        out_specs=[out_block] * n_outs,
         out_shape=out_shape,
-        scratch_shapes=scratch,
+        scratch_shapes=[pltpu.SMEM((2,), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
     )(*args)
-    key_now = outs[0][:, 0]
-    cap_now = outs[1][:, 0]
-    key_pipe = outs[2][:, 0] if pipe_items else None
-    cap_tot = outs[3][:, 0] if pipe_items else None
+
+    def as_key(row):
+        return jax.lax.bitcast_convert_type(row[0], jnp.uint32)
+
+    key_now = as_key(outs[0])
+    cap_now = outs[1][0]
+    key_pipe = as_key(outs[2]) if pipe_items else None
+    cap_tot = outs[3][0] if pipe_items else None
     return key_now, key_pipe, cap_now, cap_tot, 4, jnp.uint32
-
-
-def pallas_available() -> bool:
-    """Pallas TPU kernels need a real TPU backend (the CPU interpreter
-    path works too, for tests)."""
-    try:
-        from jax.experimental import pallas  # noqa: F401
-        return True
-    except ImportError:  # pragma: no cover
-        return False
 
 
 def task_row_reference(req, sel, tol, node_idle, node_releasing,

@@ -19,9 +19,10 @@ applied to the usage axis.  ``tools/fleet_budget.py`` pins the dispatch
 count structurally: a silent fall-back to a per-queue loop multiplies
 ``usage_decay_dispatch_total`` by Q and trips the gate.
 
-``usage_decay_np`` is the host reference: the same elementwise IEEE
-expression, asserted bit-identical in tests/test_usagedb.py (the
-CPU-backend jit compiles to the same scalar ops).
+``usage_decay_np`` is the host reference: the same elementwise
+expression, asserted equal to 1 ulp in tests/test_usagedb.py — XLA may
+contract the multiply-add into one FMA (one rounding instead of numpy's
+two), so the two are not bit-identical.
 """
 
 from __future__ import annotations

@@ -19,10 +19,17 @@ NODE_AXIS = "nodes"
 
 def cluster_mesh(n_devices: int | None = None,
                  devices=None) -> Mesh:
-    """1-D mesh over the node axis."""
+    """1-D mesh over the node axis: the first ``n_devices`` devices JAX
+    reports (all of them when None).  Asking for more than there are is
+    an error — a smaller mesh would run the work on fewer chips than
+    the caller sized it for without saying so."""
     if devices is None:
         devices = jax.devices()
         if n_devices is not None:
+            if n_devices > len(devices):
+                raise ValueError(
+                    f"mesh of {n_devices} devices requested but JAX "
+                    f"reports only {len(devices)}")
             devices = devices[:n_devices]
     return Mesh(np.array(devices), (NODE_AXIS,))
 
@@ -30,23 +37,6 @@ def cluster_mesh(n_devices: int | None = None,
 def node_sharding(mesh: Mesh) -> NamedSharding:
     """Sharding for [N, ...] per-node arrays: rows split across chips."""
     return NamedSharding(mesh, P(NODE_AXIS))
-
-
-def shard_map_compat(mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes it at the top level with ``check_vma``; 0.4.x only
-    has ``jax.experimental.shard_map.shard_map`` with the equivalent
-    ``check_rep`` knob.  Both sharded kernels decorate through here so
-    the multi-chip suite runs on whichever jax the image bakes in."""
-    import functools
-    if hasattr(jax, "shard_map"):
-        return functools.partial(jax.shard_map, mesh=mesh,
-                                 in_specs=in_specs, out_specs=out_specs,
-                                 check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return functools.partial(_shard_map, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
 
 
 def replicated(mesh: Mesh) -> NamedSharding:

@@ -24,7 +24,7 @@ from jax.sharding import PartitionSpec as P
 from ..ops.allocate import NEG, AllocationResult
 from ..ops.predicates import feasibility_row
 from ..ops.scoring import BINPACK, score_row
-from .mesh import NODE_AXIS, shard_map_compat
+from .mesh import NODE_AXIS
 
 
 def _global_minmax(free_local, valid_local, axis_name):
@@ -68,8 +68,8 @@ def sharded_allocate_jobs(mesh, node_allocatable, node_idle, node_releasing,
     node_spec = P(NODE_AXIS)
     rep = P()
 
-    @shard_map_compat(
-        mesh,
+    @functools.partial(
+        jax.shard_map, mesh=mesh, check_vma=False,
         in_specs=(node_spec, node_spec, node_spec, node_spec, node_spec,
                   node_spec, rep, rep, rep, rep, rep, P(None, NODE_AXIS)),
         out_specs=(rep, rep, rep, node_spec, node_spec))

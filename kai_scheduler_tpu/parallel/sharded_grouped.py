@@ -33,7 +33,7 @@ from ..ops.allocate import NEG, AllocationResult
 from ..ops.allocate_grouped import _next_pow2, _score_keys, group_tasks
 from ..ops.predicates import feasibility_row
 from ..ops.scoring import BINPACK, score_row
-from .mesh import NODE_AXIS, shard_map_compat
+from .mesh import NODE_AXIS
 from .sharded import _global_minmax
 
 
@@ -132,8 +132,8 @@ def sharded_allocate_groups_kernel(mesh, node_allocatable, node_idle,
     node_spec = P(NODE_AXIS)
     rep = P()
 
-    @shard_map_compat(
-        mesh,
+    @functools.partial(
+        jax.shard_map, mesh=mesh, check_vma=False,
         in_specs=(node_spec,) * 6 + (rep,) * 6,
         out_specs=(rep, rep, rep, rep, node_spec, node_spec))
     def run(alloc, idle, rel, labels, taints, room,

@@ -70,13 +70,24 @@ from .utils.tracing import TRACER
 def healthz_payload(state: dict | None = None) -> dict:
     """Liveness + degraded-mode report: alive is HTTP 200 regardless;
     ``status`` flips to "degraded" while the device-guard breaker is not
-    closed (scheduling continues on the CPU fallback path).  When the
+    closed (scheduling continues on the CPU fallback path).  ``device``
+    names the platform, device kind and device count JAX reported at
+    start-up and ``node_store`` the session's node-mirror backing.  When the
     daemon runs leader-elected/journaled, a ``control_plane`` section
     reports the leadership epoch, watch-gap count, and the last startup
     reconcile summary (docs/DEGRADATION.md failure matrix)."""
     guard = device_guard()
     payload = {"status": "degraded" if guard.degraded else "ok",
                "device_guard": guard.status()}
+    state = state or {}
+    if state.get("device") is not None:
+        # What JAX found at start-up (run_app records it once; the HTTP
+        # thread never asks the backend): a daemon that came up on the
+        # CPU says so here instead of passing for an accelerator run.
+        payload["device"] = state["device"]
+    ssn = state.get("last_session")
+    if ssn is not None:
+        payload["node_store"] = ssn.node_store
     if LOCKTRACE.installed:
         # Runtime lock-order validator (KAI_LOCKTRACE=1): surface the
         # journal so a fleet run shows the validator actually recorded
@@ -92,7 +103,6 @@ def healthz_payload(state: dict | None = None) -> dict:
         # model (docs/STATIC_ANALYSIS.md).
         jittrace_sync_metrics()
         payload["jittrace"] = JITTRACE.stats()
-    state = state or {}
     elector = state.get("lease_elector")
     control: dict = {}
     if elector is not None:
@@ -444,8 +454,20 @@ def run_app(argv=None) -> None:
         from .controllers.httpclient import HTTPKubeAPI
         api = HTTPKubeAPI(args.api_server)
 
+    import jax
+
+    from .utils.compile_cache import enable_compile_cache
+    cache_dir = enable_compile_cache()
+    # Touch the backend here, once, on the main thread: the first
+    # guarded dispatch then pays a compile and not the runtime's
+    # start-up, and /healthz can name the device without asking JAX.
+    devices = jax.devices()
+    device = {"platform": devices[0].platform,
+              "device_kind": devices[0].device_kind,
+              "count": len(devices)}
+    LOG.info("devices: %s; compile cache: %s", device, cache_dir)
+
     if args.profile_dir:
-        import jax
         jax.profiler.start_trace(args.profile_dir)
 
     lease_elector = None
@@ -471,7 +493,7 @@ def run_app(argv=None) -> None:
         pipelined_cycles=bool(args.pipeline),
         scheduling_enabled=not args.controllers_only), api=api)
 
-    state: dict = {"system": system}
+    state: dict = {"system": system, "device": device}
     if lease_elector is not None:
         # Fenced leadership: scheduler writes carry the Lease epoch; a
         # deposed incarnation's writes are rejected at the store.
@@ -537,7 +559,6 @@ def run_app(argv=None) -> None:
         except Exception as exc:
             LOG.warning("pipeline flush on shutdown: %s", exc)
         if args.profile_dir:
-            import jax
             jax.profiler.stop_trace()
         if STACKPROF.running:
             STACKPROF.stop()  # dumps to KAI_STACKPROF_DIR when armed

@@ -95,6 +95,8 @@ def main(argv=None) -> int:
     # Arm the compile-signature journal BEFORE bench imports bind any
     # kernel references — the whole budget run records under trace.
     from kai_scheduler_tpu.utils import jittrace
+    from kai_scheduler_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     jittrace.install()
     import bench
     from kai_scheduler_tpu.utils.metrics import METRICS

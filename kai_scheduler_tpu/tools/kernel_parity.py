@@ -105,6 +105,8 @@ def main(argv=None) -> int:
     ap.add_argument("--smoke", action="store_true",
                     help="2-seed quick gate (the ci_check.sh step)")
     args = ap.parse_args(argv)
+    from ..utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     seeds = range(2 if args.smoke else args.seeds)
 
     failures = []

@@ -2,8 +2,8 @@
 
 The scheduler's latency-critical cycle puts a JAX/XLA device in the middle
 of every placement decision — and a hung PJRT client blocks in C where no
-in-process alarm can interrupt it (four bench rounds lost to exactly that,
-VERDICT.md).  Production AI-cluster schedulers treat accelerator-path
+in-process alarm can interrupt it.  Production AI-cluster schedulers
+treat accelerator-path
 failure as a first-class *degraded mode*, not a crash.  This module gives
 the fleet that property:
 

@@ -1,7 +1,7 @@
 """Decay-math property ring for utils/usagedb.py + prometheus_usage.py.
 
 The tensor-backed usage store's contract (DESIGN §13): half-life
-exactness of the decayed fold, kernel/numpy bit-parity, the sliding
+exactness of the decayed fold, kernel/numpy parity to 1 ulp, the sliding
 window cap, checkpoint-log restart restore (commit-log pattern, torn
 tails included), and the staleness -> proportion-degraded transition.
 """
@@ -27,7 +27,11 @@ def vec(gpu=0.0, cpu=0.0, mem=0.0):
 
 
 class TestDecayKernelParity:
-    def test_kernel_bit_identical_to_numpy(self):
+    def test_kernel_within_one_ulp_of_numpy(self):
+        """Same expression, but XLA may contract ``usage * decay +
+        alloc`` into one fused multiply-add (one rounding where numpy
+        takes two): the results agree to 1 ulp of the test's dtype, not
+        bit for bit."""
         rng = np.random.default_rng(SEED_BASE + 1)
         for _ in range(20):
             q = int(rng.integers(1, 64))
@@ -38,7 +42,8 @@ class TestDecayKernelParity:
             got = np.asarray(usage_decay_kernel(usage, alloc, keep,
                                                 decay))
             want = usage_decay_np(usage, alloc, keep, decay)
-            assert np.array_equal(got, want)
+            assert got.dtype == want.dtype
+            assert np.all(np.abs(got - want) <= np.spacing(np.abs(want)))
 
 
 class TestHalfLife:
