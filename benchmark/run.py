@@ -1,0 +1,225 @@
+"""Run one cell of the benchmark: ``--workload --seed --seconds --trace``.
+
+One run is one process: build the fleet from the seed, prime the cell's
+kernel, warm cycles, the measured window, the comparison that decides
+``correct``, and one JSON object as the last line of standard output.
+See ``benchmark/README.md`` for the run's anatomy.
+"""
+
+from __future__ import annotations
+
+import time
+
+T_PROCESS = time.perf_counter()
+
+import argparse  # noqa: E402
+import json  # noqa: E402
+import os  # noqa: E402
+import shutil  # noqa: E402
+import sys  # noqa: E402
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+# Cycles of the window that a --trace 1 run records with the profiler.
+TRACE_CYCLES = 1
+TRACE_DIR = os.path.join(ROOT, "benchmark", ".trace")
+
+
+def log(*parts) -> None:
+    print(*parts, file=sys.stderr, flush=True)
+
+
+def device_info(chips: int, require_chip: bool) -> dict:
+    import jax
+    devices = jax.devices()
+    if require_chip and (devices[0].platform == "cpu"
+                         or len(devices) < chips):
+        log(f"no accelerator for this cell: platform "
+            f"{devices[0].platform}, {len(devices)} device(s), "
+            f"{chips} needed")
+        raise SystemExit(3)
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
+
+
+def memory_peak(chips: int) -> int:
+    import jax
+    peak = 0
+    for d in jax.local_devices()[:max(1, chips)]:
+        stats = d.memory_stats() or {}
+        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
+    return peak
+
+
+def run_cell(workload: str, seed: int, seconds: float, trace: bool,
+             require_chip: bool = True, root: str = ROOT) -> dict:
+    """Everything a run does; returns the result object."""
+    try:
+        from kai_scheduler_tpu.utils.compile_cache import \
+            enable_compile_cache
+    except ImportError as exc:
+        log(f"the scheduler is not in this checkout: {exc}")
+        raise SystemExit(4)
+    from benchmark.harness import compare, loop, readers, spec
+    from benchmark.harness import trace as tr
+
+    cell = spec.Cell(spec.load_benchmark(root), workload, root)
+    device = device_info(cell.chips, require_chip)
+    cache_dir = enable_compile_cache()
+    watch = loop.CompileWatch()
+    wall = {"import_s": time.perf_counter() - T_PROCESS}
+
+    t = time.perf_counter()
+    client = loop.Client(cell.config, cell.traffic, seed,
+                         counters=readers.counters_wanted(cell.per_layer))
+    wall["build_s"] = time.perf_counter() - t
+
+    t = time.perf_counter()
+    primed = loop.prime(client, watch)
+    wall["prime_s"] = time.perf_counter() - t
+
+    guard0 = loop.guard_counters()
+    t = time.perf_counter()
+    warm = []
+    for _ in range(int(cell.traffic.get("warm_cycles", 1))):
+        c0 = watch.snapshot()
+        client.cycle()
+        c1 = watch.snapshot()
+        warm.append({k: c1[k] - c0[k] for k in ("compiles", "misses")})
+    wall["warm_s"] = time.perf_counter() - t
+    guard_warm = loop.guard_counters()
+
+    compiles0 = watch.snapshot()
+    if trace:
+        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+    t_window = time.perf_counter()
+    setup_s = t_window - T_PROCESS
+    window = loop.measure(client, seconds,
+                          trace_cycles=TRACE_CYCLES if trace else 0,
+                          trace_dir=TRACE_DIR if trace else None)
+    wall["window_s"] = window["elapsed_s"]
+    compiles1 = watch.snapshot()
+    guard1 = loop.guard_counters()
+    device["memory_peak_bytes"] = memory_peak(cell.chips)
+
+    records = client.records[window["first"]:]
+    ledger = client.ledger
+    client.close()
+
+    t = time.perf_counter()
+    numbers = compare.compare(records, ledger, cell.config)
+    correct, compared = compare.verdict(numbers)
+    wall["compare_s"] = time.perf_counter() - t
+
+    attempted = len(records)
+    bound_pods = sum(len(r.gang.bound) for r in records)
+    failed = sum(1 for r in records
+                 if len(r.gang.bound) != len(r.gang.names))
+    guard_moved = loop.moved(guard0, guard1)
+    window_compiles = compiles1["compiles"] - compiles0["compiles"]
+    if guard_moved or window_compiles:
+        # The guard fell back, timed out or refused a result, or something
+        # compiled inside the window: no cycle of this run counts.
+        failed = attempted
+    elapsed = window["elapsed_s"]
+
+    result = {"correct": bool(correct), "attempted": attempted,
+              "failed": failed}
+    run = {"records": records, "device_kind": device["kind"],
+           "traced_cycles": window["traced_cycles"],
+           "kernel_shape": {"steps": len(records[0].gang.names),
+                            "nodes": primed["nodes"],
+                            "resources": primed["resources"],
+                            "has_mask": bool(records[0].gang.topology),
+                            "label_cols": primed["label_cols"],
+                            "taint_cols": primed["taint_cols"]}}
+    if trace:
+        t = time.perf_counter()
+        path = tr.find_xplane(TRACE_DIR)
+        raw = tr.read(path) if path else {"devices": [], "annotations": []}
+        reduced = tr.reduce(raw, window["traced_s"])
+        wall["trace_reduce_s"] = time.perf_counter() - t
+        trace_bytes = os.path.getsize(path) if path else 0
+        run["reduced"] = reduced
+        result["metrics"] = readers.read_all(cell.per_layer, run)
+        if reduced:
+            device["busy_s"] = reduced["busy_s"]
+            device["window_s"] = reduced["window_s"]
+            result["breakdown"] = {
+                "device_ops": tr.top(reduced["ops"] or reduced["programs"]),
+                "idle_gaps": tr.name_gaps(
+                    reduced, raw, spans_on_trace_clock(records, raw))}
+        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+    else:
+        values = {
+            "cycle_ms": 1e3 * elapsed / attempted,
+            "pods_bound_per_s": bound_pods / elapsed,
+            "setup_s": setup_s,
+        }
+        result["metrics"] = {
+            m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
+            for m in cell.end_to_end}
+    result["device"] = device
+    wall["total_s"] = time.perf_counter() - T_PROCESS
+    result["run"] = {
+        "wall_s": {k: round(v, 3) for k, v in wall.items()},
+        "cycles_in_window": attempted, "cycle_s": window["cycle_s"],
+        "compile_cache": cache_dir,
+        "primed": primed, "warm_cycles": warm,
+        "window_compiles": window_compiles,
+        "guard_moved_in_warm": loop.moved(guard0, guard_warm),
+        "guard_moved": guard_moved,
+        "gangs": numbers["gangs"],
+        "gang_roles": [{"name": r["name"], "count": int(r["count"])}
+                       for r in cell.traffic["gang"]["roles"]],
+        "placements_checked": numbers["placements_checked"]}
+    if trace:
+        result["run"]["trace_bytes"] = trace_bytes
+    result["compared"] = compared
+    return result
+
+
+def spans_on_trace_clock(records, raw):
+    """For the annotation at index i of the trace: if it is a cycle's
+    ``bench:run_once``, the program's flight-recorder spans of that cycle
+    as (name, start_ns, end_ns) on the profiler's clock.  Both clocks are
+    read at ``run_once``'s entry, which ties them."""
+    ann = raw["annotations"]
+
+    def spans_of(i: int):
+        if ann[i][0] != "bench:run_once":
+            return []
+        cycle = sum(1 for a in ann[:i] if a[0] == "bench:run_once")
+        if cycle >= len(records):
+            return []
+        rec = records[cycle]
+        origin = ann[i][1] + (rec.trace_t0 - rec.t_sched) * 1e9
+        return [(name, origin + start * 1e9, origin + (start + dur) * 1e9)
+                for name, _k, _sid, _p, start, dur in rec.spans
+                if name != "cycle"]
+    return spans_of
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__)
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    args = ap.parse_args(argv)
+    result = run_cell(args.workload, args.seed, args.seconds,
+                      bool(args.trace))
+    print(json.dumps({"run": result["run"]}), flush=True)
+    compared = result["compared"]
+    log("compared (value, limit): " + ", ".join(
+        f"{k}={v[0]:g} (<= {v[1]:g})" for k, v in compared.items()))
+    log(f"correct={result['correct']} attempted={result['attempted']} "
+        f"failed={result['failed']}")
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
