@@ -15,6 +15,7 @@ import numpy as np
 
 from ..api.podgroup_info import PodGroupInfo
 from ..api.pod_status import PodStatus
+from ..utils.tracing import TRACER
 from .utils import INFINITE, JobsOrderByQueues
 
 
@@ -34,9 +35,11 @@ class AllocateAction:
             if not jobs:
                 return
 
-        order = JobsOrderByQueues(
-            ssn, jobs,
-            ssn.config.queue_depth_per_action.get(self.name, INFINITE))
+        with TRACER.span("allocate:order", kind="allocate",
+                         jobs=len(jobs)):
+            order = JobsOrderByQueues(
+                ssn, jobs,
+                ssn.config.queue_depth_per_action.get(self.name, INFINITE))
         failed_signatures: set[str] = set()
 
         while not order.empty():
@@ -272,9 +275,9 @@ def _execute_bulk(ssn, jobs):
                 >= len(rows_req))
 
         if ssn.mesh is None:
-            # Guard verdict + resolved rung stamped on the cycle thread
-            # (the sharded kernel has no ladder, so mesh dispatches emit
-            # no allocate_fused span).
+            # Guard verdict stamped on the cycle thread, the rung by the
+            # wrapper (the sharded kernel has no ladder, so mesh
+            # dispatches emit no allocate_fused span).
             from ..ops.allocate_grouped import fused_dispatch_span
             with fused_dispatch_span(bulk=True):
                 result = dispatch()
@@ -285,24 +288,34 @@ def _execute_bulk(ssn, jobs):
         placements = np.asarray(result.placements)
         pipelined = np.asarray(result.pipelined)
         progressed = False
-        ti = 0
-        for j, tasks in enumerate(chunks):
-            n = len(tasks)
-            if success[j]:
-                stmt = ssn.statement()
-                pairs = [
-                    (task, ssn.snapshot.node_names[int(placements[ti + i])],
-                     bool(pipelined[ti + i]))
-                    for i, task in enumerate(tasks)]
-                # Rank-aware reorder (ops/rankplace.py): the registered
-                # fn re-verifies interchangeability before permuting, so
-                # heterogeneous bulk chunks pass through untouched.
-                stmt.apply_bulk(ssn.apply_rank_placement(tasks, pairs))
-                if ordered[j].should_pipeline():
-                    stmt.convert_all_allocated_to_pipelined(ordered[j].uid)
-                stmt.commit()
-                progressed = True
-            ti += n
+        ti = jobs_bound = ops = 0
+        # One span for the wave, not two a job: a fill wave is thousands
+        # of one-pod jobs, and a span costs what a tenth of one's
+        # statement does (PERF.md section 6, PR 25).
+        with TRACER.span("statement:bulk", kind="commit") as sp:
+            for j, tasks in enumerate(chunks):
+                n = len(tasks)
+                if success[j]:
+                    stmt = ssn.statement()
+                    pairs = [
+                        (task,
+                         ssn.snapshot.node_names[int(placements[ti + i])],
+                         bool(pipelined[ti + i]))
+                        for i, task in enumerate(tasks)]
+                    # Rank-aware reorder (ops/rankplace.py): the
+                    # registered fn re-verifies interchangeability before
+                    # permuting, so heterogeneous bulk chunks pass
+                    # through untouched.
+                    stmt.apply_bulk(ssn.apply_rank_placement(tasks, pairs))
+                    if ordered[j].should_pipeline():
+                        stmt.convert_all_allocated_to_pipelined(
+                            ordered[j].uid)
+                    stmt.commit()
+                    progressed = True
+                    jobs_bound += 1
+                    ops += n
+                ti += n
+            sp.set(jobs=jobs_bound, ops=ops)
         if not progressed:
             # Record failures for explainability; leave retries to the
             # scenario actions.
@@ -352,6 +365,21 @@ def attempt_to_allocate_job(ssn, job: PodGroupInfo,
             ssn.cache.record_event("Unschedulable", result.message)
         return False
 
+    # The span of one request: every placement attempt under it.  A job
+    # turned away above (no tasks, queue capacity, pre-predicates) never
+    # reached one and opens none.
+    with TRACER.span("allocate:job", kind="allocate", job=job.name,
+                     queue=job.queue_id, tasks=len(tasks)) as sp:
+        placed = _place_gated_job(ssn, job, tasks, pipeline_only, stmt,
+                                  commit)
+        sp.set(success=placed)
+    return placed
+
+
+def _place_gated_job(ssn, job: PodGroupInfo, tasks, pipeline_only: bool,
+                     stmt, commit: bool) -> bool:
+    """The placement attempts of a job that passed its gates: node
+    subsets in order under checkpoint/rollback, then the commit."""
     own_stmt = stmt is None
     if own_stmt:
         stmt = ssn.statement()
@@ -392,7 +420,7 @@ def attempt_to_allocate_job(ssn, job: PodGroupInfo,
             if job.should_pipeline():
                 stmt.convert_all_allocated_to_pipelined(job.uid)
             if own_stmt and commit:
-                stmt.commit()
+                _commit_job(stmt)
             return True
         stmt.rollback(cp_all)
         if own_stmt:
@@ -404,13 +432,27 @@ def attempt_to_allocate_job(ssn, job: PodGroupInfo,
         if _allocate_tasks_on_subset(ssn, stmt, job, tasks, node_subset,
                                      pipeline_only):
             if own_stmt and commit:
-                stmt.commit()
+                _commit_job(stmt)
             return True
         stmt.rollback(cp)
 
     if own_stmt:
         stmt.discard()
     return False
+
+
+def _commit_job(stmt) -> None:
+    """The commit of one job's own statement."""
+    with TRACER.span("statement:commit", kind="commit") as sp:
+        sp.set(binds=len(stmt.commit()))
+
+
+def _apply_task(stmt, task, node_name: str, pipelined: bool,
+                gpu_group: str = "") -> None:
+    """One task of a job the host path places task by task."""
+    with TRACER.span("statement:apply", kind="commit", ops=1):
+        place = stmt.pipeline if pipelined else stmt.allocate
+        place(task, node_name, gpu_group=gpu_group)
 
 
 def _allocate_tasks_on_subset(ssn, stmt, job, tasks, node_subset,
@@ -433,9 +475,11 @@ def _allocate_tasks_on_subset(ssn, stmt, job, tasks, node_subset,
         if not proposal.success:
             _record_chunk_failure(ssn, job, tasks)
             return False
-        stmt.apply_bulk(
-            (task, node_name, bool(pipelined or pipeline_only))
-            for task, node_name, pipelined in proposal.placements)
+        with TRACER.span("statement:apply", kind="commit",
+                         ops=len(proposal.placements)):
+            stmt.apply_bulk(
+                (task, node_name, bool(pipelined or pipeline_only))
+                for task, node_name, pipelined in proposal.placements)
         ok = True
     if not ok:
         return False
@@ -468,10 +512,7 @@ def _allocate_task_by_task(ssn, stmt, job, tasks, node_subset,
             placed = proposal.success
             if placed:
                 t, node_name, pipelined = proposal.placements[0]
-                if pipelined or pipeline_only:
-                    stmt.pipeline(t, node_name)
-                else:
-                    stmt.allocate(t, node_name)
+                _apply_task(stmt, t, node_name, pipelined or pipeline_only)
         if not placed:
             _record_chunk_failure(ssn, job, tasks, failed_task=task,
                                   placed_count=i)
@@ -496,12 +537,12 @@ def _allocate_fractional(ssn, stmt, task, node_subset,
             groups = node.find_gpu_groups_for_task(task,
                                                    allow_releasing=False)
             if groups is not None:
-                stmt.allocate(task, node.name, gpu_group=",".join(groups))
+                _apply_task(stmt, task, node.name, False, ",".join(groups))
                 return True
         if node.is_task_allocatable_on_releasing_or_idle(task):
             groups = node.find_gpu_groups_for_task(task, allow_releasing=True)
             if groups is not None:
-                stmt.pipeline(task, node.name, gpu_group=",".join(groups))
+                _apply_task(stmt, task, node.name, True, ",".join(groups))
                 return True
     return False
 
@@ -523,10 +564,10 @@ def _allocate_mig(ssn, stmt, task, node_subset,
             continue
         node = ssn.cluster.nodes[ssn.snapshot.node_names[int(node_idx)]]
         if not pipeline_only and node.is_task_allocatable(task):
-            stmt.allocate(task, node.name)
+            _apply_task(stmt, task, node.name, False)
             return True
         if node.is_task_allocatable_on_releasing_or_idle(task):
-            stmt.pipeline(task, node.name)
+            _apply_task(stmt, task, node.name, True)
             return True
     return False
 
@@ -549,10 +590,10 @@ def _allocate_with_claims(ssn, stmt, task, node_subset,
         if dra is not None and not dra.claims_schedulable(task, node.name):
             continue
         if not pipeline_only and node.is_task_allocatable(task):
-            stmt.allocate(task, node.name)
+            _apply_task(stmt, task, node.name, False)
             return True
         if node.is_task_allocatable_on_releasing_or_idle(task):
-            stmt.pipeline(task, node.name)
+            _apply_task(stmt, task, node.name, True)
             return True
     return False
 
@@ -575,6 +616,5 @@ def _record_chunk_failure(ssn, job, tasks, failed_task=None,
     # Explainability ledger: the rejection lands in the live cycle trace
     # the moment it happens (GET /explain?podgroup=<name>); the cycle
     # driver merges fit errors again at end_cycle, deduplicated.
-    from ..utils.tracing import TRACER
     TRACER.note_rejection(job.name, msg)
     ssn.cache.record_event("Unschedulable", msg)

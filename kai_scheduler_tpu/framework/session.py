@@ -14,6 +14,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Callable
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -23,6 +24,7 @@ from ..api.podgroup_info import PodGroupInfo
 from ..api.snapshot import SnapshotTensors, pack
 from ..ops.allocate import allocate_jobs_kernel
 from ..ops.scoring import BINPACK
+from ..utils.metrics import METRICS
 from ..utils.tracing import TRACER
 from .statement import Statement
 
@@ -102,6 +104,40 @@ def _allocation_shape_check(t_pad: int):
     return ok
 
 
+def _stage(*operands):
+    """The host operands of an exact-kernel call as device arrays, in the
+    order given (None stays None, a tuple of arrays comes back a tuple).
+
+    Runs inside the dispatch thunk, on the guard's worker: ``jnp.asarray``
+    converts a host array whose dtype the regime narrows (f64 to f32
+    without x64) on the host, then enqueues the upload.  The one place
+    host operands cross to the device, so the bytes are counted here:
+    ``device_upload_bytes`` (device side, every operand) and
+    ``host_convert_bytes`` (host side, the operands whose dtype changed)."""
+    with TRACER.span("seam:stage", kind="seam") as sp:
+        host = device = converted = count = 0
+
+        def put(a):
+            nonlocal host, device, converted, count
+            if isinstance(a, jax.Array):
+                return a
+            out = jnp.asarray(a)
+            a = np.asarray(a)
+            count += 1
+            host += a.nbytes
+            device += out.nbytes
+            if out.dtype != a.dtype:
+                converted += a.nbytes
+            return out
+
+        staged = jax.tree_util.tree_map(put, operands)
+        sp.set(bytes_host=host, bytes_device=device,
+               bytes_converted=converted, operands=count)
+    METRICS.inc("device_upload_bytes", device)
+    METRICS.inc("host_convert_bytes", converted)
+    return staged
+
+
 def _unpack_allocation(result, t: int):
     """(placed [t], piped [t], success [J]) from an AllocationResult.
 
@@ -110,15 +146,32 @@ def _unpack_allocation(result, t: int):
     serves all three — three separate fetches are three transfers and
     three waits.  The layout is sliced here and nowhere else.  The fallback
     exists for results whose arrays are already host-side (the grouped
-    kernels return numpy) or hand-built results in tests."""
-    if result.packed is not None:
-        flat = np.asarray(result.packed)
+    kernels return numpy) or hand-built results in tests.
+
+    ``seam:wait`` is the host waiting for the device (an upload still in
+    flight, then the kernel); ``seam:download`` the copy back and the
+    slicing."""
+    packed = result.packed is not None
+    arrays = ((result.packed,) if packed else
+              (result.placements[:t], result.pipelined[:t],
+               result.job_success))
+    with TRACER.span("seam:wait", kind="seam"):
+        # This IS the guarded sync: it runs in the `_fetch` thunk, under
+        # the guard's watchdog (the retry path calls it on a result the
+        # guard materialized already).
+        # kailint: disable=KAI002 — inside the guard's watchdog window
+        jax.block_until_ready(arrays)
+    with TRACER.span("seam:download", kind="seam") as sp:
+        host = [np.asarray(a) for a in arrays]
+        nbytes = sum(a.nbytes for a in host)
+        sp.set(bytes=nbytes)
+        METRICS.inc("device_download_bytes", nbytes)
+        if not packed:
+            return tuple(host)
+        flat = host[0]
         tp = result.placements.shape[0]
         return (flat[:t], flat[tp:tp + t].astype(bool),
                 flat[2 * tp:].astype(bool))
-    return (np.asarray(result.placements[:t]),
-            np.asarray(result.pipelined[:t]),
-            np.asarray(result.job_success))
 
 
 class Session:
@@ -345,7 +398,10 @@ class Session:
         through here so fault handling is uniform and the whole-cycle
         deadline is enforced at dispatch granularity.  Each dispatch is a
         flight-recorder span carrying the guard's verdict (device vs
-        CPU-fallback, breaker state) for post-mortem triage.
+        CPU-fallback, breaker state) for post-mortem triage.  The guard
+        runs the thunk on its worker thread and knows nothing of tracing:
+        the trace is handed across here, so spans the thunk opens
+        (``seam:*``) are children of this dispatch's span.
 
         ``blocking=False`` is the pipelined mode: the dispatch returns at
         ENQUEUE time without forcing device completion, so the caller can
@@ -356,11 +412,12 @@ class Session:
         from ..utils.deviceguard import device_guard
         guard = device_guard()
         with TRACER.span(f"dispatch:{label}", kind="kernel",
-                         kernel=label, pipelined=not blocking) as sp:
+                         kernel=label, pipelined=not blocking) as sp, \
+                TRACER.hand_off() as seam:
             fb0, to0 = guard.fallback_calls, guard.timeouts
             try:
                 return guard.call(
-                    thunk, label=label, validate=validate,
+                    seam.adopting(thunk), label=label, validate=validate,
                     record_event=getattr(self.cache, "record_event", None),
                     cycle_deadline_at=self.cycle_deadline_at,
                     materialize=blocking)
@@ -613,11 +670,20 @@ class Session:
         """Topology plugin hook: ordered list of candidate node-index sets
         (None = all nodes).  Mirrors ssn.SubsetNodesFn; ``podset`` scopes
         the constraint to one subgroup (allocateSubGroupSet recursion)."""
-        for fn in self.subset_nodes_fns:
-            sets = fn(job, tasks, podset)
-            if sets is not None:
-                return sets
-        return [None]
+        if not self.subset_nodes_fns:
+            return [None]
+        with TRACER.span("topology:subset_nodes", kind="topology") as sp:
+            for fn in self.subset_nodes_fns:
+                sets = fn(job, tasks, podset)
+                if sets is not None:
+                    break
+            else:
+                sets = [None]
+            first = sets[0] if sets else None
+            sp.set(sets=len(sets),
+                   nodes_in_first=(self.node_idle.shape[0] if first is None
+                                   else int(np.count_nonzero(first))))
+        return sets
 
     def apply_rank_placement(self, tasks, placements):
         """Rank-aware reorder of one gang chunk's placements: the first
@@ -634,6 +700,22 @@ class Session:
         return placements
 
     # -- device-kernel placement proposals ---------------------------------
+    def _add_extra_scores(self, tasks, extra: np.ndarray) -> None:
+        """Sum every registered extra-score fn's [T,N] term into
+        ``extra[:len(tasks)]``, each call (not the sum) under a span of
+        its own."""
+        t = len(tasks)
+        for fn in self.extra_score_fns:
+            # Named as the plugin's ``plugin:<name>`` span is; a bare
+            # function (tests) has no plugin to name.
+            plugin = getattr(getattr(fn, "__self__", None), "name", "fn")
+            with TRACER.span(f"extra_scores:{plugin}",
+                             kind="topology") as sp:
+                contrib = fn(tasks)
+                sp.set(bytes=getattr(contrib, "nbytes", 0))
+            if contrib is not None:
+                extra[:t] += contrib
+
     def propose_placements_multi(self, job_chunks,
                                  pipeline_only: bool = True):
         """Place SEVERAL jobs' chunks in ONE kernel call (the scenario
@@ -644,80 +726,84 @@ class Session:
         per-job gang atomicity (the kernel's per-job success gating), or
         None when any chunk needs per-job machinery the concatenated call
         cannot express (domain rows from anti/affinity plugins)."""
-        from ..utils.metrics import METRICS
         METRICS.inc("device_kernel_calls")
         snap = self.snapshot
         all_tasks = [t for _job, tasks in job_chunks for t in tasks]
         t = len(all_tasks)
         if t == 0:
             return {}
-        for fn in self.anti_domain_fns + self.affinity_domain_fns:
-            if fn(all_tasks) is not None:
-                return None
-
-        t_pad = _next_pow2(t)
-        task_req = np.zeros((t_pad, snap.task_req.shape[1]))
-        task_sel = np.full((t_pad, snap.task_selector.shape[1]), -1,
-                           np.int32)
-        task_tol = np.full((t_pad, snap.task_tolerations.shape[1]), -1,
-                           np.int32)
-        task_job = np.full(t_pad, len(job_chunks), np.int32)  # padding job
-        row = 0
-        for j, (_job, tasks) in enumerate(job_chunks):
-            for task in tasks:
-                req, sel, tol = self._task_row(task)
-                if req is None:
-                    return None
-                task_req[row], task_sel[row, :len(sel)] = req, sel
-                task_tol[row, :len(tol)] = tol
-                task_job[row] = j
-                row += 1
-        # Bucket the job axis too (KJT001): [J+1] exact would retrace
-        # the allocate kernel per distinct live gang count.  Padding
-        # jobs are gated out (allowed=False) and own no tasks, so the
-        # kernel never reads them; consumers index success[j] for real
-        # jobs only.
-        j_pad = _next_pow2(len(job_chunks) + 1)
-        job_allowed = np.ones(j_pad, bool)
-        job_allowed[len(job_chunks):] = False
-
         n_nodes = self.node_idle.shape[0]
-        extra = np.zeros((t_pad, n_nodes))
-        for fn in self.extra_score_fns:
-            contrib = fn(all_tasks)
-            if contrib is not None:
-                extra[:t] += contrib
-        mask = self.compute_hard_mask(all_tasks)
-        mask_pad = None
-        if mask is not None:
-            mask_pad = np.ones((t_pad, n_nodes), bool)
-            mask_pad[:t] = mask
+        t_pad = _next_pow2(t)
+        with TRACER.span("propose:operands", kind="propose", t=t,
+                         t_pad=t_pad, nodes=n_nodes, path="multi"):
+            for fn in self.anti_domain_fns + self.affinity_domain_fns:
+                if fn(all_tasks) is not None:
+                    return None
+
+            task_req = np.zeros((t_pad, snap.task_req.shape[1]))
+            task_sel = np.full((t_pad, snap.task_selector.shape[1]), -1,
+                               np.int32)
+            task_tol = np.full((t_pad, snap.task_tolerations.shape[1]), -1,
+                               np.int32)
+            task_job = np.full(t_pad, len(job_chunks), np.int32)  # padding
+            row = 0
+            for j, (_job, tasks) in enumerate(job_chunks):
+                for task in tasks:
+                    req, sel, tol = self._task_row(task)
+                    if req is None:
+                        return None
+                    task_req[row], task_sel[row, :len(sel)] = req, sel
+                    task_tol[row, :len(tol)] = tol
+                    task_job[row] = j
+                    row += 1
+            # Bucket the job axis too (KJT001): [J+1] exact would retrace
+            # the allocate kernel per distinct live gang count.  Padding
+            # jobs are gated out (allowed=False) and own no tasks, so the
+            # kernel never reads them; consumers index success[j] for real
+            # jobs only.
+            j_pad = _next_pow2(len(job_chunks) + 1)
+            job_allowed = np.ones(j_pad, bool)
+            job_allowed[len(job_chunks):] = False
+
+            extra = np.zeros((t_pad, n_nodes))
+            self._add_extra_scores(all_tasks, extra)
+            mask = self.compute_hard_mask(all_tasks)
+            mask_pad = None
+            if mask is not None:
+                mask_pad = np.ones((t_pad, n_nodes), bool)
+                mask_pad[:t] = mask
 
         node_arrays = self._device_arrays()
+
+        def thunk():
+            (d_req, d_job, d_sel, d_tol, d_allowed, d_extra,
+             d_mask) = _stage(task_req, task_job, task_sel, task_tol,
+                              job_allowed, extra, mask_pad)
+            with TRACER.span("seam:launch", kind="seam",
+                             kernel="allocate_jobs_multi"):
+                return allocate_jobs_kernel(
+                    *node_arrays, d_req, d_job, d_sel, d_tol, d_allowed,
+                    d_extra, task_node_mask=d_mask,
+                    gpu_strategy=self.gpu_strategy,
+                    cpu_strategy=self.cpu_strategy,
+                    allow_pipeline=True, pipeline_only=pipeline_only)
+
         placed, piped, success = self._dispatch_and_fetch(
-            lambda: allocate_jobs_kernel(
-                *node_arrays,
-                jnp.asarray(task_req), jnp.asarray(task_job),
-                jnp.asarray(task_sel), jnp.asarray(task_tol),
-                jnp.asarray(job_allowed), jnp.asarray(extra),
-                task_node_mask=(None if mask_pad is None
-                                else jnp.asarray(mask_pad)),
-                gpu_strategy=self.gpu_strategy,
-                cpu_strategy=self.cpu_strategy,
-                allow_pipeline=True, pipeline_only=pipeline_only),
-            label="allocate_jobs_multi",
+            thunk, label="allocate_jobs_multi",
             validate=_allocation_shape_check(t_pad), t=t)
-        out = {}
-        row = 0
-        for j, (job, tasks) in enumerate(job_chunks):
-            rows = range(row, row + len(tasks))
-            row += len(tasks)
-            if not bool(success[j]) or any(placed[r] < 0 for r in rows):
-                out[job.uid] = Proposal(False, [])
-                continue
-            out[job.uid] = Proposal(True, [
-                (task, snap.node_names[int(placed[r])], bool(piped[r]))
-                for task, r in zip(tasks, rows)])
+        with TRACER.span("propose:unpack", kind="propose", t=t):
+            out = {}
+            row = 0
+            for j, (job, tasks) in enumerate(job_chunks):
+                rows = range(row, row + len(tasks))
+                row += len(tasks)
+                if not bool(success[j]) or any(placed[r] < 0
+                                               for r in rows):
+                    out[job.uid] = Proposal(False, [])
+                    continue
+                out[job.uid] = Proposal(True, [
+                    (task, snap.node_names[int(placed[r])], bool(piped[r]))
+                    for task, r in zip(tasks, rows)])
         return out
 
     def propose_placements(self, tasks: list[PodInfo],
@@ -727,94 +813,131 @@ class Session:
                            ) -> Proposal:
         """Run the gang-allocation kernel for one job's task chunk against
         the current (statement-mutated) node state."""
-        from ..utils.metrics import METRICS
         METRICS.inc("device_kernel_calls")
         snap = self.snapshot
         t = len(tasks)
         t_pad = _next_pow2(max(t, 1))
-
-        task_req = np.zeros((t_pad, snap.task_req.shape[1]))
-        task_sel = np.full((t_pad, snap.task_selector.shape[1]), -1, np.int32)
-        task_tol = np.full((t_pad, snap.task_tolerations.shape[1]), -1,
-                           np.int32)
-        for i, task in enumerate(tasks):
-            req, sel, tol = self._task_row(task)
-            if req is None:
-                return Proposal(False, [])
-            task_req[i], task_sel[i, :len(sel)] = req, sel
-            task_tol[i, :len(tol)] = tol
-        task_job = np.zeros(t_pad, np.int32)
-        task_job[t:] = 1  # padding rows belong to a gated-out dummy job
-        job_allowed = np.array([True, False])
-
         n_nodes = self.node_idle.shape[0]
-        extra = np.zeros((t_pad, n_nodes))
-        for fn in self.extra_score_fns:
-            contrib = fn(tasks)
-            if contrib is not None:
-                extra[:t] += contrib
 
-        # Hard per-task node masks (inter-pod affinity terms, upstream
-        # predicate verdicts): False = infeasible, enforced in-kernel.
-        mask = self.compute_hard_mask(tasks)
-        if node_subset is not None:
-            # The topology node subset is a hard mask (matching the
-            # fractional/MIG handlers, which skip out-of-subset nodes
-            # unconditionally): an out-of-subset node is infeasible, not a
-            # soft last resort.  Folded in here once so the homogeneous
-            # fast path and the per-task path share identical semantics.
-            subset = np.asarray(node_subset, bool)
-            # Read-only broadcast view: downstream only reads mask
-            # (mask_pad[:t] = mask copies; row_mask takes a row view).
-            mask = (np.broadcast_to(subset, (t, n_nodes))
-                    if mask is None else mask & subset[None, :])
-        # Self-anti-affinity domain rows (spread-one-per-domain gangs).
-        anti_dom = None
-        for fn in self.anti_domain_fns:
-            contrib = fn(tasks)
-            if contrib is not None:
-                anti_dom = contrib
-                break
-        # In-gang required-affinity domain rows (co-locate gangs).
-        aff_dom = None
-        for fn in self.affinity_domain_fns:
-            contrib = fn(tasks)
-            if contrib is not None:
-                aff_dom = contrib
-                break
+        with TRACER.span("propose:operands", kind="propose", t=t,
+                         t_pad=t_pad, nodes=n_nodes) as operands_span:
+            task_req = np.zeros((t_pad, snap.task_req.shape[1]))
+            task_sel = np.full((t_pad, snap.task_selector.shape[1]), -1,
+                               np.int32)
+            task_tol = np.full((t_pad, snap.task_tolerations.shape[1]), -1,
+                               np.int32)
+            for i, task in enumerate(tasks):
+                req, sel, tol = self._task_row(task)
+                if req is None:
+                    return Proposal(False, [])
+                task_req[i], task_sel[i, :len(sel)] = req, sel
+                task_tol[i, :len(tol)] = tol
+            task_job = np.zeros(t_pad, np.int32)
+            task_job[t:] = 1  # padding rows: a gated-out dummy job
+            job_allowed = np.array([True, False])
 
-        # Homogeneous chunks take the grouped fill-plan kernel: one scan
-        # step instead of one per task.  Extra score terms and hard masks
-        # ride along when per-job uniform (one [N] row for the whole
-        # chunk) — extras must be tier constants (multiples of 10) for
-        # the fill plan's ordering invariance (allocate_groups_kernel);
-        # a node subset becomes a hard mask row.
-        homogeneous = (
-            t > 1 and anti_dom is None and aff_dom is None
-            and self.gpu_strategy == BINPACK
-            and self.cpu_strategy == BINPACK
-            and (task_req[1:t] == task_req[0]).all()
-            and (task_sel[1:t] == task_sel[0]).all()
-            and (task_tol[1:t] == task_tol[0]).all())
-        row_extra = row_mask = None
-        if homogeneous and extra.any():
-            row = extra[0]
-            if (extra[1:t] == row).all() and bool(
-                    np.all(np.remainder(row, 10.0) == 0.0)):
-                row_extra = row[None, :]
-            else:
-                homogeneous = False
-        if homogeneous and mask is not None:
-            if (mask[1:t] == mask[0]).all():
-                row_mask = mask[0][None, :]
-            else:
-                homogeneous = False
+            extra = np.zeros((t_pad, n_nodes))
+            self._add_extra_scores(tasks, extra)
+
+            # Hard per-task node masks (inter-pod affinity terms, upstream
+            # predicate verdicts): False = infeasible, enforced in-kernel.
+            mask = self.compute_hard_mask(tasks)
+            if node_subset is not None:
+                # The topology node subset is a hard mask (matching the
+                # fractional/MIG handlers, which skip out-of-subset nodes
+                # unconditionally): an out-of-subset node is infeasible,
+                # not a soft last resort.  Folded in here once so the
+                # homogeneous fast path and the per-task path share
+                # identical semantics.
+                subset = np.asarray(node_subset, bool)
+                # Read-only broadcast view: downstream only reads mask
+                # (mask_pad[:t] = mask copies; row_mask takes a row view).
+                mask = (np.broadcast_to(subset, (t, n_nodes))
+                        if mask is None else mask & subset[None, :])
+            # Self-anti-affinity domain rows (spread-one-per-domain gangs).
+            anti_dom = None
+            for fn in self.anti_domain_fns:
+                contrib = fn(tasks)
+                if contrib is not None:
+                    anti_dom = contrib
+                    break
+            # In-gang required-affinity domain rows (co-locate gangs).
+            aff_dom = None
+            for fn in self.affinity_domain_fns:
+                contrib = fn(tasks)
+                if contrib is not None:
+                    aff_dom = contrib
+                    break
+
+            # Homogeneous chunks take the grouped fill-plan kernel: one
+            # scan step instead of one per task.  Extra score terms and
+            # hard masks ride along when per-job uniform (one [N] row for
+            # the whole chunk) — extras must be tier constants (multiples
+            # of 10) for the fill plan's ordering invariance
+            # (allocate_groups_kernel); a node subset becomes a hard mask
+            # row.
+            homogeneous = (
+                t > 1 and anti_dom is None and aff_dom is None
+                and self.gpu_strategy == BINPACK
+                and self.cpu_strategy == BINPACK
+                and (task_req[1:t] == task_req[0]).all()
+                and (task_sel[1:t] == task_sel[0]).all()
+                and (task_tol[1:t] == task_tol[0]).all())
+            row_extra = row_mask = None
+            if homogeneous and extra.any():
+                row = extra[0]
+                if (extra[1:t] == row).all() and bool(
+                        np.all(np.remainder(row, 10.0) == 0.0)):
+                    row_extra = row[None, :]
+                else:
+                    homogeneous = False
+            if homogeneous and mask is not None:
+                if (mask[1:t] == mask[0]).all():
+                    row_mask = mask[0][None, :]
+                else:
+                    homogeneous = False
+            # Multi-chip exact kernel (parallel/sharded.py): node axis
+            # sharded over the mesh, bit-identical tie-breaks.  Domain
+            # rows, extra score terms, and pipeline-only proposals stay
+            # on the single-chip kernel (unsupported under shard_map).
+            sharded = (not homogeneous and self.mesh is not None
+                       and anti_dom is None and aff_dom is None
+                       and not pipeline_only and not np.any(extra))
+            operands_span.set(path="grouped" if homogeneous else
+                              "sharded" if sharded else "exact")
+            if not homogeneous:
+                mask_pad = dom_pad = aff_pad = None
+                if mask is not None:
+                    mask_pad = np.ones((t_pad, n_nodes), bool)
+                    mask_pad[:t] = mask
+                if anti_dom is not None:
+                    doms, marks, avoids = anti_dom
+                    d = np.full((t_pad, n_nodes), -1, np.int32)
+                    d[:t] = doms
+                    m = np.zeros(t_pad, bool)
+                    m[:t] = marks
+                    a = np.zeros(t_pad, bool)
+                    a[:t] = avoids
+                    dom_pad = (d, m, a)
+                if aff_dom is not None:
+                    doms, marks, avoids, static_ok, boot = aff_dom
+                    d = np.full((t_pad, n_nodes), -1, np.int32)
+                    d[:t] = doms
+                    m = np.zeros(t_pad, bool)
+                    m[:t] = marks
+                    a = np.zeros(t_pad, bool)
+                    a[:t] = avoids
+                    st = np.ones((t_pad, n_nodes), bool)
+                    st[:t] = static_ok
+                    b = np.zeros(t_pad, bool)
+                    b[:t] = boot
+                    aff_pad = (d, m, a, st, b)
+
+        node_arrays = self._device_arrays()
         if homogeneous:
             from ..ops import allocate_grouped as ag
-            node_arrays = self._device_arrays()
-            # The span helper stamps the guard verdict + the wrapper's
-            # resolved rung on the cycle thread (the wrapper may run on
-            # the guard's worker thread, where cycle spans no-op).
+            # The span helper stamps the guard verdict on the cycle
+            # thread; the wrapper stamps the rung it resolved.
             with ag.fused_dispatch_span():
                 result = self.dispatch_kernel(
                     lambda: ag.allocate_grouped(
@@ -831,98 +954,70 @@ class Session:
                     validate=_allocation_shape_check(t))
             if not bool(result.job_success[0]):
                 return Proposal(False, [])
+            with TRACER.span("propose:unpack", kind="propose", t=t):
+                placements = []
+                placed = np.asarray(result.placements)
+                piped = np.asarray(result.pipelined)
+                for i, task in enumerate(tasks):
+                    node_idx = int(placed[i])
+                    if node_idx < 0:
+                        return Proposal(False, [])
+                    placements.append((task, snap.node_names[node_idx],
+                                       bool(piped[i])))
+                # The homogeneous check above proved the chunk's tasks
+                # interchangeable — the one precondition rank reorder
+                # needs.
+                return Proposal(True, self.apply_rank_placement(
+                    tasks, placements))
+
+        if sharded:
+            from ..parallel.sharded import sharded_allocate_jobs
+
+            def thunk():
+                d_req, d_job, d_sel, d_tol, d_allowed, d_mask = _stage(
+                    task_req, task_job, task_sel, task_tol, job_allowed,
+                    mask_pad)
+                with TRACER.span("seam:launch", kind="seam",
+                                 kernel="allocate_jobs_sharded"):
+                    return sharded_allocate_jobs(
+                        self.mesh, *node_arrays, d_req, d_job, d_sel,
+                        d_tol, d_allowed, task_node_mask=d_mask,
+                        gpu_strategy=self.gpu_strategy,
+                        cpu_strategy=self.cpu_strategy,
+                        allow_pipeline=allow_pipeline)
+            label = "allocate_jobs_sharded"
+        else:
+            def thunk():
+                (d_req, d_job, d_sel, d_tol, d_allowed, d_extra, d_mask,
+                 d_dom, d_aff) = _stage(
+                    task_req, task_job, task_sel, task_tol, job_allowed,
+                    extra, mask_pad, dom_pad, aff_pad)
+                with TRACER.span("seam:launch", kind="seam",
+                                 kernel="allocate_jobs"):
+                    return allocate_jobs_kernel(
+                        *node_arrays, d_req, d_job, d_sel, d_tol,
+                        d_allowed, d_extra, task_node_mask=d_mask,
+                        task_anti_domain=d_dom, task_aff_domain=d_aff,
+                        gpu_strategy=self.gpu_strategy,
+                        cpu_strategy=self.cpu_strategy,
+                        allow_pipeline=allow_pipeline,
+                        pipeline_only=pipeline_only)
+            label = "allocate_jobs"
+        placed, piped, success = self._dispatch_and_fetch(
+            thunk, label=label, validate=_allocation_shape_check(t_pad),
+            t=t)
+        if not bool(success[0]):
+            return Proposal(False, [])
+        with TRACER.span("propose:unpack", kind="propose", t=t):
             placements = []
-            placed = np.asarray(result.placements)
-            piped = np.asarray(result.pipelined)
             for i, task in enumerate(tasks):
                 node_idx = int(placed[i])
                 if node_idx < 0:
                     return Proposal(False, [])
+                if node_subset is not None and not node_subset[node_idx]:
+                    return Proposal(False, [])
                 placements.append((task, snap.node_names[node_idx],
                                    bool(piped[i])))
-            # The homogeneous check above proved the chunk's tasks
-            # interchangeable — the one precondition rank reorder needs.
-            return Proposal(True,
-                            self.apply_rank_placement(tasks, placements))
-        mask_pad = None
-        if mask is not None:
-            mask_pad = np.ones((t_pad, n_nodes), bool)
-            mask_pad[:t] = mask
-        dom_pad = None
-        if anti_dom is not None:
-            doms, marks, avoids = anti_dom
-            d = np.full((t_pad, n_nodes), -1, np.int32)
-            d[:t] = doms
-            m = np.zeros(t_pad, bool)
-            m[:t] = marks
-            a = np.zeros(t_pad, bool)
-            a[:t] = avoids
-            dom_pad = (jnp.asarray(d), jnp.asarray(m), jnp.asarray(a))
-        aff_pad = None
-        if aff_dom is not None:
-            doms, marks, avoids, static_ok, boot = aff_dom
-            d = np.full((t_pad, n_nodes), -1, np.int32)
-            d[:t] = doms
-            m = np.zeros(t_pad, bool)
-            m[:t] = marks
-            a = np.zeros(t_pad, bool)
-            a[:t] = avoids
-            st = np.ones((t_pad, n_nodes), bool)
-            st[:t] = static_ok
-            b = np.zeros(t_pad, bool)
-            b[:t] = boot
-            aff_pad = (jnp.asarray(d), jnp.asarray(m), jnp.asarray(a),
-                       jnp.asarray(st), jnp.asarray(b))
-        if (self.mesh is not None and dom_pad is None and aff_pad is None
-                and not pipeline_only and not np.any(extra)):
-            # Multi-chip exact kernel (parallel/sharded.py): node axis
-            # sharded over the mesh, bit-identical tie-breaks.  Domain
-            # rows, extra score terms, and pipeline-only proposals stay
-            # on the single-chip kernel (unsupported under shard_map).
-            from ..parallel.sharded import sharded_allocate_jobs
-            node_arrays = self._device_arrays()
-            placed, piped, success = self._dispatch_and_fetch(
-                lambda: sharded_allocate_jobs(
-                    self.mesh, *node_arrays,
-                    jnp.asarray(task_req), jnp.asarray(task_job),
-                    jnp.asarray(task_sel), jnp.asarray(task_tol),
-                    jnp.asarray(job_allowed),
-                    task_node_mask=(None if mask_pad is None
-                                    else jnp.asarray(mask_pad)),
-                    gpu_strategy=self.gpu_strategy,
-                    cpu_strategy=self.cpu_strategy,
-                    allow_pipeline=allow_pipeline),
-                label="allocate_jobs_sharded",
-                validate=_allocation_shape_check(t_pad), t=t)
-        else:
-            node_arrays = self._device_arrays()
-            placed, piped, success = self._dispatch_and_fetch(
-                lambda: allocate_jobs_kernel(
-                    *node_arrays,
-                    jnp.asarray(task_req), jnp.asarray(task_job),
-                    jnp.asarray(task_sel), jnp.asarray(task_tol),
-                    jnp.asarray(job_allowed), jnp.asarray(extra),
-                    task_node_mask=(None if mask_pad is None
-                                    else jnp.asarray(mask_pad)),
-                    task_anti_domain=dom_pad,
-                    task_aff_domain=aff_pad,
-                    gpu_strategy=self.gpu_strategy,
-                    cpu_strategy=self.cpu_strategy,
-                    allow_pipeline=allow_pipeline,
-                    pipeline_only=pipeline_only),
-                label="allocate_jobs",
-                validate=_allocation_shape_check(t_pad), t=t)
-        if not bool(success[0]):
-            return Proposal(False, [])
-        placements = []
-        for i, task in enumerate(tasks):
-            node_idx = int(placed[i])
-            if node_idx < 0:
-                return Proposal(False, [])
-            if node_subset is not None and not node_subset[node_idx]:
-                return Proposal(False, [])
-            placements.append((task, snap.node_names[node_idx],
-                               bool(piped[i])))
         return Proposal(True, placements)
 
     def _task_row(self, task: PodInfo):

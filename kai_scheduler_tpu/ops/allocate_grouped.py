@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..utils.tracing import TRACER
 from .allocate import NEG, AllocationResult
 from .predicates import feasibility_caps_row, feasibility_row
 from .scoring import AVAILABILITY, BINPACK, score_row, score_row_selected
@@ -73,30 +74,25 @@ _FUSED_ENV = "KAI_FUSED_ALLOC"
 # (16 for u32) against per-level reduction fan-out on both CPU and TPU.
 SELECT_DIGIT_BITS = 2
 
-# Stats of the most recent wrapper dispatch (mode/groups/nodes/
-# releasing_empty): the traced call sites read these to stamp the
-# ``allocate_fused`` span on the cycle thread (the wrapper itself may run
-# on the device guard's worker thread, where cycle spans no-op).
-LAST_DISPATCH: dict = {}
-
 
 @contextlib.contextmanager
 def fused_dispatch_span(**attrs):
     """Cycle-thread ``allocate_fused`` span around a guarded grouped
     dispatch: yields, then stamps the guard verdict (fallback/timeout/
-    breaker — the contract every kernel-kind span carries) plus the
-    wrapper's resolved-rung stats from ``LAST_DISPATCH``.  One
-    definition for the session fast path and the bulk action, so the
-    span contract cannot drift one-sided."""
+    breaker — the contract every kernel-kind span carries).  The rung
+    (mode/groups/nodes/releasing_empty) is stamped onto it by
+    ``allocate_grouped`` itself, where it is resolved: the cycle's trace
+    follows the dispatch onto the guard's worker thread.  One definition
+    for the session fast path and the bulk action, so the span contract
+    cannot drift one-sided."""
     from ..utils.deviceguard import device_guard
-    from ..utils.tracing import TRACER
     guard = device_guard()
     fb0, to0 = guard.fallback_calls, guard.timeouts
     with TRACER.span("allocate_fused", kind="kernel", **attrs) as sp:
         yield
         sp.set(fallback=guard.fallback_calls > fb0,
                timed_out=guard.timeouts > to0,
-               breaker=guard.breaker.state, **LAST_DISPATCH)
+               breaker=guard.breaker.state)
 
 
 def group_tasks(task_req: np.ndarray, task_job: np.ndarray,
@@ -909,13 +905,8 @@ def allocate_grouped(node_arrays, task_req, task_job, task_selector,
     from ..utils.metrics import METRICS
     if mode != "legacy":
         METRICS.inc("allocate_fused_taken_total", mode=mode)
-    # The guard may run this wrapper on its watchdog worker thread, where
-    # cycle spans deliberately no-op — so the resolved rung is published
-    # here and the CALL SITES (session fast path, bulk action) emit the
-    # ``allocate_fused`` span on the cycle thread from these stats.
-    LAST_DISPATCH.update(mode=mode, groups=n_real_groups,
-                         nodes=n_nodes_padded,
-                         releasing_empty=releasing_empty)
+    TRACER.stamp("allocate_fused", mode=mode, groups=n_real_groups,
+                 nodes=n_nodes_padded, releasing_empty=releasing_empty)
     packed, idle, rel = _allocate_groups_packed(
         *node_arrays, jnp.asarray(g_req), jnp.asarray(g_sel),
         jnp.asarray(g_tol), jnp.asarray(g_count), jnp.asarray(g_job),

@@ -18,10 +18,15 @@ class TopologyPlugin(Plugin):
         from ..ops.topology import TopologySession
         self._topo = TopologySession(ssn)
         ssn.subset_nodes_fns.append(self._topo.subset_nodes)
-        ssn.extra_score_fns.append(self._topo.extra_scores)
+        ssn.extra_score_fns.append(self.extra_scores)
         # Rank-aware gang placement (ops/rankplace.py): reorder an
         # interchangeable chunk's placements so consecutive MPI ranks
         # land topology-adjacent.  A pure post-fill permutation — the
         # fill plan's node multiset (and thus every capacity/feasibility
         # verdict) is untouched.
         ssn.rank_assign_fns.append(self._topo.assign_ranks)
+
+    def extra_scores(self, tasks):
+        """The preferred-level boosts, registered as the plugin's own
+        method so the session's span carries the plugin's name."""
+        return self._topo.extra_scores(tasks)

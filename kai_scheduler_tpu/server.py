@@ -20,15 +20,15 @@ endpoints:
                       — load in Perfetto (docs/OBSERVABILITY.md)
   GET /explain        latest unschedulability reasons for a PodGroup
                       (?podgroup=<name>; without it, the known names)
-  GET /debug/pprof    the SamplingProfiler's folded stacks (flamegraph/
-                      speedscope-ready; requires --enable-profiler)
   GET /debug/latency  pod-lifecycle timelines (submit -> watch-observed ->
                       grouped -> snapshotted -> scheduled -> bind-requested
                       -> bound/evicted) joined to the /explain ledger
                       (?queue=|podgroup=|limit=; docs/OBSERVABILITY.md)
-  GET /debug/flame    the continuous fleet profiler's folded stacks
-                      (utils/stackprof.py; arm with --stackprof or
-                      KAI_STACKPROF=1)
+  GET /debug/flame    the continuous fleet profiler's folded stacks,
+                      flamegraph/speedscope-ready (utils/stackprof.py;
+                      arm with --stackprof, --enable-profiler or
+                      KAI_STACKPROF=1; ?summary=1 for the leaf table;
+                      /debug/pprof and /debug/profile are the same page)
 
 Leader election comes in two flavors:
 
@@ -207,24 +207,6 @@ def _make_handler(server_state):
                 body = json.dumps(
                     server_state.get("job_order", {})).encode()
                 ctype = "application/json"
-            elif path == "/debug/profile":
-                prof = server_state.get("profiler")
-                if prof is None:
-                    self.send_error(
-                        404, "profiler disabled (--enable-profiler)")
-                    return
-                if q.get("summary") in ("1", "true"):
-                    body = json.dumps(prof.summary()).encode()
-                    ctype = "application/json"
-                else:
-                    # pprof collapsed-stack format (flamegraph-ready).
-                    try:
-                        top = int(q.get("top", 5000))
-                    except ValueError:
-                        self.send_error(400, "top must be an integer")
-                        return
-                    body = prof.folded(top=top).encode()
-                    ctype = "text/plain"
             elif path == "/debug/cycles":
                 # Flight recorder: last-N cycle summaries, newest first,
                 # plus the device arena's pack/residency stats (delta
@@ -307,35 +289,32 @@ def _make_handler(server_state):
                         payload["unschedulable_message"] = mark
                 body = json.dumps(payload).encode()
                 ctype = "application/json"
-            elif path == "/debug/flame":
+            elif path in ("/debug/flame", "/debug/pprof",
+                          "/debug/profile"):
                 # Continuous fleet profiler (whole-cycle host stacks, not
                 # just run_once): folded format for flamegraph.pl /
-                # speedscope.
+                # speedscope, or the leaf table with ?summary=1.
                 if not STACKPROF.running and not STACKPROF.total_samples:
                     self.send_error(
-                        404, "stackprof not running (arm with --stackprof "
-                             "or KAI_STACKPROF=1)")
+                        404, "stackprof not running (arm with --stackprof, "
+                             "--enable-profiler or KAI_STACKPROF=1)")
                     return
-                try:
-                    # Clamped: top=0/-1 would silently drop the heaviest
-                    # stacks via slice semantics.
-                    top = max(1, min(1 << 20, int(q.get("top", 5000))))
-                except ValueError:
-                    self.send_error(400, "top must be an integer")
-                    return
-                body = STACKPROF.folded(top=top).encode()
-                ctype = "text/plain"
-            elif path == "/debug/pprof":
-                # The SamplingProfiler's collapsed stacks as a first-class
-                # endpoint (was reachable only via /debug/profile's query
-                # dance): pipe into flamegraph.pl / speedscope directly.
-                prof = server_state.get("profiler")
-                if prof is None:
-                    self.send_error(
-                        404, "profiler disabled (--enable-profiler)")
-                    return
-                body = prof.folded().encode()
-                ctype = "text/plain"
+                if q.get("summary") in ("1", "true"):
+                    body = json.dumps({
+                        **STACKPROF.status(),
+                        "total_samples": STACKPROF.total_samples,
+                        "top_leaves": STACKPROF.top_frames(30)}).encode()
+                    ctype = "application/json"
+                else:
+                    try:
+                        # Clamped: top=0/-1 would silently drop the
+                        # heaviest stacks via slice semantics.
+                        top = max(1, min(1 << 20, int(q.get("top", 5000))))
+                    except ValueError:
+                        self.send_error(400, "top must be an integer")
+                        return
+                    body = STACKPROF.folded(top=top).encode()
+                    ctype = "text/plain"
             else:
                 self.send_error(404)
                 return
@@ -397,10 +376,9 @@ def run_app(argv=None) -> None:
     ap.add_argument("--cycles", type=int, default=0,
                     help="stop after N cycles (0 = forever)")
     ap.add_argument("--enable-profiler", action="store_true",
-                    help="continuous sampling profiler (pprof/Pyroscope "
+                    help="same as --stackprof (the pprof/Pyroscope "
                          "analog, cmd/scheduler/profiling/): collapsed "
-                         "stacks at GET /debug/profile, summary at "
-                         "/debug/profile?summary=1")
+                         "stacks at GET /debug/flame")
     ap.add_argument("--profile-dir", default=None,
                     help="write a JAX profiler trace of the run here "
                          "(the pprof/Pyroscope analog)")
@@ -503,10 +481,7 @@ def run_app(argv=None) -> None:
     # Restart crash-consistency pass BEFORE the first cycle: replay the
     # bind journal, GC orphaned reservations, reap dead BindRequests.
     state["reconcile_summary"] = system.startup_reconcile()
-    if args.enable_profiler:
-        from .utils.profiling import SamplingProfiler
-        state["profiler"] = SamplingProfiler().start()
-    if args.stackprof:
+    if args.stackprof or args.enable_profiler:
         STACKPROF.start()
     else:
         ensure_started_from_env()

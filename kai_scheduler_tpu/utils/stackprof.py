@@ -10,7 +10,8 @@ rate (default ~67Hz — deliberately off 100Hz so it never phase-locks
 with 10ms-period work) and aggregates **collapsed stacks** (pprof folded
 format, flamegraph.pl / speedscope ready).
 
-Differences from the per-run ``utils/profiling.SamplingProfiler``:
+The daemon's one sampling profiler (``--stackprof``,
+``--enable-profiler``, ``KAI_STACKPROF=1``):
 
 - frames are ``file.py:function`` WITHOUT line numbers — line-level
   frames explode one logical stack into dozens of series and defeat

@@ -49,13 +49,35 @@ span IS the wire time, visible in Perfetto instead of lost.  Threads
 that carry no live cycle (the commit executor) arm an **ambient wire
 context** (``set_wire_context``) so their requests still stamp the
 owning cycle's trace and their client spans attach post-hoc.
+
+Across the device guard's worker thread (PR 25): the guard runs a
+dispatch's thunk on a ``deviceguard-worker`` thread while the cycle
+thread is parked in the guard's wait.  ``Tracer.hand_off`` captures the
+live trace and its open-span stack on the cycle thread; the thunk it
+wraps adopts them on whichever thread runs it, so spans opened there are
+children of the open ``dispatch:<label>`` span.  One writer at a time: a
+later adopter (a retry, the CPU fallback) supersedes an earlier one, and
+a worker the guard abandoned finds its hand-off revoked once the dispatch
+span closed.  An adopter records under the ring lock, which superseding
+and revoking take too, so a worker abandoned in the middle of a close
+writes either before the cycle thread moves on or never; what it opens
+or closes afterwards is counted in ``trace_spans_revoked_total`` and on
+no trace (the trace it meant may have ended by then).
+
+One clock: while a cycle is live every span is also a
+``jax.profiler.TraceAnnotation`` named ``kai:<span name>``, so a profiler
+session started by anyone holds the scheduler's phases on the device
+trace's own clock.  A process that never imported jax (the apiserver
+child) records spans without it.
 """
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -77,7 +99,8 @@ class Span:
     serialize directly into Chrome trace-event ``ts``/``dur`` pairs."""
 
     __slots__ = ("trace_id", "span_id", "parent_id", "name", "kind",
-                 "start_s", "duration_s", "attrs", "status", "error")
+                 "start_s", "duration_s", "attrs", "status", "error",
+                 "annotation")
 
     def __init__(self, trace_id: str, span_id: str, parent_id: str | None,
                  name: str, kind: str, start_s: float):
@@ -91,6 +114,7 @@ class Span:
         self.attrs: dict = {}
         self.status = "ok"
         self.error = ""
+        self.annotation = None  # the open kai:<name> TraceAnnotation
 
     def set(self, **attrs) -> None:
         """Attach attributes (kernel label, breaker state, ...)."""
@@ -222,6 +246,60 @@ class _ClientSpanCtx:
 NULL_CLIENT_SPAN = _ClientSpanCtx(None)
 
 
+class _HandOff:
+    """The live trace and its open-span stack, captured on the cycle
+    thread for a thunk that another thread will run (``Tracer.hand_off``);
+    outside a cycle ``trace`` is None and there is nothing to carry.
+
+    ``writer`` is the ident of the one thread that may record at the
+    moment: each adoption takes it over, and leaving the ``with`` block
+    (the dispatch span is about to close) clears it for good.  Both
+    happen under the tracer's ring lock, as an adopter's record does."""
+
+    __slots__ = ("_tracer", "trace", "stack", "writer")
+
+    def __init__(self, tracer: "Tracer", trace: "CycleTrace | None",
+                 stack: list):
+        self._tracer = tracer
+        self.trace = trace
+        self.stack = stack
+        self.writer: int | None = None
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        with self._tracer._lock:
+            self.writer = None
+        return False
+
+    def adopting(self, thunk):
+        """``thunk``, recording into this trace on the thread that calls
+        it."""
+        if self.trace is None:
+            return thunk
+
+        def adopted():
+            with self._tracer._adopt(self):
+                return thunk()
+        return adopted
+
+
+# Span kinds that get no ``cycle_span_<kind>_latency_ms`` histogram: each
+# mixes unlike intervals under one kind (``seam`` is a 5 s staging beside
+# a 1 ms launch), so a quantile over it says nothing.  ``span_names`` in
+# /debug/cycles carries their totals by name.
+_NO_HISTOGRAM_KINDS = frozenset({"allocate", "topology", "propose", "seam"})
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` where this process has loaded
+    jax, else None: without jax no profiler session exists to write to,
+    and the tracer must not be what imports it (the apiserver child)."""
+    jax = sys.modules.get("jax")
+    return getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+
+
 class CycleTrace:
     """One complete scheduling cycle: the root span, its children, the
     abort/degraded verdict, and the explainability ledger."""
@@ -232,6 +310,11 @@ class CycleTrace:
     # counted (dropped_rejections), never silent.
     MAX_EXPLAIN_GROUPS = 256
     MAX_REASONS_PER_GROUP = 8
+    # Per-name totals bound: names are a small fixed vocabulary except
+    # the per-object kubeapi spans (``bind:<pod>``), which past this
+    # many distinct names fold into one ``OTHER_NAMES`` row.
+    MAX_SPAN_NAMES = 128
+    OTHER_NAMES = "(other)"
 
     def __init__(self, trace_id: str, cycle: int, max_spans: int):
         self.trace_id = trace_id
@@ -241,6 +324,10 @@ class CycleTrace:
         self.spans: list[Span] = []   # completed spans, completion order
         self.max_spans = max_spans
         self.dropped_spans = 0
+        # name -> [count, seconds] over every span that closed, kept or
+        # dropped by the cap: sums by name stay right in a cycle with a
+        # thousand jobs.
+        self.name_totals: dict[str, list] = {}
         self.aborted: str | None = None
         self.degraded = False
         self.duration_ms = 0.0
@@ -267,6 +354,21 @@ class CycleTrace:
             return
         reasons.append(reason)
 
+    def record(self, span: Span, keep: bool = False) -> None:
+        """A span closed: add it to its name's total, and to the span
+        list while there is room (``keep``: the root's reserved seat)."""
+        total = self.name_totals.get(span.name)
+        if total is None:
+            name = (span.name if len(self.name_totals) < self.MAX_SPAN_NAMES
+                    else self.OTHER_NAMES)
+            total = self.name_totals.setdefault(name, [0, 0.0])
+        total[0] += 1
+        total[1] += span.duration_s
+        if keep or len(self.spans) < self.max_spans - 1:
+            self.spans.append(span)
+        else:
+            self.dropped_spans += 1
+
     def span_summary(self) -> dict:
         """kind -> {count, total_ms, errors}: where the cycle went."""
         out: dict = {}
@@ -286,6 +388,9 @@ class CycleTrace:
                 "duration_ms": round(self.duration_ms, 3),
                 "aborted": self.aborted, "degraded": self.degraded,
                 "spans": self.span_summary(),
+                "span_names": {
+                    name: {"count": n, "total_ms": round(secs * 1e3, 3)}
+                    for name, (n, secs) in self.name_totals.items()},
                 "dropped_spans": self.dropped_spans,
                 "dropped_rejections": self.dropped_rejections,
                 "rejected_podgroups": sorted(self.explain),
@@ -309,7 +414,9 @@ class Tracer:
 
     The active trace is thread-local: one scheduler thread drives one
     cycle, and spans opened on other threads (status-updater workers)
-    deliberately no-op instead of racing the cycle's span stack.  Reads
+    deliberately no-op instead of racing the cycle's span stack; the
+    one way in for another thread is a ``hand_off`` from the cycle
+    thread while that thread waits (the device guard's worker).  Reads
     (`cycles`, `get_trace`, `explain_for`) come from HTTP handler threads
     and take the ring lock; finished traces are immutable."""
 
@@ -336,6 +443,8 @@ class Tracer:
         self._lock = threading.Lock()
         self._local = threading.local()
         self._ids = itertools.count(1)
+        # TraceAnnotation class, looked up at each begin_cycle.
+        self._annotation = None
         # podgroup -> latest rejection record ({"cycle", "trace_id",
         # "reasons"}); bounded like ClusterCache._warned_selectors.
         self._explain_latest: dict = {}
@@ -344,7 +453,8 @@ class Tracer:
     def _state(self) -> dict:
         st = getattr(self._local, "state", None)
         if st is None:
-            st = self._local.state = {"trace": None, "stack": []}
+            st = self._local.state = {"trace": None, "stack": [],
+                                      "hand_off": None}
         return st
 
     def begin_cycle(self, cycle: int) -> str:
@@ -358,12 +468,12 @@ class Tracer:
             self.end_cycle(aborted="trace abandoned by next cycle")
         trace_id = f"t{next(self._ids):06d}"
         trace = CycleTrace(trace_id, cycle, self.max_spans_per_trace)
-        root = Span(trace_id, f"s{next(self._ids)}", None,
-                    "cycle", "cycle", 0.0)
-        root.set(cycle=cycle)
-        trace.root = root
+        self._annotation = _trace_annotation()
         st["trace"] = trace
-        st["stack"] = [root]
+        st["stack"] = []
+        root = trace.root = self._open(st, trace, "cycle", "cycle",
+                                       {"cycle": cycle})
+        root.start_s = 0.0  # the trace's origin, not when _open ran
         return trace_id
 
     def end_cycle(self, aborted: str | None = None, degraded: bool = False,
@@ -391,15 +501,17 @@ class Tracer:
         # exception bypassed their context managers; close deepest-first.
         while len(st["stack"]) > 1:
             sp = st["stack"].pop()
+            self._end_annotation(sp)
             sp.duration_s = (now - trace.t0) - sp.start_s
             if aborted and sp.status == "ok":
                 sp.mark_error(aborted)
-            self._record_span(trace, sp)
+            trace.record(sp)
         root = st["stack"].pop()
+        self._end_annotation(root)
         root.duration_s = now - trace.t0
         if aborted:
             root.mark_error(aborted)
-        trace.spans.append(root)  # the root always survives the span cap
+        trace.record(root, keep=True)  # the root survives the span cap
         trace.aborted = aborted
         trace.degraded = bool(degraded)
         trace.duration_ms = root.duration_s * 1e3
@@ -410,8 +522,9 @@ class Tracer:
         st["trace"] = None
         st["stack"] = []
         for sp in trace.spans:
-            METRICS.observe(f"cycle_span_{sp.kind}_latency_ms",
-                            sp.duration_s * 1e3)
+            if sp.kind not in _NO_HISTOGRAM_KINDS:
+                METRICS.observe(f"cycle_span_{sp.kind}_latency_ms",
+                                sp.duration_s * 1e3)
         with self._lock:
             self._ring.append(trace)
             for name in resolved:
@@ -436,6 +549,15 @@ class Tracer:
         trace: CycleTrace | None = st["trace"]
         if trace is None:
             return _SpanCtx(self, _NULL_SPAN)
+        if self._superseded(st):
+            METRICS.inc("trace_spans_revoked_total")
+            return _SpanCtx(self, _NULL_SPAN)
+        return _SpanCtx(self, self._open(st, trace, name, kind, attrs))
+
+    def _open(self, st: dict, trace: CycleTrace, name: str, kind: str,
+              attrs: dict) -> Span:
+        """A new span under this thread's innermost open one, and its
+        ``kai:<name>`` annotation on the profiler's clock."""
         parent = st["stack"][-1] if st["stack"] else None
         sp = Span(trace.trace_id, f"s{next(self._ids)}",
                   parent.span_id if parent is not None else None,
@@ -443,7 +565,24 @@ class Tracer:
         if attrs:
             sp.attrs.update(attrs)
         st["stack"].append(sp)
-        return _SpanCtx(self, sp)
+        if self._annotation is not None:
+            sp.annotation = self._annotation(f"kai:{name}")
+            sp.annotation.__enter__()
+        return sp
+
+    @staticmethod
+    def _end_annotation(span: Span) -> None:
+        if span.annotation is not None:
+            span.annotation.__exit__(None, None, None)
+            span.annotation = None
+
+    @staticmethod
+    def _superseded(st: dict) -> bool:
+        """True on an adopting thread that may no longer record: the
+        dispatch span it was handed closed (the guard abandoned this
+        worker at its deadline), or a later attempt adopted after it."""
+        hand_off = st["hand_off"]
+        return hand_off is not None and hand_off.writer != st["ident"]
 
     def _close_span(self, span: Span) -> None:
         st = self._state()
@@ -455,17 +594,62 @@ class Tracer:
                 st["stack"].remove(span)
             except ValueError:
                 pass
+        self._end_annotation(span)
         if trace is None:
             return
         span.duration_s = (time.perf_counter() - trace.t0) - span.start_s
-        self._record_span(trace, span)
+        if st["hand_off"] is None:  # the cycle thread, the trace's owner
+            trace.record(span)
+            return
+        with self._lock:
+            live = not self._superseded(st)
+            if live:
+                trace.record(span)
+        if not live:
+            METRICS.inc("trace_spans_revoked_total")
 
-    @staticmethod
-    def _record_span(trace: CycleTrace, span: Span) -> None:
-        if len(trace.spans) < trace.max_spans - 1:  # -1: root's seat
-            trace.spans.append(span)
-        else:
-            trace.dropped_spans += 1
+    # -- across a thread seam (the device guard's worker) ------------------
+    def hand_off(self):
+        """On the cycle thread, around a call that runs a thunk on another
+        thread while this one waits: ``with TRACER.hand_off() as seam:``
+        and give the other side ``seam.adopting(thunk)``.  Spans the thunk
+        opens become children of this thread's innermost open span.
+        Outside a cycle the thunk comes back as it is."""
+        st = self._state()
+        return _HandOff(self, st["trace"], list(st["stack"]))
+
+    @contextlib.contextmanager
+    def _adopt(self, hand_off: _HandOff):
+        st = self._state()
+        ident = threading.get_ident()
+        with self._lock:
+            hand_off.writer = ident  # whoever adopted before is superseded
+        if st["trace"] is hand_off.trace:
+            # The guard ran the thunk inline (no deadline): this thread
+            # owns the trace already.
+            yield
+            return
+        st.update(trace=hand_off.trace, stack=list(hand_off.stack),
+                  hand_off=hand_off, ident=ident)
+        try:
+            yield
+        finally:
+            st.update(trace=None, stack=[], hand_off=None)
+
+    def stamp(self, name: str, **attrs) -> None:
+        """Set attributes on the nearest open span called ``name`` above
+        the caller: how a wrapper that resolves something deep inside a
+        dispatch (the grouped kernel's rung) marks the call site's span."""
+        st = self._state()
+        if st["trace"] is None:
+            return
+        with self._lock:  # as an adopter's record: not after a revoke
+            if self._superseded(st):
+                return
+            for sp in reversed(st["stack"]):
+                if sp.name == name:
+                    sp.attrs.update(attrs)
+                    return
 
     def current_trace_id(self) -> str | None:
         st = getattr(self._local, "state", None)
@@ -510,13 +694,7 @@ class Tracer:
         st = self._state()
         trace: CycleTrace | None = st["trace"]
         if trace is not None:  # live: a real span on this thread's stack
-            parent = st["stack"][-1] if st["stack"] else None
-            sp = Span(trace.trace_id, f"s{next(self._ids)}",
-                      parent.span_id if parent is not None else None,
-                      name, kind, time.perf_counter() - trace.t0)
-            if attrs:
-                sp.attrs.update(attrs)
-            st["stack"].append(sp)
+            sp = self._open(st, trace, name, kind, attrs)
             return _ClientSpanCtx(self, trace.trace_id, sp.span_id,
                                   span=sp)
         ambient = getattr(self._local, "ambient", None)
@@ -574,7 +752,7 @@ class Tracer:
                 sp.duration_s = duration_s
                 if attrs:
                     sp.attrs.update(attrs)
-                self._record_span(trace, sp)
+                trace.record(sp)
                 return True
         return False
 
@@ -648,7 +826,7 @@ class Tracer:
                                          "lag_frames", "stream")
                      if k in rec})
                 srv.attrs["remote_id"] = rid
-                self._record_span(trace, srv)
+                trace.record(srv)
                 cursor = start
                 phases = rec.get("phases") or {}
                 for phase in self._SERVER_PHASES:
@@ -661,7 +839,7 @@ class Tracer:
                                  f"server_{phase}", cursor)
                     child.duration_s = phase_s
                     cursor += phase_s
-                    self._record_span(trace, child)
+                    trace.record(child)
                 out["grafted"] += 1
         if out["grafted"]:
             METRICS.inc("wire_spans_grafted_total", out["grafted"])

@@ -22,7 +22,6 @@ def test_daemon_cli_smoke(tmp_path):
         [sys.executable, "-m", "kai_scheduler_tpu.server",
          "--http-port", str(port), "--cycles", "400",
          "--schedule-period", "0.05", "--enable-profiler",
-         "--stackprof",
          "--lock-file", str(tmp_path / "lease.lock")],
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
 
@@ -94,7 +93,7 @@ def test_daemon_cli_smoke(tmp_path):
         latency = json.loads(get("/debug/latency"))
         assert "timelines" in latency and "pod_latency" in latency
         assert latency["status"]["ring_capacity"] >= 1
-        # Continuous fleet profiler: folded stacks from --stackprof.
+        # Continuous fleet profiler: --enable-profiler armed stackprof.
         deadline = time.monotonic() + 30
         flame = b""
         while time.monotonic() < deadline and not flame.strip():

@@ -300,6 +300,445 @@ class TestFlightRecorderBounds:
         assert tracer.current_trace_id() is None
 
 
+# -- the trace across the guard's worker thread (PR 25) -----------------------
+
+def cycle_session():
+    """A live cycle on this thread and a session to dispatch through."""
+    from kai_scheduler_tpu.framework.session import Session
+    TRACER.begin_cycle(1)
+    return Session(small_cluster())
+
+
+def by_name(trace):
+    out = {}
+    for sp in trace.spans:
+        out.setdefault(sp.name, []).append(sp)
+    return out
+
+
+class TestHandOff:
+    @pytest.mark.parametrize("path", ("device", "fallback", "inline"))
+    def test_span_in_guarded_thunk_lands_under_its_dispatch(self, path):
+        """The guard runs the thunk on its worker thread (device attempt
+        and CPU fallback alike) or, without a deadline, inline: a span
+        opened inside is a child of the open ``dispatch:<label>`` span
+        either way."""
+        import threading
+        configure_device_guard(
+            deadline_s=0.0 if path == "inline" else 5.0, retries=0,
+            breaker_threshold=100,
+            fault="error" if path == "fallback" else "")
+        ssn = cycle_session()
+        ran_on = []
+
+        def thunk():
+            with TRACER.span("seam:probe", kind="seam", n=1):
+                ran_on.append(threading.get_ident())
+            return 1
+
+        assert ssn.dispatch_kernel(thunk, label="probe") == 1
+        trace = TRACER.end_cycle()
+        spans = by_name(trace)
+        (dispatch,), (probe,) = spans["dispatch:probe"], spans["seam:probe"]
+        assert probe.parent_id == dispatch.span_id
+        assert probe.attrs == {"n": 1} and probe.duration_s > 0
+        assert dispatch.attrs["fallback"] is (path == "fallback")
+        assert (ran_on[0] == threading.get_ident()) is (path == "inline")
+        assert dispatch.start_s <= probe.start_s
+        assert probe.start_s + probe.duration_s \
+            <= dispatch.start_s + dispatch.duration_s + 1e-6
+        assert trace.dropped_spans == 0
+
+    @pytest.mark.parametrize("fallback", (True, False))
+    def test_abandoned_worker_writes_into_no_trace(self, fallback):
+        """A thunk that outlives the guard's deadline: what it opens or
+        closes afterwards lands neither in its own cycle's trace (ended
+        by then, or taken over by the CPU fallback) nor in the next
+        one's, and is counted."""
+        import threading
+
+        from kai_scheduler_tpu.utils.deviceguard import DeviceGuardError
+        configure_device_guard(deadline_s=0.1, retries=0,
+                               breaker_threshold=100,
+                               fallback_enabled=fallback)
+        ssn = cycle_session()
+        release, finished = threading.Event(), threading.Event()
+        calls = []
+
+        def revoked():
+            return METRICS.counters.get("trace_spans_revoked_total", 0)
+        revoked0 = revoked()
+
+        def thunk():
+            calls.append(1)
+            if len(calls) > 1:  # the CPU fallback's clean re-run
+                with TRACER.span("seam:fallback", kind="seam"):
+                    return 1
+            try:
+                with TRACER.span("seam:early", kind="seam"):
+                    assert release.wait(30.0)
+                with TRACER.span("seam:late", kind="seam"):
+                    pass
+                TRACER.stamp("dispatch:slow", late=True)
+            finally:
+                finished.set()
+            return 1
+
+        if fallback:
+            assert ssn.dispatch_kernel(thunk, label="slow") == 1
+        else:
+            with pytest.raises(DeviceGuardError):
+                ssn.dispatch_kernel(thunk, label="slow")
+        first = TRACER.end_cycle()
+        TRACER.begin_cycle(2)
+        release.set()
+        assert finished.wait(30.0)
+        second = TRACER.end_cycle()
+        for trace in (first, second):
+            names = set(by_name(trace))
+            assert not {"seam:early", "seam:late"} & names, names
+        assert ("seam:fallback" in by_name(first)) is fallback
+        assert "late" not in by_name(first)["dispatch:slow"][0].attrs
+        # seam:early's close and seam:late's open were both dropped, and
+        # counted on the tracer: the first trace had ended by then.
+        assert revoked() - revoked0 == 2
+        assert first.dropped_spans == 0 and second.dropped_spans == 0
+        assert first.name_totals.get("seam:early") is None
+
+    def test_hand_off_outside_a_cycle_carries_nothing(self):
+        def thunk():
+            return 7
+        with TRACER.hand_off() as seam:
+            assert seam.adopting(thunk) is thunk
+
+
+def topology_gang_cluster():
+    """8 nodes in 2 zones x 2 racks and one gang that prefers a rack: a
+    master beside two workers makes the chunk non-homogeneous, so it
+    takes the exact kernel with a live mask and live boosts."""
+    nodes = {f"n{i}": {"gpu": 8, "labels": {"zone": f"z{i // 4}",
+                                            "rack": f"r{i // 2}"}}
+             for i in range(8)}
+    return build_cluster({
+        "nodes": nodes, "queues": {"q": {}},
+        "topologies": {"topo": {"levels": ["zone", "rack"]}},
+        "jobs": {"gang": {
+            "queue": "q", "min_available": 3, "topology": "topo",
+            "preferred_topology_level": "rack",
+            "tasks": [{"cpu": "2", "mem": "1Gi", "gpu": 1},
+                      {"cpu": "1", "mem": "1Gi", "gpu": 1},
+                      {"cpu": "1", "mem": "1Gi", "gpu": 1}]}}})
+
+
+# Span of the issue's table -> its parent.
+TABLE = {
+    "allocate:order": "action:allocate",
+    "allocate:job": "action:allocate",
+    "topology:subset_nodes": "allocate:job",
+    "propose:operands": "allocate:job",
+    "extra_scores:topology": "propose:operands",
+    "dispatch:allocate_jobs": "allocate:job",
+    "seam:stage": "dispatch:allocate_jobs",
+    "seam:launch": "dispatch:allocate_jobs",
+    "dispatch:allocate_jobs_fetch": "allocate:job",
+    "seam:wait": "dispatch:allocate_jobs_fetch",
+    "seam:download": "dispatch:allocate_jobs_fetch",
+    "propose:unpack": "allocate:job",
+    "statement:apply": "allocate:job",
+    "statement:commit": "allocate:job",
+}
+OLD_NAMES = ("dispatch:allocate_jobs", "dispatch:allocate_jobs_fetch")
+COUNTERS = ("device_upload_bytes", "host_convert_bytes",
+            "device_download_bytes")
+
+
+def counters_now():
+    return {c: METRICS.counters.get(c, 0.0) for c in COUNTERS}
+
+
+class TestAllocateSpans:
+    @pytest.fixture
+    def gang_cycle(self):
+        """(trace, counter movement, session) of one topology-gang
+        cycle."""
+        before = counters_now()
+        ssn = Scheduler(topology_gang_cluster, SchedulerConfig()).run_once()
+        moved = {c: v - before[c] for c, v in counters_now().items()}
+        return TRACER.get_trace(), moved, ssn
+
+    def test_each_span_of_the_table_once_under_its_parent(self, gang_cycle):
+        trace, _moved, ssn = gang_cycle
+        assert len(ssn.cluster.bind_requests) == 3
+        spans = by_name(trace)
+        ids = {sp.span_id: sp for sp in trace.spans}
+        for name, parent in TABLE.items():
+            assert len(spans.get(name, ())) == 1, (name, sorted(spans))
+            assert ids[spans[name][0].parent_id].name == parent, name
+        job = spans["allocate:job"][0].attrs
+        assert job == {"job": "gang", "queue": "q", "tasks": 3,
+                       "success": True}
+        operands = spans["propose:operands"][0].attrs
+        assert operands == {"t": 3, "t_pad": 4, "nodes": 8,
+                            "path": "exact"}
+        assert spans["topology:subset_nodes"][0].attrs["nodes_in_first"] == 2
+        assert spans["extra_scores:topology"][0].attrs["bytes"] == 3 * 8 * 8
+        assert spans["seam:launch"][0].attrs == {"kernel": "allocate_jobs"}
+        assert spans["statement:apply"][0].attrs == {"ops": 3}
+        assert spans["statement:commit"][0].attrs == {"binds": 3}
+        # Every registered score fn has a span named after its plugin,
+        # all of one kind.
+        scores = [sp for sp in trace.spans
+                  if sp.name.startswith("extra_scores:")]
+        assert len(scores) == len(ssn.extra_score_fns) >= 2
+        assert {sp.name.partition(":")[2] for sp in scores} \
+            <= {p.name for p in ssn.plugins}
+        assert all(sp.kind == "topology" for sp in scores)
+
+    def test_no_new_span_reads_as_a_dispatch(self, gang_cycle):
+        """``allocate_host_ms`` is ``action:allocate`` minus every
+        ``dispatch:*`` under it: nothing this PR names may match."""
+        from fnmatch import fnmatchcase
+        trace, _moved, _ssn = gang_cycle
+        new = {sp.name for sp in trace.spans
+               if sp.kind in ("allocate", "topology", "propose", "seam")
+               or sp.name.startswith("statement:")}
+        assert set(TABLE) - set(OLD_NAMES) <= new
+        assert not [n for n in new if fnmatchcase(n, "dispatch:*")]
+
+    def test_the_new_kinds_feed_no_per_kind_histogram(self, gang_cycle):
+        """A quantile over ``seam`` would mix a staging with a launch:
+        these kinds are read by name (``span_names``), not by kind."""
+        trace, _moved, _ssn = gang_cycle
+        kinds = {sp.kind for sp in trace.spans}
+        assert {"allocate", "topology", "propose", "seam"} <= kinds
+        for kind in ("allocate", "topology", "propose", "seam"):
+            assert f"cycle_span_{kind}_latency_ms" not in METRICS.histograms
+        assert METRICS.histograms["cycle_span_commit_latency_ms"].n >= 2
+
+    def test_counters_equal_the_sizes_the_shapes_give(self, gang_cycle):
+        """Tier-1 runs in x64, where an f64 operand is uploaded as it is:
+        nothing is converted, and the score matrix costs 8 bytes a cell."""
+        trace, moved, ssn = gang_cycle
+        t_pad, n = 4, 8
+        snap = ssn.snapshot
+        rows = (t_pad * snap.task_req.shape[1] * 8            # f64
+                + t_pad * 4                                   # task_job
+                + t_pad * snap.task_selector.shape[1] * 4
+                + t_pad * snap.task_tolerations.shape[1] * 4
+                + 2)                                          # job_allowed
+        dense = t_pad * n * (8 + 1)      # extra f64 + the subset mask
+        stage = by_name(trace)["seam:stage"][0].attrs
+        assert moved == {"device_upload_bytes": rows + dense,
+                         "host_convert_bytes": 0,
+                         "device_download_bytes": (2 * t_pad + 2) * 4}
+        assert stage == {"bytes_host": rows + dense,
+                         "bytes_device": rows + dense,
+                         "bytes_converted": 0, "operands": 7}
+        assert by_name(trace)["seam:download"][0].attrs["bytes"] \
+            == moved["device_download_bytes"]
+
+    def test_stage_counts_what_the_32_bit_regime_converts(self):
+        """The regime the chip runs: f64 narrows to f32 on the host."""
+        import jax
+        import numpy as np
+
+        from kai_scheduler_tpu.framework.session import _stage
+        before = counters_now()
+        with jax.enable_x64(False):
+            req, mask, pair, nothing = _stage(
+                np.zeros((4, 8)), np.ones((4, 8), bool),
+                (np.zeros(4, np.int32), np.zeros((4, 8))), None)
+        assert (req.dtype, mask.dtype) == (np.float32, np.bool_)
+        assert nothing is None and pair[1].dtype == np.float32
+        moved = {c: v - before[c] for c, v in counters_now().items()}
+        assert moved["host_convert_bytes"] == 2 * 4 * 8 * 8
+        assert moved["device_upload_bytes"] == 2 * 4 * 8 * 4 + 32 + 16
+
+    def test_a_bulk_wave_is_one_statement_span_not_two_a_job(self):
+        """The bulk path binds a wave of jobs under one ``statement:bulk``:
+        spans must not scale with the jobs of a fill."""
+        cluster = small_cluster()
+        Scheduler(lambda: cluster,
+                  SchedulerConfig(bulk_allocation_threshold=2)).run_once()
+        assert len(cluster.bind_requests) == 8
+        spans = by_name(TRACER.get_trace())
+        (bulk,) = spans["statement:bulk"]
+        assert bulk.kind == "commit" and bulk.attrs == {"jobs": 4, "ops": 8}
+        assert not {"statement:apply", "statement:commit",
+                    "allocate:job"} & set(spans)
+
+    def test_grouped_rung_is_stamped_where_it_is_resolved(self):
+        """``allocate_grouped`` runs on the guard's worker and stamps the
+        rung on the call site's ``allocate_fused`` span, beside the
+        guard's verdict."""
+        Scheduler(lambda: small_cluster(), SchedulerConfig()).run_once()
+        fused = by_name(TRACER.get_trace())["allocate_fused"]
+        assert fused and all(
+            {"mode", "groups", "nodes", "releasing_empty", "fallback",
+             "timed_out", "breaker"} <= set(sp.attrs) for sp in fused)
+        assert {sp.attrs["mode"] for sp in fused} == {"jnp"}
+
+
+class TestNameTotals:
+    def test_totals_by_name_survive_the_span_cap(self):
+        tracer = Tracer(capacity=2, max_spans_per_trace=8)
+        tracer.begin_cycle(1)
+        for _ in range(50):
+            with tracer.span("allocate:job", kind="allocate"):
+                with tracer.span("seam:stage", kind="seam"):
+                    pass
+        trace = tracer.end_cycle()
+        assert trace.dropped_spans == 100 - 7
+        names = trace.to_summary()["span_names"]
+        assert names["allocate:job"]["count"] == 50
+        assert names["seam:stage"]["count"] == 50
+        assert names["cycle"]["count"] == 1
+        assert 0 < names["seam:stage"]["total_ms"] \
+            <= names["allocate:job"]["total_ms"] \
+            <= names["cycle"]["total_ms"]
+        # By kind is what the kept spans say, as before.
+        kinds = trace.to_summary()["spans"]
+        assert sum(k["count"] for k in kinds.values()) == 8
+
+    def test_distinct_names_are_bounded(self):
+        from kai_scheduler_tpu.utils.tracing import CycleTrace
+        tracer = Tracer(capacity=2, max_spans_per_trace=8)
+        tracer.begin_cycle(1)
+        extra = 40
+        for i in range(CycleTrace.MAX_SPAN_NAMES + extra):
+            with tracer.span(f"bind:pod-{i}", kind="kubeapi"):
+                pass
+        trace = tracer.end_cycle()
+        totals = trace.name_totals
+        assert len(totals) == CycleTrace.MAX_SPAN_NAMES + 1
+        # The root closes last: it and the overflow share the last row.
+        assert totals[CycleTrace.OTHER_NAMES][0] == extra + 1
+
+
+class TestProfilerClock:
+    @staticmethod
+    def profiled_cycle(sched, out_dir):
+        """(spans by name, {annotation: (line, start_ns, duration_ns)}) of
+        one cycle run under a profiler session."""
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.enable_hlo_proto = False
+        jax.profiler.start_trace(str(out_dir), profiler_options=options)
+        try:
+            sched.run_once()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(out_dir / "plugins" / "profile" / "*"
+                                / "*.xplane.pb"))
+        found = {}
+        lines = [line for plane in ProfileData.from_file(path).planes
+                 for line in plane.lines]
+        for i, line in enumerate(lines):
+            for ev in line.events:
+                if ev.name.startswith("kai:"):
+                    assert ev.name not in found, ev.name
+                    found[ev.name] = (i, ev.start_ns, ev.duration_ns)
+        return by_name(TRACER.get_trace()), found
+
+    def test_spans_are_annotations_on_the_profilers_clock(self, tmp_path):
+        """Under a profiler session the trace holds ``kai:<span name>``
+        for the cycle thread's spans and, on another line, for the
+        worker's; offsets between them agree with the flight
+        recorder's."""
+        sched = Scheduler(topology_gang_cluster, SchedulerConfig())
+        sched.run_once()  # compile outside the profiled cycle
+        # The two clocks are read a few bytecodes apart, and under a
+        # loaded test run the interpreter can switch threads in between
+        # (5 ms): a cycle with such a stall is measured again, a clock
+        # that is off would be off every time.
+        for attempt in range(3):
+            spans, found = self.profiled_cycle(sched, tmp_path / str(attempt))
+            assert {f"kai:{name}" for name in TABLE} <= set(found)
+            cycle_line = found["kai:cycle"][0]
+            worker_line = found["kai:seam:stage"][0]
+            assert worker_line != cycle_line
+            assert found["kai:seam:launch"][0] == worker_line
+            for name in ("action:allocate", "allocate:job",
+                         "propose:operands", "statement:commit"):
+                assert found[f"kai:{name}"][0] == cycle_line, name
+            # One clock: the offset of every span from action:allocate,
+            # and its length, as the profiler has them and as the
+            # recorder does.
+            base_ns = found["kai:action:allocate"][1]
+            base_s = spans["action:allocate"][0].start_s
+            off = {}
+            for name in TABLE:
+                (sp,) = spans[name]
+                _line, start_ns, dur_ns = found[f"kai:{name}"]
+                off[name] = max(
+                    abs((start_ns - base_ns) / 1e9 - (sp.start_s - base_s)),
+                    abs(dur_ns / 1e9 - sp.duration_s))
+            if max(off.values()) < 1e-3:
+                return
+        pytest.fail(f"offsets beyond a millisecond in 3 cycles: {off}")
+
+    def test_without_jax_loaded_spans_record_as_before(self, monkeypatch):
+        import sys
+        monkeypatch.delitem(sys.modules, "jax")
+        tracer = Tracer(capacity=2)
+        tracer.begin_cycle(1)
+        with tracer.span("s", kind="action") as sp:
+            assert sp.annotation is None
+        trace = tracer.end_cycle()
+        assert [s.name for s in trace.spans] == ["s", "cycle"]
+
+
+class TestProfileEndpoints:
+    """``--enable-profiler`` arms the one sampling profiler there is
+    (tests/test_server.py starts the daemon with it); the three paths it
+    was ever served under are one page."""
+
+    @pytest.fixture
+    def serve(self):
+        import threading
+        import urllib.request
+        from http.server import ThreadingHTTPServer
+
+        from kai_scheduler_tpu.server import _make_handler
+        from kai_scheduler_tpu.utils.stackprof import STACKPROF
+        STACKPROF.stop(dump=False)
+        STACKPROF.reset()
+        httpd = ThreadingHTTPServer(("127.0.0.1", 0), _make_handler({}))
+        threading.Thread(target=httpd.serve_forever, daemon=True).start()
+
+        def get(path):
+            return urllib.request.urlopen(
+                f"http://127.0.0.1:{httpd.server_port}{path}",
+                timeout=5).read()
+        yield get
+        httpd.shutdown()
+        STACKPROF.stop(dump=False)
+        STACKPROF.reset()
+
+    @pytest.mark.parametrize("path", ("/debug/flame", "/debug/pprof",
+                                      "/debug/profile"))
+    def test_every_profile_path_serves_stackprof(self, serve, path):
+        import time
+        import urllib.error
+
+        from kai_scheduler_tpu.utils.stackprof import STACKPROF
+        with pytest.raises(urllib.error.HTTPError) as err:
+            serve(path)
+        assert err.value.code == 404
+        STACKPROF.start()
+        deadline = time.monotonic() + 10
+        while not STACKPROF.total_samples and time.monotonic() < deadline:
+            time.sleep(0.02)
+        folded = serve(path).decode()
+        assert "socketserver.py:serve_forever" in folded
+        summary = json.loads(serve(path + "?summary=1"))
+        assert summary["total_samples"] > 0 and summary["running"]
+
+
 # -- fleet correlation (BindRequest spec + events over the API) ---------------
 
 class TestFleetCorrelation:
