@@ -1396,6 +1396,15 @@ class ClusterCache:
             self._wholesale_invalidate()
             arena.invalidate("watch-resync")
             resync_fired = True
+        # Frozen copy of the speculative view (overlapped commits whose
+        # writes are still in flight), taken BEFORE the queued watch
+        # changes: an epilogue on the commit executor queues a bind's
+        # echo and THEN clears its entry, so a copy taken after the
+        # changes could miss both — the pod reads pending and is bound
+        # twice.  Copied first, either the entry is still here or its
+        # echo is already in the queue taken below.
+        with self._changes_lock:
+            speculative = dict(self._speculative)
         was_primed = self._primed
         if self._watch_mode and self._primed:
             changed = self._apply_changes(*self._take_changes())
@@ -1432,7 +1441,8 @@ class ClusterCache:
             try:
                 with TRACER.span("snapshot_columnar",
                                  kind="snapshot_columnar") as sp:
-                    cluster = self._snapshot_columnar(changed, events, sp)
+                    cluster = self._snapshot_columnar(changed, events, sp,
+                                                      speculative)
             except Exception:
                 # The fast path must degrade, never crash the cycle; the
                 # parity ring (tests/test_columnar_store.py) keeps this
@@ -1451,7 +1461,7 @@ class ClusterCache:
                 METRICS.inc("columnar_fallback_total")
             self.last_columnar_stats = {"path": "object",
                                         "reason": reason}
-            cluster = self._snapshot_objects(changed)
+            cluster = self._snapshot_objects(changed, speculative)
         self.last_snapshot_stats["columnar"] = self.last_columnar_stats
         METRICS.observe("snapshot_build_latency_ms",
                         (_time.perf_counter() - t0) * 1000.0)
@@ -1525,7 +1535,7 @@ class ClusterCache:
                            prewired=prewired)
 
     def _snapshot_columnar(self, changed: dict, events: dict,
-                           span) -> ClusterInfo:
+                           span, speculative: dict) -> ClusterInfo:
         """Array-native snapshot build (DESIGN §11): one index build +
         vectorized segment reductions over the columnar store, with
         per-cycle ``PodInfo`` views fast-instantiated from row
@@ -1593,9 +1603,6 @@ class ClusterCache:
                                   len(nvocab.strs))]
 
         # -- speculative overlay (DESIGN §10), applied on the columns ----
-        with self._changes_lock:
-            speculative = dict(self._speculative) if self._speculative \
-                else {}
         applied_overlay: dict = {}
         overlay_names: dict = {}
         n_overlaid = 0
@@ -1823,7 +1830,8 @@ class ClusterCache:
         cluster.cache_stats = self.last_snapshot_stats
         return cluster
 
-    def _snapshot_objects(self, changed: dict) -> ClusterInfo:
+    def _snapshot_objects(self, changed: dict,
+                          speculative: dict) -> ClusterInfo:
         arena = self.arena
         nodes = self._build_nodes()
         queues = self._build_queues()
@@ -1833,15 +1841,12 @@ class ClusterCache:
         cache_seen = set()
         pod_sigs: dict = {}
         pod_mirror = self._mirror["Pod"]
-        # Frozen copy of the speculative view (overlapped commits whose
-        # writes are still in flight): applied onto the parsed pods below
-        # so this snapshot sees the previous cycle's decisions whether or
-        # not their watch echo has arrived.  A frozen copy — the commit
-        # epilogue may clear entries concurrently, and a half-applied
-        # clear mid-loop would make the snapshot internally inconsistent.
-        with self._changes_lock:
-            speculative = dict(self._speculative) if self._speculative \
-                else {}
+        # ``speculative`` (snapshot()'s frozen copy) is applied onto the
+        # parsed pods below, so this snapshot sees the previous cycle's
+        # decisions whether or not their watch echo has arrived.  A
+        # frozen copy — the commit epilogue may clear entries
+        # concurrently, and a half-applied clear mid-loop would make the
+        # snapshot internally inconsistent.
         n_overlaid = 0
         overlay_now: dict = {}
         for pod_key in self._iter_order("Pod"):

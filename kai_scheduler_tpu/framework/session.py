@@ -82,6 +82,24 @@ def _next_pow2(n: int) -> int:
     return p
 
 
+def _pad_rows(rows, t_pad: int, fill):
+    """A per-task [t, N] operand padded to the kernel's [t_pad, N] with
+    ``fill`` rows for the padding tasks; None stays None."""
+    if rows is None or rows.shape[0] == t_pad:
+        return rows
+    out = np.full((t_pad,) + rows.shape[1:], fill, rows.dtype)
+    out[:rows.shape[0]] = rows
+    return out
+
+
+def _note_operand_forms(span, extras: str, mask: str) -> None:
+    """Say on ``propose:operands``, and count, which form the score and
+    hard-mask operands of this dispatch took: ``none`` (no operand),
+    ``row`` (one [N] row a job) or ``dense`` ([T,N])."""
+    span.set(extras=extras, mask=mask)
+    METRICS.inc("propose_operand_form_total", extras=extras, mask=mask)
+
+
 def _allocation_shape_check(t_pad: int):
     """Device-guard validator for allocation results: the task axis must
     match what was dispatched (a truncated/garbled device answer — the
@@ -700,11 +718,18 @@ class Session:
         return placements
 
     # -- device-kernel placement proposals ---------------------------------
-    def _add_extra_scores(self, tasks, extra: np.ndarray) -> None:
-        """Sum every registered extra-score fn's [T,N] term into
-        ``extra[:len(tasks)]``, each call (not the sum) under a span of
-        its own."""
-        t = len(tasks)
+    def _sum_extra_scores(self, tasks) -> "np.ndarray | None":
+        """Sum of every registered extra-score fn over ONE job chunk, in
+        registration order and in the host's float, each call (not the
+        sum) under a span of its own.
+
+        A fn returns None, one [N] row (it holds for every task of the
+        chunk) or [T,N] (rows that differ by task).  The sum keeps the
+        cheapest form that holds what it was given: None, [N], or
+        [len(tasks), N] once any fn returned [T,N] (rows broadcast into
+        it, so the values are those a [T,N] sum gives).  Nothing [T,N] is
+        made that no fn asked for."""
+        acc = None
         for fn in self.extra_score_fns:
             # Named as the plugin's ``plugin:<name>`` span is; a bare
             # function (tests) has no plugin to name.
@@ -713,8 +738,17 @@ class Session:
                              kind="topology") as sp:
                 contrib = fn(tasks)
                 sp.set(bytes=getattr(contrib, "nbytes", 0))
-            if contrib is not None:
-                extra[:t] += contrib
+            if contrib is None:
+                continue
+            contrib = np.asarray(contrib)
+            if acc is None:
+                # A copy: a plugin may hand over a row it keeps.
+                acc = contrib.astype(np.float64)
+            elif acc.ndim >= contrib.ndim:
+                acc += contrib
+            else:
+                acc = acc + contrib
+        return acc
 
     def propose_placements_multi(self, job_chunks,
                                  pipeline_only: bool = True):
@@ -735,7 +769,8 @@ class Session:
         n_nodes = self.node_idle.shape[0]
         t_pad = _next_pow2(t)
         with TRACER.span("propose:operands", kind="propose", t=t,
-                         t_pad=t_pad, nodes=n_nodes, path="multi"):
+                         t_pad=t_pad, nodes=n_nodes,
+                         path="multi") as operands_span:
             for fn in self.anti_domain_fns + self.affinity_domain_fns:
                 if fn(all_tasks) is not None:
                     return None
@@ -746,8 +781,28 @@ class Session:
             task_tol = np.full((t_pad, snap.task_tolerations.shape[1]), -1,
                                np.int32)
             task_job = np.full(t_pad, len(job_chunks), np.int32)  # padding
+            # Bucket the job axis too (KJT001): [J+1] exact would retrace
+            # the allocate kernel per distinct live gang count.  Padding
+            # jobs are gated out (allowed=False) and own only padding
+            # tasks, so nothing the kernel reads of them is used;
+            # consumers index success[j] for real jobs only.
+            j_pad = _next_pow2(len(job_chunks) + 1)
+            job_allowed = np.ones(j_pad, bool)
+            job_allowed[len(job_chunks):] = False
+            # Each chunk's extra scores are its own job's: a row goes to
+            # the job's row, per-task rows to the chunk's task rows.
+            job_extra = task_extra = None
             row = 0
             for j, (_job, tasks) in enumerate(job_chunks):
+                extra = self._sum_extra_scores(tasks)
+                if extra is not None and extra.ndim == 1:
+                    if job_extra is None:
+                        job_extra = np.zeros((j_pad, n_nodes))
+                    job_extra[j] = extra
+                elif extra is not None:
+                    if task_extra is None:
+                        task_extra = np.zeros((t_pad, n_nodes))
+                    task_extra[row:row + len(tasks)] = extra
                 for task in tasks:
                     req, sel, tol = self._task_row(task)
                     if req is None:
@@ -756,34 +811,28 @@ class Session:
                     task_tol[row, :len(tol)] = tol
                     task_job[row] = j
                     row += 1
-            # Bucket the job axis too (KJT001): [J+1] exact would retrace
-            # the allocate kernel per distinct live gang count.  Padding
-            # jobs are gated out (allowed=False) and own no tasks, so the
-            # kernel never reads them; consumers index success[j] for real
-            # jobs only.
-            j_pad = _next_pow2(len(job_chunks) + 1)
-            job_allowed = np.ones(j_pad, bool)
-            job_allowed[len(job_chunks):] = False
 
-            extra = np.zeros((t_pad, n_nodes))
-            self._add_extra_scores(all_tasks, extra)
-            mask = self.compute_hard_mask(all_tasks)
-            mask_pad = None
-            if mask is not None:
-                mask_pad = np.ones((t_pad, n_nodes), bool)
-                mask_pad[:t] = mask
+            mask_pad = _pad_rows(self.compute_hard_mask(all_tasks), t_pad,
+                                 True)
+            _note_operand_forms(
+                operands_span,
+                extras="dense" if task_extra is not None else
+                "row" if job_extra is not None else "none",
+                mask="none" if mask_pad is None else "dense")
 
         node_arrays = self._device_arrays()
 
         def thunk():
-            (d_req, d_job, d_sel, d_tol, d_allowed, d_extra,
-             d_mask) = _stage(task_req, task_job, task_sel, task_tol,
-                              job_allowed, extra, mask_pad)
+            (d_req, d_job, d_sel, d_tol, d_allowed, d_extra, d_mask,
+             d_job_extra) = _stage(task_req, task_job, task_sel, task_tol,
+                                   job_allowed, task_extra, mask_pad,
+                                   job_extra)
             with TRACER.span("seam:launch", kind="seam",
                              kernel="allocate_jobs_multi"):
                 return allocate_jobs_kernel(
                     *node_arrays, d_req, d_job, d_sel, d_tol, d_allowed,
                     d_extra, task_node_mask=d_mask,
+                    job_extra_scores=d_job_extra,
                     gpu_strategy=self.gpu_strategy,
                     cpu_strategy=self.cpu_strategy,
                     allow_pipeline=True, pipeline_only=pipeline_only)
@@ -836,24 +885,25 @@ class Session:
             task_job[t:] = 1  # padding rows: a gated-out dummy job
             job_allowed = np.array([True, False])
 
-            extra = np.zeros((t_pad, n_nodes))
-            self._add_extra_scores(tasks, extra)
+            # None, one [N] row for the whole chunk, or [t, N].
+            extra = self._sum_extra_scores(tasks)
 
             # Hard per-task node masks (inter-pod affinity terms, upstream
             # predicate verdicts): False = infeasible, enforced in-kernel.
             mask = self.compute_hard_mask(tasks)
-            if node_subset is not None:
-                # The topology node subset is a hard mask (matching the
-                # fractional/MIG handlers, which skip out-of-subset nodes
-                # unconditionally): an out-of-subset node is infeasible,
-                # not a soft last resort.  Folded in here once so the
-                # homogeneous fast path and the per-task path share
-                # identical semantics.
-                subset = np.asarray(node_subset, bool)
-                # Read-only broadcast view: downstream only reads mask
-                # (mask_pad[:t] = mask copies; row_mask takes a row view).
-                mask = (np.broadcast_to(subset, (t, n_nodes))
-                        if mask is None else mask & subset[None, :])
+            # The topology node subset is a hard mask too (matching the
+            # fractional/MIG handlers, which skip out-of-subset nodes
+            # unconditionally): an out-of-subset node is infeasible, not
+            # a soft last resort.  It is the job's row, ANDed with the
+            # per-task mask wherever both exist, on every path.
+            subset = (None if node_subset is None
+                      else np.asarray(node_subset, bool))
+            _note_operand_forms(
+                operands_span,
+                extras="none" if extra is None else
+                "row" if extra.ndim == 1 else "dense",
+                mask="dense" if mask is not None else
+                "none" if subset is None else "row")
             # Self-anti-affinity domain rows (spread-one-per-domain gangs).
             anti_dom = None
             for fn in self.anti_domain_fns:
@@ -884,32 +934,49 @@ class Session:
                 and (task_sel[1:t] == task_sel[0]).all()
                 and (task_tol[1:t] == task_tol[0]).all())
             row_extra = row_mask = None
-            if homogeneous and extra.any():
-                row = extra[0]
-                if (extra[1:t] == row).all() and bool(
+            if homogeneous and extra is not None and extra.any():
+                row = extra if extra.ndim == 1 else extra[0]
+                if (extra.ndim == 1 or (extra[1:] == row).all()) and bool(
                         np.all(np.remainder(row, 10.0) == 0.0)):
                     row_extra = row[None, :]
                 else:
                     homogeneous = False
             if homogeneous and mask is not None:
-                if (mask[1:t] == mask[0]).all():
-                    row_mask = mask[0][None, :]
+                if (mask[1:] == mask[0]).all():
+                    row_mask = (mask[:1] if subset is None
+                                else mask[:1] & subset)
                 else:
                     homogeneous = False
+            elif homogeneous and subset is not None:
+                row_mask = subset[None, :]
             # Multi-chip exact kernel (parallel/sharded.py): node axis
             # sharded over the mesh, bit-identical tie-breaks.  Domain
             # rows, extra score terms, and pipeline-only proposals stay
             # on the single-chip kernel (unsupported under shard_map).
             sharded = (not homogeneous and self.mesh is not None
                        and anti_dom is None and aff_dom is None
-                       and not pipeline_only and not np.any(extra))
+                       and not pipeline_only and extra is None)
             operands_span.set(path="grouped" if homogeneous else
                               "sharded" if sharded else "exact")
             if not homogeneous:
-                mask_pad = dom_pad = aff_pad = None
-                if mask is not None:
-                    mask_pad = np.ones((t_pad, n_nodes), bool)
-                    mask_pad[:t] = mask
+                dom_pad = aff_pad = None
+                # A job's rows are [2,N]: row 1 is the padding job's,
+                # read by the padding tasks and never used.
+                job_extra = task_extra = job_mask = None
+                if extra is not None and extra.ndim == 1:
+                    job_extra = np.zeros((2, n_nodes))
+                    job_extra[0] = extra
+                else:
+                    task_extra = _pad_rows(extra, t_pad, 0.0)
+                if subset is not None and sharded:
+                    # The sharded kernel takes no job rows: its [T,N]
+                    # mask is built here, for this path alone.
+                    mask = (np.broadcast_to(subset, (t, n_nodes))
+                            if mask is None else mask & subset)
+                elif subset is not None:
+                    job_mask = np.ones((2, n_nodes), bool)
+                    job_mask[0] = subset
+                mask_pad = _pad_rows(mask, t_pad, True)
                 if anti_dom is not None:
                     doms, marks, avoids = anti_dom
                     d = np.full((t_pad, n_nodes), -1, np.int32)
@@ -989,15 +1056,18 @@ class Session:
         else:
             def thunk():
                 (d_req, d_job, d_sel, d_tol, d_allowed, d_extra, d_mask,
-                 d_dom, d_aff) = _stage(
+                 d_dom, d_aff, d_job_extra, d_job_mask) = _stage(
                     task_req, task_job, task_sel, task_tol, job_allowed,
-                    extra, mask_pad, dom_pad, aff_pad)
+                    task_extra, mask_pad, dom_pad, aff_pad, job_extra,
+                    job_mask)
                 with TRACER.span("seam:launch", kind="seam",
                                  kernel="allocate_jobs"):
                     return allocate_jobs_kernel(
                         *node_arrays, d_req, d_job, d_sel, d_tol,
                         d_allowed, d_extra, task_node_mask=d_mask,
                         task_anti_domain=d_dom, task_aff_domain=d_aff,
+                        job_extra_scores=d_job_extra,
+                        job_node_mask=d_job_mask,
                         gpu_strategy=self.gpu_strategy,
                         cpu_strategy=self.cpu_strategy,
                         allow_pipeline=allow_pipeline,
@@ -1081,7 +1151,9 @@ class Session:
         for fn in self.extra_score_fns:
             contrib = fn([task])
             if contrib is not None:
-                out += np.asarray(contrib)[0]
+                contrib = np.asarray(contrib)
+                # One [N] row for the chunk, or the one task's of [1,N].
+                out += contrib if contrib.ndim == 1 else contrib[0]
         return out
 
     def node_index(self, name: str) -> int:

@@ -60,7 +60,8 @@ def allocate_jobs_kernel(node_allocatable, node_idle, node_releasing,
                          task_req, task_job, task_selector, task_tolerations,
                          job_allowed, task_extra_scores=None,
                          task_node_mask=None, task_anti_domain=None,
-                         task_aff_domain=None,
+                         task_aff_domain=None, job_extra_scores=None,
+                         job_node_mask=None,
                          gpu_strategy: int = BINPACK,
                          cpu_strategy: int = BINPACK,
                          allow_pipeline: bool = True,
@@ -69,11 +70,17 @@ def allocate_jobs_kernel(node_allocatable, node_idle, node_releasing,
 
     job_allowed: [J] bool gate (e.g. queue capacity check, proportion
     capacity_policy) — a gated-out job fails without touching state.
-    task_extra_scores: optional [T,N] additive score terms (topology,
-    nominated node) computed by other kernels.
+    task_extra_scores: optional [T,N] additive score terms whose rows
+    differ by task (nominated node, preferred affinity).
     task_node_mask: optional [T,N] bool hard predicate (inter-pod affinity
     terms against existing pods, upstream-predicate verdicts): a False
     node is infeasible for that task, not merely low-scored.
+    job_extra_scores, job_node_mask: optional [J,N] rows that hold for
+    every task of a job (the topology plugin's preferred-level boosts,
+    the chosen domain's node subset): step ``t`` reads row
+    ``task_job[t]``, added to / ANDed with the per-task row where both
+    are given.  A padding job's row is read and never used: its job is
+    gated out.
     task_anti_domain: optional (dom [T,N] int32, marks [T] bool,
     avoids [T] bool) — in-gang REQUIRED anti-affinity for ONE term.
     ``dom`` maps nodes to the term's topology domains (-1 = no domain);
@@ -94,26 +101,15 @@ def allocate_jobs_kernel(node_allocatable, node_idle, node_releasing,
     pipeline_only: scenario-simulation mode — all placements pipeline
     (statement.go ConvertAllAllocatedToPipelined semantics come free:
     nothing claims idle).
+
+    An optional operand that is None is left out of the step when it is
+    traced; no [T,N] constant stands in for it.
     """
     T = task_req.shape[0]
     N = node_allocatable.shape[0]
-    if task_extra_scores is None:
-        task_extra_scores = jnp.zeros((T, N))
-    if task_node_mask is None:
-        task_node_mask = jnp.ones((T, N), bool)
-    if task_anti_domain is None:
-        anti_dom = jnp.full((T, N), -1, jnp.int32)
-        anti_marks = jnp.zeros(T, bool)
-        anti_avoids = jnp.zeros(T, bool)
-    else:
+    if task_anti_domain is not None:
         anti_dom, anti_marks, anti_avoids = task_anti_domain
-    if task_aff_domain is None:
-        aff_dom = jnp.full((T, N), -1, jnp.int32)
-        aff_marks = jnp.zeros(T, bool)
-        aff_avoids = jnp.zeros(T, bool)
-        aff_static = jnp.ones((T, N), bool)
-        aff_boot = jnp.zeros(T, bool)
-    else:
+    if task_aff_domain is not None:
         aff_dom, aff_marks, aff_avoids, aff_static, aff_boot = \
             task_aff_domain
 
@@ -166,17 +162,27 @@ def allocate_jobs_kernel(node_allocatable, node_idle, node_releasing,
             fit_now = jnp.zeros_like(fit_now)
         feasible = fit_now | (fit_future if (allow_pipeline or pipeline_only)
                               else jnp.zeros_like(fit_future))
-        feasible = feasible & task_node_mask[t] \
-            & ~(anti_avoids[t] & blocked_avoiders) \
-            & ~(anti_marks[t] & blocked_markers)
-        # Required affinity: an avoider needs a matching pod in its domain
-        # — pre-existing (static), placed by this gang (union), or itself
-        # under the first-pod bootstrap rule.
-        aff_ok = aff_static[t] | aff_union | (aff_boot[t] & ~any_marker)
-        feasible = feasible & jnp.where(aff_avoids[t], aff_ok, True)
+        if task_node_mask is not None:
+            feasible = feasible & task_node_mask[t]
+        if job_node_mask is not None:
+            feasible = feasible & job_node_mask[j]
+        if task_anti_domain is not None:
+            feasible = feasible \
+                & ~(anti_avoids[t] & blocked_avoiders) \
+                & ~(anti_marks[t] & blocked_markers)
+        if task_aff_domain is not None:
+            # Required affinity: an avoider needs a matching pod in its
+            # domain — pre-existing (static), placed by this gang (union),
+            # or itself under the first-pod bootstrap rule.
+            aff_ok = aff_static[t] | aff_union \
+                | (aff_boot[t] & ~any_marker)
+            feasible = feasible & jnp.where(aff_avoids[t], aff_ok, True)
         score = score_row(node_allocatable, idle, req, feasible,
                           fit_now, gpu_strategy, cpu_strategy)
-        score = score + task_extra_scores[t]
+        if task_extra_scores is not None:
+            score = score + task_extra_scores[t]
+        if job_extra_scores is not None:
+            score = score + job_extra_scores[j]
         found = ok & jnp.any(feasible)
         best = jnp.argmax(jnp.where(feasible, score, NEG))
         pipelined = found & ~fit_now[best]
@@ -190,19 +196,21 @@ def allocate_jobs_kernel(node_allocatable, node_idle, node_releasing,
         rel = rel - take_rel
         room = room - one_hot.astype(room.dtype)
 
-        # Self-anti-affinity: close the winning node's whole topology
-        # domain to the complementary role for the rest of the gang.
-        dom_row = anti_dom[t]
-        won_dom = dom_row[best]
-        in_dom = found & (won_dom >= 0) & (dom_row == won_dom)
-        blocked_avoiders = blocked_avoiders | (anti_marks[t] & in_dom)
-        blocked_markers = blocked_markers | (anti_avoids[t] & in_dom)
+        if task_anti_domain is not None:
+            # Self-anti-affinity: close the winning node's whole topology
+            # domain to the complementary role for the rest of the gang.
+            dom_row = anti_dom[t]
+            won_dom = dom_row[best]
+            in_dom = found & (won_dom >= 0) & (dom_row == won_dom)
+            blocked_avoiders = blocked_avoiders | (anti_marks[t] & in_dom)
+            blocked_markers = blocked_markers | (anti_avoids[t] & in_dom)
 
-        a_row = aff_dom[t]
-        a_won = a_row[best]
-        a_in_dom = found & (a_won >= 0) & (a_row == a_won)
-        aff_union = aff_union | (aff_marks[t] & a_in_dom)
-        any_marker = any_marker | (aff_marks[t] & found)
+        if task_aff_domain is not None:
+            a_row = aff_dom[t]
+            a_won = a_row[best]
+            a_in_dom = found & (a_won >= 0) & (a_row == a_won)
+            aff_union = aff_union | (aff_marks[t] & a_in_dom)
+            any_marker = any_marker | (aff_marks[t] & found)
 
         ok = ok & found
         out = (jnp.where(found, best, -1).astype(jnp.int32), pipelined, found)

@@ -340,12 +340,11 @@ class TopologySession:
 
     # -- the extra-score extension point -----------------------------------
     def extra_scores(self, tasks):
+        """The job's preferred-level boosts: one [N] row that holds for
+        every task of the chunk (all of one job), or None."""
         if not tasks:
             return None
-        boosts = self._job_node_scores.get(tasks[0].job_id)
-        if boosts is None:
-            return None
-        return np.tile(boosts, (len(tasks), 1))
+        return self._job_node_scores.get(tasks[0].job_id)
 
 
 def _pack_ratio(total_req: np.ndarray, free: np.ndarray) -> float:

@@ -295,32 +295,51 @@ class TestSessionFastPathRouting:
         assert prop.success and len(prop.placements) == 4
         assert len(calls) == 1
 
-    def test_uniform_tier_extra_routes_grouped(self, monkeypatch):
+    # An extra-score fn may return one [N] row for the chunk or [T,N];
+    # a uniform term routes the same in either form.
+    FORMS = {"row": lambda boost, ts: boost,
+             "tiled": lambda boost, ts: np.tile(boost, (len(ts), 1))}
+
+    @pytest.mark.parametrize("form", FORMS)
+    def test_uniform_tier_extra_routes_grouped(self, monkeypatch, form):
         ssn, tasks = self._session()
         n = ssn.node_idle.shape[0]
         boost = np.zeros(n)
         boost[3] = 10000.0
         ssn.extra_score_fns.append(
-            lambda ts: np.tile(boost, (len(ts), 1)))
+            lambda ts: self.FORMS[form](boost, ts))
         calls = self._spy(monkeypatch)
         prop = ssn.propose_placements(tasks)
         assert prop.success
         assert len(calls) == 1
-        assert calls[0].get("extra_scores") is not None
+        assert calls[0]["extra_scores"].shape == (1, n)
         # The boost decides the placement: everything lands on n3.
         assert {p[1] for p in prop.placements} == {"n3"}
 
-    def test_non_tier_extra_falls_back_to_exact(self, monkeypatch):
+    @pytest.mark.parametrize("form", FORMS)
+    def test_non_tier_extra_falls_back_to_exact(self, monkeypatch, form):
         ssn, tasks = self._session()
         n = ssn.node_idle.shape[0]
         boost = np.zeros(n)
         boost[3] = 5.0  # not a multiple of 10: fill-plan parity unsafe
         ssn.extra_score_fns.append(
-            lambda ts: np.tile(boost, (len(ts), 1)))
+            lambda ts: self.FORMS[form](boost, ts))
         calls = self._spy(monkeypatch)
         prop = ssn.propose_placements(tasks)
         assert prop.success
         assert calls == []
+        # The exact kernel still reads the boost, in either form.
+        assert prop.placements[0][1] == "n3"
+
+    @pytest.mark.parametrize("form", FORMS)
+    def test_all_zero_extra_rides_no_operand(self, monkeypatch, form):
+        ssn, tasks = self._session()
+        boost = np.zeros(ssn.node_idle.shape[0])
+        ssn.extra_score_fns.append(
+            lambda ts: self.FORMS[form](boost, ts))
+        calls = self._spy(monkeypatch)
+        assert ssn.propose_placements(tasks).success
+        assert len(calls) == 1 and calls[0]["extra_scores"] is None
 
     def test_per_task_varying_extra_falls_back(self, monkeypatch):
         ssn, tasks = self._session()
@@ -348,6 +367,46 @@ class TestSessionFastPathRouting:
         assert len(calls) == 1
         assert calls[0].get("node_mask") is not None
         assert {p[1] for p in prop.placements} <= {"n4", "n5"}
+
+    def test_node_subset_ands_with_a_uniform_task_mask(self, monkeypatch):
+        """A subset (the job's row) beside a per-task mask whose rows are
+        alike: still the grouped kernel, on the AND of the two."""
+        ssn, tasks = self._session()
+        n = ssn.node_idle.shape[0]
+        subset = np.zeros(n, bool)
+        subset[2:] = True
+        allowed = np.ones(n, bool)
+        allowed[4:] = False
+        ssn.hard_node_mask_fns.append(
+            lambda ts: np.tile(allowed, (len(ts), 1)))
+        calls = self._spy(monkeypatch)
+        prop = ssn.propose_placements(tasks, node_subset=subset)
+        assert prop.success
+        assert len(calls) == 1
+        assert calls[0]["node_mask"].tolist() == [
+            [False, False, True, True, False, False]]
+        assert {p[1] for p in prop.placements} <= {"n2", "n3"}
+
+    def test_node_subset_ands_with_a_varying_task_mask(self, monkeypatch):
+        """The exact kernel ANDs the job's row with the per-task mask."""
+        ssn, tasks = self._session()
+        n = ssn.node_idle.shape[0]
+        subset = np.zeros(n, bool)
+        subset[2:] = True
+
+        def varying_mask(ts):
+            mask = np.ones((len(ts), n), bool)
+            mask[0, :5] = False     # the first task: n5 only
+            return mask
+
+        ssn.hard_node_mask_fns.append(varying_mask)
+        calls = self._spy(monkeypatch)
+        prop = ssn.propose_placements(tasks, node_subset=subset)
+        assert prop.success
+        assert calls == []
+        nodes = [p[1] for p in prop.placements]
+        assert nodes[0] == "n5"
+        assert set(nodes) <= {"n2", "n3", "n4", "n5"}
 
     def test_per_task_varying_mask_falls_back(self, monkeypatch):
         ssn, tasks = self._session()

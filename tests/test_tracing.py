@@ -479,9 +479,11 @@ class TestAllocateSpans:
                        "success": True}
         operands = spans["propose:operands"][0].attrs
         assert operands == {"t": 3, "t_pad": 4, "nodes": 8,
-                            "path": "exact"}
+                            "path": "exact", "extras": "row",
+                            "mask": "row"}
         assert spans["topology:subset_nodes"][0].attrs["nodes_in_first"] == 2
-        assert spans["extra_scores:topology"][0].attrs["bytes"] == 3 * 8 * 8
+        # One [N] row for the gang, not a row a task.
+        assert spans["extra_scores:topology"][0].attrs["bytes"] == 8 * 8
         assert spans["seam:launch"][0].attrs == {"kernel": "allocate_jobs"}
         assert spans["statement:apply"][0].attrs == {"ops": 3}
         assert spans["statement:commit"][0].attrs == {"binds": 3}
@@ -517,7 +519,9 @@ class TestAllocateSpans:
 
     def test_counters_equal_the_sizes_the_shapes_give(self, gang_cycle):
         """Tier-1 runs in x64, where an f64 operand is uploaded as it is:
-        nothing is converted, and the score matrix costs 8 bytes a cell."""
+        nothing is converted, and a score costs 8 bytes.  The gang's
+        boosts and its subset are one [N] row a job (the gang and the
+        padding job), never a row a task."""
         trace, moved, ssn = gang_cycle
         t_pad, n = 4, 8
         snap = ssn.snapshot
@@ -526,13 +530,14 @@ class TestAllocateSpans:
                 + t_pad * snap.task_selector.shape[1] * 4
                 + t_pad * snap.task_tolerations.shape[1] * 4
                 + 2)                                          # job_allowed
-        dense = t_pad * n * (8 + 1)      # extra f64 + the subset mask
+        job_rows = 2 * n * (8 + 1)       # boosts f64 + the subset mask
+        assert job_rows < 3 * n * (8 + 1)
         stage = by_name(trace)["seam:stage"][0].attrs
-        assert moved == {"device_upload_bytes": rows + dense,
+        assert moved == {"device_upload_bytes": rows + job_rows,
                          "host_convert_bytes": 0,
                          "device_download_bytes": (2 * t_pad + 2) * 4}
-        assert stage == {"bytes_host": rows + dense,
-                         "bytes_device": rows + dense,
+        assert stage == {"bytes_host": rows + job_rows,
+                         "bytes_device": rows + job_rows,
                          "bytes_converted": 0, "operands": 7}
         assert by_name(trace)["seam:download"][0].attrs["bytes"] \
             == moved["device_download_bytes"]
