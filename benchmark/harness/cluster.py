@@ -162,7 +162,8 @@ def gang_size(traffic: dict) -> int:
 
 
 def padded(t: int) -> int:
-    """The exact kernel's task axis: the next power of two."""
+    """A kernel's padded axis (the exact kernel's tasks, the prescreen's
+    prefixes and rows): the next power of two."""
     t_pad = 1
     while t_pad < t:
         t_pad *= 2

@@ -13,9 +13,14 @@ Kinds, and what they read:
   trace_program_time  device time of the programs matching ``match`` in
                       the profiler's trace, per traced cycle, in ms
   trace_idle          1 - device busy time / traced window, in %
-  roofline            least time the chip needs for the bytes
-                      ``benchmark/roofline.py`` computes, over the traced
-                      time of the programs matching ``match``, in %
+  roofline            least time the chip needs for the bytes that the
+                      byte count ``model`` computes from the shapes the
+                      cell's generator gives for it
+                      (``run["kernel_shapes"][model]``), over the traced
+                      time of the programs matching ``match``, in %.  The
+                      count is looked for in the cell's generator module
+                      first, then in ``benchmark/roofline.py``: a cell on
+                      another kernel brings its count as a new file
 """
 
 from __future__ import annotations
@@ -101,8 +106,12 @@ def roofline(reader, run):
     secs = tr.program_seconds(red, reader["match"])
     if not secs:
         return None
-    model = getattr(rf, reader["model"])
-    need = model(**run["kernel_shape"]) * run["traced_cycles"]
+    name = reader["model"]
+    shape = run.get("kernel_shapes", {}).get(name)
+    if shape is None:
+        return None
+    model = getattr(run.get("generator"), name, None) or getattr(rf, name)
+    need = model(**shape) * run["traced_cycles"]
     peak = rf.peaks(run["device_kind"])[reader["peak"]]
     return 100.0 * (need / peak) / secs
 

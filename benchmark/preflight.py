@@ -1,14 +1,21 @@
 """Reckon every cell's device bytes before any run, on the CPU sandbox.
 
-The driver refuses a cell whose fullest device peaks under a quarter of a
-chip's memory (4.0 GiB of a v5e's 16), and a chip run that finds that out
-has already been paid for.  So before a cell is asked for: reckon what its
-kernel holds on the device from the configuration and traffic files, and,
-where the TPU compiler can describe a ``v5e:2x2`` chip here, compile the
-kernel at its timed shape and read ``memory_analysis()``.  Exits non-zero
-where a cell is under the floor.  Costs no chip time.
+The driver refuses a NEW cell whose fullest device peaks under a quarter
+of a chip's memory (4.0 GiB of a v5e's 16), and a chip run that finds that
+out has already been paid for.  So before a cell is asked for: its
+generator reckons, from the configuration and traffic files, what its
+cycle holds on the device (``reckon``), and, where the TPU compiler can
+describe a ``v5e:2x2`` chip here, compiles the cycle's kernel at its timed
+shape (``compile_for``), whose ``memory_analysis()`` is printed.  Costs no
+chip time.
 
     JAX_PLATFORMS=cpu python3 benchmark/preflight.py [--no-compile]
+        [--workload <cell> ...] [--root <dir with BENCHMARK.json>]
+
+Every cell is printed.  The exit code judges the cells named with
+``--workload``, which is how the author of a new cell calls it: non-zero
+where one of them is under the floor.  An admitted cell is not held to
+the floor, so with none named the exit code is 0.
 """
 
 from __future__ import annotations
@@ -25,35 +32,14 @@ GIB = 2.0 ** 30
 FLOOR_BYTES = 4.0 * GIB
 
 
-def cell_shape(cell) -> dict:
-    from benchmark.harness import cluster as gen
-    t = gen.gang_size(cell.traffic)
-    return {"t": t, "t_pad": gen.padded(t),
-            "nodes": int(cell.config["nodes"]["count"]),
-            "has_mask": bool(cell.traffic["gang"].get("topology"))}
-
-
-def reckoned_bytes(shape: dict) -> float:
-    """The exact kernel's dense matrices over the gang's own rows (the
-    rows that padding to a power of two adds hold nothing and are not
-    counted): the f32 score matrix, the bool hard mask where the gang has
-    a node subset, and the node tables."""
-    cells = shape["t"] * shape["nodes"]
-    return cells * 4 + (cells if shape["has_mask"] else 0) \
-        + shape["nodes"] * 4 * (3 * 3 + 3)
-
-
-def compiled_bytes(shape: dict):
-    """arguments + outputs + temporaries of ``allocate_jobs_kernel`` at
-    the cell's shape, compiled for a described v5e chip; None where the
-    topology cannot be described here."""
+def described_chip():
+    """``sds(shape, dtype)`` making 32-bit operands on one described v5e
+    chip; None where the topology cannot be described here."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
-    import jax.numpy as jnp
+    import numpy as np
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
-
-    from kai_scheduler_tpu.ops.allocate import allocate_jobs_kernel
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
@@ -61,49 +47,59 @@ def compiled_bytes(shape: dict):
         print(f"  (no v5e:2x2 topology can be described here: {exc})")
         return None
     chip = SingleDeviceSharding(topo.devices[0])
+    narrow = {np.dtype(np.float64): np.float32, np.dtype(np.int64): np.int32}
 
-    def sds(shape_, dtype):
-        return jax.ShapeDtypeStruct(shape_, dtype, sharding=chip)
+    def sds(shape, dtype):
+        dtype = np.dtype(dtype)
+        return jax.ShapeDtypeStruct(shape, narrow.get(dtype, dtype),
+                                    sharding=chip)
+    return sds
 
-    n, t = shape["nodes"], shape["t_pad"]
-    f32, i32 = jnp.float32, jnp.int32
-    compiled = allocate_jobs_kernel.lower(
-        sds((n, 3), f32), sds((n, 3), f32), sds((n, 3), f32),
-        sds((n, 1), i32), sds((n, 1), i32), sds((n,), f32),
-        sds((t, 3), f32), sds((t,), i32), sds((t, 1), i32), sds((t, 1), i32),
-        sds((2,), jnp.bool_), sds((t, n), f32),
-        task_node_mask=sds((t, n), jnp.bool_) if shape["has_mask"] else None,
-        gpu_strategy=0, cpu_strategy=0, allow_pipeline=True,
-        pipeline_only=False).compile()
-    m = compiled.memory_analysis()
-    return (m.argument_size_in_bytes + m.output_size_in_bytes
-            + m.temp_size_in_bytes)
+
+def compiled_bytes(cell, sds) -> float:
+    """arguments + outputs + temporaries of the cell's kernel."""
+    m = cell.generator.compile_for(cell, sds).memory_analysis()
+    return float(m.argument_size_in_bytes + m.output_size_in_bytes
+                 + m.temp_size_in_bytes)
+
+
+def size(b: float) -> str:
+    return f"{b / GIB:.2f} GiB" if b >= 0.01 * GIB else f"{b / 1e6:.1f} MB"
 
 
 def main(argv=None) -> int:
     from benchmark.harness import spec
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--no-compile", action="store_true")
+    ap.add_argument("--workload", action="append", default=[],
+                    help="a cell the exit code judges (may repeat)")
+    ap.add_argument("--root", default=ROOT,
+                    help="the directory that holds BENCHMARK.json")
     args = ap.parse_args(argv)
-    bench = spec.load_benchmark()
+    bench = spec.load_benchmark(args.root)
+    names = [w["name"] for w in bench["workloads"]]
+    for name in args.workload:
+        if name not in names:
+            raise SystemExit(f"unknown workload {name!r}; BENCHMARK.json "
+                             f"has {sorted(names)}")
+    sds = None if args.no_compile else described_chip()
     bad = 0
-    for w in bench["workloads"]:
-        cell = spec.Cell(bench, w["name"])
-        shape = cell_shape(cell)
-        reck = reckoned_bytes(shape)
-        line = (f"{w['name']}: allocate_jobs_kernel {shape['t_pad']} x "
-                f"{shape['nodes']} ({shape['t']} rows live), "
-                f"mask={shape['has_mask']}: reckoned live "
-                f"{reck / GIB:.2f} GiB")
-        smallest = reck
-        if not args.no_compile:
-            comp = compiled_bytes(shape)
-            if comp is not None:
-                line += f", compiled for v5e {comp / GIB:.2f} GiB"
-                smallest = min(smallest, comp)
-        ok = smallest >= FLOOR_BYTES
-        print(line + ("" if ok else "  UNDER THE 4.00 GiB FLOOR"))
-        bad += not ok
+    for name in names:
+        cell = spec.Cell(bench, name, args.root)
+        reck = cell.generator.reckon(cell)
+        line = f"{name}: {reck['what']}: reckoned {size(reck['bytes'])}"
+        smallest = reck["bytes"]
+        if sds is not None:
+            comp = compiled_bytes(cell, sds)
+            line += f", compiled for v5e {size(comp)}"
+            smallest = min(smallest, comp)
+        if smallest < FLOOR_BYTES:
+            judged = name in args.workload
+            line += ("  UNDER THE 4.00 GiB FLOOR" if judged else
+                     "  (under the 4.00 GiB floor for a new cell, which "
+                     "an admitted cell is not held to)")
+            bad += judged
+        print(line)
     return 1 if bad else 0
 
 

@@ -3,7 +3,10 @@
 One run is one process: build the fleet from the seed, prime the cell's
 kernel, warm cycles, the measured window, the comparison that decides
 ``correct``, and one JSON object as the last line of standard output.
-See ``benchmark/README.md`` for the run's anatomy.
+What belongs to the cell (the client of the loop, the prime, the
+comparison, its kernels' shapes) is the generator's that the cell's traffic
+file names; this file keeps what every cell shares.  See
+``benchmark/README.md`` for the run's anatomy.
 """
 
 from __future__ import annotations
@@ -62,22 +65,23 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     except ImportError as exc:
         log(f"the scheduler is not in this checkout: {exc}")
         raise SystemExit(4)
-    from benchmark.harness import compare, loop, readers, spec
+    from benchmark.harness import loop, readers, spec
     from benchmark.harness import trace as tr
 
     cell = spec.Cell(spec.load_benchmark(root), workload, root)
+    gen = cell.generator
     device = device_info(cell.chips, require_chip)
     cache_dir = enable_compile_cache()
     watch = loop.CompileWatch()
     wall = {"import_s": time.perf_counter() - T_PROCESS}
 
     t = time.perf_counter()
-    client = loop.Client(cell.config, cell.traffic, seed,
-                         counters=readers.counters_wanted(cell.per_layer))
+    client = gen.build(cell, seed,
+                       counters=readers.counters_wanted(cell.per_layer))
     wall["build_s"] = time.perf_counter() - t
 
     t = time.perf_counter()
-    primed = loop.prime(client, watch)
+    primed = gen.prime(client, watch)
     wall["prime_s"] = time.perf_counter() - t
 
     guard0 = loop.guard_counters()
@@ -86,8 +90,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     for _ in range(int(cell.traffic.get("warm_cycles", 1))):
         c0 = watch.snapshot()
         client.cycle()
-        c1 = watch.snapshot()
-        warm.append({k: c1[k] - c0[k] for k in ("compiles", "misses")})
+        warm.append(watch.since(c0))
     wall["warm_s"] = time.perf_counter() - t
     guard_warm = loop.guard_counters()
 
@@ -100,41 +103,34 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
                           trace_cycles=TRACE_CYCLES if trace else 0,
                           trace_dir=TRACE_DIR if trace else None)
     wall["window_s"] = window["elapsed_s"]
-    compiles1 = watch.snapshot()
+    in_window = watch.since(compiles0)
     guard1 = loop.guard_counters()
     device["memory_peak_bytes"] = memory_peak(cell.chips)
 
     records = client.records[window["first"]:]
     ledger = client.ledger
+    kernel_shapes = gen.kernel_shapes(client)
     client.close()
 
     t = time.perf_counter()
-    numbers = compare.compare(records, ledger, cell.config)
-    correct, compared = compare.verdict(numbers)
+    verdict = gen.compare(records, ledger, cell)
     wall["compare_s"] = time.perf_counter() - t
 
-    attempted = len(records)
-    bound_pods = sum(len(r.gang.bound) for r in records)
-    failed = sum(1 for r in records
-                 if len(r.gang.bound) != len(r.gang.names))
+    cycles = len(records)
+    attempted, failed = verdict["attempted"], verdict["failed"]
     guard_moved = loop.moved(guard0, guard1)
-    window_compiles = compiles1["compiles"] - compiles0["compiles"]
+    window_compiles = in_window["compiles"]
     if guard_moved or window_compiles:
         # The guard fell back, timed out or refused a result, or something
-        # compiled inside the window: no cycle of this run counts.
+        # compiled inside the window: nothing this run attempted counts.
         failed = attempted
     elapsed = window["elapsed_s"]
 
-    result = {"correct": bool(correct), "attempted": attempted,
+    result = {"correct": bool(verdict["correct"]), "attempted": attempted,
               "failed": failed}
     run = {"records": records, "device_kind": device["kind"],
            "traced_cycles": window["traced_cycles"],
-           "kernel_shape": {"steps": len(records[0].gang.names),
-                            "nodes": primed["nodes"],
-                            "resources": primed["resources"],
-                            "has_mask": bool(records[0].gang.topology),
-                            "label_cols": primed["label_cols"],
-                            "taint_cols": primed["taint_cols"]}}
+           "generator": gen, "kernel_shapes": kernel_shapes}
     if trace:
         t = time.perf_counter()
         path = tr.find_xplane(TRACE_DIR)
@@ -154,8 +150,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
     else:
         values = {
-            "cycle_ms": 1e3 * elapsed / attempted,
-            "pods_bound_per_s": bound_pods / elapsed,
+            "cycle_ms": 1e3 * elapsed / cycles,
+            "pods_bound_per_s": verdict["bound_pods"] / elapsed,
             "setup_s": setup_s,
         }
         result["metrics"] = {
@@ -165,19 +161,19 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     wall["total_s"] = time.perf_counter() - T_PROCESS
     result["run"] = {
         "wall_s": {k: round(v, 3) for k, v in wall.items()},
-        "cycles_in_window": attempted, "cycle_s": window["cycle_s"],
+        "cycles_in_window": cycles, "cycle_s": window["cycle_s"],
         "compile_cache": cache_dir,
+        "generator": cell.traffic["generator"],
+        "reference": cell.config["reference"],
+        "scheduler": cell.config["scheduler"],
         "primed": primed, "warm_cycles": warm,
         "window_compiles": window_compiles,
+        "window_compiled": in_window["compiled"],
         "guard_moved_in_warm": loop.moved(guard0, guard_warm),
-        "guard_moved": guard_moved,
-        "gangs": numbers["gangs"],
-        "gang_roles": [{"name": r["name"], "count": int(r["count"])}
-                       for r in cell.traffic["gang"]["roles"]],
-        "placements_checked": numbers["placements_checked"]}
+        "guard_moved": guard_moved, **verdict["run"]}
     if trace:
         result["run"]["trace_bytes"] = trace_bytes
-    result["compared"] = compared
+    result["compared"] = verdict["compared"]
     return result
 
 

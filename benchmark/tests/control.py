@@ -36,11 +36,11 @@ KINDS = ("stale", "no_topology", "no_queue_limit")
 def run_control(workload: str, seed: int, kind: str = "stale",
                 cycles: int = 2, root: str = ROOT) -> dict:
     from benchmark.harness import cluster as gen
-    from benchmark.harness import compare, loop, spec
-    from benchmark.reference import placement as ref
+    from benchmark.harness import spec
 
     cell = spec.Cell(spec.load_benchmark(root), workload, root)
-    client = loop.Client(cell.config, cell.traffic, seed)
+    ref = cell.reference
+    client = cell.generator.build(cell, seed)
     ledger = client.ledger
 
     def control_cycle():
@@ -49,7 +49,7 @@ def run_control(workload: str, seed: int, kind: str = "stale",
         gang = client.pending_gang
         state = (ledger.capacity, ledger.used, ledger.pods, ledger.max_pods,
                  gang.req)
-        levels = compare.level_order(cell.config, gang.topology)
+        levels = cell.generator.level_order(cell.config, gang.topology)
         if kind == "no_topology":
             nodes = ref.place_gang(*state)
         elif kind == "no_queue_limit":
@@ -70,10 +70,9 @@ def run_control(workload: str, seed: int, kind: str = "stale",
     client.sched.run_once = control_cycle
     for _ in range(cycles):
         client.cycle()
-    numbers = compare.compare(client.records, ledger, cell.config)
-    correct, compared = compare.verdict(numbers)
+    verdict = cell.generator.compare(client.records, ledger, cell)
     return {"workload": workload, "seed": seed, "control": kind,
-            "correct": correct, "compared": compared}
+            "correct": verdict["correct"], "compared": verdict["compared"]}
 
 
 def main(argv=None) -> int:

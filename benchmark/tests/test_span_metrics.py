@@ -10,7 +10,7 @@ import pytest
 
 from conftest import DATA
 
-from benchmark.harness import loop, readers, spec
+from benchmark.harness import readers, spec
 
 SPAN_METRICS = ("topology_ms", "operands_ms", "statement_ms", "stage_ms",
                 "device_wait_ms")
@@ -32,8 +32,8 @@ def cycle(real_cell):
     import jax
     assert not jax.config.jax_enable_x64
     tiny = spec.Cell(spec.load_benchmark(DATA), "tiny-tas-gang", DATA)
-    client = loop.Client(
-        tiny.config, tiny.traffic, 3000000019,
+    client = tiny.generator.build(
+        tiny, 3000000019,
         counters=readers.counters_wanted(real_cell.per_layer))
     client.cycle()
     return client.cycle()
@@ -76,25 +76,28 @@ def test_each_reader_returns_a_number_on_the_fixtures_cycle(real_cell,
 
 
 def test_the_counts_are_what_the_fixtures_shapes_give(real_cell, cycle):
-    """256 pods (no padding row) against 1,024 nodes, 32-bit: the score
-    matrix is built in f64 and narrowed at the seam, the mask is bool."""
+    """256 pods (no padding row) against 1,024 nodes, 32-bit, in the row
+    form (since PR 26): the task rows and the job's ``[2,N]`` score rows
+    (built in f64, narrowed at the seam) and bool mask rows go up, one
+    packed int32 result comes down."""
     rec = cycle
     stage = [s for s in rec.spans if s[0] == "seam:stage"]
     assert len(stage) == 1
-    t_pad, n = 256, 1024
-    dense_device = t_pad * n * (4 + 1)
-    dense_host_f64 = t_pad * n * 8
-    up = rec.counters["device_upload_bytes"]
-    conv = rec.counters["host_convert_bytes"]
-    # The task rows: [t_pad, R] f64 requests (converted) and the int32
-    # job, selector and toleration rows, a few bytes a pod.
-    rows_device = up - dense_device
-    rows_f64 = conv - dense_host_f64
-    assert 0 < rows_device < 64 * t_pad
-    assert 0 < rows_f64 < 64 * t_pad and rows_f64 % (8 * t_pad) == 0
-    assert rows_device >= rows_f64 // 2 + 4 * t_pad + 2
+    t_pad, n, r = 256, 1024, 3
+    # Task rows: [t_pad, R] requests, the job, selector and toleration
+    # columns (one each), and the two jobs' allowed flags.
+    task_rows = t_pad * 4 * (r + 1 + 1 + 1) + 2
+    job_rows = 2 * n * 4 + 2 * n
+    assert rec.counters["device_upload_bytes"] == task_rows + job_rows
+    # Narrowed on the host: the f64 requests and the f64 score rows.
+    assert rec.counters["host_convert_bytes"] == t_pad * r * 8 + 2 * n * 8
     # One packed int32 result: placements ++ pipelined ++ job_success.
     assert rec.counters["device_download_bytes"] == (2 * t_pad + 2) * 4
+    # The generator reckons the same operands from the files alone.
+    tiny = spec.Cell(spec.load_benchmark(DATA), "tiny-tas-gang", DATA)
+    reck = tiny.generator.reckon(tiny)
+    tables = n * 4 * (3 * r + 1 + 1 + 1)
+    assert reck["bytes"] == tables + (task_rows - 2) + job_rows
 
 
 def test_counts_repeat_exactly_across_seeds(real_cell):
@@ -102,8 +105,7 @@ def test_counts_repeat_exactly_across_seeds(real_cell):
     wanted = readers.counters_wanted(real_cell.per_layer)
     seen = set()
     for seed in (1, 2):
-        client = loop.Client(tiny.config, tiny.traffic, seed,
-                             counters=wanted)
+        client = tiny.generator.build(tiny, seed, counters=wanted)
         rec = client.cycle()
         seen.add((rec.counters["device_upload_bytes"],
                   rec.counters["host_convert_bytes"],
