@@ -15,7 +15,7 @@ import numpy as np
 
 from . import resources as rs
 from .node_info import NodeInfo
-from .pod_status import PodStatus
+from .pod_status import PodStatus, is_active_allocated
 from .podgroup_info import PodGroupInfo
 from .queue_info import QueueInfo
 
@@ -157,8 +157,14 @@ class ClusterInfo:
         must not pay it twice.  Memoized until the next snapshot build or
         the next Statement mutation (which calls invalidate_aggregates)."""
         cached = getattr(self, "_queue_aggregates", None)
-        if cached is not None:
-            return cached
+        if cached is None:
+            cached = self._queue_aggregates = (
+                self._aggregates_by_count() or self._aggregates_in_turn())
+        return cached
+
+    def _aggregates_in_turn(self) -> tuple[dict, dict]:
+        """One vector addition a pod, in the walk's order: what the sums
+        are defined as, whatever the requests look like."""
         min_gpu_mem = self.min_node_gpu_memory()
         allocated = {qid: rs.zeros() for qid in self.queues}
         requested = {qid: rs.zeros() for qid in self.queues}
@@ -176,8 +182,47 @@ class ClusterInfo:
                     requested[qid] += t.req_vec(min_gpu_mem)
                 elif t.status == PodStatus.PENDING:
                     requested[qid] += t.req_vec(min_gpu_mem)
-        self._queue_aggregates = (allocated, requested)
-        return self._queue_aggregates
+        return allocated, requested
+
+    def _aggregates_by_count(self) -> tuple[dict, dict] | None:
+        """The same sums where they are exact in any order: the pods of a
+        gang share a handful of requirement objects, so the walk counts
+        pods per object and adds ``count * vector`` once.  That equals the
+        additions in turn to the bit only while every vector is made of
+        whole non-negative numbers and every total stays under 2**53
+        (milli-cores, bytes, whole GPUs); a fractional or gpu-memory
+        request anywhere returns None and the sums are taken in turn."""
+        pending = PodStatus.PENDING
+        allocated = {qid: rs.zeros() for qid in self.queues}
+        requested = {qid: rs.zeros() for qid in self.queues}
+        for pg in self.podgroups.values():
+            qid = pg.queue_id
+            if qid not in allocated:
+                continue
+            counts: dict = {}     # id(requirements) -> [them, active, pending]
+            for t in pg.pods.values():
+                status = t.status
+                if is_active_allocated(status):
+                    slot = 1
+                elif status == pending:
+                    slot = 2
+                else:
+                    continue
+                req = t.res_req
+                entry = counts.get(id(req))
+                if entry is None:
+                    entry = counts[id(req)] = [req, 0, 0]
+                entry[slot] += 1
+            for req, active, waiting in counts.values():
+                vec = req.to_vec()
+                if req.gpu_memory_bytes > 0.0 or (vec < 0.0).any() \
+                        or (vec != np.floor(vec)).any():
+                    return None
+                allocated[qid] += active * vec
+                requested[qid] += (active + waiting) * vec
+        if any((total >= 2.0 ** 53).any() for total in requested.values()):
+            return None
+        return allocated, requested
 
     def min_node_gpu_memory(self) -> float:
         """Smallest per-GPU memory across nodes that report one — the

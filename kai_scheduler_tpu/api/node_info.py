@@ -10,6 +10,7 @@ host-side (SURVEY.md §7 "hard parts").
 
 from __future__ import annotations
 
+import itertools
 import uuid
 from dataclasses import dataclass, field
 
@@ -18,6 +19,10 @@ import numpy as np
 from . import resources as rs
 from .pod_info import PodInfo
 from .pod_status import PodStatus
+
+# Stamps for ``NodeInfo.version``: one process-wide, ever-growing series,
+# so no two node objects and no two states of one object share a stamp.
+_VERSIONS = itertools.count(1)
 
 
 @dataclass
@@ -76,6 +81,22 @@ class NodeInfo:
         # (node_info.go:91 AccessibleStorageCapacities, populated by
         # api/storage_info.link_storage_objects).
         self.accessible_capacities: dict[str, list] = {}
+        self.version = next(_VERSIONS)
+
+    def touch(self) -> None:
+        """Note that what ``api/snapshot.pack`` reads of this node moved:
+        ``used``, ``releasing``, the pod set, or which buffer ``used`` and
+        ``releasing`` are views of.  ``add_task``/``remove_task`` stamp
+        themselves; code that writes those fields another way calls this.
+
+        ``version`` is the record: a reader that kept the stamp it saw
+        finds the node changed when the stamp differs (a replaced object
+        never carries an old stamp), and reading takes nothing away, so
+        any number of readers can hold a baseline (framework/arena.py
+        ``HostArena``).  ``allocatable``, ``labels``, ``taints`` and
+        ``max_pods`` do not change on a live node: a new Node manifest is
+        a new object."""
+        self.version = next(_VERSIONS)
 
     # -- derived quantities ------------------------------------------------
     @property
@@ -105,6 +126,7 @@ class NodeInfo:
         n.mig_used = {}
         n.mig_releasing = {}
         n.accessible_capacities = {}
+        n.version = next(_VERSIONS)
         return n
 
     def clone(self) -> "NodeInfo":
@@ -149,6 +171,7 @@ class NodeInfo:
             self.used += req
             self._mig_account(task, used=+1)
         self.pod_infos[task.uid] = task
+        self.version = next(_VERSIONS)
         self._add_task_storage(task)
         if task.is_fractional and task.gpu_group:
             self._add_to_gpu_group(task)
@@ -166,6 +189,7 @@ class NodeInfo:
             self.used -= req
             self._mig_account(task, used=-1)
         self.pod_infos.pop(task.uid, None)
+        self.version = next(_VERSIONS)
         self._remove_task_storage(task)
         if task.is_fractional and task.gpu_group:
             self._remove_from_gpu_group(task)

@@ -143,6 +143,35 @@ def build_codec(cluster: ClusterInfo,
     return codec
 
 
+def vocabulary_signature(cluster: ClusterInfo) -> tuple:
+    """What of the cluster the label codec, the codec-derived widths and
+    the label and taint rows of the nodes are made from, in the order
+    ``pack`` meets it: every pod that carries a selector or a toleration,
+    and every node that carries a taint or a label some pod selects on.
+
+    Two packs of one cluster whose signatures are equal share the codec,
+    ``max_tols``, ``max_taints``, ``node_labels`` and ``node_taints``:
+    the proof ``pack_incremental`` wants of a caller that has no watch
+    stream to tell it (framework/arena.py ``HostArena``).  A fleet that
+    selects on nothing and taints nothing reads ``((), ())`` after two
+    plain walks; an unequal signature costs a full pack, never a wrong
+    one."""
+    pods = [
+        (t.uid, tuple(t.node_selector.items()), tuple(sorted(t.tolerations)))
+        for pg in cluster.podgroups.values() for t in pg.pods.values()
+        if t.node_selector or t.tolerations]
+    keys = {k for _uid, selector, _tols in pods for k, _v in selector}
+    # Without a selector anywhere no label has a column: taints alone.
+    nodes = [
+        (name, tuple((k, v) for k, v in node.labels.items() if k in keys),
+         tuple(node.taints))
+        for name, node in cluster.nodes.items()
+        if node.taints or node.labels] if keys else [
+        (name, (), tuple(node.taints))
+        for name, node in cluster.nodes.items() if node.taints]
+    return pods, nodes
+
+
 def _select_jobs(cluster: ClusterInfo,
                  jobs: list[PodGroupInfo] | None) -> list[PodGroupInfo]:
     if jobs is None:
@@ -357,7 +386,8 @@ def pack_incremental(cluster: ClusterInfo, prev: SnapshotTensors,
 
     Bit-identical to ``pack(cluster, queue_usage=..., pad_nodes_to=...)``
     under the caller's preconditions (ClusterArena verifies them from the
-    watch-event-derived dirty state before calling):
+    watch-event-derived dirty state before calling, HostArena from the
+    ``NodeInfo.version`` stamps and ``vocabulary_signature``):
 
     - the node set and order are unchanged and no Node object changed
       (else: topology change, full rebuild);
@@ -394,13 +424,17 @@ def pack_incremental(cluster: ClusterInfo, prev: SnapshotTensors,
     node_alloc = prev.node_allocatable
     rows = sorted(cluster.nodes[nm].idx for nm in dirty_nodes
                   if nm in cluster.nodes)
-    for i in rows:
-        nd = cluster.nodes[node_names[i]]
-        # Same float expressions as the vectorized full-pack fill —
-        # elementwise identical on identical inputs.
-        node_idle[i] = node_alloc[i] - nd.used
-        node_rel[i] = nd.releasing
-        node_room[i] = max(0, nd.max_pods - len(nd.pod_infos))
+    if rows:
+        # The full pack's own fill over the dirty rows alone: the same
+        # stacked gathers and the same float expressions, elementwise
+        # identical on identical inputs.
+        objs = [cluster.nodes[node_names[i]] for i in rows]
+        node_idle[rows] = node_alloc[rows] - np.stack(
+            [nd.used for nd in objs])
+        node_rel[rows] = np.stack([nd.releasing for nd in objs])
+        node_room[rows] = np.fromiter(
+            (max(0, nd.max_pods - len(nd.pod_infos)) for nd in objs),
+            float, count=len(rows))
 
     if reuse_tasks \
             and [pg.uid for pg in jobs] == prev.job_uids \

@@ -34,6 +34,13 @@ transitions so degraded mode never reads a stale TPU buffer
 (docs/DEGRADATION.md).  The arena is single-writer: only the scheduler
 thread that runs the cycle touches it, like the Session mirrors it backs.
 
+The host half also serves a scheduler whose cache is not a
+``ClusterCache``: ``HostArena`` (one per ``Scheduler``) carries the packed
+arrays, the native node table and the name index from one session to the
+next and reads what moved off the ``NodeInfo`` stamps instead of a watch
+stream (docs/DESIGN.md section 8).  The device half stays with
+``ClusterArena``.
+
 Observability: ``snapshot_delta``/``arena_scatter`` tracing spans,
 ``snapshot_delta_ratio`` gauge, ``arena_full_rebuild_total`` /
 ``arena_scatter_rows`` / ``arena_device_invalidation_total`` counters, and
@@ -43,10 +50,13 @@ pack stats on ``GET /debug/cycles`` (docs/OBSERVABILITY.md).
 from __future__ import annotations
 
 import time
+import weakref
+from operator import attrgetter
 
 import numpy as np
 
-from ..api.snapshot import SnapshotTensors, pack, pack_incremental
+from ..api.snapshot import (SnapshotTensors, pack, pack_incremental,
+                            vocabulary_signature)
 from ..utils.logging import LOG
 from ..utils.metrics import METRICS
 from ..utils.tracing import TRACER
@@ -57,6 +67,20 @@ from .session import _next_pow2
 # streams).  Conservative midpoint; the bench's steady_state config
 # measures the real crossover per deployment.
 SCATTER_MAX_FRACTION = 0.5
+
+# Above this fraction of dirty rows ``HostArena`` packs from scratch.  The
+# patch gathers ``used``, ``releasing`` and the pod count of every dirty
+# node as the full pack does of every node, and re-binds it, on top of a
+# fixed scan of the stamps and the vocabulary; what it saves is the static
+# half (allocatable, labels, taints, codec) and the clean rows.  At 65,536
+# nodes a patched session costs about 85 + 250 f ms at dirty fraction f
+# and a session from scratch 305 (CPU sandbox, an ordering, PERF.md
+# section 6, PR 30): they cross near 0.9, and a full pack renews the
+# whole baseline besides.
+DELTA_MAX_FRACTION = 0.75
+
+_IDX = attrgetter("idx")
+_VERSION = attrgetter("version")
 
 
 class GuardWatch:
@@ -210,6 +234,189 @@ class DeviceStateCache:
         return self._dev
 
 
+def _delta_or_full(cluster, prev, reason, dirty_nodes, queue_usage,
+                   pad_nodes_to, reuse_tasks) -> tuple:
+    """The pack itself, once an arena has settled its ``reason``: patch
+    ``prev`` where there is none, pack from scratch where there is one or
+    where the patch cannot be applied.  Returns ``(tensors, changed rows
+    or None after a full pack, reason)``."""
+    if reason is None:
+        try:
+            snap, rows = pack_incremental(
+                cluster, prev, dirty_nodes, queue_usage=queue_usage,
+                pad_nodes_to=pad_nodes_to, reuse_tasks=reuse_tasks)
+            return snap, rows, None
+        except Exception as exc:
+            # A delta that cannot be applied must degrade to a rebuild,
+            # never crash the cycle; the property suite keeps this branch
+            # honest (it asserts delta packs DO happen, so a silent
+            # always-fallback would fail).
+            LOG.warning("arena: incremental pack failed (%r); "
+                        "falling back to full rebuild", exc)
+            reason = "delta-error"
+    snap = pack(cluster, queue_usage=queue_usage, pad_nodes_to=pad_nodes_to)
+    METRICS.inc("arena_full_rebuild_total")
+    return snap, None, reason
+
+
+def _verdict(cluster, rows, reason, generation: int, t0: float) -> dict:
+    """The pack's verdict as ``run_once`` copies it onto the ``snapshot``
+    span and ``/debug/cycles`` shows it; sets ``snapshot_delta_ratio``."""
+    n = max(1, len(cluster.node_order))
+    ratio = 1.0 if rows is None else len(rows) / n
+    METRICS.set_gauge("snapshot_delta_ratio", ratio)
+    return {
+        "full_rebuild": rows is None,
+        "reason": reason or "",
+        "changed_rows": (n if rows is None else int(len(rows))),
+        "total_rows": n,
+        "delta_ratio": round(ratio, 6),
+        "generation": generation,
+        "pack_s": round(time.perf_counter() - t0, 6),
+    }
+
+
+class HostArena:
+    """The host half of the arena, for a scheduler whose cache brings no
+    ``ClusterArena``: the previous session's packed arrays, native node
+    table and name-to-row index are carried to the next session over the
+    same ``ClusterInfo`` object and patched on the rows that moved.
+
+    Nobody tells this arena what changed: it reads it off the objects.
+    ``NodeInfo.version`` is re-stamped wherever a node's accounting, pod
+    set or row binding changes, so the rows to patch are those whose stamp
+    differs from the one kept at the last pack; ``vocabulary_signature``
+    stands in for the watch stream's vocabulary events.  Whatever cannot
+    be proven packs from scratch (``_reason_and_rows``).  Reading marks
+    nothing, so a bystander's ``pack(cluster)`` between two cycles changes
+    nothing here.
+
+    Host only: the session keeps its own per-session device arrays
+    (``Session._device_arrays``), so no scatter program is dispatched and
+    nothing compiles that a from-scratch session would not compile.
+    Owned by one ``Scheduler`` and touched on its cycle thread alone."""
+
+    def __init__(self):
+        # kairace: single-writer=main
+        self.generation = 0
+        self.last_pack: dict = {}
+        # kairace: single-writer=main
+        self._prev: SnapshotTensors | None = None
+        self._prev_pad: int | None = None
+        self._cluster = None          # weakref to the baseline's cluster
+        self._vocab: tuple | None = None
+        # Per row, the node's stamp when the baseline was settled; None
+        # between ``pack`` and ``settle`` (a session that died in between
+        # leaves no baseline to patch).
+        # kairace: single-writer=main
+        self._versions: np.ndarray | None = None
+        # What the last pack patched (None after a full pack).
+        self._rows: np.ndarray | None = None
+        self._table = None            # the sessions' NativeNodeTable
+        self._node_index: dict | None = None
+
+    def _reason_and_rows(self, cluster, pad_nodes_to, vocab) -> tuple:
+        """``(reason, None)``: why this cluster cannot be patched from the
+        baseline; or ``(None, rows)``: the rows whose nodes moved."""
+        prev = self._prev
+        if prev is None:
+            return "no-previous-pack", None
+        if self._cluster is None or self._cluster() is not cluster:
+            return "other-cluster", None
+        if self._versions is None:
+            return "unsettled-baseline", None
+        if pad_nodes_to != self._prev_pad:
+            return "node-bucket-growth", None
+        order, nodes = cluster.node_order, cluster.nodes
+        if len(nodes) != len(order) or order != prev.node_names:
+            return "topology-change", None
+        if vocab != self._vocab:
+            return "vocab-change", None
+        n = len(order)
+        now = self._stamps(cluster)
+        if now is None or now.shape != self._versions.shape:
+            return "topology-change", None
+        rows = np.nonzero(now != self._versions)[0]
+        if rows.size > n * DELTA_MAX_FRACTION:
+            return "mostly-dirty", None
+        moved = [nodes[order[i]] for i in rows]
+        if any(nd.idx != i for nd, i in zip(moved, rows)):
+            return "topology-change", None
+        if moved and not np.array_equal(
+                np.stack([nd.allocatable for nd in moved]),
+                prev.node_allocatable[rows]):
+            # A re-stamped node whose hardware differs is a new Node.
+            return "node-change", None
+        return None, rows
+
+    @staticmethod
+    def _stamps(cluster) -> np.ndarray | None:
+        """Each node's stamp at the row its own idx names, or None where
+        some idx names no row.  Stamps are never reused, so a row that no
+        node or the wrong node claims cannot read as it did; the rows
+        that moved are then held to ``node_order`` one by one."""
+        nodes = cluster.nodes
+        n = len(nodes)
+        idx = np.fromiter(map(_IDX, nodes.values()), np.int64, count=n)
+        if n and (idx.min() < 0 or idx.max() >= n):
+            return None
+        now = np.zeros(n, np.int64)
+        now[idx] = np.fromiter(map(_VERSION, nodes.values()), np.int64,
+                               count=n)
+        return now
+
+    def pack(self, cluster, queue_usage=None,
+             pad_nodes_to: int | None = None
+             ) -> tuple[SnapshotTensors, dict]:
+        """Pack ``cluster`` for one Session: the baseline patched where
+        ``_reason_and_rows`` allows it, ``api.snapshot.pack`` otherwise;
+        bit-identical to the latter either way.  Opens no span: the
+        verdict rides on the caller's ``snapshot`` span as attributes
+        (``Session.pack_stats``; docs/OBSERVABILITY.md)."""
+        t0 = time.perf_counter()
+        vocab = vocabulary_signature(cluster)
+        reason, rows = self._reason_and_rows(cluster, pad_nodes_to, vocab)
+        dirty = () if rows is None else [cluster.node_order[i] for i in rows]
+        # Task, job and queue arrays are rebuilt: podgroups, pod statuses
+        # and queues are plain fields that no stamp covers.
+        snap, rows, reason = _delta_or_full(
+            cluster, self._prev, reason, dirty, queue_usage, pad_nodes_to,
+            reuse_tasks=False)
+        if rows is None:
+            self.generation += 1
+        self._prev = snap
+        self._prev_pad = pad_nodes_to
+        self._cluster = weakref.ref(cluster)
+        self._vocab = vocab
+        self._rows = rows
+        self._versions = None
+        self.last_pack = _verdict(cluster, rows, reason, self.generation, t0)
+        return snap, self.last_pack
+
+    def carried(self, snap: SnapshotTensors) -> tuple:
+        """``(table, dirty rows, node index)`` of the session before, for
+        the session being built on ``snap``; ``(None, None, None)`` after
+        a full pack.  The table's ``used`` and ``releasing`` rows are the
+        memory the ``NodeInfo`` objects write to, so they are current;
+        ``room`` and the binding of the dirty rows are the session's to
+        patch."""
+        if self._rows is None:
+            return None, None, None
+        table = self._table
+        if table is not None and (table.n_nodes, table.n_res) \
+                != snap.node_allocatable.shape:
+            table = None
+        return table, self._rows, self._node_index
+
+    def settle(self, session) -> None:
+        """The session is built: keep its table and index, and the stamps
+        its nodes carry now that it has re-bound (and so re-stamped) the
+        rows it had to."""
+        self._versions = self._stamps(session.cluster)
+        self._table = session._native
+        self._node_index = session._node_index
+
+
 class ClusterArena:
     """Cross-cycle pack + device residency cache, one per ClusterCache.
 
@@ -330,31 +537,14 @@ class ClusterArena:
             t0 = time.perf_counter()
             reason = self._full_rebuild_reason(cluster, pad_nodes_to,
                                                queue_usage)
-            snap = None
-            rows = None
-            if reason is None:
-                reuse_tasks = (not self._tasks_dirty
-                               and self._usage_equal(queue_usage,
-                                                     self._prev_usage))
-                try:
-                    snap, rows = pack_incremental(
-                        cluster, self._prev, self._dirty_nodes,
-                        queue_usage=queue_usage, pad_nodes_to=pad_nodes_to,
-                        reuse_tasks=reuse_tasks)
-                except Exception as exc:
-                    # A delta that cannot be applied must degrade to a
-                    # rebuild, never crash the cycle; the property suite
-                    # keeps this branch honest (it asserts delta packs DO
-                    # happen, so a silent always-fallback would fail).
-                    LOG.warning("arena: incremental pack failed (%r); "
-                                "falling back to full rebuild", exc)
-                    reason = "delta-error"
-                    snap = None
-            if snap is None:
-                snap = pack(cluster, queue_usage=queue_usage,
-                            pad_nodes_to=pad_nodes_to)
+            reuse_tasks = (reason is None and not self._tasks_dirty
+                           and self._usage_equal(queue_usage,
+                                                 self._prev_usage))
+            snap, rows, reason = _delta_or_full(
+                cluster, self._prev, reason, self._dirty_nodes,
+                queue_usage, pad_nodes_to, reuse_tasks)
+            if rows is None:
                 self.generation += 1
-                METRICS.inc("arena_full_rebuild_total")
             self._prev = snap
             self._prev_pad = pad_nodes_to
             self._prev_usage = queue_usage
@@ -371,18 +561,7 @@ class ClusterArena:
                 # set no longer describes "changes since the baseline",
                 # so the next pack must rebuild regardless.
                 self._full_reason = "stale-baseline"
-            n = max(1, len(cluster.node_order))
-            ratio = 1.0 if rows is None else len(rows) / n
-            METRICS.set_gauge("snapshot_delta_ratio", ratio)
-            stats = {
-                "full_rebuild": rows is None,
-                "reason": reason or "",
-                "changed_rows": (n if rows is None else int(len(rows))),
-                "total_rows": n,
-                "delta_ratio": round(ratio, 6),
-                "generation": self.generation,
-                "pack_s": round(time.perf_counter() - t0, 6),
-            }
+            stats = _verdict(cluster, rows, reason, self.generation, t0)
             self.last_pack = stats
             sp.set(**stats)
         return snap, stats

@@ -51,7 +51,10 @@ class InMemoryCache:
     # crash-safe bind journal and a fencing-epoch provider; statements
     # consult both at commit time.  ``arena`` may be set to a
     # framework.arena.ClusterArena to opt a test/offline session into
-    # cross-cycle snapshot + device residency.
+    # device residency and a pack fed by ``note_*`` calls.  Left None,
+    # a Scheduler's sessions still carry the host half of the pack from
+    # one to the next (its own HostArena reads what moved off the
+    # NodeInfo stamps); only the device half needs the ClusterArena.
     commitlog = None
     epoch_provider = None
     arena = None
@@ -194,7 +197,10 @@ def _unpack_allocation(result, t: int):
 
 class Session:
     def __init__(self, cluster: ClusterInfo, config=None, cache=None,
-                 queue_usage: dict | None = None):
+                 queue_usage: dict | None = None, host_arena=None):
+        """``host_arena``: the ``framework.arena.HostArena`` of the
+        ``Scheduler`` that builds its sessions one after another; it
+        serves where the cache brings no arena of its own."""
         from .conf import SchedulerConfig  # local import to avoid cycle
         self.cluster = cluster
         self.config = config or SchedulerConfig()
@@ -276,9 +282,13 @@ class Session:
         # Persistent arena (framework/arena.py): when the cache carries
         # one (ClusterCache does), the pack is incremental against the
         # previous cycle's arrays and the device tensors stay resident
-        # across sessions.  Caches without an arena (tests, offline
-        # replay) pack from scratch exactly as before.
+        # across sessions.  Otherwise the scheduler's host arena carries
+        # the host half (packed arrays, node table, name index) from its
+        # session before, wherever it can prove the cluster is the one it
+        # packed; a session nobody handed either (tests, offline replay)
+        # packs from scratch.
         self._arena = getattr(self.cache, "arena", None)
+        host = host_arena if self._arena is None else None
         self.pack_stats: dict | None = None
         # Stale usage never reaches the packed tensors: the degraded
         # mode (docs/DEGRADATION.md) is "ignore usage", enforced here
@@ -286,13 +296,16 @@ class Session:
         # the host-side attributes (which also counts the cycle).
         pack_usage = {} if getattr(queue_usage, "stale", False) \
             else queue_usage
-        if self._arena is not None:
-            self.snapshot, self.pack_stats = self._arena.pack(
+        if self._arena is not None or host is not None:
+            self.snapshot, self.pack_stats = (self._arena or host).pack(
                 cluster, queue_usage=pack_usage, pad_nodes_to=pad)
         else:
             self.snapshot: SnapshotTensors = pack(
                 cluster, queue_usage=pack_usage, pad_nodes_to=pad)
         self.phase_timings["snapshot_pack"] = _time.perf_counter() - _t
+        snap = self.snapshot
+        table, dirty_rows, node_index = (
+            host.carried(snap) if host is not None else (None, None, None))
         # Dense mutable mirrors: backed by the native C++ state store
         # (contiguous C-owned tables, zero-copy views) unless the machine
         # has no compiler to build it with, then plain numpy.  ``/healthz``
@@ -301,13 +314,22 @@ class Session:
         if self.config.use_native_store:
             from ..native import NativeNodeTable, native_available
             if native_available():
-                snap = self.snapshot
-                table = NativeNodeTable(snap.node_allocatable.shape[0],
-                                        snap.node_allocatable.shape[1])
-                table.bulk_load(
-                    snap.node_allocatable,
-                    snap.node_allocatable - snap.node_idle,
-                    snap.node_releasing, snap.node_pod_room)
+                if table is None:
+                    table = NativeNodeTable(snap.node_allocatable.shape[0],
+                                            snap.node_allocatable.shape[1])
+                    table.bulk_load(
+                        snap.node_allocatable,
+                        snap.node_allocatable - snap.node_idle,
+                        snap.node_releasing, snap.node_pod_room)
+                    to_bind = cluster.nodes.values()
+                else:
+                    # The table of the session before: its used and
+                    # releasing rows are what the NodeInfo objects have
+                    # been writing to since, so only the pod room and the
+                    # binding of the rows that moved are stale.
+                    table.room[dirty_rows] = snap.node_pod_room[dirty_rows]
+                    to_bind = [cluster.nodes[snap.node_names[i]]
+                               for i in dirty_rows]
                 self._native = table
                 # Single source of truth: rebind each NodeInfo's
                 # used/releasing to zero-copy VIEWS of its table row.
@@ -318,7 +340,7 @@ class Session:
                 # in-place (+=/-=); clone() detaches via .copy().
                 used_rows = table.used
                 rel_rows = table.releasing
-                for name, node in cluster.nodes.items():
+                for node in to_bind:
                     i = node.idx
                     if 0 <= i < table.n_nodes and \
                             node.used.shape[0] == table.n_res:
@@ -326,12 +348,16 @@ class Session:
                         rel_rows[i] = node.releasing
                         node.used = used_rows[i]
                         node.releasing = rel_rows[i]
+                        # Whose rows these views are is part of what a
+                        # carried table rests on: a session that re-binds
+                        # a node says so.
+                        node.touch()
         if self._native is None:
             self._np_idle = self.snapshot.node_idle.copy()
             self._np_releasing = self.snapshot.node_releasing.copy()
             self._np_room = self.snapshot.node_pod_room.copy()
-        self._node_index = {n: i for i, n in
-                            enumerate(self.snapshot.node_names)}
+        self._node_index = node_index if node_index is not None else {
+            n: i for i, n in enumerate(self.snapshot.node_names)}
         self.gpu_strategy = BINPACK
         self.cpu_strategy = BINPACK
         # Sessions are scheduler-thread-owned end to end: statements
@@ -366,6 +392,8 @@ class Session:
         # Releasing-pool hint memo for the fused grouped kernel (see
         # has_releasing): (tick, value), recomputed only after mutations.
         self._rel_hint: tuple[int, bool] | None = None
+        if host is not None:
+            host.settle(self)
 
     # -- lifecycle ---------------------------------------------------------
     def open(self) -> "Session":

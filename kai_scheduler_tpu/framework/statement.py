@@ -132,6 +132,7 @@ class Statement:
             else:
                 task.status = status
             node.pod_infos[task.uid] = task
+            node.touch()
             ssn.fire_allocate_handlers(task)
             ops.append(op)
             idx[i] = node.idx
@@ -217,6 +218,7 @@ class Statement:
             # the NodeInfo graph consistent).
             if node is not None:
                 node.pod_infos.pop(task.uid, None)
+                node.touch()
                 self.session._native.remove_task(
                     op.node_idx, op.native_req,
                     self._STATUS_CODE.get(task.status, 0))
@@ -275,6 +277,7 @@ class Statement:
                         op.task.status = PodStatus.PIPELINED
                     self.session._native.add_task(
                         op.node_idx, op.native_req, 2)
+                    node.touch()
                     self.session.mutation_count += 1
                     self.session._dirty_rows.add(op.node_idx)
                     op.kind = "pipeline"

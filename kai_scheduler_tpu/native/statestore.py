@@ -27,6 +27,20 @@ def _as_dptr(arr: np.ndarray):
     return arr.ctypes.data_as(ctypes.POINTER(ctypes.c_double))
 
 
+class _Store:
+    """The C table.  Freed when its last holder lets go, and every view of
+    its buffers is a holder: ``NodeInfo.used`` stays a view of a session's
+    table after that session is gone, until the next one re-binds it."""
+
+    def __init__(self, lib, n_nodes: int, n_res: int):
+        self._lib = lib
+        self.handle = ctypes.c_void_p(lib.ss_create(n_nodes, n_res))
+
+    def __del__(self):
+        if self.handle:
+            self._lib.ss_destroy(self.handle)
+
+
 class NativeNodeTable:
     def __init__(self, n_nodes: int, n_res: int):
         self._lib = load_statestore_lib()
@@ -34,16 +48,16 @@ class NativeNodeTable:
             raise RuntimeError("native toolchain unavailable")
         self.n_nodes = n_nodes
         self.n_res = n_res
-        self._handle = ctypes.c_void_p(self._lib.ss_create(n_nodes, n_res))
+        self._store = _Store(self._lib, n_nodes, n_res)
+        self._handle = self._store.handle
         self._checkpoints: list = []
         self._views: dict = {}
 
     def __del__(self):
         lib = getattr(self, "_lib", None)
-        if lib is not None and getattr(self, "_handle", None):
-            for cp in self._checkpoints:
+        if lib is not None:
+            for cp in getattr(self, "_checkpoints", ()):
                 lib.ss_destroy(cp)
-            lib.ss_destroy(self._handle)
 
     # -- loading -----------------------------------------------------------
     def set_node(self, i: int, allocatable: np.ndarray,
@@ -102,8 +116,12 @@ class NativeNodeTable:
             # A table of no nodes (the embedded daemon before any Node
             # exists) has no buffer: its C vectors' data() is NULL.
             return np.zeros(shape)
-        buf = np.ctypeslib.as_array(ptr, shape=(size,))
-        return buf.reshape(shape)
+        buf = ctypes.cast(
+            ptr, ctypes.POINTER(ctypes.c_double * size)).contents
+        # The array's base is ``buf``, and every slice of the array keeps
+        # that base: through it each view holds the C table alive.
+        buf._store = self._store
+        return np.frombuffer(buf, np.float64).reshape(shape)
 
     def _cached_view(self, name: str, fn_name: str, shape):
         view = self._views.get(name)

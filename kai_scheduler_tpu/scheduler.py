@@ -12,6 +12,7 @@ import time
 
 from .actions import build_actions
 from .api.snapshot import fragmentation_stats
+from .framework.arena import HostArena
 from .framework.conf import SchedulerConfig
 from .framework.session import InMemoryCache, Session
 from .utils.deviceguard import (CycleDeadlineExceeded, DeviceGuardError,
@@ -32,6 +33,9 @@ class Scheduler:
         self.config = config or SchedulerConfig()
         self.cache = cache or InMemoryCache()
         self.usage_provider = usage_provider
+        # What one session hands the next on the host where the cache
+        # brings no arena (framework/arena.py): nothing to configure.
+        self.host_arena = HostArena()
         # kairace: single-writer=main
         self.session_id = 0
         # kairace: single-writer=main
@@ -71,7 +75,8 @@ class Scheduler:
                 usage = (self.usage_provider()
                          if self.usage_provider else None)
                 ssn = Session(cluster, self.config, self.cache,
-                              queue_usage=usage)
+                              queue_usage=usage,
+                              host_arena=self.host_arena)
                 snap_sp.set(nodes=len(cluster.nodes),
                             podgroups=len(cluster.podgroups))
                 cache_stats = getattr(cluster, "cache_stats", None)

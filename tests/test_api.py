@@ -259,3 +259,69 @@ class TestSnapshotPack:
         assert len(ci2.nodes["n1"].pod_infos) == 1
         # and the clone's pod ref is the cloned task, not the original
         assert ci2.nodes["n1"].pod_infos["t0"] is ci2.podgroups["pg1"].pods["t0"]
+
+
+# -- queue aggregates: by count where that is exact, in turn otherwise ------
+
+def _aggregate_cluster(seed, spoiler=None):
+    """Gangs of pods sharing requirement objects over three queues, in
+    every status; ``spoiler`` adds one pod the count cannot take."""
+    rng = np.random.default_rng(seed)
+    statuses = [PodStatus.PENDING, PodStatus.RUNNING, PodStatus.ALLOCATED,
+                PodStatus.PIPELINED, PodStatus.RELEASING, PodStatus.GATED]
+    nodes = {f"n{i}": mknode(f"n{i}", cpu="64", mem="512Gi") for i in range(4)}
+    queues = {q: QueueInfo(q, quota=QueueQuota.from_spec())
+              for q in ("qa", "qb", "qc")}
+    podgroups = {}
+    for g in range(int(rng.integers(3, 9))):
+        pg = PodGroupInfo(f"pg{g}", f"pg{g}",
+                          queue_id=("qa", "qb", "qc", "lost")[g % 4])
+        shapes = [ResourceRequirements.from_spec(
+            f"{int(rng.integers(100, 9000))}m",
+            f"{int(rng.integers(1, 64))}Gi", int(rng.integers(0, 9)))
+            for _ in range(2)]
+        for k in range(int(rng.integers(1, 40))):
+            pg.add_task(PodInfo(
+                uid=f"pg{g}-{k}", name=f"pg{g}-{k}",
+                status=statuses[int(rng.integers(len(statuses)))],
+                res_req=shapes[k % 2]))
+        podgroups[pg.uid] = pg
+    if spoiler is not None:
+        podgroups["pg0"].add_task(PodInfo(
+            uid="spoiler", name="spoiler", status=PodStatus.RUNNING,
+            res_req=spoiler))
+    return ClusterInfo(nodes, podgroups, queues)
+
+
+def _same_bits(a, b):
+    assert a.keys() == b.keys()
+    for q in a:
+        assert a[q].dtype == b[q].dtype and a[q].tobytes() == b[q].tobytes()
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3, 4])
+def test_queue_aggregates_by_count_equal_those_in_turn(seed):
+    ci = _aggregate_cluster(seed)
+    counted = ci._aggregates_by_count()
+    assert counted is not None
+    in_turn = ci._aggregates_in_turn()
+    _same_bits(counted[0], in_turn[0])
+    _same_bits(counted[1], in_turn[1])
+    assert "lost" not in counted[0]
+    assert ci.queue_aggregates()[0]["qa"].tobytes() \
+        == in_turn[0]["qa"].tobytes()
+
+
+@pytest.mark.parametrize("spoiler", [
+    ResourceRequirements.from_spec("1", "1Gi", 0, gpu_fraction=0.3),
+    ResourceRequirements.from_spec("1", "1Gi", 0, gpu_memory="4Gi"),
+    ResourceRequirements.from_spec("0.0005", "1Gi", 0),
+    ResourceRequirements(base=np.array([1000.0, 2.0 ** 53, 0.0])),
+], ids=["fraction", "gpu_memory", "half_a_millicore", "past_2_53"])
+def test_queue_aggregates_take_turns_where_a_count_is_not_exact(spoiler):
+    ci = _aggregate_cluster(5, spoiler)
+    assert ci._aggregates_by_count() is None
+    got = ci.queue_aggregates()
+    want = ci._aggregates_in_turn()
+    _same_bits(got[0], want[0])
+    _same_bits(got[1], want[1])

@@ -78,3 +78,26 @@ class TestNativeStore:
         #                        a benchmark: generous bound for CI load)
         # Rollback restores the post-add state the checkpoint captured.
         assert t.idle[0, 2] == 7.0
+
+
+def test_a_view_keeps_the_c_table_alive():
+    """``NodeInfo.used`` stays a view of a session's table after the
+    session is gone: the C memory must outlive the table object for as
+    long as any view of it is held."""
+    import gc
+    import weakref
+    t = NativeNodeTable(3, 3)
+    for i in range(3):
+        t.set_node(i, np.array([8000.0, 64e9, 8.0]), 110)
+    t.add_task(1, np.array([1000.0, 1e9, 2.0]), status=0)
+    store = weakref.ref(t._store)
+    row = t.used[1]
+    del t
+    gc.collect()
+    assert store() is not None
+    assert row.tolist() == [1000.0, 1e9, 2.0]
+    row += 1.0
+    assert row[2] == 3.0
+    del row
+    gc.collect()
+    assert store() is None

@@ -415,3 +415,376 @@ def test_sharded_provider_cluster_packs_from_scratch():
     snap, stats = cache.arena.pack(cluster)
     assert stats["full_rebuild"] and stats["reason"] == "unstamped-cluster"
     assert_snapshots_identical(snap, pack(cluster))
+
+
+# ---------------------------------------------------------------------------
+# The host arena: a bare ClusterInfo under one Scheduler, no watch stream
+# ---------------------------------------------------------------------------
+#
+# ``Scheduler`` carries the host half of the pack from one session to the
+# next whatever its cache (framework/arena.py ``HostArena``): the dirty
+# rows come from the stamps the NodeInfo mutators leave.  The loop below is
+# the benchmark client's (benchmark/generators/closed_loop_gangs.py): a
+# bound gang completes through ``node.remove_task`` and ``del
+# cluster.podgroups[...]``, a gang arrives, ``run_once`` commits, the
+# bound pods turn RUNNING.  After every Session construction each array of
+# ``ssn.snapshot`` must equal a from-scratch ``pack()`` of the same
+# cluster, dtype and bytes, and a patched pack must have patched exactly
+# the rows the test touched.
+
+def _bare_spec(nodes=12, busy=range(0, 12, 3), labels=None, tainted=(),
+               selector=None, tolerations=()):
+    """``nodes`` four-GPU nodes, those of ``tainted`` with the taint
+    ``dedicated``; a RUNNING two-GPU pod on each of ``busy`` (with
+    ``selector`` and ``tolerations`` if given)."""
+    return {
+        "nodes": {f"n{i:02d}": {"gpu": 4, "cpu": "32", "mem": "256Gi",
+                                "labels": labels(i) if labels else None,
+                                "taints": ("dedicated",) if i in tainted
+                                else ()}
+                  for i in range(nodes)},
+        "queues": {"q0": {}, "q1": {}},
+        "jobs": {"base": {"queue": "q0", "min_available": 1, "tasks": [
+            {"name": f"base-{i}", "gpu": 2, "status": "RUNNING",
+             "node": f"n{i:02d}", "selector": selector or {},
+             "tolerations": tolerations} for i in busy]}},
+    }
+
+
+class BareLoop:
+    """One persistent ClusterInfo, one Scheduler, the client's moves."""
+
+    def __init__(self, spec=None):
+        from kai_scheduler_tpu.scheduler import Scheduler
+        from tests.fixtures import build_cluster
+        self.spec = spec or _bare_spec()
+        self.cluster = build_cluster(self.spec)
+        self.sched = Scheduler(self._provide, SchedulerConfig())
+        self.provided = None     # a stand-in the next provider call returns
+        self.expected = None     # pack() from scratch at provider time
+        self.touched = set()     # nodes touched since the last session
+        self.seq = 0
+        self.sessions = []
+
+    def _provide(self):
+        cluster, self.provided = self.provided or self.cluster, None
+        bucket = self.sched.config.node_pad_bucket
+        n = len(cluster.nodes)
+        pad = max(bucket, -(-n // bucket) * bucket) if bucket else None
+        # A bystander's pack of the very cluster the session is about to
+        # pack: it reads what the session reads and must disturb nothing.
+        self.expected = pack(cluster, pad_nodes_to=pad)
+        return cluster
+
+    def arrive(self, size, gpu=1, gpu_fraction=0.0, **pod):
+        from kai_scheduler_tpu.api import PodGroupInfo, PodInfo
+        from kai_scheduler_tpu.api.resources import ResourceRequirements
+        self.seq += 1
+        uid = f"gang-{self.seq:03d}"
+        pg = PodGroupInfo(uid, uid, queue_id="q1", min_available=size)
+        for k in range(size):
+            pg.add_task(PodInfo(
+                uid=f"{uid}-{k}", name=f"{uid}-{k}",
+                res_req=ResourceRequirements.from_spec(
+                    "1", "1Gi", gpu, gpu_fraction=gpu_fraction), **pod))
+        self.cluster.podgroups[uid] = pg
+        self.cluster.invalidate_aggregates()
+        return pg
+
+    def complete(self, pg):
+        for task in pg.pods.values():
+            node = self.cluster.nodes.get(task.node_name)
+            if node is not None:
+                node.remove_task(task)
+                self.touched.add(node.name)
+        del self.cluster.podgroups[pg.uid]
+        self.cluster.invalidate_aggregates()
+
+    def cycle(self):
+        """One ``run_once``: the session's snapshot is held to the
+        from-scratch pack of the same cluster and its verdict to the
+        rows touched since the session before; then the binds settle."""
+        from kai_scheduler_tpu.api import PodStatus
+        touched, self.touched = self.touched, set()
+        ssn = self.sched.run_once()
+        assert_snapshots_identical(ssn.snapshot, self.expected)
+        stats = ssn.pack_stats
+        assert stats["total_rows"] == len(ssn.cluster.nodes)
+        if not stats["full_rebuild"]:
+            # At least: an action that tried victims and rolled back
+            # (reclaim for a gang left pending) re-stamps rows too.
+            assert stats["changed_rows"] >= len(touched), (stats, touched)
+        cache = self.sched.cache
+        bound = dict(cache.bound)
+        self.touched.update(bound.values())
+        self.touched.update(node for _uid, node in cache.pipelined)
+        cache.bound.clear()
+        cache.pipelined.clear()
+        ssn.cluster.bind_requests.clear()
+        for pg in ssn.cluster.podgroups.values():
+            for task in pg.pods.values():
+                if task.uid in bound:
+                    pg.update_task_status(task, PodStatus.RUNNING)
+        self.sessions.append(ssn)
+        return ssn
+
+
+def _placed(pg):
+    return sorted((t.uid, t.node_name, t.status.name)
+                  for t in pg.pods.values())
+
+
+def _case_apply_bulk(loop):
+    """Plain pods: the exact kernel proposes, ``apply_bulk`` commits
+    through the native table's batch call."""
+    live = []
+    for _ in range(4):
+        if len(live) >= 2:
+            loop.complete(live.pop(0))
+        pg = loop.arrive(5)
+        loop.cycle()
+        assert all(t.node_name for t in pg.pods.values()), _placed(pg)
+        live.append(pg)
+
+
+def _case_per_task(loop):
+    """Fractional pods take the statement's task-by-task path
+    (``Statement.allocate``, sharing groups and whole-device charges)."""
+    live = []
+    for _ in range(4):
+        if len(live) >= 2:
+            loop.complete(live.pop(0))
+        pg = loop.arrive(3, gpu=0, gpu_fraction=0.5)
+        loop.cycle()
+        assert all(t.gpu_group for t in pg.pods.values()), _placed(pg)
+        live.append(pg)
+
+
+def _case_rollback_and_abort(loop):
+    """A statement rolled back through the native undo path and one left
+    to ``abort_uncommitted``: the rows they touched read as they did, and
+    are patched all the same."""
+    from kai_scheduler_tpu.api import PodStatus
+    loop.arrive(4)
+    ssn = loop.cycle()
+    tasks = list(loop.arrive(3).pods.values())
+    st = ssn.statement()
+    st.apply_bulk([(t, "n01", False) for t in tasks])
+    assert all(t.status == PodStatus.ALLOCATED for t in tasks)
+    st.rollback()
+    st = ssn.statement()
+    st.allocate(tasks[0], "n02")
+    st.pipeline(tasks[1], "n04")
+    assert ssn.abort_uncommitted() == 1
+    assert all(t.status == PodStatus.PENDING and not t.node_name
+               for t in tasks)
+    loop.touched.update(("n01", "n02", "n04"))
+    loop.cycle()
+    loop.cycle()
+
+
+def _case_evict_and_pipeline(loop):
+    """An eviction leaves RELEASING rows; a gang that fits only with what
+    is being released is pipelined onto them."""
+    from kai_scheduler_tpu.api import PodStatus
+    loop.arrive(2)
+    ssn = loop.cycle()
+    victims = [t for t in ssn.cluster.podgroups["base"].pods.values()][:2]
+    st = ssn.statement()
+    for t in victims:
+        st.evict(t)
+        loop.touched.add(t.node_name)
+    st.commit()
+    assert all(t.status == PodStatus.RELEASING for t in victims)
+    waiting = loop.arrive(11)    # 10 GPUs idle, 4 more being released
+    ssn = loop.cycle()
+    assert ssn.snapshot.node_releasing.any()
+    assert {t.status for t in waiting.pods.values()} \
+        == {PodStatus.PIPELINED}, _placed(waiting)
+    ssn = loop.cycle()
+    assert ssn.snapshot.node_releasing.any()
+
+
+def _case_bystander_pack(loop):
+    """The generator's ``prime``: a direct ``pack(cluster)`` with a gang
+    pending, between two cycles, takes no delta path and eats no mark."""
+    loop.arrive(4)
+    loop.cycle()
+    probe = loop.arrive(3)
+    assert pack(loop.cluster).num_tasks == 3
+    del loop.cluster.podgroups[probe.uid]
+    loop.cluster.invalidate_aggregates()
+    loop.cycle()     # patches the first cycle's binds: nothing was eaten
+    assert loop.sessions[-1].pack_stats["changed_rows"] > 0
+    loop.cycle()
+
+
+def _case_vocabulary_at_rest(loop):
+    """Selectors, tolerations, labels and taints that do not change do
+    not stand in the way: the codec and its rows are carried."""
+    assert loop.cluster.nodes["n00"].taints
+    for _ in range(3):
+        pg = loop.arrive(3)
+        ssn = loop.cycle()
+        assert all(t.node_name for t in pg.pods.values()), _placed(pg)
+        loop.complete(pg)
+    assert ssn.snapshot.codec.key_cols and ssn.snapshot.codec.taint_codes
+
+
+_AT_REST = _bare_spec(labels=lambda i: {"zone": f"z{i % 3}", "rack": "r"},
+                      tainted=(0, 3), selector={"zone": "z0"},
+                      tolerations=("dedicated",))
+_NEARLY_FULL = _bare_spec(busy=())
+_NEARLY_FULL["jobs"]["base"]["tasks"] = [
+    {"name": f"base-{i}", "gpu": 2, "status": "RUNNING",
+     "node": f"n{i // 2:02d}"} for i in range(18)]
+
+BARE_CASES = {
+    "apply_bulk": (_case_apply_bulk, None),
+    "per_task": (_case_per_task, None),
+    "rollback_and_abort": (_case_rollback_and_abort, None),
+    "evict_and_pipeline": (_case_evict_and_pipeline, _NEARLY_FULL),
+    "bystander_pack": (_case_bystander_pack, None),
+    "vocabulary_at_rest": (_case_vocabulary_at_rest, _AT_REST),
+}
+
+
+@pytest.mark.parametrize("case", sorted(BARE_CASES))
+def test_bare_cluster_sessions_equal_a_pack_from_scratch(case):
+    drive, spec = BARE_CASES[case]
+    loop = BareLoop(spec)
+    drive(loop)
+    stats = [s.pack_stats for s in loop.sessions]
+    assert stats[0]["full_rebuild"] \
+        and stats[0]["reason"] == "no-previous-pack"
+    # The property must not hold vacuously: every later session patched.
+    assert [s["full_rebuild"] for s in stats[1:]] \
+        == [False] * (len(stats) - 1), stats
+
+
+# -- what the host arena cannot prove, it packs from scratch ---------------
+
+def _clone(loop):
+    loop.provided = loop.cluster.clone()
+
+
+def _equal_content(loop):
+    from tests.fixtures import build_cluster
+    loop.provided = build_cluster(loop.spec)
+
+
+def _node_added(loop):
+    from kai_scheduler_tpu.api import NodeInfo
+    cluster = loop.cluster
+    like = cluster.nodes["n00"]
+    cluster.nodes["n99"] = NodeInfo("n99", like.allocatable.copy())
+    cluster.node_order = sorted(cluster.nodes)
+    for i, name in enumerate(cluster.node_order):
+        cluster.nodes[name].idx = i
+
+
+def _node_replaced(loop):
+    from kai_scheduler_tpu.api import NodeInfo
+    old = loop.cluster.nodes["n05"]
+    assert not old.pod_infos
+    loop.cluster.nodes["n05"] = NodeInfo(
+        "n05", old.allocatable * 2.0, idx=old.idx)
+
+
+def _selector_arrives(loop):
+    loop.arrive(1, node_selector={"zone": "z1"})
+
+
+def _toleration_arrives(loop):
+    loop.arrive(1, tolerations={"dedicated"})
+
+
+def _relabelled(loop):
+    loop.cluster.nodes["n04"].labels["zone"] = "z9"
+
+
+def _pad_changes(loop):
+    loop.sched.config.node_pad_bucket = 16
+
+
+def _mostly_dirty(loop):
+    pg = loop.arrive(10, gpu=2)   # ten nodes of twelve
+    for k, task in enumerate(pg.pods.values()):
+        task.node_name, task.status = f"n{k:02d}", _running()
+        loop.cluster.nodes[task.node_name].add_task(task)
+
+
+def _bystander_session(loop):
+    """Another Session over the same cluster re-binds every node's
+    ``used`` and ``releasing`` to its own table: the carried one is
+    stale, and every node says so."""
+    Session(loop.cluster, SchedulerConfig(), InMemoryCache())
+
+
+def _running():
+    from kai_scheduler_tpu.api import PodStatus
+    return PodStatus.RUNNING
+
+
+REBUILD_CASES = {
+    "cloned_cluster": (_clone, "other-cluster", None),
+    "equal_content_new_object": (_equal_content, "other-cluster", None),
+    "node_order_changed": (_node_added, "topology-change", None),
+    "node_replaced": (_node_replaced, "node-change", None),
+    "selector_arrives": (_selector_arrives, "vocab-change", None),
+    "toleration_arrives": (_toleration_arrives, "vocab-change", None),
+    "selected_label_changes": (_relabelled, "vocab-change", _AT_REST),
+    "pad_nodes_to_changes": (_pad_changes, "node-bucket-growth", None),
+    "mostly_dirty": (_mostly_dirty, "mostly-dirty", None),
+    "bystander_session": (_bystander_session, "mostly-dirty", None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(REBUILD_CASES))
+def test_bare_cluster_rebuilds_where_it_cannot_prove(case):
+    change, reason, spec = REBUILD_CASES[case]
+    loop = BareLoop(spec)
+    loop.arrive(3)
+    loop.cycle()
+    loop.arrive(2)
+    assert not loop.cycle().pack_stats["full_rebuild"]
+    change(loop)
+    stats = loop.cycle().pack_stats       # identical to pack() all the same
+    assert stats["full_rebuild"] and stats["reason"] == reason, stats
+    # ... and the baseline it left is patched again.
+    loop.cycle()
+    loop.arrive(2)
+    stats = loop.cycle().pack_stats
+    assert not stats["full_rebuild"], stats
+
+
+def test_host_arena_engages_once_and_says_so_on_the_snapshot_span():
+    """Counts only (ROADMAP D13): over k cycles on one persistent cluster
+    the full-rebuild counter moves once, every later ``snapshot`` span
+    carries ``full_rebuild=False`` and as many ``changed_rows`` as nodes
+    were touched, and no ``snapshot_delta`` span is opened on this path
+    (``benchmark/layer_metrics/snapshot_ms.json`` sums both names)."""
+    from kai_scheduler_tpu.utils.tracing import TRACER
+    loop = BareLoop()
+    rebuilds0 = METRICS.counters.get("arena_full_rebuild_total", 0)
+    live = []
+    for k in range(6):
+        if len(live) >= 2:
+            loop.complete(live.pop(0))
+        live.append(loop.arrive(4))
+        touched = set(loop.touched)
+        loop.cycle()
+        spans = TRACER.get_trace().spans
+        assert not [s.name for s in spans if s.name == "snapshot_delta"]
+        (snapshot,) = [s for s in spans if s.name == "snapshot"]
+        verdict = snapshot.attrs
+        assert verdict["total_rows"] == 12
+        if k == 0:
+            assert verdict["full_rebuild"] is True
+            assert verdict["reason"] == "no-previous-pack"
+            continue
+        assert verdict["full_rebuild"] is False and verdict["reason"] == ""
+        assert verdict["changed_rows"] == len(touched) > 0, (verdict, touched)
+        assert METRICS.gauges["snapshot_delta_ratio"] \
+            == pytest.approx(len(touched) / 12)
+    assert METRICS.counters["arena_full_rebuild_total"] - rebuilds0 == 1
