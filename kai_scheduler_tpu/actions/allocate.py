@@ -14,9 +14,14 @@ from __future__ import annotations
 import numpy as np
 
 from ..api.podgroup_info import PodGroupInfo
-from ..api.pod_status import PodStatus
+from ..framework import propose
 from ..utils.tracing import TRACER
 from .utils import INFINITE, JobsOrderByQueues
+
+
+# Rounds of bulk mode: each re-orders the jobs still pending and places
+# them in one wave.
+BULK_MAX_ROUNDS = 8
 
 
 class AllocateAction:
@@ -83,28 +88,9 @@ def _execute_bulk(ssn, jobs):
     path; returns those leftovers.
     """
 
-    from ..ops.scoring import BINPACK
-
-    # The grouped kernel implements bin-pack only and carries no extra
-    # score terms; other configurations use the per-job path wholesale.
-    if ssn.gpu_strategy != BINPACK or ssn.cpu_strategy != BINPACK:
+    takes = propose.wave_filter(ssn)
+    if takes is None:
         return jobs
-
-    # Anti-affinity symmetry: existing pods' anti terms can repel incoming
-    # pods the bulk kernel knows nothing about.  Collect the active terms
-    # once and gate only jobs a term could actually match — a single guard
-    # pod must not knock every labeled job off the fleet path.
-    hints = getattr(ssn.cluster, "columnar_hints", None)
-    if hints and hints.get("no_affinity_terms"):
-        # Columnar snapshot: the store proved no pod carries an
-        # anti-affinity term — identical result, no O(pods) walk.
-        repeller_terms = []
-    else:
-        repeller_terms = [
-            term
-            for pg in ssn.cluster.podgroups.values()
-            for t in pg.pods.values() if t.is_active_allocated()
-            for term in t.anti_affinity_terms]
 
     leftovers = []
     eligible = []
@@ -112,32 +98,9 @@ def _execute_bulk(ssn, jobs):
         tasks = pg.tasks_to_allocate(
             subgroup_order_fn=ssn.pod_set_order_key,
             task_order_fn=ssn.task_order_key, cache_ordered=True)
-        host_side = (
-            not tasks
-            or any(t.is_fractional or t.resource_claims
-                   or t.res_req.mig_resources for t in tasks)
-            or any(ps.has_own_topology_constraint()
-                   for ps in pg.pod_sets.values())
-            or pg.required_topology_level or pg.preferred_topology_level
-            # Nominated-node stickiness / affinity peers are extra score
-            # terms the grouped kernel doesn't model.
-            or any(t.status == PodStatus.PIPELINED
-                   for t in pg.pods.values())
-            or any(t.nominated_node or t.pod_affinity_peers
-                   or t.pod_anti_affinity_peers for t in tasks)
-            # Hard node masks (affinity terms, host ports, bound PVCs)
-            # are enforced per-proposal; the bulk kernel doesn't model
-            # them, so such jobs take the per-job path.
-            or any(t.affinity_terms or t.anti_affinity_terms
-                   or t.preferred_affinity_terms
-                   or t.preferred_anti_affinity_terms
-                   or t.node_affinity_required or t.node_affinity_preferred
-                   or t.host_ports or t.pvc_names
-                   or any(term.matches(t.labels, t.namespace)
-                          for term in repeller_terms) for t in tasks))
-        (leftovers if host_side else eligible).append(pg)
+        (eligible if takes(pg, tasks) else leftovers).append(pg)
 
-    for _ in range(ssn.config.bulk_allocation_max_rounds):
+    for _ in range(BULK_MAX_ROUNDS):
         pending = [pg for pg in eligible if pg.has_tasks_to_allocate()]
         if not pending:
             break
@@ -220,108 +183,35 @@ def _execute_bulk(ssn, jobs):
         if not any(job_allowed):
             break
 
-        # Pack all chunks into one kernel call.
-        rows_req, rows_sel, rows_tol, task_jobs, flat_tasks = \
-            [], [], [], [], []
-        ok = True
-        for j, tasks in enumerate(chunks):
-            for t in tasks:
-                req, sel, tol = ssn._task_row(t)
-                if req is None:
-                    ok = False
-                    break
-                rows_req.append(req)
-                rows_sel.append(sel)
-                rows_tol.append(tol)
-                task_jobs.append(j)
-                flat_tasks.append(t)
-            if not ok:
-                break
-        if not ok or not flat_tasks:
+        # All chunks in one kernel call.
+        proposals = propose.place_wave(ssn, list(zip(ordered, chunks)),
+                                       job_allowed)
+        if proposals is None:
             break
 
-        import functools as _functools
-        kw = {}
-        if ssn.mesh is not None:
-            # Multi-chip: node axis sharded over the configured mesh
-            # (parallel/sharded_grouped.py; bit-identical to single-chip).
-            from ..parallel.sharded_grouped import sharded_allocate_grouped
-            kernel = _functools.partial(sharded_allocate_grouped, ssn.mesh)
-        else:
-            from ..ops.allocate_grouped import allocate_grouped
-            kernel = allocate_grouped
-            # Single-task chunks place independently: identical adjacent
-            # ones merge into one scan step (burst waves of one-pod jobs
-            # collapse from thousands of steps to a handful).
-            kw["independent_jobs"] = np.array(
-                [len(tasks) == 1 for tasks in chunks])
-            # Host-mirror releasing hint: engages the fused kernel's
-            # no-releasing specialization without touching device state.
-            kw["has_releasing"] = ssn.has_releasing()
-        node_arrays = ssn._device_arrays()
-
-        def dispatch():
-            return ssn.dispatch_kernel(
-                lambda: kernel(
-                    node_arrays,
-                    np.stack(rows_req), np.array(task_jobs, np.int32),
-                    np.stack(rows_sel), np.stack(rows_tol),
-                    np.array(job_allowed),
-                    gpu_strategy=ssn.gpu_strategy,
-                    cpu_strategy=ssn.cpu_strategy,
-                    **kw),
-                label="allocate_bulk",
-                validate=lambda r: getattr(r.placements, "shape", (0,))[0]
-                >= len(rows_req))
-
-        if ssn.mesh is None:
-            # Guard verdict stamped on the cycle thread, the rung by the
-            # wrapper (the sharded kernel has no ladder, so mesh
-            # dispatches emit no allocate_fused span).
-            from ..ops.allocate_grouped import fused_dispatch_span
-            with fused_dispatch_span(bulk=True):
-                result = dispatch()
-        else:
-            result = dispatch()
-
-        success = np.asarray(result.job_success)
-        placements = np.asarray(result.placements)
-        pipelined = np.asarray(result.pipelined)
         progressed = False
-        ti = jobs_bound = ops = 0
+        jobs_bound = ops = 0
         # One span for the wave, not two a job: a fill wave is thousands
         # of one-pod jobs, and a span costs what a tenth of one's
         # statement does (PERF.md section 6, PR 25).
         with TRACER.span("statement:bulk", kind="commit") as sp:
-            for j, tasks in enumerate(chunks):
-                n = len(tasks)
-                if success[j]:
+            for pg, proposal in zip(ordered, proposals):
+                if proposal.success:
                     stmt = ssn.statement()
-                    pairs = [
-                        (task,
-                         ssn.snapshot.node_names[int(placements[ti + i])],
-                         bool(pipelined[ti + i]))
-                        for i, task in enumerate(tasks)]
-                    # Rank-aware reorder (ops/rankplace.py): the
-                    # registered fn re-verifies interchangeability before
-                    # permuting, so heterogeneous bulk chunks pass
-                    # through untouched.
-                    stmt.apply_bulk(ssn.apply_rank_placement(tasks, pairs))
-                    if ordered[j].should_pipeline():
-                        stmt.convert_all_allocated_to_pipelined(
-                            ordered[j].uid)
+                    stmt.apply_bulk(proposal.placements)
+                    if pg.should_pipeline():
+                        stmt.convert_all_allocated_to_pipelined(pg.uid)
                     stmt.commit()
                     progressed = True
                     jobs_bound += 1
-                    ops += n
-                ti += n
+                    ops += len(proposal.placements)
             sp.set(jobs=jobs_bound, ops=ops)
         if not progressed:
             # Record failures for explainability; leave retries to the
             # scenario actions.
-            for j, tasks in enumerate(chunks):
-                if not success[j] and tasks:
-                    _record_chunk_failure(ssn, ordered[j], tasks)
+            for pg, tasks, proposal in zip(ordered, chunks, proposals):
+                if not proposal.success and tasks:
+                    _record_chunk_failure(ssn, pg, tasks)
             break
 
     # Unplaced jobs need fit errors for explainability (and the
@@ -520,19 +410,23 @@ def _allocate_task_by_task(ssn, stmt, job, tasks, node_subset,
     return True
 
 
-def _allocate_fractional(ssn, stmt, task, node_subset,
-                         pipeline_only: bool) -> bool:
-    """gpu_sharing.AllocateFractionalGPUTaskToNode (gpuSharing.go:20)."""
-    # Restrict to real (non-padding) node rows.
+def _nodes_best_first(ssn, task, node_subset):
+    """The nodes a host-path task may take, best score first: inside the
+    node subset and the task's hard mask, real (non-padding) rows only."""
     scores = ssn.score_nodes_for_task(task)[:len(ssn.snapshot.node_names)]
-    order = np.argsort(-scores, kind="stable")
     hard_mask = ssn.compute_hard_mask([task])
-    for node_idx in order:
+    for node_idx in np.argsort(-scores, kind="stable"):
         if node_subset is not None and not node_subset[node_idx]:
             continue
         if hard_mask is not None and not hard_mask[0][node_idx]:
             continue
-        node = ssn.cluster.nodes[ssn.snapshot.node_names[int(node_idx)]]
+        yield ssn.cluster.nodes[ssn.snapshot.node_names[int(node_idx)]]
+
+
+def _allocate_fractional(ssn, stmt, task, node_subset,
+                         pipeline_only: bool) -> bool:
+    """gpu_sharing.AllocateFractionalGPUTaskToNode (gpuSharing.go:20)."""
+    for node in _nodes_best_first(ssn, task, node_subset):
         if not pipeline_only and node.is_task_allocatable(task):
             groups = node.find_gpu_groups_for_task(task,
                                                    allow_releasing=False)
@@ -554,15 +448,7 @@ def _allocate_mig(ssn, stmt, task, node_subset,
     reference resource_info.go:153-165 scalar accounting) and CSI storage
     capacity (node_info.is_task_storage_allocatable; reference
     node_info.go:200-268), both folded into is_task_allocatable."""
-    scores = ssn.score_nodes_for_task(task)[:len(ssn.snapshot.node_names)]
-    order = np.argsort(-scores, kind="stable")
-    hard_mask = ssn.compute_hard_mask([task])
-    for node_idx in order:
-        if node_subset is not None and not node_subset[node_idx]:
-            continue
-        if hard_mask is not None and not hard_mask[0][node_idx]:
-            continue
-        node = ssn.cluster.nodes[ssn.snapshot.node_names[int(node_idx)]]
+    for node in _nodes_best_first(ssn, task, node_subset):
         if not pipeline_only and node.is_task_allocatable(task):
             _apply_task(stmt, task, node.name, False)
             return True
@@ -578,15 +464,7 @@ def _allocate_with_claims(ssn, stmt, task, node_subset,
     available (dynamicresources.go PrePredicate + assume)."""
     dra = next((p for p in ssn.plugins
                 if p.name == "dynamicresources"), None)
-    scores = ssn.score_nodes_for_task(task)[:len(ssn.snapshot.node_names)]
-    order = np.argsort(-scores, kind="stable")
-    hard_mask = ssn.compute_hard_mask([task])
-    for node_idx in order:
-        if node_subset is not None and not node_subset[node_idx]:
-            continue
-        if hard_mask is not None and not hard_mask[0][node_idx]:
-            continue
-        node = ssn.cluster.nodes[ssn.snapshot.node_names[int(node_idx)]]
+    for node in _nodes_best_first(ssn, task, node_subset):
         if dra is not None and not dra.claims_schedulable(task, node.name):
             continue
         if not pipeline_only and node.is_task_allocatable(task):

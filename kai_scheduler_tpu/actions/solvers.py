@@ -23,8 +23,9 @@ import numpy as np
 
 from ..api import resources as rs
 from ..api.podgroup_info import PodGroupInfo
-from ..utils.metrics import METRICS
+from ..framework import propose
 from ..ops.allocate_grouped import _next_pow2
+from ..utils.metrics import METRICS
 from .allocate import attempt_to_allocate_job
 
 
@@ -244,8 +245,6 @@ def _prefix_prescreen(ssn, tasks, builder: "ScenarioBuilder"):
         if fn(tasks) is not None:
             return None
 
-    import jax.numpy as jnp
-
     from ..ops.scenario_batch import batch_prefix_feasibility
 
     METRICS.inc("device_kernel_calls")
@@ -274,37 +273,23 @@ def _prefix_prescreen(ssn, tasks, builder: "ScenarioBuilder"):
     release_vec = np.zeros((m_pad, n_res))
     release_vec[:len(rows_vec)] = rows_vec
 
-    rows = [ssn._task_row(t) for t in tasks]
-    if any(r[0] is None for r in rows):
-        return None
-    t_pad = _next_pow2(len(tasks))
-    task_req = np.zeros((t_pad, n_res))
-    task_req[:len(rows)] = [r[0] for r in rows]
-    task_sel = np.full((t_pad, rows[0][1].shape[0]), -1, np.int32)
-    task_sel[:len(rows)] = [r[1] for r in rows]
-    task_tol = np.full((t_pad, rows[0][2].shape[0]), -1, np.int32)
-    task_tol[:len(rows)] = [r[2] for r in rows]
     # Padding rows form their own job 1 so they can never fail job 0's
     # gang (a zero-req row could still miss on pod room).
-    task_job = np.zeros(t_pad, np.int32)
-    task_job[len(rows):] = 1
+    rows = propose.task_operands(
+        ssn, [(builder.scenario.pending_job, tasks)])
+    if rows is None:
+        return None
 
-    alloc, idle, rel, labels, taints, room = ssn._device_arrays()
     from ..utils.deviceguard import CycleDeadlineExceeded, DeviceGuardError
     try:
-        feasible = ssn.dispatch_kernel(
-            lambda: batch_prefix_feasibility(
-                alloc, idle, rel, labels, taints, room,
-                jnp.asarray(release_step), jnp.asarray(release_node),
-                jnp.asarray(release_vec),
-                jnp.asarray(task_req), jnp.asarray(task_job),
-                jnp.asarray(task_sel), jnp.asarray(task_tol),
-                num_prefixes=num_prefixes,
-                gpu_strategy=ssn.gpu_strategy,
-                cpu_strategy=ssn.cpu_strategy),
+        feasible = propose.run_on_nodes(
+            ssn, batch_prefix_feasibility,
+            (release_step, release_node, release_vec, rows.task_req,
+             rows.task_job, rows.task_sel, rows.task_tol),
             label="scenario_prescreen",
-            validate=lambda r: getattr(r, "shape", (0,))[0]
-            >= len(steps))
+            validate=lambda r: getattr(r, "shape", (0,))[0] >= len(steps),
+            num_prefixes=num_prefixes, gpu_strategy=ssn.gpu_strategy,
+            cpu_strategy=ssn.cpu_strategy)
     except CycleDeadlineExceeded:
         raise
     except DeviceGuardError:

@@ -1270,10 +1270,12 @@ class _Handler(BaseHTTPRequestHandler):
                         METRICS.inc("wire_faults_injected_total",
                                     mode="wire-stall")
                         time.sleep(stall_s)
+                    # Counted before the bytes leave: a client that has
+                    # read its frames must find them counted.
+                    METRICS.inc("watch_frame_cache_hits_total", n_frames)
                     t_burst = time.perf_counter()
                     self.wfile.write(buf)
                     burst_s = time.perf_counter() - t_burst
-                    METRICS.inc("watch_frame_cache_hits_total", n_frames)
                     # Fanout accounting: the burst left in ONE sendall
                     # of preserialized (cache-served) bytes; lag is
                     # what already accumulated behind this watcher

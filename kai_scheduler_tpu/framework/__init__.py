@@ -3,7 +3,8 @@
 
 from .conf import DEFAULT_ACTIONS, DEFAULT_PLUGINS, PluginConfig, \
     SchedulerConfig
-from .session import InMemoryCache, Proposal, SchedulableResult, Session
+from .propose import Proposal
+from .session import InMemoryCache, SchedulableResult, Session
 from .statement import Statement
 
 __all__ = ["DEFAULT_ACTIONS", "DEFAULT_PLUGINS", "PluginConfig",

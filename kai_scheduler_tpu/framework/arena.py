@@ -57,10 +57,10 @@ import numpy as np
 
 from ..api.snapshot import (SnapshotTensors, pack, pack_incremental,
                             vocabulary_signature)
+from ..ops.allocate_grouped import _next_pow2
 from ..utils.logging import LOG
 from ..utils.metrics import METRICS
 from ..utils.tracing import TRACER
-from .session import _next_pow2
 
 # Above this fraction of dirty rows a scatter loses to one contiguous
 # upload (scatter pays gather+kernel overhead per row; a bulk transfer

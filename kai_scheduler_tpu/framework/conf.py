@@ -64,9 +64,6 @@ class SchedulerConfig:
     use_scheduling_signatures: bool = True
     # Node-axis padding bucket to stabilize kernel shapes across cycles.
     node_pad_bucket: int = 0
-    # Back the session's dense node mirrors with the native C++ state
-    # store when the toolchain is available (native/statestore.cpp).
-    use_native_store: bool = True
     # Multi-chip: shard the node axis of the bulk-allocation kernel over
     # this many devices (0 = single chip).  The node axis pads to a mesh
     # multiple automatically.
@@ -76,7 +73,6 @@ class SchedulerConfig:
     # round (job order fixed per round) instead of one call per job.
     # 0 disables bulk mode.
     bulk_allocation_threshold: int = 32
-    bulk_allocation_max_rounds: int = 8
     # Fair-share division path: "forest" runs the whole queue hierarchy
     # as ONE jitted dispatch with cached host prep (ops/fairshare.py
     # fair_share_forest, DESIGN §2b); "levels" keeps the per-level

@@ -22,10 +22,11 @@ from ..api.cluster_info import ClusterInfo
 from ..api.pod_info import PodInfo
 from ..api.podgroup_info import PodGroupInfo
 from ..api.snapshot import SnapshotTensors, pack
-from ..ops.allocate import allocate_jobs_kernel
 from ..ops.scoring import BINPACK
 from ..utils.metrics import METRICS
 from ..utils.tracing import TRACER
+from . import propose
+from .propose import Proposal
 from .statement import Statement
 
 
@@ -34,13 +35,6 @@ class SchedulableResult:
     schedulable: bool = True
     reason: str = ""
     message: str = ""
-
-
-@dataclass
-class Proposal:
-    """A gang placement proposal from the device kernel."""
-    success: bool
-    placements: list  # [(task, node_name, pipelined)]
 
 
 class InMemoryCache:
@@ -76,87 +70,6 @@ class InMemoryCache:
 
     def record_event(self, kind: str, message: str) -> None:
         self.events.append((kind, message))
-
-
-def _next_pow2(n: int) -> int:
-    p = 1
-    while p < n:
-        p *= 2
-    return p
-
-
-def _pad_rows(rows, t_pad: int, fill):
-    """A per-task [t, N] operand padded to the kernel's [t_pad, N] with
-    ``fill`` rows for the padding tasks; None stays None."""
-    if rows is None or rows.shape[0] == t_pad:
-        return rows
-    out = np.full((t_pad,) + rows.shape[1:], fill, rows.dtype)
-    out[:rows.shape[0]] = rows
-    return out
-
-
-def _note_operand_forms(span, extras: str, mask: str) -> None:
-    """Say on ``propose:operands``, and count, which form the score and
-    hard-mask operands of this dispatch took: ``none`` (no operand),
-    ``row`` (one [N] row a job) or ``dense`` ([T,N])."""
-    span.set(extras=extras, mask=mask)
-    METRICS.inc("propose_operand_form_total", extras=extras, mask=mask)
-
-
-def _allocation_shape_check(t_pad: int):
-    """Device-guard validator for allocation results: the task axis must
-    match what was dispatched (a truncated/garbled device answer — the
-    ``badshape`` fault class — must read as a device failure, never be
-    silently unpacked)."""
-    def ok(result) -> bool:
-        try:
-            if result.placements.shape[0] < t_pad:
-                return False
-            packed = getattr(result, "packed", None)
-            if packed is not None and \
-                    packed.shape[0] != 2 * result.placements.shape[0] \
-                    + result.job_success.shape[0]:
-                # packed is placements ++ pipelined ++ job_success
-                # ([T + T + J], ops/allocate.py AllocationResult).
-                return False
-            return True
-        except Exception:
-            return False
-    return ok
-
-
-def _stage(*operands):
-    """The host operands of an exact-kernel call as device arrays, in the
-    order given (None stays None, a tuple of arrays comes back a tuple).
-
-    Runs inside the dispatch thunk, on the guard's worker: ``jnp.asarray``
-    converts a host array whose dtype the regime narrows (f64 to f32
-    without x64) on the host, then enqueues the upload.  The one place
-    host operands cross to the device, so the bytes are counted here:
-    ``device_upload_bytes`` (device side, every operand) and
-    ``host_convert_bytes`` (host side, the operands whose dtype changed)."""
-    with TRACER.span("seam:stage", kind="seam") as sp:
-        host = device = converted = count = 0
-
-        def put(a):
-            nonlocal host, device, converted, count
-            if isinstance(a, jax.Array):
-                return a
-            out = jnp.asarray(a)
-            a = np.asarray(a)
-            count += 1
-            host += a.nbytes
-            device += out.nbytes
-            if out.dtype != a.dtype:
-                converted += a.nbytes
-            return out
-
-        staged = jax.tree_util.tree_map(put, operands)
-        sp.set(bytes_host=host, bytes_device=device,
-               bytes_converted=converted, operands=count)
-    METRICS.inc("device_upload_bytes", device)
-    METRICS.inc("host_convert_bytes", converted)
-    return staged
 
 
 def _unpack_allocation(result, t: int):
@@ -311,47 +224,46 @@ class Session:
         # has no compiler to build it with, then plain numpy.  ``/healthz``
         # reports which one a daemon runs on.
         self._native = None
-        if self.config.use_native_store:
-            from ..native import NativeNodeTable, native_available
-            if native_available():
-                if table is None:
-                    table = NativeNodeTable(snap.node_allocatable.shape[0],
-                                            snap.node_allocatable.shape[1])
-                    table.bulk_load(
-                        snap.node_allocatable,
-                        snap.node_allocatable - snap.node_idle,
-                        snap.node_releasing, snap.node_pod_room)
-                    to_bind = cluster.nodes.values()
-                else:
-                    # The table of the session before: its used and
-                    # releasing rows are what the NodeInfo objects have
-                    # been writing to since, so only the pod room and the
-                    # binding of the rows that moved are stale.
-                    table.room[dirty_rows] = snap.node_pod_room[dirty_rows]
-                    to_bind = [cluster.nodes[snap.node_names[i]]
-                               for i in dirty_rows]
-                self._native = table
-                # Single source of truth: rebind each NodeInfo's
-                # used/releasing to zero-copy VIEWS of its table row.
-                # Statement accounting then updates the object graph
-                # and the packed kernel inputs in one native write —
-                # no per-task copy-back (the dominant host cost at
-                # 100k-node scale).  All in-tree mutations are
-                # in-place (+=/-=); clone() detaches via .copy().
-                used_rows = table.used
-                rel_rows = table.releasing
-                for node in to_bind:
-                    i = node.idx
-                    if 0 <= i < table.n_nodes and \
-                            node.used.shape[0] == table.n_res:
-                        used_rows[i] = node.used
-                        rel_rows[i] = node.releasing
-                        node.used = used_rows[i]
-                        node.releasing = rel_rows[i]
-                        # Whose rows these views are is part of what a
-                        # carried table rests on: a session that re-binds
-                        # a node says so.
-                        node.touch()
+        from ..native import NativeNodeTable, native_available
+        if native_available():
+            if table is None:
+                table = NativeNodeTable(snap.node_allocatable.shape[0],
+                                        snap.node_allocatable.shape[1])
+                table.bulk_load(
+                    snap.node_allocatable,
+                    snap.node_allocatable - snap.node_idle,
+                    snap.node_releasing, snap.node_pod_room)
+                to_bind = cluster.nodes.values()
+            else:
+                # The table of the session before: its used and
+                # releasing rows are what the NodeInfo objects have
+                # been writing to since, so only the pod room and the
+                # binding of the rows that moved are stale.
+                table.room[dirty_rows] = snap.node_pod_room[dirty_rows]
+                to_bind = [cluster.nodes[snap.node_names[i]]
+                           for i in dirty_rows]
+            self._native = table
+            # Single source of truth: rebind each NodeInfo's
+            # used/releasing to zero-copy VIEWS of its table row.
+            # Statement accounting then updates the object graph
+            # and the packed kernel inputs in one native write —
+            # no per-task copy-back (the dominant host cost at
+            # 100k-node scale).  All in-tree mutations are
+            # in-place (+=/-=); clone() detaches via .copy().
+            used_rows = table.used
+            rel_rows = table.releasing
+            for node in to_bind:
+                i = node.idx
+                if 0 <= i < table.n_nodes and \
+                        node.used.shape[0] == table.n_res:
+                    used_rows[i] = node.used
+                    rel_rows[i] = node.releasing
+                    node.used = used_rows[i]
+                    node.releasing = rel_rows[i]
+                    # Whose rows these views are is part of what a
+                    # carried table rests on: a session that re-binds
+                    # a node says so.
+                    node.touch()
         if self._native is None:
             self._np_idle = self.snapshot.node_idle.copy()
             self._np_releasing = self.snapshot.node_releasing.copy()
@@ -787,101 +699,14 @@ class Session:
         ``job_chunks``: [(job, tasks)].  Returns {job_uid: Proposal} with
         per-job gang atomicity (the kernel's per-job success gating), or
         None when any chunk needs per-job machinery the concatenated call
-        cannot express (domain rows from anti/affinity plugins)."""
-        METRICS.inc("device_kernel_calls")
-        snap = self.snapshot
-        all_tasks = [t for _job, tasks in job_chunks for t in tasks]
-        t = len(all_tasks)
-        if t == 0:
-            return {}
-        n_nodes = self.node_idle.shape[0]
-        t_pad = _next_pow2(t)
-        with TRACER.span("propose:operands", kind="propose", t=t,
-                         t_pad=t_pad, nodes=n_nodes,
-                         path="multi") as operands_span:
-            for fn in self.anti_domain_fns + self.affinity_domain_fns:
-                if fn(all_tasks) is not None:
-                    return None
-
-            task_req = np.zeros((t_pad, snap.task_req.shape[1]))
-            task_sel = np.full((t_pad, snap.task_selector.shape[1]), -1,
-                               np.int32)
-            task_tol = np.full((t_pad, snap.task_tolerations.shape[1]), -1,
-                               np.int32)
-            task_job = np.full(t_pad, len(job_chunks), np.int32)  # padding
-            # Bucket the job axis too (KJT001): [J+1] exact would retrace
-            # the allocate kernel per distinct live gang count.  Padding
-            # jobs are gated out (allowed=False) and own only padding
-            # tasks, so nothing the kernel reads of them is used;
-            # consumers index success[j] for real jobs only.
-            j_pad = _next_pow2(len(job_chunks) + 1)
-            job_allowed = np.ones(j_pad, bool)
-            job_allowed[len(job_chunks):] = False
-            # Each chunk's extra scores are its own job's: a row goes to
-            # the job's row, per-task rows to the chunk's task rows.
-            job_extra = task_extra = None
-            row = 0
-            for j, (_job, tasks) in enumerate(job_chunks):
-                extra = self._sum_extra_scores(tasks)
-                if extra is not None and extra.ndim == 1:
-                    if job_extra is None:
-                        job_extra = np.zeros((j_pad, n_nodes))
-                    job_extra[j] = extra
-                elif extra is not None:
-                    if task_extra is None:
-                        task_extra = np.zeros((t_pad, n_nodes))
-                    task_extra[row:row + len(tasks)] = extra
-                for task in tasks:
-                    req, sel, tol = self._task_row(task)
-                    if req is None:
-                        return None
-                    task_req[row], task_sel[row, :len(sel)] = req, sel
-                    task_tol[row, :len(tol)] = tol
-                    task_job[row] = j
-                    row += 1
-
-            mask_pad = _pad_rows(self.compute_hard_mask(all_tasks), t_pad,
-                                 True)
-            _note_operand_forms(
-                operands_span,
-                extras="dense" if task_extra is not None else
-                "row" if job_extra is not None else "none",
-                mask="none" if mask_pad is None else "dense")
-
-        node_arrays = self._device_arrays()
-
-        def thunk():
-            (d_req, d_job, d_sel, d_tol, d_allowed, d_extra, d_mask,
-             d_job_extra) = _stage(task_req, task_job, task_sel, task_tol,
-                                   job_allowed, task_extra, mask_pad,
-                                   job_extra)
-            with TRACER.span("seam:launch", kind="seam",
-                             kernel="allocate_jobs_multi"):
-                return allocate_jobs_kernel(
-                    *node_arrays, d_req, d_job, d_sel, d_tol, d_allowed,
-                    d_extra, task_node_mask=d_mask,
-                    job_extra_scores=d_job_extra,
-                    gpu_strategy=self.gpu_strategy,
-                    cpu_strategy=self.cpu_strategy,
-                    allow_pipeline=True, pipeline_only=pipeline_only)
-
-        placed, piped, success = self._dispatch_and_fetch(
-            thunk, label="allocate_jobs_multi",
-            validate=_allocation_shape_check(t_pad), t=t)
-        with TRACER.span("propose:unpack", kind="propose", t=t):
-            out = {}
-            row = 0
-            for j, (job, tasks) in enumerate(job_chunks):
-                rows = range(row, row + len(tasks))
-                row += len(tasks)
-                if not bool(success[j]) or any(placed[r] < 0
-                                               for r in rows):
-                    out[job.uid] = Proposal(False, [])
-                    continue
-                out[job.uid] = Proposal(True, [
-                    (task, snap.node_names[int(placed[r])], bool(piped[r]))
-                    for task, r in zip(tasks, rows)])
-        return out
+        cannot express (domain rows from anti/affinity plugins) or holds
+        a task that cannot be encoded."""
+        out = propose.propose(self, job_chunks, "multi",
+                              pipeline_only=pipeline_only)
+        if out is None:
+            return None
+        return {job.uid: prop for (job, _tasks), prop
+                in zip(job_chunks, out)}
 
     def propose_placements(self, tasks: list[PodInfo],
                            pipeline_only: bool = False,
@@ -889,234 +714,13 @@ class Session:
                            node_subset: np.ndarray | None = None
                            ) -> Proposal:
         """Run the gang-allocation kernel for one job's task chunk against
-        the current (statement-mutated) node state."""
-        METRICS.inc("device_kernel_calls")
-        snap = self.snapshot
-        t = len(tasks)
-        t_pad = _next_pow2(max(t, 1))
-        n_nodes = self.node_idle.shape[0]
-
-        with TRACER.span("propose:operands", kind="propose", t=t,
-                         t_pad=t_pad, nodes=n_nodes) as operands_span:
-            task_req = np.zeros((t_pad, snap.task_req.shape[1]))
-            task_sel = np.full((t_pad, snap.task_selector.shape[1]), -1,
-                               np.int32)
-            task_tol = np.full((t_pad, snap.task_tolerations.shape[1]), -1,
-                               np.int32)
-            for i, task in enumerate(tasks):
-                req, sel, tol = self._task_row(task)
-                if req is None:
-                    return Proposal(False, [])
-                task_req[i], task_sel[i, :len(sel)] = req, sel
-                task_tol[i, :len(tol)] = tol
-            task_job = np.zeros(t_pad, np.int32)
-            task_job[t:] = 1  # padding rows: a gated-out dummy job
-            job_allowed = np.array([True, False])
-
-            # None, one [N] row for the whole chunk, or [t, N].
-            extra = self._sum_extra_scores(tasks)
-
-            # Hard per-task node masks (inter-pod affinity terms, upstream
-            # predicate verdicts): False = infeasible, enforced in-kernel.
-            mask = self.compute_hard_mask(tasks)
-            # The topology node subset is a hard mask too (matching the
-            # fractional/MIG handlers, which skip out-of-subset nodes
-            # unconditionally): an out-of-subset node is infeasible, not
-            # a soft last resort.  It is the job's row, ANDed with the
-            # per-task mask wherever both exist, on every path.
-            subset = (None if node_subset is None
-                      else np.asarray(node_subset, bool))
-            _note_operand_forms(
-                operands_span,
-                extras="none" if extra is None else
-                "row" if extra.ndim == 1 else "dense",
-                mask="dense" if mask is not None else
-                "none" if subset is None else "row")
-            # Self-anti-affinity domain rows (spread-one-per-domain gangs).
-            anti_dom = None
-            for fn in self.anti_domain_fns:
-                contrib = fn(tasks)
-                if contrib is not None:
-                    anti_dom = contrib
-                    break
-            # In-gang required-affinity domain rows (co-locate gangs).
-            aff_dom = None
-            for fn in self.affinity_domain_fns:
-                contrib = fn(tasks)
-                if contrib is not None:
-                    aff_dom = contrib
-                    break
-
-            # Homogeneous chunks take the grouped fill-plan kernel: one
-            # scan step instead of one per task.  Extra score terms and
-            # hard masks ride along when per-job uniform (one [N] row for
-            # the whole chunk) — extras must be tier constants (multiples
-            # of 10) for the fill plan's ordering invariance
-            # (allocate_groups_kernel); a node subset becomes a hard mask
-            # row.
-            homogeneous = (
-                t > 1 and anti_dom is None and aff_dom is None
-                and self.gpu_strategy == BINPACK
-                and self.cpu_strategy == BINPACK
-                and (task_req[1:t] == task_req[0]).all()
-                and (task_sel[1:t] == task_sel[0]).all()
-                and (task_tol[1:t] == task_tol[0]).all())
-            row_extra = row_mask = None
-            if homogeneous and extra is not None and extra.any():
-                row = extra if extra.ndim == 1 else extra[0]
-                if (extra.ndim == 1 or (extra[1:] == row).all()) and bool(
-                        np.all(np.remainder(row, 10.0) == 0.0)):
-                    row_extra = row[None, :]
-                else:
-                    homogeneous = False
-            if homogeneous and mask is not None:
-                if (mask[1:] == mask[0]).all():
-                    row_mask = (mask[:1] if subset is None
-                                else mask[:1] & subset)
-                else:
-                    homogeneous = False
-            elif homogeneous and subset is not None:
-                row_mask = subset[None, :]
-            # Multi-chip exact kernel (parallel/sharded.py): node axis
-            # sharded over the mesh, bit-identical tie-breaks.  Domain
-            # rows, extra score terms, and pipeline-only proposals stay
-            # on the single-chip kernel (unsupported under shard_map).
-            sharded = (not homogeneous and self.mesh is not None
-                       and anti_dom is None and aff_dom is None
-                       and not pipeline_only and extra is None)
-            operands_span.set(path="grouped" if homogeneous else
-                              "sharded" if sharded else "exact")
-            if not homogeneous:
-                dom_pad = aff_pad = None
-                # A job's rows are [2,N]: row 1 is the padding job's,
-                # read by the padding tasks and never used.
-                job_extra = task_extra = job_mask = None
-                if extra is not None and extra.ndim == 1:
-                    job_extra = np.zeros((2, n_nodes))
-                    job_extra[0] = extra
-                else:
-                    task_extra = _pad_rows(extra, t_pad, 0.0)
-                if subset is not None and sharded:
-                    # The sharded kernel takes no job rows: its [T,N]
-                    # mask is built here, for this path alone.
-                    mask = (np.broadcast_to(subset, (t, n_nodes))
-                            if mask is None else mask & subset)
-                elif subset is not None:
-                    job_mask = np.ones((2, n_nodes), bool)
-                    job_mask[0] = subset
-                mask_pad = _pad_rows(mask, t_pad, True)
-                if anti_dom is not None:
-                    doms, marks, avoids = anti_dom
-                    d = np.full((t_pad, n_nodes), -1, np.int32)
-                    d[:t] = doms
-                    m = np.zeros(t_pad, bool)
-                    m[:t] = marks
-                    a = np.zeros(t_pad, bool)
-                    a[:t] = avoids
-                    dom_pad = (d, m, a)
-                if aff_dom is not None:
-                    doms, marks, avoids, static_ok, boot = aff_dom
-                    d = np.full((t_pad, n_nodes), -1, np.int32)
-                    d[:t] = doms
-                    m = np.zeros(t_pad, bool)
-                    m[:t] = marks
-                    a = np.zeros(t_pad, bool)
-                    a[:t] = avoids
-                    st = np.ones((t_pad, n_nodes), bool)
-                    st[:t] = static_ok
-                    b = np.zeros(t_pad, bool)
-                    b[:t] = boot
-                    aff_pad = (d, m, a, st, b)
-
-        node_arrays = self._device_arrays()
-        if homogeneous:
-            from ..ops import allocate_grouped as ag
-            # The span helper stamps the guard verdict on the cycle
-            # thread; the wrapper stamps the rung it resolved.
-            with ag.fused_dispatch_span():
-                result = self.dispatch_kernel(
-                    lambda: ag.allocate_grouped(
-                        node_arrays, task_req[:t], np.zeros(t, np.int32),
-                        task_sel[:t], task_tol[:t], np.ones(1, bool),
-                        gpu_strategy=self.gpu_strategy,
-                        cpu_strategy=self.cpu_strategy,
-                        allow_pipeline=allow_pipeline,
-                        pipeline_only=pipeline_only,
-                        extra_scores=row_extra,
-                        node_mask=row_mask,
-                        has_releasing=self.has_releasing()),
-                    label="allocate_grouped",
-                    validate=_allocation_shape_check(t))
-            if not bool(result.job_success[0]):
-                return Proposal(False, [])
-            with TRACER.span("propose:unpack", kind="propose", t=t):
-                placements = []
-                placed = np.asarray(result.placements)
-                piped = np.asarray(result.pipelined)
-                for i, task in enumerate(tasks):
-                    node_idx = int(placed[i])
-                    if node_idx < 0:
-                        return Proposal(False, [])
-                    placements.append((task, snap.node_names[node_idx],
-                                       bool(piped[i])))
-                # The homogeneous check above proved the chunk's tasks
-                # interchangeable — the one precondition rank reorder
-                # needs.
-                return Proposal(True, self.apply_rank_placement(
-                    tasks, placements))
-
-        if sharded:
-            from ..parallel.sharded import sharded_allocate_jobs
-
-            def thunk():
-                d_req, d_job, d_sel, d_tol, d_allowed, d_mask = _stage(
-                    task_req, task_job, task_sel, task_tol, job_allowed,
-                    mask_pad)
-                with TRACER.span("seam:launch", kind="seam",
-                                 kernel="allocate_jobs_sharded"):
-                    return sharded_allocate_jobs(
-                        self.mesh, *node_arrays, d_req, d_job, d_sel,
-                        d_tol, d_allowed, task_node_mask=d_mask,
-                        gpu_strategy=self.gpu_strategy,
-                        cpu_strategy=self.cpu_strategy,
-                        allow_pipeline=allow_pipeline)
-            label = "allocate_jobs_sharded"
-        else:
-            def thunk():
-                (d_req, d_job, d_sel, d_tol, d_allowed, d_extra, d_mask,
-                 d_dom, d_aff, d_job_extra, d_job_mask) = _stage(
-                    task_req, task_job, task_sel, task_tol, job_allowed,
-                    task_extra, mask_pad, dom_pad, aff_pad, job_extra,
-                    job_mask)
-                with TRACER.span("seam:launch", kind="seam",
-                                 kernel="allocate_jobs"):
-                    return allocate_jobs_kernel(
-                        *node_arrays, d_req, d_job, d_sel, d_tol,
-                        d_allowed, d_extra, task_node_mask=d_mask,
-                        task_anti_domain=d_dom, task_aff_domain=d_aff,
-                        job_extra_scores=d_job_extra,
-                        job_node_mask=d_job_mask,
-                        gpu_strategy=self.gpu_strategy,
-                        cpu_strategy=self.cpu_strategy,
-                        allow_pipeline=allow_pipeline,
-                        pipeline_only=pipeline_only)
-            label = "allocate_jobs"
-        placed, piped, success = self._dispatch_and_fetch(
-            thunk, label=label, validate=_allocation_shape_check(t_pad),
-            t=t)
-        if not bool(success[0]):
-            return Proposal(False, [])
-        with TRACER.span("propose:unpack", kind="propose", t=t):
-            placements = []
-            for i, task in enumerate(tasks):
-                node_idx = int(placed[i])
-                if node_idx < 0:
-                    return Proposal(False, [])
-                if node_subset is not None and not node_subset[node_idx]:
-                    return Proposal(False, [])
-                placements.append((task, snap.node_names[node_idx],
-                                   bool(piped[i])))
-        return Proposal(True, placements)
+        the current (statement-mutated) node state: the one-chunk call of
+        ``framework/propose.py``."""
+        out = propose.propose(self, [(None, tasks)], "single",
+                              pipeline_only=pipeline_only,
+                              allow_pipeline=allow_pipeline,
+                              node_subset=node_subset)
+        return Proposal(False, []) if out is None else out[0]
 
     def _task_row(self, task: PodInfo):
         """(req [R], selector [L], tolerations [Tl]) for any task: packed
@@ -1146,43 +750,7 @@ class Session:
 
     def score_nodes_for_task(self, task: PodInfo) -> np.ndarray:
         """[N] score row for host-side paths (fractional GPU placement)."""
-        from ..ops.predicates import feasibility_masks
-        from ..ops.scoring import score_matrix
-        snap = self.snapshot
-        req_row, sel_row, tol_row = self._task_row(task)
-        if req_row is None:
-            return np.zeros(self.node_idle.shape[0])
-        req = req_row[None, :]
-        alloc, idle, rel, labels, taints, room = self._device_arrays()
-        n_nodes = self.node_idle.shape[0]
-
-        def score_thunk():
-            # Fractional tasks: capacity-check the cpu/mem axes; GPU
-            # device fit is decided host-side by the sharing-group logic.
-            fit_now, fit_future = feasibility_masks(
-                idle, rel, labels, taints, room, jnp.asarray(req),
-                jnp.asarray(sel_row[None, :]),
-                jnp.asarray(tol_row[None, :]))
-            score = score_matrix(
-                alloc, idle, jnp.asarray(req), fit_now, fit_future,
-                gpu_strategy=self.gpu_strategy,
-                cpu_strategy=self.cpu_strategy)
-            return np.asarray(score[0]).copy()
-
-        out = self.dispatch_kernel(
-            score_thunk, label="score_nodes",
-            validate=lambda r: getattr(r, "shape", (0,))[0] == n_nodes)
-        # Plugin score terms apply to host-side paths too: without them a
-        # nominated (pipelined-last-cycle) fractional task loses its
-        # sticky node and flaps between devices across cycles; preferred
-        # node affinity would likewise be ignored.
-        for fn in self.extra_score_fns:
-            contrib = fn([task])
-            if contrib is not None:
-                contrib = np.asarray(contrib)
-                # One [N] row for the chunk, or the one task's of [1,N].
-                out += contrib if contrib.ndim == 1 else contrib[0]
-        return out
+        return propose.score_nodes(self, task)
 
     def node_index(self, name: str) -> int:
         return self._node_index.get(name, -1)

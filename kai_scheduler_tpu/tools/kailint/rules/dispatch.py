@@ -19,7 +19,8 @@ from host layers, resolving ``from ..ops.x import k`` aliases and
 ``lambda`` are exempt — that is precisely the thunk handed to
 ``dispatch_kernel`` — and so are calls inside a named nested function
 that is itself passed to a ``dispatch_kernel(...)`` call (the
-multi-statement thunk idiom).
+multi-statement thunk idiom) or to ``Session._dispatch_and_fetch(...)``,
+the pipelined form of the same guarded dispatch.
 """
 
 from __future__ import annotations
@@ -69,12 +70,14 @@ class UnguardedDispatchRule(Rule):
     @staticmethod
     def _dispatch_thunk_names(tree: ast.AST) -> set[str]:
         """Names of functions passed (as a bare Name argument) to a
-        ``dispatch_kernel(...)`` call — named thunks are guarded."""
+        ``dispatch_kernel(...)`` or ``_dispatch_and_fetch(...)`` call —
+        named thunks are guarded."""
         out: set[str] = set()
         for node in ast.walk(tree):
             if isinstance(node, ast.Call) and \
                     isinstance(node.func, ast.Attribute) and \
-                    node.func.attr == "dispatch_kernel":
+                    node.func.attr in ("dispatch_kernel",
+                                       "_dispatch_and_fetch"):
                 for arg in node.args:
                     if isinstance(arg, ast.Name):
                         out.add(arg.id)

@@ -295,6 +295,62 @@ class TestSessionOperandForms:
             assert prop.success
             assert {n for _t, n, _p in prop.placements} == {node}
 
+    @pytest.mark.parametrize("seed", range(3))
+    @pytest.mark.parametrize("hard_mask", (False, True))
+    @pytest.mark.parametrize("form", ("none", "row", "dense"))
+    def test_the_single_call_is_the_one_chunk_multi_call(
+            self, form, hard_mask, seed, monkeypatch):
+        """One path behind both names: the same operands cross the seam,
+        array for array, and the same placements come back."""
+        import jax
+
+        from kai_scheduler_tpu.framework import propose
+        n, workers = 16, 5
+        rng = np.random.default_rng(400 + seed)
+        ssn = build_session(rack_cluster(n_nodes=n, workers=workers,
+                                         topology=False))
+        job = ssn.cluster.podgroups["gang"]
+        tasks = list(job.pods.values())
+        # Scores that decide placements (no multiples of anything) and a
+        # mask that leaves every task room.
+        scores = {"none": None, "row": rng.random(n) * 50.0,
+                  "dense": rng.random((len(tasks), n)) * 50.0}[form]
+        ssn.extra_score_fns[:] = [lambda ts: scores]
+        if hard_mask:
+            mask = rng.random((len(tasks), n)) < 0.7
+            ssn.hard_node_mask_fns.append(lambda ts: mask)
+
+        staged = []
+        real_stage = propose._stage
+
+        def recording_stage(*operands):
+            staged.append(operands)
+            return real_stage(*operands)
+        monkeypatch.setattr(propose, "_stage", recording_stage)
+
+        before = form_count(form, "dense" if hard_mask else "none")
+        single = ssn.propose_placements(tasks)
+        multi = ssn.propose_placements_multi([(job, tasks)],
+                                             pipeline_only=False)
+        assert form_count(form, "dense" if hard_mask else "none") \
+            == before + 2
+        assert single.success and multi[job.uid] == single
+        if form != "none" or hard_mask:
+            ssn.extra_score_fns[:] = []
+            ssn.hard_node_mask_fns[:] = []
+            assert ssn.propose_placements(tasks) != single, \
+                "the operands decide nothing: the case proves nothing"
+            del staged[2:]
+
+        by_single, by_multi = staged
+        assert jax.tree_util.tree_structure(by_single) \
+            == jax.tree_util.tree_structure(by_multi)
+        leaves = jax.tree_util.tree_leaves(by_single)
+        assert len(leaves) == 5 + (form != "none") + hard_mask
+        for a, b in zip(leaves, jax.tree_util.tree_leaves(by_multi)):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(a, b)
+
     def test_multi_mixes_a_row_job_and_a_per_task_job(self):
         spec = rack_cluster(n_nodes=16, workers=1)
         spec["jobs"]["other"] = dict(spec["jobs"]["gang"])

@@ -277,12 +277,16 @@ class TestKAI004UnguardedDispatch:
                         ("kai_scheduler_tpu/actions/fix.py", action))
         assert [f for f in findings if f.rule == "KAI004"] == []
 
-    def test_named_thunk_is_guarded(self):
+    @pytest.mark.parametrize("entry", ("dispatch_kernel",
+                                       "_dispatch_and_fetch"))
+    def test_named_thunk_is_guarded(self, entry):
+        """Blocking or pipelined: both entries run the thunk under the
+        guard."""
         action = ("from ..ops.kern import fast_kernel\n"
                   "def run(ssn, x):\n"
                   "    def thunk():\n"
                   "        return fast_kernel(x)\n"
-                  "    return ssn.dispatch_kernel(thunk, label='x')\n")
+                  f"    return ssn.{entry}(thunk, label='x')\n")
         findings = lint(OPS_MODULE,
                         ("kai_scheduler_tpu/actions/fix.py", action))
         assert [f for f in findings if f.rule == "KAI004"] == []
@@ -1279,6 +1283,77 @@ class TestPackageGate:
             cwd=REPO_ROOT, capture_output=True, text=True, timeout=120)
         assert proc.returncode == 0, proc.stdout + proc.stderr
         assert "0 new finding(s)" in proc.stdout
+
+
+# ---------------------------------------------------------------------------
+# the seam: actions ask framework/propose.py, they do not reach around it
+# ---------------------------------------------------------------------------
+
+ACTIONS = os.path.join(PACKAGE, "actions")
+# Allocation kernels and their span helper: what only framework/propose.py
+# may import.  actions/solvers.py keeps its own kernel,
+# ops.scenario_batch.batch_prefix_feasibility.
+KERNEL_MODULES = ("ops.allocate_grouped", "ops.allocate")
+KERNEL_NAMES = {"allocate_grouped", "allocate_groups_kernel",
+                "allocate_jobs_kernel", "fused_dispatch_span"}
+SESSION_PRIVATES = {"_task_row", "_device_arrays"}
+
+
+def _reach_arounds(source: str) -> list[str]:
+    import ast
+    found = []
+    for node in ast.walk(ast.parse(source)):
+        if isinstance(node, ast.ImportFrom):
+            module = node.module or ""
+            names = {alias.name for alias in node.names}
+            if module.split(".")[0] == "parallel" or "parallel" in names \
+                    and not module:
+                found.append(f"line {node.lineno}: imports from parallel")
+            if module in KERNEL_MODULES and names & KERNEL_NAMES:
+                found.append(f"line {node.lineno}: imports "
+                             f"{sorted(names & KERNEL_NAMES)} from {module}")
+            if module == "ops" and names & {"allocate_grouped", "allocate"}:
+                found.append(f"line {node.lineno}: imports a kernel module")
+        elif isinstance(node, ast.Import):
+            for alias in node.names:
+                if ".parallel" in alias.name or any(
+                        alias.name.endswith(m) for m in KERNEL_MODULES):
+                    found.append(f"line {node.lineno}: import {alias.name}")
+        elif isinstance(node, ast.Attribute) \
+                and node.attr in SESSION_PRIVATES:
+            found.append(f"line {node.lineno}: reads .{node.attr}")
+    return found
+
+
+class TestActionsAskPropose:
+    @pytest.mark.parametrize("module", sorted(
+        f for f in os.listdir(ACTIONS) if f.endswith(".py")))
+    def test_no_action_reaches_around_the_seam(self, module):
+        """No module under actions/ imports from ..parallel, imports an
+        allocation kernel or ``fused_dispatch_span``, or reads
+        ``_task_row`` / ``_device_arrays`` off a session."""
+        with open(os.path.join(ACTIONS, module)) as f:
+            assert _reach_arounds(f.read()) == []
+
+    @pytest.mark.parametrize("source", (
+        "from ..parallel.sharded_grouped import sharded_allocate_grouped",
+        "from ..parallel import cluster_mesh",
+        "from .. import parallel",
+        "from ..ops.allocate_grouped import allocate_grouped",
+        "from ..ops.allocate_grouped import fused_dispatch_span",
+        "from ..ops.allocate import allocate_jobs_kernel",
+        "from ..ops import allocate_grouped as ag",
+        "def f(ssn, t):\n    return ssn._task_row(t)",
+        "def f(ssn):\n    return ssn._device_arrays()",
+    ))
+    def test_the_check_sees_each_reach_around(self, source):
+        assert _reach_arounds(source)
+
+    def test_the_check_lets_the_prescreen_keep_its_kernel(self):
+        assert _reach_arounds(
+            "from ..ops.scenario_batch import batch_prefix_feasibility\n"
+            "from ..ops.allocate_grouped import _next_pow2\n"
+            "from ..framework import propose\n") == []
 
 
 if __name__ == "__main__":

@@ -261,6 +261,46 @@ class TestBatchedPrescreen:
         # successful simulation, instead of 4 sequential scenarios.
         assert after - before == 2
 
+    def test_prescreen_operands_are_counted_at_the_seam(self):
+        """The prescreen's operands cross where every kernel's do:
+        ``device_upload_bytes`` moves by the bytes of the release rows
+        and the claimer's padded task rows."""
+        from kai_scheduler_tpu.utils.metrics import METRICS
+        from kai_scheduler_tpu.utils.tracing import TRACER
+        jobs = {
+            f"v{i}": {"queue": "b", "tasks": [
+                {"gpu": 1, "status": "RUNNING", "node": "n1"}]}
+            for i in range(8)}
+        jobs["claimer"] = {"queue": "a", "tasks": [{"gpu": 4}]}
+        ssn = build_session({
+            "nodes": {"n1": {"gpu": 8}},
+            "queues": {"a": {"deserved": {"gpu": 4}},
+                       "b": {"deserved": {"gpu": 4}}},
+            "jobs": jobs,
+        })
+        before = METRICS.counters.get("device_upload_bytes", 0.0)
+        TRACER.begin_cycle(1)
+        run_action(ssn, "reclaim")
+        trace = TRACER.end_cycle()
+        moved = METRICS.counters.get("device_upload_bytes", 0.0) - before
+        ids = {sp.span_id: sp for sp in trace.spans}
+        stages = [sp for sp in trace.spans if sp.name == "seam:stage"]
+        (prescreen,) = [sp for sp in stages if ids[sp.parent_id].name
+                        == "dispatch:scenario_prescreen"]
+        # Seven victim steps are left after the one failed simulation:
+        # 8 prefixes, 8 release rows; one claimer task, no padding.
+        snap = ssn.snapshot
+        n_res = snap.task_req.shape[1]
+        release = 8 * 4 + 8 * 4 + 8 * n_res * 8
+        task_rows = (n_res * 8 + 4 + snap.task_selector.shape[1] * 4
+                     + snap.task_tolerations.shape[1] * 4)
+        assert prescreen.attrs["operands"] == 7
+        assert prescreen.attrs["bytes_device"] == release + task_rows
+        # Nothing else uploads uncounted: the cycle's count is its
+        # stagings', the prescreen's among them.
+        assert moved == sum(sp.attrs["bytes_device"] for sp in stages)
+        assert moved > prescreen.attrs["bytes_device"] > 0
+
     def test_prescreen_disabled_matches(self):
         """Soundness guard: results identical with prescreen off."""
         from kai_scheduler_tpu.framework import SchedulerConfig

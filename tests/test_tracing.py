@@ -547,7 +547,7 @@ class TestAllocateSpans:
         import jax
         import numpy as np
 
-        from kai_scheduler_tpu.framework.session import _stage
+        from kai_scheduler_tpu.framework.propose import _stage
         before = counters_now()
         with jax.enable_x64(False):
             req, mask, pair, nothing = _stage(
