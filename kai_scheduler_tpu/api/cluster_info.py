@@ -10,6 +10,7 @@ dense tensor view shipped to the device once per cycle.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 import numpy as np
 
@@ -40,6 +41,21 @@ class BindRequest:
     # object as spec.traceId so `GET /debug/trace?cycle=<id>` explains
     # any bind after the fact.
     trace_id: str | None = None
+
+
+class QueueAggregates(NamedTuple):
+    """Per-leaf-queue sums of one pod walk (``ClusterInfo._aggregates``).
+    The last two are there only where the sums were counted, which proves
+    them equal to the additions in turn to the bit."""
+    allocated: dict
+    requested: dict
+    non_preemptible: dict | None = None   # active pods of guaranteed groups
+    adds: dict | None = None              # additions a pod-by-pod roll-up makes
+
+
+# Sums of whole non-negative float64 numbers are exact, in any order, while
+# they stay under this.
+EXACT_BELOW = 2.0 ** 53
 
 
 class ClusterInfo:
@@ -151,18 +167,31 @@ class ClusterInfo:
         task statuses have moved."""
         self._queue_aggregates = None
 
-    def queue_aggregates(self) -> tuple[dict, dict]:
-        """(allocated, requested) in ONE pod walk — at 100k-node scale the
-        walk itself dominates, so callers needing both (snapshot.pack)
-        must not pay it twice.  Memoized until the next snapshot build or
-        the next Statement mutation (which calls invalidate_aggregates)."""
+    def _aggregates(self) -> "QueueAggregates":
+        """The cycle's ONE pod walk for the per-leaf-queue sums — at
+        100k-node scale the walk itself dominates, so whoever needs them
+        (snapshot.pack, the proportion plugin's roll-up) must not pay it
+        again.  Memoized until the next snapshot build or the next
+        Statement mutation (which calls invalidate_aggregates)."""
         cached = getattr(self, "_queue_aggregates", None)
         if cached is None:
             cached = self._queue_aggregates = (
                 self._aggregates_by_count() or self._aggregates_in_turn())
         return cached
 
-    def _aggregates_in_turn(self) -> tuple[dict, dict]:
+    def queue_aggregates(self) -> tuple[dict, dict]:
+        """(allocated, requested) per leaf queue."""
+        return self._aggregates()[:2]
+
+    def queue_rollup(self) -> "QueueAggregates | None":
+        """The aggregates where they were counted, with the third sum and
+        the number of additions the proportion plugin's roll-up wants; None
+        where they were taken in turn or pre-filled by a snapshot builder,
+        and the plugin walks the pods itself."""
+        agg = self._aggregates()
+        return agg if agg.non_preemptible is not None else None
+
+    def _aggregates_in_turn(self) -> "QueueAggregates":
         """One vector addition a pod, in the walk's order: what the sums
         are defined as, whatever the requests look like."""
         min_gpu_mem = self.min_node_gpu_memory()
@@ -182,19 +211,26 @@ class ClusterInfo:
                     requested[qid] += t.req_vec(min_gpu_mem)
                 elif t.status == PodStatus.PENDING:
                     requested[qid] += t.req_vec(min_gpu_mem)
-        return allocated, requested
+        return QueueAggregates(allocated, requested)
 
-    def _aggregates_by_count(self) -> tuple[dict, dict] | None:
+    def _aggregates_by_count(self) -> "QueueAggregates | None":
         """The same sums where they are exact in any order: the pods of a
         gang share a handful of requirement objects, so the walk counts
         pods per object and adds ``count * vector`` once.  That equals the
         additions in turn to the bit only while every vector is made of
         whole non-negative numbers and every total stays under 2**53
         (milli-cores, bytes, whole GPUs); a fractional or gpu-memory
-        request anywhere returns None and the sums are taken in turn."""
+        request anywhere returns None and the sums are taken in turn.
+
+        Counted, the walk also gives what the proportion plugin rolls up
+        the queue tree: the active pods of non-preemptible PodGroups (a
+        PodGroup's property, so the same count) and how many additions a
+        pod-by-pod roll-up would have made at each leaf."""
         pending = PodStatus.PENDING
         allocated = {qid: rs.zeros() for qid in self.queues}
         requested = {qid: rs.zeros() for qid in self.queues}
+        non_preemptible = {qid: rs.zeros() for qid in self.queues}
+        adds = dict.fromkeys(self.queues, 0)
         for pg in self.podgroups.values():
             qid = pg.queue_id
             if qid not in allocated:
@@ -213,6 +249,7 @@ class ClusterInfo:
                 if entry is None:
                     entry = counts[id(req)] = [req, 0, 0]
                 entry[slot] += 1
+            guaranteed = not pg.is_preemptible()
             for req, active, waiting in counts.values():
                 vec = req.to_vec()
                 if req.gpu_memory_bytes > 0.0 or (vec < 0.0).any() \
@@ -220,9 +257,12 @@ class ClusterInfo:
                     return None
                 allocated[qid] += active * vec
                 requested[qid] += (active + waiting) * vec
-        if any((total >= 2.0 ** 53).any() for total in requested.values()):
+                if guaranteed:
+                    non_preemptible[qid] += active * vec
+                adds[qid] += (3 if guaranteed else 2) * active + waiting
+        if any((total >= EXACT_BELOW).any() for total in requested.values()):
             return None
-        return allocated, requested
+        return QueueAggregates(allocated, requested, non_preemptible, adds)
 
     def min_node_gpu_memory(self) -> float:
         """Smallest per-GPU memory across nodes that report one — the

@@ -17,6 +17,7 @@ import numpy as np
 
 from ..api import (ClusterInfo, NodeInfo, PodGroupInfo, PodInfo, PodSet,
                    PodStatus, QueueInfo, QueueQuota, resources as rs)
+from ..api.cluster_info import QueueAggregates
 from ..api.resources import ResourceRequirements
 from .admission import GPU_FRACTION_ANNOTATION, GPU_MEMORY_ANNOTATION
 from .binder import GPU_GROUP_ANNOTATION
@@ -1788,8 +1789,10 @@ class ClusterCache:
         }
         # Memoized queue aggregates (same accumulation order as the
         # object walk); statement mutations invalidate and recompute
-        # from the materialized objects as usual.
-        cluster._queue_aggregates = (allocated, requested)
+        # from the materialized objects as usual.  Summed in turn, not
+        # counted: the proportion plugin rolls these pods up from the
+        # batch below, not from this memo.
+        cluster._queue_aggregates = QueueAggregates(allocated, requested)
         # Wire-order row batch for plugin-side vectorization (the
         # proportion roll-up): request rows + queue index + status masks,
         # exactly the walk's inputs in the walk's order.

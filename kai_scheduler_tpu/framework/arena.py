@@ -311,7 +311,10 @@ class HostArena:
         # kairace: single-writer=main
         self._versions: np.ndarray | None = None
         # What the last pack patched (None after a full pack).
-        self._rows: np.ndarray | None = None
+        self.patched_rows: np.ndarray | None = None
+        # What the sessions' plugins keep from one session to the next
+        # (``Session.products``): a full pack starts it empty.
+        self.products: dict = {}
         self._table = None            # the sessions' NativeNodeTable
         self._node_index: dict | None = None
 
@@ -384,11 +387,12 @@ class HostArena:
             reuse_tasks=False)
         if rows is None:
             self.generation += 1
+            self.products = {}
         self._prev = snap
         self._prev_pad = pad_nodes_to
         self._cluster = weakref.ref(cluster)
         self._vocab = vocab
-        self._rows = rows
+        self.patched_rows = rows
         self._versions = None
         self.last_pack = _verdict(cluster, rows, reason, self.generation, t0)
         return snap, self.last_pack
@@ -400,13 +404,13 @@ class HostArena:
         memory the ``NodeInfo`` objects write to, so they are current;
         ``room`` and the binding of the dirty rows are the session's to
         patch."""
-        if self._rows is None:
+        if self.patched_rows is None:
             return None, None, None
         table = self._table
         if table is not None and (table.n_nodes, table.n_res) \
                 != snap.node_allocatable.shape:
             table = None
-        return table, self._rows, self._node_index
+        return table, self.patched_rows, self._node_index
 
     def settle(self, session) -> None:
         """The session is built: keep its table and index, and the stamps
@@ -463,6 +467,10 @@ class ClusterArena:
         self._static_gen = -1
         self.guard_watch = GuardWatch()
         self.last_pack: dict = {}
+        # As ``HostArena``'s: the rows the last pack patched (None after
+        # a full pack), and what plugins keep between sessions.
+        self.patched_rows: np.ndarray | None = None
+        self.products: dict = {}
 
     # -- producer side (ClusterCache.snapshot) -----------------------------
     def note_nodes(self, names) -> None:
@@ -545,6 +553,8 @@ class ClusterArena:
                 queue_usage, pad_nodes_to, reuse_tasks)
             if rows is None:
                 self.generation += 1
+                self.products = {}
+            self.patched_rows = rows
             self._prev = snap
             self._prev_pad = pad_nodes_to
             self._prev_usage = queue_usage

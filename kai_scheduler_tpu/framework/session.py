@@ -209,9 +209,20 @@ class Session:
         # the host-side attributes (which also counts the cycle).
         pack_usage = {} if getattr(queue_usage, "stale", False) \
             else queue_usage
-        if self._arena is not None or host is not None:
-            self.snapshot, self.pack_stats = (self._arena or host).pack(
+        # What a plugin may take over from the session before
+        # (docs/DESIGN.md section 8): ``patched_rows`` are the node rows
+        # the arena patched, None where it packed from scratch or there is
+        # no arena; ``products`` is the arena's place for what plugins
+        # derive from rows it proves unchanged, emptied by a full pack and
+        # this session's own where nothing outlives it.
+        self.patched_rows: np.ndarray | None = None
+        self.products: dict = {}
+        arena = self._arena or host
+        if arena is not None:
+            self.snapshot, self.pack_stats = arena.pack(
                 cluster, queue_usage=pack_usage, pad_nodes_to=pad)
+            self.patched_rows = arena.patched_rows
+            self.products = arena.products
         else:
             self.snapshot: SnapshotTensors = pack(
                 cluster, queue_usage=pack_usage, pad_nodes_to=pad)

@@ -48,6 +48,8 @@ class TopologyTree:
     # Per level: [N] int32 domain index (-1 = node lacks the label chain).
     node_domain: dict = field(default_factory=dict)   # level -> np.ndarray
     domain_names: dict = field(default_factory=dict)  # level -> [id->path]
+    # Per level below the root: label-value path (a tuple) -> domain id.
+    domain_ids: dict = field(default_factory=dict)
 
     def num_domains(self, level: str) -> int:
         return len(self.domain_names.get(level, []))
@@ -80,7 +82,72 @@ def build_tree(name: str, levels: list, node_names: list,
             seg[i] = ids[key]
         tree.node_domain[label_key] = seg
         tree.domain_names[label_key] = names
+        tree.domain_ids[label_key] = ids
     return tree
+
+
+# The session product (``Session.products``) the trees are kept under.
+_KEPT_TREES = "topology_trees"
+
+
+def _rows_hold(tree: TopologyTree, ssn, rows: list) -> bool:
+    """True where each of ``rows`` still lies in the domains ``tree`` has
+    it in: its node's level labels, read again, give the tree's own path.
+    ``build_tree``'s rule row by row: a missing label ends the chain."""
+    nodes, names = ssn.cluster.nodes, ssn.snapshot.node_names
+    levels = tree.levels
+    ids = [tree.domain_ids[level] for level in levels]
+    have = [tree.node_domain[level][rows].tolist() for level in levels]
+    for k, i in enumerate(rows):
+        node = nodes.get(names[i])
+        labels = node.labels if node is not None else {}
+        path = ()
+        for depth, level in enumerate(levels):
+            value = labels.get(level) if path is not None else None
+            if value is None:
+                path, want = None, -1
+            else:
+                path = path + (value,)
+                want = ids[depth].get(path)
+            if want != have[depth][k]:
+                return False
+    return True
+
+
+def session_trees(ssn) -> tuple[dict, int | None]:
+    """``({name: TopologyTree}, rows checked or None)`` for one session.
+
+    The trees are a function of the node order, the nodes' level labels
+    and the Topology specs.  Where the session's pack was a patch
+    (``ssn.patched_rows``: same cluster, same node order, and a node whose
+    row is not among them was not touched), the trees of the session
+    before are taken over once the specs are equal and every patched
+    row's labels have been read again: a node replaced by one of the same
+    name and hardware is a patched row, and a label no pod selects on is
+    in no vocabulary, so that part of the proof is made here.  A row that
+    differs builds the trees anew, as does any full pack (which empties
+    ``ssn.products``) and a session with no arena.  Shared trees are
+    read-only."""
+    # In the cluster's order: the first tree is a job's default.
+    spec = tuple((name, tuple(topo.get("levels", [])))
+                 for name, topo in ssn.cluster.topologies.items())
+    rows = ssn.patched_rows
+    kept = ssn.products.get(_KEPT_TREES)
+    if rows is not None and kept is not None and kept[0] == spec:
+        rows = rows.tolist()
+        trees = kept[1]
+        if all(_rows_hold(tree, ssn, rows) for tree in trees.values()):
+            return trees, len(rows)
+    node_names = ssn.snapshot.node_names
+    node_labels = {name: ssn.cluster.nodes[name].labels
+                   for name in node_names if name in ssn.cluster.nodes}
+    trees = {name: build_tree(name, list(levels), node_names, node_labels)
+             for name, levels in spec}
+    for tree in trees.values():
+        for seg in tree.node_domain.values():
+            seg.setflags(write=False)
+    ssn.products[_KEPT_TREES] = (spec, trees)
+    return trees, None
 
 
 @functools.partial(jax.jit, static_argnames=("num_domains",))
@@ -115,14 +182,9 @@ class TopologySession:
 
     def __init__(self, ssn):
         self.ssn = ssn
-        self.trees: dict[str, TopologyTree] = {}
-        node_labels = {name: ssn.cluster.nodes[name].labels
-                       for name in ssn.snapshot.node_names
-                       if name in ssn.cluster.nodes}
-        for name, spec in ssn.cluster.topologies.items():
-            levels = list(spec.get("levels", []))
-            self.trees[name] = build_tree(
-                name, levels, ssn.snapshot.node_names, node_labels)
+        # {name: TopologyTree}, possibly the session before's and then
+        # shared: never written.
+        self.trees, self.rows_checked = session_trees(ssn)
         # job uid -> [N] preferred-level score boosts (set by subset_nodes).
         # kairace: single-writer=main
         self._job_node_scores: dict[str, np.ndarray] = {}

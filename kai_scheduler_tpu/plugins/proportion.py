@@ -20,9 +20,11 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..api import resources as rs
+from ..api.cluster_info import EXACT_BELOW
 from ..api.podgroup_info import PodGroupInfo
 from ..framework.session import SchedulableResult
 from ..ops import fairshare as fsops
+from ..utils.tracing import TRACER
 from .base import Plugin, register_plugin
 
 UNLIMITED = rs.UNLIMITED
@@ -104,6 +106,8 @@ class ProportionPlugin(Plugin):
         self.total = rs.zeros()
         self.saturation_multiplier = 1.0
         self.min_gpu_mem = 0.0
+        # How the session's roll-up was taken: columnar, counted, walked.
+        self.rollup = ""
 
     # -- session wiring ----------------------------------------------------
     def on_session_open(self, ssn) -> None:
@@ -226,12 +230,24 @@ class ProportionPlugin(Plugin):
         if rebuilt:
             METRICS.inc("queue_attrs_rebuilt_total", rebuilt)
         # Roll allocated/non-preemptible/request up the parent chain
-        # (proportion.go:347-401).  Pending gpu-memory requests are charged
-        # gpu_memory / MinNodeGPUMemory devices rather than a whole GPU.
-        min_gpu_mem = self.min_gpu_mem = cluster.min_node_gpu_memory()
+        # (proportion.go:347-401), by the cheapest way that is proven to
+        # give the pod-by-pod walk's sums to the bit; the span says which.
+        self.min_gpu_mem = cluster.min_node_gpu_memory()
         batch = getattr(cluster, "columnar_batch", None)
         if batch is not None and self._roll_up_columnar(batch):
-            return
+            self.rollup = "columnar"
+        elif self._roll_up_counted(cluster.queue_rollup()):
+            self.rollup = "counted"
+        else:
+            self._roll_up_walked(cluster)
+            self.rollup = "walked"
+        TRACER.stamp(f"plugin:{self.name}", rollup=self.rollup)
+
+    def _roll_up_walked(self, cluster) -> None:
+        """One ``_walk`` a pod and attribute: what the roll-up is defined
+        as.  Pending gpu-memory requests are charged gpu_memory /
+        MinNodeGPUMemory devices rather than a whole GPU."""
+        min_gpu_mem = self.min_gpu_mem
         for pg in cluster.podgroups.values():
             if pg.queue_id not in self.queues:
                 continue
@@ -251,6 +267,44 @@ class ProportionPlugin(Plugin):
                     # (proportion.go updateQueuesCurrentResourceUsage) —
                     # unschedulable gated pods must not inflate fair share.
                     self._walk(pg.queue_id, "request", req)
+
+    def _roll_up_counted(self, counted) -> bool:
+        """The roll-up from the leaf sums the cycle's one pod walk already
+        took (``ClusterInfo.queue_rollup``, which the pack has just asked
+        for): each leaf's three vectors go to the leaf and its ancestors.
+        The cluster hands them over only where it has proven them the
+        additions in turn to the bit (whole non-negative vectors, no
+        gpu-memory request, so every normalisation above is the identity);
+        the same proof is asked of every ancestor's total here.  False,
+        with nothing written, where either is missing."""
+        if counted is None:
+            return False
+        totals: dict = {}   # qid -> [attributes, the three sums [3,R], adds]
+        for leaf, adds in counted.adds.items():
+            if not adds:
+                continue
+            sums = np.stack((counted.allocated[leaf], counted.requested[leaf],
+                             counted.non_preemptible[leaf]))
+            q = self.queues.get(leaf)
+            while q is not None:
+                entry = totals.get(q.uid)
+                if entry is None:
+                    totals[q.uid] = [q, sums, adds]
+                else:
+                    entry[1] = entry[1] + sums
+                    entry[2] += adds
+                q = self.queues.get(q.parent) if q.parent else None
+        if any((sums[1] >= EXACT_BELOW).any() for _q, sums, _n in
+               totals.values()):
+            return False
+        for q, sums, adds in totals.values():
+            # The accumulators were zeroed above, as the walk finds them.
+            q.allocated = q.allocated + sums[0]
+            q.request = q.request + sums[1]
+            q.allocated_non_preemptible = \
+                q.allocated_non_preemptible + sums[2]
+            q.version += adds
+        return True
 
     def _roll_up_columnar(self, batch: dict) -> bool:
         """Vectorized ``_walk`` roll-up over the columnar snapshot batch
@@ -328,7 +382,6 @@ class ProportionPlugin(Plugin):
         import time as _time
 
         from ..utils.metrics import METRICS
-        from ..utils.tracing import TRACER
         qids = sorted(self.queues)
         index = {qid: i for i, qid in enumerate(qids)}
         n = len(qids)

@@ -16,7 +16,19 @@ class TopologyPlugin(Plugin):
         if not ssn.cluster.topologies:
             return
         from ..ops.topology import TopologySession
+        from ..utils.metrics import METRICS
+        from ..utils.tracing import TRACER
         self._topo = TopologySession(ssn)
+        # How often the trees outlive the session (ops/topology.py
+        # ``session_trees``; docs/OBSERVABILITY.md).
+        checked = self._topo.rows_checked
+        if checked is None:
+            METRICS.inc("topology_tree_built_total")
+            TRACER.stamp(f"plugin:{self.name}", tree="built")
+        else:
+            METRICS.inc("topology_tree_reused_total")
+            TRACER.stamp(f"plugin:{self.name}", tree="reused",
+                         rows_checked=checked)
         ssn.subset_nodes_fns.append(self._topo.subset_nodes)
         ssn.extra_score_fns.append(self.extra_scores)
         # Rank-aware gang placement (ops/rankplace.py): reorder an
