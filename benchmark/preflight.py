@@ -2,12 +2,26 @@
 
 The driver refuses a NEW cell whose fullest device peaks under a quarter
 of a chip's memory (4.0 GiB of a v5e's 16), and a chip run that finds that
-out has already been paid for.  So before a cell is asked for: its
-generator reckons, from the configuration and traffic files, what its
-cycle holds on the device (``reckon``), and, where the TPU compiler can
-describe a ``v5e:2x2`` chip here, compiles the cycle's kernel at its timed
-shape (``compile_for``), whose ``memory_analysis()`` is printed.  Costs no
-chip time.
+out has already been paid for.  So before a cell is asked for, its two
+kinds of device memory are printed apart, as ``run.py``'s ``memory_peak``
+will read them on the chip (``device_memory`` there says why they are two):
+
+- the client's buffers: what the cell's generator reckons, from the
+  configuration and traffic files, that its cycle keeps in arrays on the
+  device (``reckon``: ``bytes``);
+- the program's reservation: where the TPU compiler can describe a
+  ``v5e:2x2`` chip here, the cycle's kernel is compiled at its timed shape
+  (``compile_for``) and its ``memory_analysis()`` read (``reserved_bytes``):
+  the smaller of ``temp_size_in_bytes``, which adds temporaries that never
+  live together (7.69 GiB for the prescreen at 98,304 nodes, where the
+  chip reserves 6.47), and ``peak_memory_in_bytes`` less the arguments and
+  outputs (6.47 GiB there, but 19 MB for the exact scan, which reserves
+  0.85).  The sum is printed beside it.  With ``--no-compile`` it is what
+  the generator reckons (``program_bytes``).
+
+A cell is judged by the larger of the two, which is ``memory_peak``'s
+rule where it cannot see one instant: it never says "over" for a cell the
+chip would read under.  Costs no chip time.
 
     JAX_PLATFORMS=cpu python3 benchmark/preflight.py [--no-compile]
         [--workload <cell> ...] [--root <dir with BENCHMARK.json>]
@@ -56,11 +70,25 @@ def described_chip():
     return sds
 
 
-def compiled_bytes(cell, sds) -> float:
-    """arguments + outputs + temporaries of the cell's kernel."""
-    m = cell.generator.compile_for(cell, sds).memory_analysis()
-    return float(m.argument_size_in_bytes + m.output_size_in_bytes
-                 + m.temp_size_in_bytes)
+def reserved_bytes(m) -> float:
+    """What the runtime reserves for a program whose ``memory_analysis()``
+    is ``m``, at the most: the smaller of its temporaries summed and its
+    peak less its arguments and outputs.  On the chip (PR 34) the prescreen
+    at 98,304 nodes reserved 6,946,799,616 bytes (summed 8,256,042,496,
+    peak less operands 6,946,176,512) and the exact scan at 65,536 nodes
+    851,968 (summed 1,097,216, peak less operands 18,980,864)."""
+    peak = getattr(m, "peak_memory_in_bytes", 0)
+    summed = float(m.temp_size_in_bytes)
+    if not peak:
+        return summed
+    return min(summed, float(peak - m.argument_size_in_bytes
+                             - m.output_size_in_bytes))
+
+
+def judged_bytes(client: float, program: float) -> float:
+    """``memory_peak``'s rule on the two parts: the larger, never the sum
+    (their peaks need not fall at one instant)."""
+    return max(client, program)
 
 
 def size(b: float) -> str:
@@ -87,13 +115,17 @@ def main(argv=None) -> int:
     for name in names:
         cell = spec.Cell(bench, name, args.root)
         reck = cell.generator.reckon(cell)
-        line = f"{name}: {reck['what']}: reckoned {size(reck['bytes'])}"
-        smallest = reck["bytes"]
+        line = (f"{name}: {reck['what']}: client's buffers reckoned "
+                f"{size(reck['bytes'])}")
         if sds is not None:
-            comp = compiled_bytes(cell, sds)
-            line += f", compiled for v5e {size(comp)}"
-            smallest = min(smallest, comp)
-        if smallest < FLOOR_BYTES:
+            m = cell.generator.compile_for(cell, sds).memory_analysis()
+            program = reserved_bytes(m)
+            line += (f"; program compiled for v5e reserves {size(program)} "
+                     f"(temporaries summed {size(m.temp_size_in_bytes)})")
+        else:
+            program = float(reck.get("program_bytes", 0.0))
+            line += f"; program's temporaries reckoned {size(program)}"
+        if judged_bytes(reck["bytes"], program) < FLOOR_BYTES:
             judged = name in args.workload
             line += ("  UNDER THE 4.00 GiB FLOOR" if judged else
                      "  (under the 4.00 GiB floor for a new cell, which "

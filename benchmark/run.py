@@ -47,13 +47,40 @@ def device_info(chips: int, require_chip: bool) -> dict:
             "kind": devices[0].device_kind, "count": len(devices)}
 
 
-def memory_peak(chips: int) -> int:
+def device_memory(stats: dict | None) -> dict:
+    """One device's figure and its parts, from ONE ``memory_stats()`` call.
+
+    This runtime keeps two books (PERF.md section 6, PR 34).  A client's
+    buffers (operands, results, anything ``jnp.asarray`` made) count under
+    ``bytes_in_use``; what a loaded program reserves for its temporaries
+    counts under ``bytes_reserved``, never under ``bytes_in_use``, and is
+    carved out of what the buffers leave free, so the two never overlap.
+    The peaks of both are high-water marks of the whole process and need
+    not have fallen at one instant, so they are never added.  The figure
+    is the largest of three amounts the chip did hold at some instant:
+    the peak of buffers, the peak of reservations, and buffers plus
+    reservation as this one call reads them.  It cannot overstate; where
+    buffers and a reservation peaked together earlier than this call and
+    apart from each other's peak, it understates.  A backend without a key
+    reads 0 there (the CPU's ``memory_stats()`` is None: all 0)."""
+    stats = stats or {}
+    in_use = int(stats.get("peak_bytes_in_use", 0))
+    reserved = int(stats.get("peak_bytes_reserved", 0))
+    at_read = (int(stats.get("bytes_in_use", 0))
+               + int(stats.get("bytes_reserved", 0)))
+    return {"memory_peak_bytes": max(in_use, reserved, at_read),
+            "memory_in_use_peak_bytes": in_use,
+            "memory_reserved_peak_bytes": reserved,
+            "memory_at_read_bytes": at_read}
+
+
+def memory_peak(chips: int) -> dict:
+    """``device_memory`` of the fullest of the cell's ``chips`` devices:
+    ``memory_peak_bytes``, which the driver reads, and its parts."""
     import jax
-    peak = 0
-    for d in jax.local_devices()[:max(1, chips)]:
-        stats = d.memory_stats() or {}
-        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
-    return peak
+    return max((device_memory(d.memory_stats())
+                for d in jax.local_devices()[:max(1, chips)]),
+               key=lambda m: m["memory_peak_bytes"])
 
 
 def run_cell(workload: str, seed: int, seconds: float, trace: bool,
@@ -105,7 +132,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     wall["window_s"] = window["elapsed_s"]
     in_window = watch.since(compiles0)
     guard1 = loop.guard_counters()
-    device["memory_peak_bytes"] = memory_peak(cell.chips)
+    device.update(memory_peak(cell.chips))
 
     records = client.records[window["first"]:]
     ledger = client.ledger

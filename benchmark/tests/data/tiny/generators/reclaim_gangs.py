@@ -368,15 +368,24 @@ def kernel_shapes(client: Client) -> dict:
 
 
 def reckon(cell) -> dict:
-    """What the reclaim cycle holds on the device, from the files: the
-    prescreen makes ``[K,N,R]`` f32 arrays (the scattered releases, their
-    running sum, the pools, and the vmapped scan's carries)."""
+    """What the reclaim cycle holds on the device, from the files.  The
+    client's buffers are the kernel's operands (node tables, release rows,
+    task rows); the program's temporaries are ``[K,N,R]`` f32 arrays (the
+    scattered releases, their running sum, the pools, and the vmapped
+    scan's carries), each prefix another state of the fleet."""
     shape = file_shape(cell)
     k, n, r = shape["prefixes"], shape["nodes"], shape["resources"]
+    operands = 4 * (n * (3 * r + shape["label_cols"] + shape["taint_cols"]
+                         + 1)
+                    + shape["rows"] * (2 + r)
+                    + shape["t_pad"] * (r + 1 + shape["selector_cols"]
+                                        + shape["toleration_cols"]))
     one = k * n * r * 4
-    return {"bytes": float(PRESCREEN_ARRAYS * one),
+    return {"bytes": float(operands),
+            "program_bytes": float(PRESCREEN_ARRAYS * one),
             "what": f"batch_prefix_feasibility [K={k}, N={n}, R={r}] f32 "
-                    f"= {one:,} bytes an array x {PRESCREEN_ARRAYS}"}
+                    f"= {one:,} bytes an array x {PRESCREEN_ARRAYS}, "
+                    f"operands {operands:,} bytes"}
 
 
 def compile_for(cell, sds):

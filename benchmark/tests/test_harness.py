@@ -128,3 +128,112 @@ def test_a_broken_timed_path_is_not_correct(workload, shift, monkeypatch):
         assert c["pods_outside_domain"] == out["attempted"]
     if shift > 256 and workload in TOPOLOGY_CELLS:
         assert out["compared"]["pods_outside_domain"][0] == out["attempted"]
+
+
+GIB = 2 ** 30
+# ``memory_stats()`` as the v5e's runtime gives it (PERF.md section 6, PR
+# 34): buffers under ``bytes_in_use``, a program's reservation apart.
+MEMORY_CASES = {
+    "in use alone": (
+        {"bytes_in_use": 4 * GIB, "peak_bytes_in_use": 4 * GIB + 512,
+         "bytes_reserved": 0, "peak_bytes_reserved": 0},
+        4 * GIB + 512),
+    "reserved alone": (
+        {"bytes_in_use": 0, "peak_bytes_in_use": 0,
+         "bytes_reserved": 8 * GIB, "peak_bytes_reserved": 8 * GIB},
+        8 * GIB),
+    # A 4 GiB buffer deleted before an 8 GiB reservation: the chip never
+    # held 12.
+    "both, peaks at different instants": (
+        {"bytes_in_use": 2 ** 20, "peak_bytes_in_use": 4 * GIB,
+         "bytes_reserved": 8 * GIB, "peak_bytes_reserved": 8 * GIB},
+        8 * GIB + 2 ** 20),
+    "both, in one call": (
+        {"bytes_in_use": 3 * GIB, "peak_bytes_in_use": 3 * GIB,
+         "bytes_reserved": 6 * GIB, "peak_bytes_reserved": 6 * GIB},
+        9 * GIB),
+    "the reservation given back before the read": (
+        {"bytes_in_use": 10 * GIB, "peak_bytes_in_use": 10 * GIB,
+         "bytes_reserved": 0, "peak_bytes_reserved": 8 * GIB},
+        10 * GIB),
+    "a backend with the old key alone": (
+        {"peak_bytes_in_use": 5 * GIB}, 5 * GIB),
+    "a backend with neither key": ({"num_allocs": 3}, 0),
+    "a backend with no stats": (None, 0),
+}
+
+
+@pytest.mark.parametrize("case", MEMORY_CASES)
+def test_the_memory_figure_never_adds_two_peaks(case):
+    from benchmark import run
+    stats, figure = MEMORY_CASES[case]
+    out = run.device_memory(stats)
+    stats = stats or {}
+    assert out == {
+        "memory_peak_bytes": figure,
+        "memory_in_use_peak_bytes": stats.get("peak_bytes_in_use", 0),
+        "memory_reserved_peak_bytes": stats.get("peak_bytes_reserved", 0),
+        "memory_at_read_bytes": (stats.get("bytes_in_use", 0)
+                                 + stats.get("bytes_reserved", 0))}
+
+
+@pytest.mark.parametrize("chips, fullest", ((4, 2), (2, 1), (1, 0)))
+def test_memory_peak_is_the_fullest_of_the_cells_devices(monkeypatch, chips,
+                                                         fullest):
+    """Four devices of which the third is fullest, by its reservation: the
+    figure and the parts are that device's, and a device past the cell's
+    ``chips`` is not read."""
+    import jax
+    from benchmark import run
+
+    class Device:
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
+    devices = [Device({"peak_bytes_in_use": GIB}),
+               Device({"peak_bytes_in_use": 2 * GIB,
+                       "peak_bytes_reserved": GIB}),
+               Device({"peak_bytes_in_use": GIB // 2,
+                       "peak_bytes_reserved": 7 * GIB}),
+               Device(None)]
+    monkeypatch.setattr(jax, "local_devices", lambda: devices)
+    assert run.memory_peak(chips) == run.device_memory(
+        devices[fullest].stats)
+    assert run.memory_peak(4)["memory_reserved_peak_bytes"] == 7 * GIB
+
+
+def test_the_result_line_keeps_its_keys_and_the_device_gains_the_parts():
+    out = run_cell("tiny-plain-gang", 13)
+    assert list(out) == ["correct", "attempted", "failed", "metrics",
+                         "device", "run", "compared"]
+    assert list(out["device"]) == [
+        "platform", "kind", "count", "memory_peak_bytes",
+        "memory_in_use_peak_bytes", "memory_reserved_peak_bytes",
+        "memory_at_read_bytes"]
+
+
+def test_preflight_judges_a_cell_by_the_larger_of_its_two_parts(capsys):
+    """The reclaim fixture's parts: 6,400 bytes of operands in the
+    client's buffers, seven ``[K,N,R]`` arrays in the program."""
+    from benchmark import preflight
+    from benchmark.harness import spec
+    cell = spec.Cell(spec.load_benchmark(DATA), "tiny-reclaim-gang", DATA)
+    reck = cell.generator.reckon(cell)
+    assert reck["bytes"] == 6400 and reck["program_bytes"] == 7 * 49152
+    assert preflight.judged_bytes(reck["bytes"],
+                                  reck["program_bytes"]) == 7 * 49152
+    # 6 MB of operands beside a reservation of 6.47 GiB is over the floor,
+    # and two parts of 3 GiB, whose peaks need not coincide, are not.
+    assert preflight.judged_bytes(6e6, 6.47 * GIB) > preflight.FLOOR_BYTES
+    assert preflight.judged_bytes(3.0 * GIB, 3.0 * GIB) \
+        < preflight.FLOOR_BYTES
+    assert preflight.main(["--no-compile", "--root", DATA, "--workload",
+                           "tiny-reclaim-gang"]) == 1
+    line = [ln for ln in capsys.readouterr().out.splitlines()
+            if ln.startswith("tiny-reclaim-gang")][0]
+    assert "client's buffers reckoned 0.0 MB" in line
+    assert "program's temporaries reckoned 0.3 MB" in line
+    assert line.endswith("UNDER THE 4.00 GiB FLOOR")

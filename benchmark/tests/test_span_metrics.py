@@ -162,3 +162,31 @@ def test_a_program_without_the_spans_reports_none_of_them(real_cell,
     assert not set(SPAN_METRICS + COUNT_METRICS) & set(out)
     assert {"allocate_host_ms", "dispatch_ms", "snapshot_ms",
             "device_calls"} <= set(out)
+
+
+def test_session_open_is_the_three_plugins_spans(real_cell, cycle):
+    """Between ``snapshot`` and ``action:allocate``: what PR 32 shortened
+    and no metric held (PR 34)."""
+    m = [m for m in real_cell.per_layer if m["name"] == "session_open_ms"]
+    assert m and "workloads" not in next(
+        e for e in spec.load_benchmark()["per_layer"]
+        if e["name"] == "session_open_ms")
+    spans = {name: dur for name, _k, _i, _p, _s, dur in cycle.spans}
+    out = readers.read_all(m, {"records": [cycle]})
+    assert out["session_open_ms"]["value"] == pytest.approx(1e3 * sum(
+        spans[f"plugin:{p}"] for p in ("proportion", "topology",
+                                       "predicates")))
+    assert out["session_open_ms"]["value"] > 0
+
+
+def test_a_pack_under_the_snapshot_span_is_counted_once(real_cell):
+    """``ClusterArena.pack`` opens ``snapshot_delta`` under ``run_once``'s
+    ``snapshot``: a cell that drives it reads the parent span alone."""
+    class Rec:
+        counters = {}
+        spans = [("snapshot", "snapshot", 1, None, 0.0, 0.5),
+                 ("snapshot_delta", "snapshot", 2, 1, 0.1, 0.3)]
+
+    m = [m for m in real_cell.per_layer if m["name"] == "snapshot_ms"]
+    assert readers.read_all(m, {"records": [Rec]}) == {
+        "snapshot_ms": {"value": pytest.approx(500.0), "unit": "ms"}}
