@@ -11,6 +11,7 @@ dedup skips lookalike jobs that already failed (:74-82).
 from __future__ import annotations
 
 from ..api.podgroup_info import PodGroupInfo
+from ..utils.tracing import TRACER
 from .solvers import solve_job
 from .utils import INFINITE, JobsOrderByQueues
 
@@ -25,9 +26,11 @@ class ReclaimAction:
                    and pg.queue_id in ssn.cluster.queues]
         if not pending:
             return
-        order = JobsOrderByQueues(
-            ssn, pending,
-            ssn.config.queue_depth_per_action.get(self.name, INFINITE))
+        with TRACER.span("reclaim:order", kind="reclaim",
+                         jobs=len(pending)):
+            order = JobsOrderByQueues(
+                ssn, pending,
+                ssn.config.queue_depth_per_action.get(self.name, INFINITE))
         failed_signatures: set[str] = set()
         # Victim survey is expensive (scans every podgroup, ranks by queue
         # dominant share): compute once and invalidate only when a
@@ -46,16 +49,28 @@ class ReclaimAction:
             if not ssn.can_reclaim_resources(job):
                 order.requeue_queue(job.queue_id)
                 continue
-            if survey is None:
-                survey = survey_reclaim_victims(ssn)
-            victims = [pg for pg in survey
-                       if pg.queue_id != job.queue_id]
-            victims = ssn.filter_reclaim_victims(job, victims)
-            if not victims:
+            # The span of one reclaimer past its gates: the victim survey
+            # (the first reclaimer of the cycle pays it), the filter and
+            # the solver.  A job turned away above opens none.
+            with TRACER.span("reclaim:job", kind="reclaim", job=job.name,
+                             queue=job.queue_id) as sp:
+                if survey is None:
+                    with TRACER.span("reclaim:survey", kind="reclaim") as sv:
+                        survey = survey_reclaim_victims(ssn)
+                        sv.set(victims=len(survey))
+                victims = [pg for pg in survey
+                           if pg.queue_id != job.queue_id]
+                victims = ssn.filter_reclaim_victims(job, victims)
+                sp.set(victims=len(victims), success=False)
+                result = None
+                if victims:
+                    result = solve_job(ssn, job, victims,
+                                       ssn.validate_reclaim_scenario,
+                                       self.name)
+                    sp.set(success=result.success)
+            if result is None:
                 order.requeue_queue(job.queue_id)
                 continue
-            result = solve_job(ssn, job, victims,
-                               ssn.validate_reclaim_scenario, self.name)
             if result.success:
                 # Incremental survey maintenance: evicted victims leave the
                 # candidate pool; queue-share drift is tolerated until the

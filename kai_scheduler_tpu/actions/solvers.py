@@ -26,6 +26,7 @@ from ..api.podgroup_info import PodGroupInfo
 from ..framework import propose
 from ..ops.allocate_grouped import _next_pow2
 from ..utils.metrics import METRICS
+from ..utils.tracing import TRACER
 from .allocate import attempt_to_allocate_job
 
 
@@ -94,6 +95,7 @@ class SolverResult:
     success: bool
     evicted_jobs: list = field(default_factory=list)
     scenarios_tried: int = 0
+    scenarios_skipped: int = 0   # passed over on the prescreen's verdict
 
 
 def fractional_headroom(ssn) -> float:
@@ -130,30 +132,55 @@ def solve_job(ssn, pending_job: PodGroupInfo,
         task_order_fn=ssn.task_order_key, real_allocation=False)
     if not tasks:
         return SolverResult(False)
-
-    # Cheap infeasibility precheck: even evicting every candidate victim
-    # cannot create more than (idle + releasing + victim resources +
-    # repackable fraction headroom); a pending job larger than that can
-    # never be solved — skip simulating.  The headroom term matters
-    # because a fractional victim's request vector (0.4 GPU) understates
-    # what its relocation can free (the WHOLE backing device empties once
-    # the sharing group drains).
     ordered_victims = ordered_victims[:ssn.config.max_victims_considered]
-    total_req = np.sum([t.res_req.to_vec(mig_as_gpu=False)
-                        for t in tasks], axis=0)
-    budget = ssn.node_idle.sum(axis=0) + ssn.node_releasing.sum(axis=0)
-    budget[rs.RES_GPU] += fractional_headroom(ssn)
-    for vjob in ordered_victims:
-        for t in vjob.pods.values():
-            if t.is_active_allocated():
-                budget = budget + t.res_req.to_vec(mig_as_gpu=False)
-    if np.any(total_req > budget + 1e-9):
+    # The span of one reclaimer (or preemptor): the precheck, every
+    # simulated scenario, the prescreen and the commit lie under it.
+    with TRACER.span("solve:job", kind="solver", job=pending_job.name,
+                     action=action_name, tasks=len(tasks),
+                     victims=len(ordered_victims)) as sp:
+        result = _solve(ssn, pending_job, tasks, ordered_victims, validate,
+                        action_name, require_all_victims_replaced,
+                        try_replace_victims, sp)
+        sp.set(tried=result.scenarios_tried,
+               skipped=result.scenarios_skipped, solved=result.success)
+    if result.scenarios_skipped:
+        METRICS.inc("scenarios_skipped_by_prescreen_total",
+                    result.scenarios_skipped)
+    return result
+
+
+def _within_budget(ssn, tasks, ordered_victims) -> bool:
+    """Cheap infeasibility precheck: even evicting every candidate victim
+    cannot create more than (idle + releasing + victim resources +
+    repackable fraction headroom); a pending job larger than that can
+    never be solved — skip simulating.  The headroom term matters
+    because a fractional victim's request vector (0.4 GPU) understates
+    what its relocation can free (the WHOLE backing device empties once
+    the sharing group drains)."""
+    with TRACER.span("solve:precheck", kind="solver"):
+        total_req = np.sum([t.res_req.to_vec(mig_as_gpu=False)
+                            for t in tasks], axis=0)
+        budget = ssn.node_idle.sum(axis=0) + ssn.node_releasing.sum(axis=0)
+        budget[rs.RES_GPU] += fractional_headroom(ssn)
+        for vjob in ordered_victims:
+            for t in vjob.pods.values():
+                if t.is_active_allocated():
+                    budget = budget + t.res_req.to_vec(mig_as_gpu=False)
+        return not np.any(total_req > budget + 1e-9)
+
+
+def _solve(ssn, pending_job, tasks, ordered_victims, validate,
+           action_name: str, require_all_victims_replaced: bool,
+           try_replace_victims: bool, sp) -> SolverResult:
+    """``solve_job`` under its span ``sp``."""
+    if not _within_budget(ssn, tasks, ordered_victims):
         return SolverResult(False)
 
     # Let plugins snapshot pre-simulation state for their validators.
     ssn.on_job_solution_start()
 
     builder = ScenarioBuilder(pending_job, tasks, ordered_victims)
+    sp.set(steps=len(builder._steps))
     # LAZY batched pre-screen: the common reclaim succeeds on its first
     # or second scenario, where a prescreen kernel call is pure overhead
     # (measured 0.69x at 400-queue contention).  Only after
@@ -166,6 +193,7 @@ def solve_job(ssn, pending_job: PodGroupInfo,
     prescreen_offset = 0
     failures = 0
     tried = 0
+    skipped = 0
     step_idx = 0
     # One statement across scenarios: evictions accumulate incrementally
     # (by_pod_solver keeps recorded victims evicted and rolls back only
@@ -179,6 +207,7 @@ def solve_job(ssn, pending_job: PodGroupInfo,
             if 0 <= k < len(prescreen) and not prescreen[k]:
                 # The pending job cannot place even with this whole
                 # prefix released; simulating would fail identically.
+                skipped += 1
                 continue
         # Validators depend only on the scenario's composition (victim
         # resources vs queue shares, min-runtimes) — check them BEFORE
@@ -188,20 +217,28 @@ def solve_job(ssn, pending_job: PodGroupInfo,
             continue
         tried += 1
         METRICS.inc("scenarios_simulation_by_action", action=action_name)
-        # Evict any victims added since the last simulated scenario.
-        new_tasks = _unevicted_tasks(scenario, stmt)
-        for task in new_tasks:
-            stmt.evict(task)
-        cp = stmt.checkpoint()
-        ok = _simulate_attempt(ssn, stmt, scenario,
-                               require_all_victims_replaced,
-                               try_replace_victims)
+        with TRACER.span("solve:scenario", kind="solver",
+                         prefix=step_idx) as scenario_span:
+            # Evict any victims added since the last simulated scenario.
+            new_tasks = _unevicted_tasks(scenario, stmt)
+            for task in new_tasks:
+                stmt.evict(task)
+            cp = stmt.checkpoint()
+            ok = _simulate_attempt(ssn, stmt, scenario,
+                                   require_all_victims_replaced,
+                                   try_replace_victims)
+            scenario_span.set(evicted=len(new_tasks), fits=ok)
+            if not ok:
+                stmt.rollback(cp)
         if ok:
-            stmt.commit()
+            evictions = sum(1 for op in stmt.ops if op.kind == "evict")
+            with TRACER.span("statement:commit", kind="commit") as commit:
+                commit.set(binds=len(stmt.commit()), evictions=evictions)
+            METRICS.inc("solver_evictions_total", evictions,
+                        action=action_name)
             return SolverResult(True,
                                 [vj.uid for vj, _ in scenario.victims],
-                                tried)
-        stmt.rollback(cp)
+                                tried, skipped)
         failures += 1
         if prescreen is None and builder.has_next() \
                 and failures >= ssn.config.scenario_prescreen_after:
@@ -210,7 +247,8 @@ def solve_job(ssn, pending_job: PodGroupInfo,
             prescreen = _prefix_prescreen(ssn, tasks, builder)
             prescreen_offset = step_idx
     stmt.discard()
-    return SolverResult(False, scenarios_tried=tried)
+    return SolverResult(False, scenarios_tried=tried,
+                        scenarios_skipped=skipped)
 
 
 def _prefix_prescreen(ssn, tasks, builder: "ScenarioBuilder"):
@@ -226,28 +264,47 @@ def _prefix_prescreen(ssn, tasks, builder: "ScenarioBuilder"):
     current-state mask may be stricter than a post-eviction one — we must
     not over-prune).
     """
+    with TRACER.span("solve:prescreen", kind="solver") as sp:
+        verdict = _prescreen_verdict(ssn, tasks, builder, sp)
+        if verdict is not None and len(verdict):
+            feasible = np.flatnonzero(verdict)
+            sp.set(feasible=int(feasible.size),
+                   first_feasible=int(feasible[0]) if feasible.size else -1)
+            METRICS.inc("scenario_prescreen_prefixes_total", len(verdict))
+            METRICS.inc("scenario_prescreen_feasible_total",
+                        int(feasible.size))
+        return verdict
+
+
+def _prescreen_verdict(ssn, tasks, builder: "ScenarioBuilder", sp):
+    """``_prefix_prescreen`` under its span ``sp``: the verdict, ``()``
+    where the device was asked and gave none, or None where the batch was
+    not asked, the reason on the span."""
+    def declined(why: str) -> None:
+        sp.set(declined=why)
+
     steps = builder._steps
     cap = ssn.config.scenario_prescreen_max
-    if cap <= 0 or len(steps) < 3:
-        return None
+    if cap <= 0:
+        return declined("disabled")
+    if len(steps) < 3:
+        return declined("few-steps")
     if any(t.is_fractional or t.resource_claims or t.res_req.mig_resources
            for t in tasks):
-        return None
+        return declined("host-state-task")
     # Fractional VICTIMS release whole devices when their sharing group
     # empties (node_info._sync_group_releasing) — more than their
     # request vector — so the vector model would undercount and
     # unsoundly skip feasible prefixes.
     if any(t.is_fractional for _v, vtasks in steps for t in vtasks):
-        return None
+        return declined("fractional-victim")
     if ssn.compute_hard_mask(tasks) is not None:
-        return None
+        return declined("hard-mask")
     for fn in ssn.anti_domain_fns + ssn.affinity_domain_fns:
         if fn(tasks) is not None:
-            return None
+            return declined("domain-rows")
 
     from ..ops.scenario_batch import batch_prefix_feasibility
-
-    METRICS.inc("device_kernel_calls")
 
     steps = steps[:cap]
     # Sparse victim-release rows; padding (step index == num_prefixes)
@@ -262,7 +319,7 @@ def _prefix_prescreen(ssn, tasks, builder: "ScenarioBuilder"):
                 rows_node.append(idx)
                 rows_vec.append(t.res_req.to_vec(mig_as_gpu=False))
     if not rows_vec:
-        return None
+        return declined("no-release-rows")
     num_prefixes = _next_pow2(len(steps))
     m_pad = _next_pow2(len(rows_vec))
     n_res = ssn.node_releasing.shape[1]
@@ -278,9 +335,12 @@ def _prefix_prescreen(ssn, tasks, builder: "ScenarioBuilder"):
     rows = propose.task_operands(
         ssn, [(builder.scenario.pending_job, tasks)])
     if rows is None:
-        return None
+        return declined("no-task-rows")
+    sp.set(prefixes=num_prefixes, steps=len(steps), rows=m_pad,
+           t_pad=int(rows.task_req.shape[0]))
 
     from ..utils.deviceguard import CycleDeadlineExceeded, DeviceGuardError
+    METRICS.inc("device_kernel_calls")
     try:
         feasible = propose.run_on_nodes(
             ssn, batch_prefix_feasibility,
@@ -299,6 +359,7 @@ def _prefix_prescreen(ssn, tasks, builder: "ScenarioBuilder"):
         # None: "attempted and unavailable", so the solve loop doesn't
         # re-pay the failed dispatch on every subsequent scenario (the
         # step-index lookup skips it naturally).
+        sp.set(unavailable=True)
         return ()
     return np.asarray(feasible)[:len(steps)]
 

@@ -289,7 +289,8 @@ class _HandOff:
 # mixes unlike intervals under one kind (``seam`` is a 5 s staging beside
 # a 1 ms launch), so a quantile over it says nothing.  ``span_names`` in
 # /debug/cycles carries their totals by name.
-_NO_HISTOGRAM_KINDS = frozenset({"allocate", "topology", "propose", "seam"})
+_NO_HISTOGRAM_KINDS = frozenset({"allocate", "topology", "propose", "seam",
+                                 "reclaim", "solver"})
 
 
 def _trace_annotation():

@@ -933,6 +933,39 @@ class TestKAI008MetricsHygiene:
                    and "cycle_overlap_ratio" in f.message
                    for f in findings)
 
+    def test_solver_family_consistent_usage_is_clean(self):
+        # The scenario solver's families (actions/solvers.py, PR 35): the
+        # prescreen's three label-free counters and the evictions a solve
+        # committed, labelled by the action as
+        # scenarios_simulation_by_action is.
+        src = ("from ..utils.metrics import METRICS\n"
+               "def f(v, name):\n"
+               "    METRICS.inc('scenarios_simulation_by_action',"
+               " action=name)\n"
+               "    METRICS.inc('scenario_prescreen_prefixes_total', v)\n"
+               "    METRICS.inc('scenario_prescreen_feasible_total', v)\n"
+               "    METRICS.inc('scenarios_skipped_by_prescreen_total',"
+               " v)\n"
+               "    METRICS.inc('solver_evictions_total', v,"
+               " action=name)\n")
+        findings = lint(("kai_scheduler_tpu/actions/fix.py", src))
+        assert [f for f in findings if f.rule == "KAI008"] == []
+
+    def test_solver_label_drift_fires(self):
+        # solver_evictions_total is labelled by action everywhere: one
+        # label-free call site would split the family on /metrics.
+        a = ("from ..utils.metrics import METRICS\n"
+             "def f(v, name):\n"
+             "    METRICS.inc('solver_evictions_total', v, action=name)\n")
+        b = ("from ..utils.metrics import METRICS\n"
+             "def g(v):\n"
+             "    METRICS.inc('solver_evictions_total', v)\n")
+        findings = lint(("kai_scheduler_tpu/actions/a.py", a),
+                        ("kai_scheduler_tpu/actions/b.py", b))
+        assert any(f.rule == "KAI008" and "label keys" in f.message
+                   and "solver_evictions_total" in f.message
+                   for f in findings)
+
     def test_stackprof_family_consistent_usage_is_clean(self):
         src = ("from ..utils.metrics import METRICS\n"
                "def f(v):\n"
