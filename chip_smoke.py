@@ -17,8 +17,9 @@ checks what comes out by the repo's own means.
 * Stage B — the full-width kernels, in a second chip process started
   after the daemon has exited: the 98304-node x 1,048,576-pod grouped
   fill on the rung ``auto`` picks against the jnp rung, TAS at 65,536
-  nodes, the exact kernel at 1024n x 2048 pods, and the 10k-queue forest
-  fair share against the sequential numpy reference.
+  nodes, the exact kernel at 1024n x 2048 pods, the 10k-queue forest
+  fair share against the sequential numpy reference, and the scenario
+  prescreen counted against scanned on cpu quotients k x 4000 / 4000.
 
 One process uses the chip at a time, and this parent never initialises a
 JAX backend.  Every stage prints one JSON line naming the device, its
@@ -809,8 +810,82 @@ def stage_b_fairshare(n_queues=10000, bands=1) -> dict:
             "max_abs_err": err, "tolerance": bound}
 
 
+def stage_b_prescreen(quotients=512) -> dict:
+    """The scenario prescreen's two forms on exact-integer cpu quotients.
+
+    TPU f32 division hands ``floor(k * 4000 / 4000)`` back one low for
+    some k (ROADMAP D12), and the counted form divides.  Prefix k leaves
+    room for exactly k pods on node k and ``quotients - k`` on node 0, so
+    a gang of ``quotients`` fits every prefix only if both counts are
+    exact, and one pod more fits none only if neither is high.  The same
+    rows with one pod tolerating a taint no node carries are a gang of
+    two distinct rows, which the kernel scans: the forms must agree bit
+    for bit."""
+    import numpy as np
+
+    from kai_scheduler_tpu.ops.scenario_batch import (
+        batch_prefix_feasibility, uniform_gang)
+
+    q, cpu = quotients, 4000.0
+    n = q + 1
+    ks = np.arange(1, q + 1)
+    idle = np.zeros((n, 3))
+    idle[0, 0] = q * cpu
+    nodes = (np.tile([n * cpu, 1.0, 1.0], (n, 1)), idle, np.zeros((n, 3)),
+             np.full((n, 1), -1, np.int32), np.full((n, 1), -1, np.int32),
+             np.full(n, float(q + 1)))
+    # Prefix k (row k - 1): node k gains k pods' cpu, node k - 1 loses
+    # what it had gained, node 0 loses one pod's.
+    step = np.concatenate([ks - 1, ks[1:] - 1, ks - 1])
+    node = np.concatenate([ks, ks[:-1], np.zeros(q, int)])
+    amount = np.concatenate([ks * cpu, -ks[:-1] * cpu, np.full(q, -cpu)])
+    m_pad = 1 << int(len(step) - 1).bit_length()
+    release_step = np.full(m_pad, q, np.int32)
+    release_step[:len(step)] = step
+    release_node = np.zeros(m_pad, np.int32)
+    release_node[:len(node)] = node
+    release_vec = np.zeros((m_pad, 3))
+    release_vec[:len(amount), 0] = amount
+    t_pad = 1 << int(q).bit_length()         # room for quotients + 1 pods
+
+    def verdict(gang: int, alike: bool):
+        job = np.where(np.arange(t_pad) < gang, 0, 1).astype(np.int32)
+        req = np.where((job == 0)[:, None], [cpu, 0.0, 0.0], 0.0)
+        sel = np.full((t_pad, 1), -1, np.int32)
+        tol = np.full((t_pad, 1), -1, np.int32)
+        if not alike:
+            tol[0, 0] = 5
+        _check(bool(uniform_gang(req, job, sel, tol)) == alike,
+               f"a gang made {'alike' if alike else 'mixed'} reads otherwise")
+        return np.asarray(batch_prefix_feasibility(
+            *nodes, release_step, release_node, release_vec, req, job,
+            sel, tol, num_prefixes=q))
+
+    t0 = time.perf_counter()
+    fits = verdict(q, alike=True)
+    setup_s = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    verdict(q, alike=True)                   # ends in the host fetch
+    run_ms = (time.perf_counter() - t0) * 1e3
+    over = verdict(q + 1, alike=True)
+    differ = int((fits != verdict(q, alike=False)).sum()
+                 + (over != verdict(q + 1, alike=False)).sum())
+    _check(differ == 0,
+           f"counted and scanned disagree on {differ} of {2 * q} prefixes")
+    low, high = int((~fits).sum()), int(over.sum())
+    _check(low == 0 and high == 0,
+           f"{low} prefixes counted a pod short, {high} a pod over")
+    return {**_device(),
+            "shape": {"prefixes": q, "nodes": n, "gang": q, "t_pad": t_pad,
+                      "rows": m_pad},
+            "setup_s": round(setup_s, 2), "run_ms": round(run_ms, 2),
+            "form_mismatches": differ, "counted_low": low,
+            "counted_high": high}
+
+
 STAGE_B = {"B.grouped_fill": stage_b_grouped, "B.tas": stage_b_tas,
-           "B.exact": stage_b_exact, "B.fair_share": stage_b_fairshare}
+           "B.exact": stage_b_exact, "B.fair_share": stage_b_fairshare,
+           "B.scenario_prescreen": stage_b_prescreen}
 
 
 def stage_b_main() -> int:

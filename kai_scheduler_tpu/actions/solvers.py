@@ -304,7 +304,7 @@ def _prescreen_verdict(ssn, tasks, builder: "ScenarioBuilder", sp):
         if fn(tasks) is not None:
             return declined("domain-rows")
 
-    from ..ops.scenario_batch import batch_prefix_feasibility
+    from ..ops.scenario_batch import batch_prefix_feasibility, uniform_gang
 
     steps = steps[:cap]
     # Sparse victim-release rows; padding (step index == num_prefixes)
@@ -336,11 +336,17 @@ def _prescreen_verdict(ssn, tasks, builder: "ScenarioBuilder", sp):
         ssn, [(builder.scenario.pending_job, tasks)])
     if rows is None:
         return declined("no-task-rows")
+    # The kernel picks its form from these rows by the same predicate.
+    counted = bool(uniform_gang(rows.task_req, rows.task_job,
+                                rows.task_sel, rows.task_tol))
     sp.set(prefixes=num_prefixes, steps=len(steps), rows=m_pad,
-           t_pad=int(rows.task_req.shape[0]))
+           t_pad=int(rows.task_req.shape[0]),
+           form="counted" if counted else "scanned")
 
     from ..utils.deviceguard import CycleDeadlineExceeded, DeviceGuardError
     METRICS.inc("device_kernel_calls")
+    if counted:
+        METRICS.inc("scenario_prescreen_counted_total")
     try:
         feasible = propose.run_on_nodes(
             ssn, batch_prefix_feasibility,

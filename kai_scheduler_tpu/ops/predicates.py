@@ -23,6 +23,17 @@ NO_TAINT = -1
 EPS = 1e-9
 
 
+def hard_row(labels, taints, room, selector, tolerations):
+    """One task against all nodes, [N] bool: what no release can change
+    — every constrained label matches, every taint is tolerated, and a
+    pod of room is left."""
+    sel_ok = jnp.all((selector[None, :] == NO_LABEL)
+                     | (selector[None, :] == labels), axis=-1)
+    tol = jnp.any(taints[:, :, None] == tolerations[None, None, :], axis=-1)
+    taint_ok = jnp.all((taints == NO_TAINT) | tol, axis=-1)
+    return sel_ok & taint_ok & (room >= 1.0)
+
+
 def feasibility_row(idle, releasing, labels, taints, room,
                     req, selector, tolerations):
     """One task against all nodes: ([N,R] state, [R]/[L]/[Tl] task) ->
@@ -31,11 +42,7 @@ def feasibility_row(idle, releasing, labels, taints, room,
     fit_now: IsTaskAllocatable (idle resources); fit_future:
     IsTaskAllocatableOnReleasingOrIdle (pipelining candidates).
     """
-    sel_ok = jnp.all((selector[None, :] == NO_LABEL)
-                     | (selector[None, :] == labels), axis=-1)
-    tol = jnp.any(taints[:, :, None] == tolerations[None, None, :], axis=-1)
-    taint_ok = jnp.all((taints == NO_TAINT) | tol, axis=-1)
-    hard = sel_ok & taint_ok & (room >= 1.0)
+    hard = hard_row(labels, taints, room, selector, tolerations)
     fit_now = hard & jnp.all(req[None, :] <= idle + EPS, axis=-1)
     fit_future = hard & jnp.all(req[None, :] <= idle + releasing + EPS,
                                 axis=-1)
@@ -75,11 +82,7 @@ def feasibility_caps_row(idle, releasing, labels, taints, room,
     releasing == 0 the legacy formulas reduce to exactly that, including
     EPS behaviour).
     """
-    sel_ok = jnp.all((selector[None, :] == NO_LABEL)
-                     | (selector[None, :] == labels), axis=-1)
-    tol = jnp.any(taints[:, :, None] == tolerations[None, None, :], axis=-1)
-    taint_ok = jnp.all((taints == NO_TAINT) | tol, axis=-1)
-    hard = sel_ok & taint_ok & (room >= 1.0)
+    hard = hard_row(labels, taints, room, selector, tolerations)
 
     r_dims = idle.shape[1]
     fits_idle = hard

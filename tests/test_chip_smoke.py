@@ -174,6 +174,28 @@ def test_stage_b_tas_exact_and_fairshare():
     assert fair["max_abs_err"] <= fair["tolerance"]
 
 
+def test_stage_b_prescreen_forms_agree_on_whole_quotients():
+    row = cs.stage_b_prescreen(8)
+    assert row["shape"] == {"prefixes": 8, "nodes": 9, "gang": 8,
+                            "t_pad": 16, "rows": 32}
+    assert (row["form_mismatches"], row["counted_low"],
+            row["counted_high"]) == (0, 0, 0)
+
+
+@pytest.mark.parametrize("off", (-1, 1), ids=("low", "high"))
+def test_stage_b_prescreen_catches_a_count_one_off(monkeypatch, off):
+    """What ROADMAP D12's division would do to an uncorrected count."""
+    from kai_scheduler_tpu.ops import scenario_batch as sb
+    monkeypatch.setattr(sb, "corrected_count",
+                        lambda quotient, req, total: quotient + off)
+    try:
+        with pytest.raises(cs.SmokeFailure,
+                           match="disagree on 4 of 8 prefixes"):
+            cs.stage_b_prescreen(4)
+    finally:
+        sb.batch_prefix_feasibility.clear_cache()
+
+
 def _fake_stage_b(monkeypatch, rc, stages):
     lines = "".join('{"stage": "%s", "platform": "tpu", "device_kind": '
                     '"k", "count": 1}\n' % s for s in stages)
@@ -187,7 +209,7 @@ ALL_B = list(cs.STAGE_B)
 
 def test_stage_b_child_nonzero_exit_fails(monkeypatch, capsys):
     _fake_stage_b(monkeypatch, 0, ALL_B)
-    assert len(cs._run_stage_b()) == 4
+    assert len(cs._run_stage_b()) == len(ALL_B)
     _fake_stage_b(monkeypatch, 1, ALL_B)
     with pytest.raises(cs.SmokeFailure, match="exited 1"):
         cs._run_stage_b()

@@ -1,0 +1,236 @@
+"""The scenario prescreen's two forms (``ops/scenario_batch.py``): a gang
+of identical pods is counted in one pass over the prefix pools, any other
+is scanned by the exact kernel, and both give the same bits where both
+apply.  The choice is made from the task rows, on the device and, for the
+span and the counter, on the host."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from kai_scheduler_tpu.ops import scenario_batch as sb
+from kai_scheduler_tpu.ops.scoring import BINPACK
+from kai_scheduler_tpu.utils.metrics import METRICS
+from kai_scheduler_tpu.utils.tracing import TRACER
+from tests.fixtures import build_session, run_action
+
+N, K, M, T_PAD = 24, 16, 32, 8
+POD = np.array([4000.0, 2.0 ** 35, 1.0])     # the benchmark's worker
+
+
+def fleet(seed: int, oversize: bool = False):
+    """A random fleet, K prefixes of release rows and one gang of
+    identical pods padded to T_PAD under job 1.  Every quantity is a
+    whole multiple of the pod's, so each comparison is exact."""
+    rng = np.random.default_rng(seed)
+    labels = rng.integers(0, 3, (N, 2)).astype(np.int32)
+    taints = np.where(rng.random((N, 1)) < 0.3, 7, -1).astype(np.int32)
+    room = rng.integers(0, 4, N).astype(float)
+    idle = rng.integers(0, 2, (N, 3)) * POD
+    rel = rng.integers(0, 2, (N, 3)) * POD
+    alloc = np.tile(8 * POD, (N, 1))
+    # Release rows land on any node, those the selector refuses too; the
+    # padding rows carry step K and drop.
+    step = rng.integers(0, K, M).astype(np.int32)
+    step[rng.random(M) < 0.2] = K
+    node = rng.integers(0, N, M).astype(np.int32)
+    vec = rng.integers(0, 3, (M, 3)) * POD
+    # A resource nobody asks for: zero-request columns in some fleets.
+    req = POD * (rng.random(3) < 0.75)
+    sel = np.where(rng.random(2) < 0.5, rng.integers(0, 3, 2), -1)
+    tol = np.array([7 if rng.random() < 0.5 else -1])
+    gang = N * 4 + 1 if oversize else int(rng.integers(1, T_PAD + 1))
+    t_pad = max(T_PAD, 1 << (gang - 1).bit_length())
+    task_job = np.where(np.arange(t_pad) < gang, 0, 1).astype(np.int32)
+    real = (task_job == 0)[:, None]
+    task_req = np.where(real, req, 0.0)
+    task_sel = np.where(real, sel, -1).astype(np.int32)
+    task_tol = np.where(real, tol, -1).astype(np.int32)
+    return ((alloc, idle, rel, labels, taints, room), (step, node, vec),
+            (task_req, task_job, task_sel, task_tol))
+
+
+def pools(rel, step, node, vec, k=K):
+    """[K,N,R] prefix pools, in numpy: the scatter and the running sum."""
+    delta = np.zeros((k + 1,) + rel.shape)
+    np.add.at(delta, (np.minimum(step, k), node), vec)
+    return rel[None] + np.cumsum(delta[:k], axis=0)
+
+
+@jax.jit
+def counted(pool, nodes, tasks):
+    _alloc, idle, _rel, labels, taints, room = nodes
+    return sb.count_prefixes(pool, idle, labels, taints, room, *tasks)
+
+
+@functools.partial(jax.jit, static_argnames="masked")
+def scanned(pool, nodes, tasks, mask=None, masked=False):
+    alloc, idle, _rel, labels, taints, room = nodes
+    return sb.scan_prefixes(pool, alloc, idle, labels, taints, room, *tasks,
+                            mask if masked else None, BINPACK, BINPACK)
+
+
+def whole(nodes, release, tasks, k=K, mask=None):
+    return np.asarray(sb.batch_prefix_feasibility(
+        *nodes, *release, *tasks, num_prefixes=k, task_node_mask=mask))
+
+
+@pytest.mark.parametrize("seed", range(24))
+def test_counted_equals_scanned_on_random_fleets(seed):
+    nodes, release, tasks = fleet(seed)
+    pool = pools(nodes[2], *release)
+    want = np.asarray(scanned(pool, nodes, tasks))
+    assert np.asarray(counted(pool, nodes, tasks)).tolist() == want.tolist()
+    assert bool(sb.uniform_gang(*map(jnp.asarray, tasks)))
+    assert whole(nodes, release, tasks).tolist() == want.tolist()
+
+
+def test_the_random_fleets_hold_both_answers():
+    """The fleets above are no vacuous agreement: both bits occur."""
+    bits = []
+    for seed in range(24):
+        nodes, release, tasks = fleet(seed)
+        bits += np.asarray(counted(pools(nodes[2], *release), nodes,
+                                   tasks)).tolist()
+    assert 0.2 < np.mean(bits) < 0.8
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_a_gang_larger_than_the_fleet_can_hold_never_fits(seed):
+    nodes, release, tasks = fleet(100 + seed, oversize=True)
+    pool = pools(nodes[2], *release)
+    got = np.asarray(counted(pool, nodes, tasks))
+    assert not got.any()
+    assert got.tolist() == np.asarray(scanned(pool, nodes, tasks)).tolist()
+
+
+def test_a_gang_of_no_pods_fits_nowhere():
+    """All rows padding: the exact kernel reports a job of no tasks as not
+    placed, and so does the count."""
+    nodes, release, tasks = fleet(0)
+    task_req, task_job, task_sel, task_tol = tasks
+    tasks = (task_req * 0, np.ones_like(task_job), task_sel, task_tol)
+    pool = pools(nodes[2], *release)
+    assert not np.asarray(counted(pool, nodes, tasks)).any()
+    assert not np.asarray(scanned(pool, nodes, tasks)).any()
+
+
+@pytest.mark.parametrize("off", (-1, 0, 1), ids=("low", "exact", "high"))
+@pytest.mark.parametrize("req", (4000.0, 2.0 ** 35, 1.0),
+                         ids=("4000", "2**35", "1"))
+def test_the_count_survives_a_quotient_one_off(req, off):
+    """TPU f32 division hands ``floor(k * req / req)`` back one low
+    (ROADMAP D12): fed such a quotient, in f32, the count is exact."""
+    k = jnp.arange(1, 513, dtype=jnp.float32)
+    req = jnp.float32(req)
+    total = k * req
+    got = sb.corrected_count(k + off, req, total)
+    assert got.dtype == jnp.float32
+    assert np.asarray(got).tolist() == np.asarray(k).tolist()
+    # A remainder short of one more pod changes nothing; less than
+    # nothing counts as none.
+    part = sb.corrected_count(k + off, req, total + req / 2)
+    assert np.asarray(part).tolist() == np.asarray(k).tolist()
+    assert float(sb.corrected_count(jnp.float32(off), req, -req)) == 0.0
+
+
+def mixed_fleet():
+    """Four nodes of room for one pod each, a GPU releasing on node 0; the
+    gang is a master of two GPUs beside two one-GPU workers, and prefix k
+    releases a GPU on node k.  Counting the master's row for all three
+    would ask two GPUs a node, which node 0 alone ever has."""
+    n, k = 4, 4
+    unit = np.array([1000.0, 2.0 ** 30, 1.0])
+    rel = np.zeros((n, 3))
+    rel[0] = unit
+    nodes = (np.tile(8 * unit, (n, 1)), np.zeros((n, 3)), rel,
+             np.full((n, 1), -1, np.int32),
+             np.full((n, 1), -1, np.int32), np.ones(n))
+    step = np.arange(k, dtype=np.int32)
+    release = (step, step, np.tile(unit, (k, 1)))
+    task_req = np.array([2 * unit, unit, unit, 0 * unit])
+    task_job = np.array([0, 0, 0, 1], np.int32)
+    none = np.full((4, 1), -1, np.int32)
+    return nodes, release, (task_req, task_job, none, none), k
+
+
+def test_a_gang_of_two_distinct_rows_is_scanned():
+    nodes, release, tasks, k = mixed_fleet()
+    pool = pools(nodes[2], *release, k=k)
+    want = np.asarray(scanned(pool, nodes, tasks)).tolist()
+    assert want == [False, False, True, True]
+    assert not bool(sb.uniform_gang(*map(jnp.asarray, tasks)))
+    assert whole(nodes, release, tasks, k=k).tolist() == want
+    # The count would have answered for three masters.
+    assert np.asarray(counted(pool, nodes, tasks)).tolist() != want
+
+
+def test_a_call_with_a_task_node_mask_is_scanned():
+    nodes, release, tasks, k = mixed_fleet()
+    task_req, task_job, none, _ = tasks
+    tasks = (np.where((task_job == 0)[:, None], task_req[1], 0.0), task_job,
+             none, none)
+    assert bool(sb.uniform_gang(*map(jnp.asarray, tasks)))
+    # Identical workers, but none may use node 2.
+    mask = np.ones((4, 4), bool)
+    mask[:, 2] = False
+    pool = pools(nodes[2], *release, k=k)
+    want = np.asarray(scanned(pool, nodes, tasks, jnp.asarray(mask),
+                              masked=True)).tolist()
+    assert want == [False, False, False, True]
+    assert whole(nodes, release, tasks, k=k,
+                 mask=jnp.asarray(mask)).tolist() == want
+    assert np.asarray(counted(pool, nodes, tasks)).tolist() \
+        == [False, False, True, True]
+
+
+def reclaim_spec(claimer_tasks):
+    jobs = {f"v{i}": {"queue": "b", "tasks": [
+        {"gpu": 1, "status": "RUNNING", "node": "n1"}]} for i in range(8)}
+    jobs["claimer"] = {"queue": "a", "tasks": claimer_tasks,
+                       "min_available": len(claimer_tasks)}
+    return {"nodes": {"n1": {"gpu": 8}},
+            "queues": {"a": {"deserved": {"gpu": 4}},
+                       "b": {"deserved": {"gpu": 4}}},
+            "jobs": jobs}
+
+
+@pytest.mark.parametrize("form, claimer_tasks", [
+    ("counted", [{"gpu": 4}]),
+    ("counted", [{"gpu": 1}] * 3),
+    ("scanned", [{"gpu": 3}, {"gpu": 1}]),
+    ("scanned", [{"gpu": 2, "cpu": "2"}, {"gpu": 2, "cpu": "1"}]),
+], ids=("one-pod", "three-alike-and-a-pad", "master-and-worker",
+        "cpu-differs"))
+def test_host_and_device_name_the_same_form(monkeypatch, form,
+                                            claimer_tasks):
+    """The span's ``form`` and the counter are the host's reading of the
+    rows it sends; the kernel's ``cond`` reads the same rows by the same
+    predicate."""
+    from kai_scheduler_tpu.actions import solvers
+    sent = {}
+    run_on_nodes = solvers.propose.run_on_nodes
+
+    def spy(ssn, kernel, operands, **kw):
+        sent["rows"] = operands[3:]
+        sent["verdict"] = run_on_nodes(ssn, kernel, operands, **kw)
+        return sent["verdict"]
+
+    monkeypatch.setattr(solvers.propose, "run_on_nodes", spy)
+    ssn = build_session(reclaim_spec(claimer_tasks))
+    before = METRICS.counters.get("scenario_prescreen_counted_total", 0)
+    TRACER.begin_cycle(1)
+    run_action(ssn, "reclaim")
+    trace = TRACER.end_cycle()
+    moved = METRICS.counters.get("scenario_prescreen_counted_total",
+                                 0) - before
+    (span,) = [s for s in trace.spans if s.name == "solve:prescreen"
+               and "declined" not in s.attrs]
+    assert span.attrs["form"] == form
+    assert moved == (1 if form == "counted" else 0)
+    on_device = bool(sb.uniform_gang(*map(jnp.asarray, sent["rows"])))
+    assert on_device == (form == "counted")
+    assert len(sent["rows"][0]) == span.attrs["t_pad"]
