@@ -13,6 +13,7 @@ import numpy as np
 
 from ..api import resources as rs
 from ..api.podgroup_info import PodGroupInfo
+from ..utils.tracing import TRACER
 from .solvers import fractional_headroom, solve_job
 from .utils import INFINITE, JobsOrderByQueues
 
@@ -27,9 +28,11 @@ class ConsolidationAction:
                    and pg.queue_id in ssn.cluster.queues]
         if not pending:
             return
-        order = JobsOrderByQueues(
-            ssn, pending,
-            ssn.config.queue_depth_per_action.get(self.name, INFINITE))
+        with TRACER.span("consolidation:order", kind="consolidation",
+                         jobs=len(pending)):
+            order = JobsOrderByQueues(
+                ssn, pending,
+                ssn.config.queue_depth_per_action.get(self.name, INFINITE))
         failed_signatures: set = set()
 
         while not order.empty():
@@ -64,28 +67,77 @@ class ConsolidationAction:
                     failed_signatures.add(sig)
                 order.requeue_queue(job.queue_id)
                 continue
-            victims = collect_consolidation_victims(ssn, job)
-            if not victims:
-                order.requeue_queue(job.queue_id)
-                continue
-            result = solve_job(ssn, job, victims,
-                               lambda scenario: True, self.name,
-                               require_all_victims_replaced=True)
-            if not result.success and ssn.config.use_scheduling_signatures:
+            # The span of one job past the bound above: the walk that
+            # collects what may be moved, and the solver.  A job turned
+            # away above opens none.
+            with TRACER.span("consolidation:job", kind="consolidation",
+                             job=job.name, queue=job.queue_id) as sp:
+                with TRACER.span("consolidation:victims",
+                                 kind="consolidation") as sv:
+                    victims = collect_consolidation_victims(ssn, job, tasks)
+                    sv.set(victims=len(victims))
+                sp.set(victims=len(victims), success=False)
+                result = None
+                if victims:
+                    result = solve_job(ssn, job, victims,
+                                       lambda scenario: True, self.name,
+                                       require_all_victims_replaced=True)
+                    sp.set(success=result.success)
+            if result is not None and not result.success \
+                    and ssn.config.use_scheduling_signatures:
                 failed_signatures.add(sig)
             order.requeue_queue(job.queue_id)
 
 
-def collect_consolidation_victims(ssn, job: PodGroupInfo
+def collect_consolidation_victims(ssn, job: PodGroupInfo, tasks
                                   ) -> list[PodGroupInfo]:
     """Running preemptible jobs from any queue — candidates to shuffle, not
-    to kill (they must all land again)."""
-    victims = [
-        pg for pg in ssn.cluster.podgroups.values()
-        if pg.uid != job.uid
-        and pg.queue_id in ssn.cluster.queues
-        and pg.is_preemptible()
-        and pg.num_active_allocated() > 0
-    ]
-    victims.sort(key=lambda pg: (pg.priority, -pg.creation_ts))
-    return victims
+    to kill (they must all land again).  Lowest priority first; within a
+    priority the jobs whose leaving, alone, seats one of ``tasks`` come
+    before the others, and the newest first among those.  The solver
+    evicts a prefix of this list: a job that shares its nodes frees no
+    seat by leaving, and ahead of one that does it is moved for nothing."""
+    victims, rows_victim, rows_node, rows_vec = [], [], [], []
+    for pg in ssn.cluster.podgroups.values():
+        if pg.uid == job.uid or not pg.is_preemptible() \
+                or pg.queue_id not in ssn.cluster.queues:
+            continue
+        running = [t for t in pg.pods.values() if t.is_active_allocated()]
+        if not running:
+            continue
+        for t in running:
+            idx = ssn.node_index(t.node_name)
+            if idx >= 0:
+                rows_victim.append(len(victims))
+                rows_node.append(idx)
+                rows_vec.append(t.res_req.to_vec(mig_as_gpu=False))
+        victims.append(pg)
+    seats = _seats_a_task(ssn, tasks, len(victims), rows_victim, rows_node,
+                          rows_vec)
+    order = sorted(range(len(victims)), key=lambda i: (
+        victims[i].priority, not seats[i], -victims[i].creation_ts))
+    return [victims[i] for i in order]
+
+
+def _seats_a_task(ssn, tasks, victims: int, rows_victim, rows_node,
+                  rows_vec) -> np.ndarray:
+    """[victims] bool: the victim's leaving gives some node the room one
+    of ``tasks`` asks and does not find there now.  One row a running pod
+    of a victim: its victim, its node and what it holds there."""
+    seats = np.zeros(victims, bool)
+    if not rows_vec:
+        return seats
+    nodes = len(ssn.node_idle)
+    pair, of_row = np.unique(
+        np.asarray(rows_victim, np.int64) * nodes + np.asarray(rows_node),
+        return_inverse=True)
+    freed = np.zeros((len(pair), ssn.node_idle.shape[1]))
+    np.add.at(freed, of_row, np.asarray(rows_vec))
+    at = pair % nodes
+    room = (ssn.node_idle[at] + ssn.node_releasing[at])[:, None, :]
+    asks = np.unique([t.res_req.to_vec(mig_as_gpu=False) for t in tasks],
+                     axis=0)[None, :, :]
+    lacked = np.any(room + 1e-9 < asks, axis=2)
+    held = np.all(room + freed[:, None, :] + 1e-9 >= asks, axis=2)
+    seats[pair[np.any(lacked & held, axis=1)] // nodes] = True
+    return seats

@@ -96,6 +96,7 @@ class SolverResult:
     evicted_jobs: list = field(default_factory=list)
     scenarios_tried: int = 0
     scenarios_skipped: int = 0   # passed over on the prescreen's verdict
+    replaced: int = 0            # evicted tasks pipelined a place elsewhere
 
 
 def fractional_headroom(ssn) -> float:
@@ -142,7 +143,8 @@ def solve_job(ssn, pending_job: PodGroupInfo,
                         action_name, require_all_victims_replaced,
                         try_replace_victims, sp)
         sp.set(tried=result.scenarios_tried,
-               skipped=result.scenarios_skipped, solved=result.success)
+               skipped=result.scenarios_skipped, solved=result.success,
+               replaced=result.replaced)
     if result.scenarios_skipped:
         METRICS.inc("scenarios_skipped_by_prescreen_total",
                     result.scenarios_skipped)
@@ -231,14 +233,19 @@ def _solve(ssn, pending_job, tasks, ordered_victims, validate,
             if not ok:
                 stmt.rollback(cp)
         if ok:
-            evictions = sum(1 for op in stmt.ops if op.kind == "evict")
+            evicted = {op.task.uid for op in stmt.ops if op.kind == "evict"}
+            # A victim the same statement pipelined elsewhere lands again.
+            replaced = sum(1 for op in stmt.ops if op.kind == "pipeline"
+                           and op.task.uid in evicted)
             with TRACER.span("statement:commit", kind="commit") as commit:
-                commit.set(binds=len(stmt.commit()), evictions=evictions)
-            METRICS.inc("solver_evictions_total", evictions,
+                commit.set(binds=len(stmt.commit()), evictions=len(evicted))
+            METRICS.inc("solver_evictions_total", len(evicted),
+                        action=action_name)
+            METRICS.inc("solver_victims_replaced_total", replaced,
                         action=action_name)
             return SolverResult(True,
                                 [vj.uid for vj, _ in scenario.victims],
-                                tried, skipped)
+                                tried, skipped, replaced)
         failures += 1
         if prescreen is None and builder.has_next() \
                 and failures >= ssn.config.scenario_prescreen_after:
@@ -345,8 +352,9 @@ def _prescreen_verdict(ssn, tasks, builder: "ScenarioBuilder", sp):
 
     from ..utils.deviceguard import CycleDeadlineExceeded, DeviceGuardError
     METRICS.inc("device_kernel_calls")
-    if counted:
-        METRICS.inc("scenario_prescreen_counted_total")
+    # A scanned call adds 0: where every gang is of mixed rows the family
+    # reads 0 and is not absent.
+    METRICS.inc("scenario_prescreen_counted_total", int(counted))
     try:
         feasible = propose.run_on_nodes(
             ssn, batch_prefix_feasibility,

@@ -290,7 +290,7 @@ class _HandOff:
 # a 1 ms launch), so a quantile over it says nothing.  ``span_names`` in
 # /debug/cycles carries their totals by name.
 _NO_HISTOGRAM_KINDS = frozenset({"allocate", "topology", "propose", "seam",
-                                 "reclaim", "solver"})
+                                 "reclaim", "solver", "consolidation"})
 
 
 def _trace_annotation():

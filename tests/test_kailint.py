@@ -966,6 +966,34 @@ class TestKAI008MetricsHygiene:
                    and "solver_evictions_total" in f.message
                    for f in findings)
 
+    def test_consolidation_family_consistent_usage_is_clean(self):
+        # What PR 37 added: the victims a solved job placed again, labelled
+        # by the action as solver_evictions_total is, and the
+        # counted-prescreen family moved by 0 or 1 from its one call site.
+        src = ("from ..utils.metrics import METRICS\n"
+               "def f(v, name, counted):\n"
+               "    METRICS.inc('solver_evictions_total', v, action=name)\n"
+               "    METRICS.inc('solver_victims_replaced_total', v,"
+               " action=name)\n"
+               "    METRICS.inc('scenario_prescreen_counted_total',"
+               " int(counted))\n")
+        findings = lint(("kai_scheduler_tpu/actions/fix.py", src))
+        assert [f for f in findings if f.rule == "KAI008"] == []
+
+    def test_replaced_label_drift_fires(self):
+        a = ("from ..utils.metrics import METRICS\n"
+             "def f(v, name):\n"
+             "    METRICS.inc('solver_victims_replaced_total', v,"
+             " action=name)\n")
+        b = ("from ..utils.metrics import METRICS\n"
+             "def g(v):\n"
+             "    METRICS.inc('solver_victims_replaced_total', v)\n")
+        findings = lint(("kai_scheduler_tpu/actions/a.py", a),
+                        ("kai_scheduler_tpu/actions/b.py", b))
+        assert any(f.rule == "KAI008" and "label keys" in f.message
+                   and "solver_victims_replaced_total" in f.message
+                   for f in findings)
+
     def test_stackprof_family_consistent_usage_is_clean(self):
         src = ("from ..utils.metrics import METRICS\n"
                "def f(v):\n"

@@ -143,7 +143,9 @@ def test_the_span_tree_under_the_reclaim_action(driven):
     assert solve.attrs == {
         "job": job.attrs["job"], "action": "reclaim", "tasks": gang,
         "victims": cut["victims"], "steps": 2 * cut["victims"],
-        "tried": 2, "skipped": steps - 2, "solved": True}
+        "tried": 2, "skipped": steps - 2, "solved": True,
+        # No victim lands again: the fleet is full.
+        "replaced": 0}
     inside = children(trace, solve)
     assert [s.name for s in inside] == [
         "solve:precheck", "solve:scenario", "solve:prescreen",

@@ -173,6 +173,45 @@ class TestConsolidation:
         run_action(ssn, "consolidation")
         assert ssn.cache.evicted == []
 
+    @pytest.mark.parametrize("newest", ("a", "b", "alone"))
+    def test_moves_the_job_whose_leaving_seats_the_pending_pod(self,
+                                                               newest):
+        # n1 is shared by two jobs, n2 holds one alone, n3 has room for
+        # what is moved.  Whichever is newest, moving "alone" empties a
+        # node with one pod moved; newest first would move "a" and "b"
+        # (two pods) where either of them is the newest.
+        def frag(name, node):
+            return {"queue": "q", "creation_ts": 2.0 if name == newest
+                    else 1.0,
+                    "tasks": [{"gpu": 2, "status": "RUNNING",
+                               "node": node}]}
+        ssn = build_session({
+            "nodes": {"n1": {"gpu": 8}, "n2": {"gpu": 8}, "n3": {"gpu": 8}},
+            "queues": {"q": {}},
+            "jobs": {
+                "a": frag("a", "n1"), "b": frag("b", "n1"),
+                "alone": frag("alone", "n2"),
+                "held": {"queue": "q", "preemptible": False,
+                         "tasks": [{"gpu": 4, "status": "RUNNING",
+                                    "node": "n3"}]},
+                "big": {"queue": "q", "tasks": [{"gpu": 8}]},
+            },
+        })
+        from kai_scheduler_tpu.actions.consolidation import \
+            collect_consolidation_victims
+        big = ssn.cluster.podgroups["big"]
+        victims = collect_consolidation_victims(
+            ssn, big, list(big.pods.values()))
+        assert victims[0].name == "alone"
+        assert {v.name for v in victims} == {"a", "b", "alone"}
+        # Among the jobs that seat nothing alone: the newest first.
+        assert victims[1].name == ("b" if newest == "b" else "a")
+        run_action(ssn, "allocate")
+        run_action(ssn, "consolidation")
+        assert ssn.cache.evicted == ["alone-0"]
+        assert statuses(ssn, "big")["big-0"] == "PIPELINED"
+        assert statuses(ssn, "alone")["alone-0"] == "PIPELINED"
+
 
 class TestStaleGangEviction:
     def test_evicts_stale_gang_after_grace(self):

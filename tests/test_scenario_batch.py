@@ -187,6 +187,75 @@ def test_a_call_with_a_task_node_mask_is_scanned():
         == [False, False, True, True]
 
 
+def defrag_fleet(seed: int):
+    """The consolidation cell's gang at a width the CPU holds: one master
+    row {36 cpu, 288 Gi, 8 GPU} and 127 worker rows {32 cpu, 256 Gi,
+    8 GPU}, all of job 0, no padding row; 200 nodes of which 150 hold one
+    job of two one-GPU pods, 20 hold four such jobs and 30 a whole-node
+    pod; prefix k releases the jobs of a random order up to its k-th."""
+    rng = np.random.default_rng(seed)
+    n, k = 200, 192
+    cap = np.array([64000.0, 512 * 2.0 ** 30, 8.0])
+    jobs_on = np.array([1] * 150 + [4] * 20 + [0] * 30)
+    rng.shuffle(jobs_on)
+    used = np.where(jobs_on[:, None] > 0, 2 * jobs_on[:, None] * POD, cap)
+    none = np.full((n, 1), -1, np.int32)
+    nodes = (np.tile(cap, (n, 1)), cap - used, np.zeros((n, 3)), none, none,
+             110.0 - np.where(jobs_on > 0, 2 * jobs_on, 1))
+    order = rng.permutation(np.repeat(np.arange(n), jobs_on))[:k]
+    m = 2 * k
+    step = np.full(512, k, np.int32)
+    step[:m] = np.repeat(np.arange(k), 2)
+    node = np.zeros(512, np.int32)
+    node[:m] = np.repeat(order, 2)
+    vec = np.zeros((512, 3))
+    vec[:m] = POD
+    master = np.array([36000.0, 288 * 2.0 ** 30, 8.0])
+    worker = np.array([32000.0, 256 * 2.0 ** 30, 8.0])
+    task_req = np.vstack([master[None], np.tile(worker, (127, 1))])
+    tasks = (task_req, np.zeros(128, np.int32),
+             np.full((128, 1), -1, np.int32), np.full((128, 1), -1, np.int32))
+    return nodes, (step, node, vec), tasks, k
+
+
+def first_fit_verdict(nodes, release, tasks, k):
+    """[K] bool by a numpy loop over the prefixes: the gang's pods, the
+    master first, each onto the first node whose idle and releasing
+    resources hold it (exact for pods that take a node each)."""
+    _alloc, idle, rel, _labels, _taints, room = nodes
+    out = []
+    for pool in pools(rel, *release, k=k):
+        free, left = idle + pool, room.copy()
+        ok = True
+        for req in tasks[0]:
+            fit = np.flatnonzero(np.all(free >= req, axis=1) & (left > 0))
+            if not fit.size:
+                ok = False
+                break
+            free[fit[0]] -= req
+            left[fit[0]] -= 1
+        out.append(ok)
+    return out
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_the_consolidation_cells_gang_is_scanned(seed):
+    """A master beside its workers is no uniform gang: the program scans,
+    and its verdict is the numpy loop's, bit for bit."""
+    nodes, release, tasks, k = defrag_fleet(seed)
+    assert not bool(sb.uniform_gang(*map(jnp.asarray, tasks)))
+    want = first_fit_verdict(nodes, release, tasks, k)
+    # The gang is seated from the prefix that empties its 128th node on.
+    assert 0 < sum(want) < k and want == sorted(want)
+    assert whole(nodes, release, tasks, k=k).tolist() == want
+    pool = pools(nodes[2], *release, k=k)
+    assert np.asarray(scanned(pool, nodes, tasks)).tolist() == want
+    # Counted as 128 masters the same fleet reads the same here (a node
+    # that holds a worker holds a master), which is why the form is chosen
+    # from the rows and never from the answer.
+    assert np.asarray(counted(pool, nodes, tasks)).tolist() == want
+
+
 def reclaim_spec(claimer_tasks):
     jobs = {f"v{i}": {"queue": "b", "tasks": [
         {"gpu": 1, "status": "RUNNING", "node": "n1"}]} for i in range(8)}
