@@ -311,7 +311,8 @@ def _prescreen_verdict(ssn, tasks, builder: "ScenarioBuilder", sp):
         if fn(tasks) is not None:
             return declined("domain-rows")
 
-    from ..ops.scenario_batch import batch_prefix_feasibility, uniform_gang
+    from ..ops.scenario_batch import (batch_prefix_feasibility,
+                                      dispatched_form)
 
     steps = steps[:cap]
     # Sparse victim-release rows; padding (step index == num_prefixes)
@@ -343,18 +344,22 @@ def _prescreen_verdict(ssn, tasks, builder: "ScenarioBuilder", sp):
         ssn, [(builder.scenario.pending_job, tasks)])
     if rows is None:
         return declined("no-task-rows")
-    # The kernel picks its form from these rows by the same predicate.
-    counted = bool(uniform_gang(rows.task_req, rows.task_job,
-                                rows.task_sel, rows.task_tol))
+    # The kernel picks its form from these rows by the same predicates.
+    form, scan_steps = dispatched_form(
+        rows.task_req, rows.task_job, rows.task_sel, rows.task_tol,
+        ssn.gpu_strategy, ssn.cpu_strategy)
     sp.set(prefixes=num_prefixes, steps=len(steps), rows=m_pad,
-           t_pad=int(rows.task_req.shape[0]),
-           form="counted" if counted else "scanned")
+           t_pad=int(rows.task_req.shape[0]), form=form)
+    if form == "grouped":
+        sp.set(runs=scan_steps)
 
     from ..utils.deviceguard import CycleDeadlineExceeded, DeviceGuardError
     METRICS.inc("device_kernel_calls")
-    # A scanned call adds 0: where every gang is of mixed rows the family
-    # reads 0 and is not absent.
-    METRICS.inc("scenario_prescreen_counted_total", int(counted))
+    # Both families move by 0 where the form adds nothing, so that they
+    # read 0 and are not absent: a process whose gangs are all of mixed
+    # rows counts none, one whose gangs are all alike takes no step.
+    METRICS.inc("scenario_prescreen_counted_total", int(form == "counted"))
+    METRICS.inc("scenario_prescreen_scan_steps_total", scan_steps)
     try:
         feasible = propose.run_on_nodes(
             ssn, batch_prefix_feasibility,

@@ -15,8 +15,9 @@ exact-confirms only the smallest feasible prefix through the ordinary
 statement path (validators, victim re-placement, masks), so semantics
 stay identical to the sequential search.
 
-A prefix is answered in one of two ways, chosen in the program from what
-the task rows hold (``uniform_gang``), never by a flag:
+A prefix is answered in one of three forms, chosen in the program from
+what the task rows hold (``uniform_gang``, ``continues_run``) and from the
+static arguments, never by a flag:
 
 * **counted** — the gang's real rows (job 0) are all one pod: same
   request, selector and tolerations, and no ``task_node_mask``.  A
@@ -25,18 +26,31 @@ the task rows hold (``uniform_gang``), never by a flag:
   takes exactly ``c_n`` pods whatever the order or the score, and the
   gang fits iff the hard-feasible nodes' ``c_n`` sum to its size.  One
   elementwise pass over the pools and one reduction over N.
-* **scanned** — any other gang (a master beside its workers, a [T,N]
-  mask): the pending job's pipeline-only placement attempt,
-  ``allocate_jobs_kernel``, vmapped over the prefixes.  A count is exact
-  for one group only: a second group's fit depends on where the first
-  landed.
+* **grouped** — a gang of several runs of identical adjacent rows (a
+  master beside its workers) under bin-pack on both axes: one dependent
+  step a RUN, for all prefixes at once.  A count is exact for one run
+  only, because a second run's fit depends on where the first landed;
+  so between two runs the first run's pods are taken from the carried
+  pool and the pod room where the exact kernel would have put them.
+  That landing is the grouped kernel's fill plan (``allocate_grouped``:
+  under bin-pack, greedy fills the best-scoring node to capacity before
+  moving on, so the sequence is "sort by initial score, fill in order",
+  a threshold select with no sort), with its row and fill functions and
+  the count's capacity.  Nothing claims idle in a pipeline-only attempt,
+  so only the releasing pool and the room are carried.  The last run
+  needs no landing: it is a count.
+* **scanned** — the pending job's pipeline-only placement attempt,
+  ``allocate_jobs_kernel``, vmapped over the prefixes: one dependent step
+  a POD.  It stays where the fill plan is no proof: a spread strategy on
+  either axis (spread round-robins as nodes fill) and any call with a
+  ``task_node_mask`` (rows that differ by task).
 
-Both read the same dense per-prefix pools (scatter-add of the release
+All read the same dense per-prefix pools (scatter-add of the release
 rows, running sum over the prefix axis).  The counted form could be had
 from the touched nodes alone without ever materialising them; the pools
 stay dense because the benchmark's byte count for this program
-(``prefix_feasibility_bytes``: one [K,N,R] f32 pool written and read
-once) is what its roofline share is measured against.
+(``prefix_feasibility_bytes``: one [K,N,R] f32 pool written, and read
+once a run) is what its roofline share is measured against.
 """
 
 from __future__ import annotations
@@ -46,20 +60,60 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .allocate import allocate_jobs_kernel
+from .allocate import NEG, allocate_jobs_kernel
+from .allocate_grouped import _fill_by_score_descent, _score_keys
 from .predicates import EPS, hard_row
-from .scoring import BINPACK
+from .scoring import BINPACK, score_row_selected
 
 
 def uniform_gang(task_req, task_job, task_selector, task_tolerations):
-    """Scalar bool: is every row of job 0 the same pod as row 0?  The one
-    predicate behind the choice of form: the device evaluates it on its
+    """Scalar bool: is every row of job 0 the same pod as row 0?  The
+    predicate behind the counted form: the device evaluates it on its
     operands, the host (numpy rows) on what it sends, to label the call."""
     real = task_job == 0
     same = real
     for rows in (task_req, task_selector, task_tolerations):
         same = same & (rows == rows[0]).all(axis=-1)
     return (same == real).all()
+
+
+def continues_run(task_req, task_job, task_selector, task_tolerations):
+    """[T-1] bool: is row t+1 the pod of row t, both of job 0?  The
+    predicate behind the grouped form's runs, for jax and numpy rows
+    alike, as ``uniform_gang`` is."""
+    real = task_job == 0
+    same = real[1:] & real[:-1]
+    for rows in (task_req, task_selector, task_tolerations):
+        same = same & (rows[1:] == rows[:-1]).all(axis=-1)
+    return same
+
+
+def gang_runs(task_req, task_job, task_selector, task_tolerations):
+    """Scalar: the runs of identical adjacent rows job 0 is made of."""
+    # Every real row opens a run or continues one.
+    return (task_job == 0).sum() - continues_run(
+        task_req, task_job, task_selector, task_tolerations).sum()
+
+
+def dispatched_form(task_req, task_job, task_selector, task_tolerations,
+                    gpu_strategy: int = BINPACK,
+                    cpu_strategy: int = BINPACK, masked: bool = False):
+    """(form, dependent steps over the pools) of the call that
+    ``batch_prefix_feasibility`` makes of these rows: ``counted`` 0,
+    ``grouped`` its runs, ``scanned`` the padded rows.  The host's reading
+    of what it sends, by the predicates the program applies."""
+    rows = (task_req, task_job, task_selector, task_tolerations)
+    if not masked and bool(uniform_gang(*rows)):
+        return "counted", 0
+    if not masked and _fill_plan_holds(gpu_strategy, cpu_strategy):
+        return "grouped", int(gang_runs(*rows))
+    return "scanned", int(task_req.shape[0])
+
+
+def _fill_plan_holds(gpu_strategy: int, cpu_strategy: int) -> bool:
+    """Static: the grouped kernel's fill equals the exact kernel's
+    sequence under bin-pack (spread round-robins as nodes fill)."""
+    return gpu_strategy == BINPACK and cpu_strategy == BINPACK
 
 
 def corrected_count(quotient, req, total):
@@ -77,28 +131,104 @@ def corrected_count(quotient, req, total):
     return jnp.maximum(c, 0.0)
 
 
+def run_capacity(rel_planes, node_idle, hard, room, req):
+    """[K,N]: the pods of request ``req`` that each node takes under each
+    prefix's pool, ``rel_planes`` a [K,N] plane a resource; ``hard`` [N]
+    and ``room`` [N] or [K,N] bound it."""
+    count = jnp.where(hard, jnp.floor(room), 0.0)    # broadcasts to [K,N]
+    # R unrolled, a [K,N] plane a resource (feasibility_caps_row's way):
+    # the compiler keeps N minor in the pool, and 3 would waste the lanes.
+    for res, plane in enumerate(rel_planes):
+        rq = req[res]
+        safe = jnp.where(rq > 0, rq, 1.0)
+        total = node_idle[None, :, res] + plane
+        fits = corrected_count(jnp.floor(total / safe), safe, total)
+        count = jnp.where(rq > 0, jnp.minimum(count, fits), count)
+    return count
+
+
+def _seats(capacity, need):
+    """[K] bool: do the nodes' capacities hold ``need`` pods?  No node
+    takes more than the run, so the sum stays small and exact."""
+    return jnp.sum(jnp.minimum(capacity, need), axis=1) >= need
+
+
+def _planes(prefix_rel):
+    return tuple(prefix_rel[:, :, res] for res in range(prefix_rel.shape[2]))
+
+
 def count_prefixes(prefix_rel, node_idle, node_labels, node_taints,
                    node_room, task_req, task_job, task_selector,
                    task_tolerations):
     """The counted form: [K] bool from ``prefix_rel`` [K,N,R] for a gang
     of ``sum(task_job == 0)`` pods, each the pod of row 0."""
-    req = task_req[0]
     need = jnp.sum(task_job == 0).astype(node_idle.dtype)
+    # Prefix-invariant, [N]: evicted pods stay on their node as Releasing.
     hard = hard_row(node_labels, node_taints, node_room, task_selector[0],
                     task_tolerations[0])
-    # Prefix-invariant, [N]: evicted pods stay on their node as Releasing.
-    count = jnp.where(hard, jnp.floor(node_room), 0.0)[None, :]
-    # R unrolled, a [K,N] plane a resource (feasibility_caps_row's way):
-    # the compiler keeps N minor in the pool, and 3 would waste the lanes.
-    for res in range(prefix_rel.shape[2]):
-        rq = req[res]
-        safe = jnp.where(rq > 0, rq, 1.0)
-        total = node_idle[None, :, res] + prefix_rel[:, :, res]
-        fits = corrected_count(jnp.floor(total / safe), safe, total)
-        count = jnp.where(rq > 0, jnp.minimum(count, fits), count)
-    # No node takes more than the gang: the sum stays small and exact.
-    placed = jnp.sum(jnp.minimum(count, need), axis=1)
-    return (need > 0) & (placed >= need)
+    capacity = run_capacity(_planes(prefix_rel), node_idle, hard,
+                            node_room, task_req[0])
+    return (need > 0) & _seats(capacity, need)
+
+
+def group_prefixes(prefix_rel, node_allocatable, node_idle, node_labels,
+                   node_taints, node_room, task_req, task_job,
+                   task_selector, task_tolerations,
+                   f32_keys: bool = False):
+    """The grouped form: [K] bool from ``prefix_rel`` [K,N,R] for a gang
+    of runs of identical rows, bin-pack on both axes.  A ``while`` over
+    the runs, outside the prefix axis; ``f32_keys`` orders scores at the
+    chip's precision on any backend (``_score_keys``)."""
+    k, n, _ = prefix_rel.shape
+    t = task_req.shape[0]
+    dtype = node_idle.dtype
+    real = task_job == 0
+    opens = jnp.concatenate([real[:1], real[1:] & ~continues_run(
+        task_req, task_job, task_selector, task_tolerations)])
+    runs = opens.sum()
+    first_row = jnp.nonzero(opens, size=t, fill_value=0)[0]
+    run_size = jax.ops.segment_sum(real.astype(dtype),
+                                   jnp.cumsum(opens) - 1, num_segments=t)
+    never_now = jnp.zeros(n, bool)    # pipeline-only: nothing claims idle
+
+    def capacity_of(run, rel, room):
+        row = first_row[run]
+        # The carried room never exceeds node_room: the static row's own
+        # room test is implied by the capacity's.
+        hard = hard_row(node_labels, node_taints, node_room,
+                        task_selector[row], task_tolerations[row])
+        return run_capacity(rel, node_idle, hard, room, task_req[row])
+
+    def land(state):
+        """Count run ``i`` and take its pods where the exact kernel puts
+        them: descending score, ascending index among ties, each node to
+        its capacity."""
+        i, ok, rel, room = state
+        req, need = task_req[first_row[i]], run_size[i]
+        capacity = capacity_of(i, rel, room)
+        feasible = capacity >= 1.0
+        score = jax.vmap(
+            lambda fits: score_row_selected(
+                node_allocatable, node_idle, req, fits, never_now,
+                BINPACK, BINPACK))(feasible)
+        key, levels, utype = _score_keys(jnp.where(feasible, score, NEG),
+                                         f32_keys)
+        take = jax.vmap(
+            lambda keys, caps: _fill_by_score_descent(
+                keys, levels, utype, caps, need))(
+            key, jnp.minimum(capacity, need))
+        rel = tuple(plane - take * req[res]
+                    for res, plane in enumerate(rel))
+        return i + 1, ok & _seats(capacity, need), rel, room - take
+
+    init = (jnp.zeros((), runs.dtype), jnp.ones(k, bool),
+            _planes(prefix_rel),
+            jnp.broadcast_to(node_room.astype(dtype), (k, n)))
+    # The last run lands nowhere that matters: it is counted.
+    last, ok, rel, room = jax.lax.while_loop(
+        lambda state: state[0] < runs - 1, land, init)
+    return (runs > 0) & ok & _seats(capacity_of(last, rel, room),
+                                    run_size[last])
 
 
 def scan_prefixes(prefix_rel, node_allocatable, node_idle, node_labels,
@@ -146,9 +276,12 @@ def batch_prefix_feasibility(node_allocatable, node_idle, node_releasing,
     O(victim tasks), never O(prefixes x nodes).  node_room is
     prefix-invariant (evicted pods stay on their node as Releasing).
 
-    A gang of identical pods is counted, any other scanned (module
+    A gang of identical pods is counted, any other stepped over run by
+    run under bin-pack and scanned pod by pod otherwise (module
     docstring); a ``task_node_mask`` is static and goes to the scan.
     """
+    tasks = (task_req, task_job, task_selector, task_tolerations)
+
     def pools():
         n, r = node_releasing.shape
         delta = jnp.zeros((num_prefixes, n, r), node_releasing.dtype)
@@ -159,19 +292,23 @@ def batch_prefix_feasibility(node_allocatable, node_idle, node_releasing,
     def scanned():
         return scan_prefixes(
             pools(), node_allocatable, node_idle, node_labels,
-            node_taints, node_room, task_req, task_job, task_selector,
-            task_tolerations, task_node_mask, gpu_strategy, cpu_strategy)
+            node_taints, node_room, *tasks, task_node_mask, gpu_strategy,
+            cpu_strategy)
 
     if task_node_mask is not None:
         return scanned()
 
     def counted():
         return count_prefixes(pools(), node_idle, node_labels,
-                              node_taints, node_room, task_req, task_job,
-                              task_selector, task_tolerations)
+                              node_taints, node_room, *tasks)
+
+    def grouped():
+        return group_prefixes(pools(), node_allocatable, node_idle,
+                              node_labels, node_taints, node_room, *tasks)
 
     # At the top level, outside any vmap, where a cond would turn into a
     # select and run both.
     return jax.lax.cond(
-        uniform_gang(task_req, task_job, task_selector, task_tolerations),
-        counted, scanned)
+        uniform_gang(*tasks), counted,
+        grouped if _fill_plan_holds(gpu_strategy, cpu_strategy)
+        else scanned)

@@ -33,6 +33,7 @@ COUNTERS = ("scenario_prescreen_prefixes_total",
             "scenario_prescreen_feasible_total",
             "scenarios_skipped_by_prescreen_total",
             "scenario_prescreen_counted_total",
+            "scenario_prescreen_scan_steps_total",
             _key("solver_evictions_total", {"action": "consolidation"}),
             _key("solver_victims_replaced_total",
                  {"action": "consolidation"}))
@@ -90,7 +91,8 @@ def test_every_number_is_zero(driven):
     # One consolidation in every cycle, and from the second on the bind of
     # the gang seated a cycle before with the pods moved for it: two pods
     # moved for each node the gang lacked, a place pipelined for each and
-    # for each pod of the gang, one prescreen, of the scanned form.
+    # for each pod of the gang, one prescreen, of the grouped form (counted
+    # prescreens 0: the master and its workers are two runs).
     assert out["failed"] == 0 and out["attempted"] == 4
     assert out["run"]["evictions_per_cycle"] == [2 * gang]
     assert out["run"]["places_per_cycle"] == [3 * gang]
@@ -175,7 +177,7 @@ def test_the_span_tree_under_the_consolidation_action(driven):
     assert prescreen.attrs == {
         "prefixes": cut["victims"], "steps": scored,
         "rows": 2 * cut["victims"],
-        "t_pad": gang, "form": "scanned",
+        "t_pad": gang, "form": "grouped", "runs": 2,
         "feasible": scored - (gang - 2), "first_feasible": gang - 2}
     dispatch = only(children(trace, prescreen),
                     "dispatch:scenario_prescreen")
@@ -203,6 +205,9 @@ def test_the_counters_move_with_the_spans(driven):
             "scenario_prescreen_feasible_total": scored - (gang - 2),
             "scenarios_skipped_by_prescreen_total": gang - 2,
             "scenario_prescreen_counted_total": 0,
+            # A master and its workers: two steps over the pools, not one
+            # a pod.
+            "scenario_prescreen_scan_steps_total": 2,
             _key("solver_evictions_total", {"action": "consolidation"}):
             2 * gang,
             _key("solver_victims_replaced_total",

@@ -1,8 +1,10 @@
-"""The scenario prescreen's two forms (``ops/scenario_batch.py``): a gang
-of identical pods is counted in one pass over the prefix pools, any other
-is scanned by the exact kernel, and both give the same bits where both
-apply.  The choice is made from the task rows, on the device and, for the
-span and the counter, on the host."""
+"""The scenario prescreen's three forms (``ops/scenario_batch.py``): a
+gang of identical pods is counted in one pass over the prefix pools, a gang
+of several runs of identical pods is stepped over run by run with the
+grouped kernel's fill between two runs, any other call is scanned by the
+exact kernel, and all give the same bits where they apply.  The choice is
+made from the task rows and the static arguments, on the device and, for
+the span and the counters, on the host."""
 
 import functools
 
@@ -12,7 +14,8 @@ import numpy as np
 import pytest
 
 from kai_scheduler_tpu.ops import scenario_batch as sb
-from kai_scheduler_tpu.ops.scoring import BINPACK
+from kai_scheduler_tpu.ops.scoring import BINPACK, SPREAD
+from kai_scheduler_tpu.framework.conf import SchedulerConfig
 from kai_scheduler_tpu.utils.metrics import METRICS
 from kai_scheduler_tpu.utils.tracing import TRACER
 from tests.fixtures import build_session, run_action
@@ -66,16 +69,29 @@ def counted(pool, nodes, tasks):
     return sb.count_prefixes(pool, idle, labels, taints, room, *tasks)
 
 
-@functools.partial(jax.jit, static_argnames="masked")
-def scanned(pool, nodes, tasks, mask=None, masked=False):
+@functools.partial(jax.jit, static_argnames=("masked", "strategy"))
+def scanned(pool, nodes, tasks, mask=None, masked=False, strategy=BINPACK):
     alloc, idle, _rel, labels, taints, room = nodes
     return sb.scan_prefixes(pool, alloc, idle, labels, taints, room, *tasks,
-                            mask if masked else None, BINPACK, BINPACK)
+                            mask if masked else None, strategy, strategy)
 
 
-def whole(nodes, release, tasks, k=K, mask=None):
+@jax.jit
+def grouped(pool, nodes, tasks):
+    """Keys at the chip's precision (``_score_keys`` ``force_f32``)."""
+    alloc, idle, _rel, labels, taints, room = nodes
+    return sb.group_prefixes(pool, alloc, idle, labels, taints, room, *tasks,
+                             f32_keys=True)
+
+
+def whole(nodes, release, tasks, k=K, mask=None, strategy=BINPACK):
     return np.asarray(sb.batch_prefix_feasibility(
-        *nodes, *release, *tasks, num_prefixes=k, task_node_mask=mask))
+        *nodes, *release, *tasks, num_prefixes=k, task_node_mask=mask,
+        gpu_strategy=strategy, cpu_strategy=strategy))
+
+
+def form_of(tasks, strategy=BINPACK, masked=False):
+    return sb.dispatched_form(*tasks, strategy, strategy, masked)
 
 
 @pytest.mark.parametrize("seed", range(24))
@@ -158,11 +174,15 @@ def mixed_fleet():
 
 
 def test_a_gang_of_two_distinct_rows_is_scanned():
+    """Since PR 39 by the grouped form: two runs, two steps, and the exact
+    scan's bits."""
     nodes, release, tasks, k = mixed_fleet()
     pool = pools(nodes[2], *release, k=k)
     want = np.asarray(scanned(pool, nodes, tasks)).tolist()
     assert want == [False, False, True, True]
     assert not bool(sb.uniform_gang(*map(jnp.asarray, tasks)))
+    assert form_of(tasks) == ("grouped", 2)
+    assert np.asarray(grouped(pool, nodes, tasks)).tolist() == want
     assert whole(nodes, release, tasks, k=k).tolist() == want
     # The count would have answered for three masters.
     assert np.asarray(counted(pool, nodes, tasks)).tolist() != want
@@ -185,6 +205,221 @@ def test_a_call_with_a_task_node_mask_is_scanned():
                  mask=jnp.asarray(mask)).tolist() == want
     assert np.asarray(counted(pool, nodes, tasks)).tolist() \
         == [False, False, True, True]
+
+
+PATTERNS = {2: ("MW", "WM", "WM"), 3: ("MWX", "WMW", "WXM"),
+            4: ("MWXW", "WMXW", "WXWM")}
+DIFFERS = ("request", "selector", "tolerations", "any")
+
+
+def mixed_gang(seed: int, oversize: bool = False):
+    """``fleet(seed)``'s nodes and release rows under a gang of 2 to 4
+    runs: workers W, one master M that differs from them in the request
+    alone, the selector alone, the tolerations alone or anyhow (by
+    ``seed % 4``), a third pod X; the master first, in the middle or last
+    (by ``seed // 4 % 3``).  Padding rows under job 1."""
+    (alloc, _, rel, labels, taints, room), release, _ = fleet(seed)
+    rng = np.random.default_rng(1000 + seed)
+    differs = DIFFERS[seed % 4]
+    # More levels of free capacity than ``fleet`` has, for the score to
+    # order, and some nodes with no GPU at all.
+    idle = rng.integers(0, 4, (N, 3)) * POD
+    bare = rng.random(N) < 0.2
+    alloc, idle, rel = (np.where(bare[:, None] & (np.arange(3) == 2), 0.0, a)
+                        for a in (alloc, idle, rel))
+    nodes = (alloc, idle, rel, labels, taints, room)
+
+    def pod():
+        # Zero-request columns in most pods; never a pod of nothing.
+        req = POD * rng.integers(0, 3, 3)
+        if not req.any():
+            col = rng.integers(0, 3)
+            req[col] = POD[col]
+        return (req, np.where(rng.random(2) < 0.4, rng.integers(0, 3, 2),
+                              -1),
+                np.array([7 if rng.random() < 0.5 else -1]))
+
+    def another(*others):
+        while True:
+            new = pod()
+            if not any(all((a == b).all() for a, b in zip(new, other))
+                       for other in others):
+                return new
+
+    worker = pod()
+    master = list(another(worker) if differs == "any" else worker)
+    if differs == "request":
+        master[0] = worker[0] + POD * np.eye(3)[rng.integers(0, 3)]
+    elif differs == "selector":
+        master[1] = np.where(np.arange(2) == rng.integers(0, 2),
+                             (worker[1] + 2) % 3, worker[1])
+    elif differs == "tolerations":
+        master[2] = np.array([-1 if worker[2][0] == 7 else 7])
+    third = another(worker, master)
+    pattern = PATTERNS[int(rng.integers(2, 5))][seed // 4 % 3]
+    rows = []
+    for letter in pattern:
+        size = 1 if letter == "M" else int(rng.integers(1, 5))
+        if oversize and letter == "W":
+            size, oversize = N * 4 + 1, False
+        rows += [{"M": master, "W": worker, "X": third}[letter]] * size
+    t_pad = max(2 * T_PAD, 1 << len(rows).bit_length())
+    pad = t_pad - len(rows)
+    task_req = np.vstack([r[0] for r in rows] + [np.zeros((pad, 3))])
+    task_sel = np.vstack([r[1] for r in rows]
+                         + [np.full((pad, 2), -1)]).astype(np.int32)
+    task_tol = np.vstack([r[2] for r in rows]
+                         + [np.full((pad, 1), -1)]).astype(np.int32)
+    task_job = (np.arange(t_pad) >= len(rows)).astype(np.int32)
+    return nodes, release, (task_req, task_job, task_sel, task_tol), pattern
+
+
+def runs_alone(pool, nodes, tasks):
+    """A count without the fill: every run counted against the untouched
+    pool, as if no other run had landed."""
+    task_req, task_job, task_sel, task_tol = tasks
+    real = task_job == 0
+    starts = np.flatnonzero(np.r_[real[:1], real[1:] & ~sb.continues_run(
+        *tasks)])
+    out = np.ones(len(pool), bool)
+    for lo, hi in zip(starts, np.r_[starts[1:], real.sum()]):
+        job = np.where((np.arange(len(real)) >= lo)
+                       & (np.arange(len(real)) < hi), 0, 1).astype(np.int32)
+        order = np.argsort(job, kind="stable")
+        out &= np.asarray(counted(pool, nodes, (
+            task_req[order], job[order], task_sel[order], task_tol[order])))
+    return out
+
+
+MIXED_SEEDS = range(36)
+
+
+@pytest.mark.parametrize("seed", MIXED_SEEDS)
+def test_grouped_equals_scanned_on_random_fleets(seed):
+    nodes, release, tasks, pattern = mixed_gang(seed)
+    pool = pools(nodes[2], *release)
+    want = np.asarray(scanned(pool, nodes, tasks)).tolist()
+    assert form_of(tasks) == ("grouped", len(pattern))
+    assert int(sb.gang_runs(*map(jnp.asarray, tasks))) == len(pattern)
+    assert np.asarray(grouped(pool, nodes, tasks)).tolist() == want
+    assert whole(nodes, release, tasks).tolist() == want
+
+
+def test_the_mixed_fleets_hold_both_answers_and_need_the_fill():
+    """No vacuous agreement: both bits occur, and counting the runs with
+    no landing between them answers some of these prefixes wrongly."""
+    bits, unlike = [], 0
+    for seed in MIXED_SEEDS:
+        nodes, release, tasks, _ = mixed_gang(seed)
+        pool = pools(nodes[2], *release)
+        got = np.asarray(grouped(pool, nodes, tasks))
+        bits += got.tolist()
+        alone = runs_alone(pool, nodes, tasks)
+        # Without the landing a run sees more room, never less.
+        assert not (got & ~alone).any()
+        unlike += int((alone != got).sum())
+    assert 0.15 < np.mean(bits) < 0.85
+    assert unlike >= 10
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_a_run_larger_than_the_fleet_can_hold_never_fits(seed):
+    nodes, release, tasks, _ = mixed_gang(200 + seed, oversize=True)
+    pool = pools(nodes[2], *release)
+    got = np.asarray(grouped(pool, nodes, tasks))
+    assert not got.any()
+    assert got.tolist() == np.asarray(scanned(pool, nodes, tasks)).tolist()
+    assert whole(nodes, release, tasks).tolist() == got.tolist()
+
+
+def test_a_mixed_gang_of_no_pods_fits_nowhere():
+    nodes, release, tasks, _ = mixed_gang(0)
+    task_req, task_job, task_sel, task_tol = tasks
+    tasks = (task_req, np.ones_like(task_job), task_sel, task_tol)
+    pool = pools(nodes[2], *release)
+    assert int(sb.gang_runs(*map(jnp.asarray, tasks))) == 0
+    assert not np.asarray(grouped(pool, nodes, tasks)).any()
+    assert not np.asarray(scanned(pool, nodes, tasks)).any()
+
+
+def two_nodes(idle_gpus, releasing_gpus, late_release_on):
+    """Two nodes of room for two pods each; a one-GPU worker, then a
+    two-GPU master; prefix 1 releases one GPU more on one node."""
+    unit = np.array([1000.0, 2.0 ** 30, 1.0])
+    none = np.full((2, 1), -1, np.int32)
+    nodes = (np.tile(8 * unit, (2, 1)), np.outer(idle_gpus, unit),
+             np.outer(releasing_gpus, unit), none, none, np.full(2, 2.0))
+    release = (np.array([1], np.int32),
+               np.array([late_release_on], np.int32), unit[None])
+    task_req = np.array([unit, 2 * unit, 0 * unit, 0 * unit])
+    task_job = np.array([0, 0, 1, 1], np.int32)
+    none = np.full((4, 1), -1, np.int32)
+    return nodes, release, (task_req, task_job, none, none)
+
+
+def test_the_first_runs_landing_can_take_the_second_runs_only_node():
+    """Node 0 releases two GPUs, node 1 one; equal scores, so the worker
+    lands on node 0 and leaves the master one GPU on either: the verdict
+    is False, where each run counted alone fits.  A second GPU on node 1
+    seats the master there."""
+    nodes, release, tasks = two_nodes([0, 0], [2, 1], late_release_on=1)
+    pool = pools(nodes[2], *release, k=2)
+    want = np.asarray(scanned(pool, nodes, tasks)).tolist()
+    assert want == [False, True]
+    assert np.asarray(grouped(pool, nodes, tasks)).tolist() == want
+    assert whole(nodes, release, tasks, k=2).tolist() == want
+    assert runs_alone(pool, nodes, tasks).tolist() == [True, True]
+
+
+def test_the_first_run_can_land_where_the_second_never_could():
+    """Node 0 has an idle GPU and releases one, node 1 releases its only
+    free one: bin-pack sends the worker to node 1, the fuller, where no
+    master ever fits, and the master has node 0: True.  A landing in index
+    order would have put the worker on node 0 and answered False."""
+    nodes, release, tasks = two_nodes([1, 0], [1, 1], late_release_on=1)
+    pool = pools(nodes[2], *release, k=2)
+    want = np.asarray(scanned(pool, nodes, tasks)).tolist()
+    assert want == [True, True]
+    assert np.asarray(grouped(pool, nodes, tasks)).tolist() == want
+    assert whole(nodes, release, tasks, k=2).tolist() == want
+    assert first_fit_verdict(nodes, release, tasks, 2) == [False, True]
+
+
+@pytest.mark.parametrize("exact", ("spread", "mask"))
+def test_a_spread_strategy_and_a_mask_keep_the_exact_scan(monkeypatch,
+                                                          exact):
+    """The fill plan is bin-pack's and a run's rows share one mask row at
+    most: either way the program traced holds the exact scan and no run
+    loop, and its answer is the exact scan's under that strategy."""
+    traced = []
+    for name in ("group_prefixes", "scan_prefixes"):
+        form = getattr(sb, name)
+        monkeypatch.setattr(sb, name, lambda *a, _n=name, _f=form, **kw: (
+            traced.append(_n), _f(*a, **kw))[1])
+    nodes, release, tasks, _ = mixed_gang(5)
+    pool = pools(nodes[2], *release)
+    sb.batch_prefix_feasibility.clear_cache()
+    try:
+        if exact == "spread":
+            got = whole(nodes, release, tasks, strategy=SPREAD)
+            assert traced == ["scan_prefixes"]
+            want = scanned(pool, nodes, tasks, strategy=SPREAD)
+            assert form_of(tasks, SPREAD) == ("scanned", len(tasks[0]))
+        else:
+            mask = np.ones((len(tasks[0]), N), bool)
+            mask[:, ::3] = False
+            got = whole(nodes, release, tasks, mask=jnp.asarray(mask))
+            assert traced == ["scan_prefixes"]
+            want = scanned(pool, nodes, tasks, jnp.asarray(mask),
+                           masked=True)
+            assert form_of(tasks, masked=True) == ("scanned",
+                                                   len(tasks[0]))
+        assert got.tolist() == np.asarray(want).tolist()
+        traced.clear()
+        whole(nodes, release, tasks)
+        assert traced == ["group_prefixes"]
+    finally:
+        sb.batch_prefix_feasibility.clear_cache()
 
 
 def defrag_fleet(seed: int):
@@ -240,16 +475,20 @@ def first_fit_verdict(nodes, release, tasks, k):
 
 @pytest.mark.parametrize("seed", range(3))
 def test_the_consolidation_cells_gang_is_scanned(seed):
-    """A master beside its workers is no uniform gang: the program scans,
-    and its verdict is the numpy loop's, bit for bit."""
+    """A master beside its workers is no uniform gang: the program steps
+    over its two runs (since PR 39; pod by pod before), and its verdict is
+    the numpy loop's, bit for bit."""
     nodes, release, tasks, k = defrag_fleet(seed)
     assert not bool(sb.uniform_gang(*map(jnp.asarray, tasks)))
+    assert form_of(tasks) == ("grouped", 2)
+    assert int(sb.gang_runs(*map(jnp.asarray, tasks))) == 2
     want = first_fit_verdict(nodes, release, tasks, k)
     # The gang is seated from the prefix that empties its 128th node on.
     assert 0 < sum(want) < k and want == sorted(want)
     assert whole(nodes, release, tasks, k=k).tolist() == want
     pool = pools(nodes[2], *release, k=k)
     assert np.asarray(scanned(pool, nodes, tasks)).tolist() == want
+    assert np.asarray(grouped(pool, nodes, tasks)).tolist() == want
     # Counted as 128 masters the same fleet reads the same here (a node
     # that holds a worker holds a master), which is why the form is chosen
     # from the rows and never from the answer.
@@ -267,18 +506,25 @@ def reclaim_spec(claimer_tasks):
             "jobs": jobs}
 
 
-@pytest.mark.parametrize("form, claimer_tasks", [
-    ("counted", [{"gpu": 4}]),
-    ("counted", [{"gpu": 1}] * 3),
-    ("scanned", [{"gpu": 3}, {"gpu": 1}]),
-    ("scanned", [{"gpu": 2, "cpu": "2"}, {"gpu": 2, "cpu": "1"}]),
+SPREAD_GPUS = SchedulerConfig(gpu_placement_strategy="spread")
+
+
+@pytest.mark.parametrize("form, claimer_tasks, config", [
+    ("counted", [{"gpu": 4}], None),
+    ("counted", [{"gpu": 1}] * 3, None),
+    ("grouped", [{"gpu": 3}, {"gpu": 1}], None),
+    ("grouped", [{"gpu": 2, "cpu": "2"}, {"gpu": 2, "cpu": "1"}], None),
+    ("grouped", [{"gpu": 1}, {"gpu": 2}, {"gpu": 1}], None),
+    ("scanned", [{"gpu": 3}, {"gpu": 1}], SPREAD_GPUS),
+    ("counted", [{"gpu": 1}] * 3, SPREAD_GPUS),
 ], ids=("one-pod", "three-alike-and-a-pad", "master-and-worker",
-        "cpu-differs"))
+        "cpu-differs", "master-between-workers", "spread-gpus",
+        "three-alike-spread"))
 def test_host_and_device_name_the_same_form(monkeypatch, form,
-                                            claimer_tasks):
-    """The span's ``form`` and the counter are the host's reading of the
-    rows it sends; the kernel's ``cond`` reads the same rows by the same
-    predicate."""
+                                            claimer_tasks, config):
+    """The span's ``form`` and ``runs`` and the two counters are the
+    host's reading of the rows it sends; the kernel's ``cond`` and its run
+    loop read the same rows by the same predicates."""
     from kai_scheduler_tpu.actions import solvers
     sent = {}
     run_on_nodes = solvers.propose.run_on_nodes
@@ -289,17 +535,28 @@ def test_host_and_device_name_the_same_form(monkeypatch, form,
         return sent["verdict"]
 
     monkeypatch.setattr(solvers.propose, "run_on_nodes", spy)
-    ssn = build_session(reclaim_spec(claimer_tasks))
-    before = METRICS.counters.get("scenario_prescreen_counted_total", 0)
+    ssn = build_session(reclaim_spec(claimer_tasks), config)
+    families = ("scenario_prescreen_counted_total",
+                "scenario_prescreen_scan_steps_total")
+    before = [METRICS.counters.get(f, 0) for f in families]
     TRACER.begin_cycle(1)
     run_action(ssn, "reclaim")
     trace = TRACER.end_cycle()
-    moved = METRICS.counters.get("scenario_prescreen_counted_total",
-                                 0) - before
+    # Present after any dispatch, whichever form it took.
+    counted_moved, steps_moved = (METRICS.counters[f] - b
+                                  for f, b in zip(families, before))
     (span,) = [s for s in trace.spans if s.name == "solve:prescreen"
                and "declined" not in s.attrs]
     assert span.attrs["form"] == form
-    assert moved == (1 if form == "counted" else 0)
-    on_device = bool(sb.uniform_gang(*map(jnp.asarray, sent["rows"])))
-    assert on_device == (form == "counted")
+    assert counted_moved == (1 if form == "counted" else 0)
+    rows = tuple(map(jnp.asarray, sent["rows"]))
+    assert bool(sb.uniform_gang(*rows)) == (form == "counted")
     assert len(sent["rows"][0]) == span.attrs["t_pad"]
+    runs = len({(i, str(t)) for i, t in enumerate(claimer_tasks)
+                if i == 0 or t != claimer_tasks[i - 1]})
+    assert int(sb.gang_runs(*rows)) == runs
+    assert ("runs" in span.attrs) == (form == "grouped")
+    if form == "grouped":
+        assert span.attrs["runs"] == runs > 1
+    assert steps_moved == {"counted": 0, "grouped": runs,
+                           "scanned": span.attrs["t_pad"]}[form]
