@@ -68,6 +68,15 @@ def _log(msg):
 _T0 = time.monotonic()
 
 
+def _span_seconds(trace, *names) -> float:
+    """Seconds the spans called ``names`` took in one cycle's trace, by the
+    flight recorder's totals by name (every span that closed, kept or
+    dropped by the cap); 0.0 without a trace."""
+    if trace is None:
+        return 0.0
+    return sum(trace.name_totals.get(name, (0, 0.0))[1] for name in names)
+
+
 def build_arrays(n_nodes=N_NODES, n_jobs=N_JOBS, gang=TASKS_PER_JOB,
                  seed=0, placeable=False):
     import jax.numpy as jnp
@@ -967,6 +976,7 @@ def churn_phase(n_nodes=256, n_queues=10000, cycles=8,
     from kai_scheduler_tpu.framework.conf import SchedulerConfig
     from kai_scheduler_tpu.utils.lifecycle import LIFECYCLE
     from kai_scheduler_tpu.utils.metrics import METRICS
+    from kai_scheduler_tpu.utils.tracing import TRACER
 
     rng = np.random.default_rng(seed)
     cfg = SchedulerConfig(actions=["allocate"], fused_fairshare=mode)
@@ -1073,8 +1083,11 @@ def churn_phase(n_nodes=256, n_queues=10000, cycles=8,
             system.run_cycle()
             cycle_ts.append(time.perf_counter() - t0)
             ssn = system.schedulers[0].last_session
-            if ssn is not None and "fairshare" in ssn.phase_timings:
-                fairshare_ts.append(ssn.phase_timings["fairshare"])
+            step_s = _span_seconds(
+                TRACER.get_trace(ssn.trace_id) if ssn is not None else None,
+                "fairshare")
+            if step_s:
+                fairshare_ts.append(step_s)
             # Kubelet analog: terminations complete.
             for p in api.list("Pod", field_selector=SEL_TERMINATING):
                 if p["metadata"].get("deletionTimestamp"):
@@ -1675,27 +1688,24 @@ def main() -> int:
                     ssn = Session(cluster, SchedulerConfig())
                 ssn.open()
                 for action in build_actions(["allocate"]):
-                    ta = time.perf_counter()
                     with TRACER.span(f"action:{action.name}",
                                      kind="action"):
                         action.execute(ssn)
-                    ssn.phase_timings[f"action_{action.name}"] = \
-                        time.perf_counter() - ta
             finally:
                 trace = TRACER.end_cycle()
             secs = time.perf_counter() - t_it
             placed = sum(
                 1 for pg in ssn.cluster.podgroups.values()
                 for t in pg.pods.values() if t.node_name)
-            return secs, placed, ssn.phase_timings, trace
+            return secs, placed, trace
 
         # Cold = includes this cluster shape's jit compiles (paid once
         # per binary life / compile-cache fill); steady = the cycle
         # the daemon actually repeats.  The reference's Go cycle has
         # no compile analog, so steady is the comparable number.
-        first_s, pipeline_placed, _, _ = one_cycle(1)
+        first_s, pipeline_placed, _ = one_cycle(1)
         _log(f"host pipeline cold cycle {first_s:.2f}s; steady run")
-        steady_s, pipeline_placed, breakdown, trace = one_cycle(2)
+        steady_s, pipeline_placed, trace = one_cycle(2)
         entry = {
             "config": f"{pipe_nodes}nodes_"
                       f"{pipe_jobs * pipe_gang}pods",
@@ -1703,11 +1713,11 @@ def main() -> int:
             "first_cycle_s": round(first_s, 2),
             "pods_placed": pipeline_placed,
         }
-        if breakdown:
-            entry["breakdown_s"] = {
-                k: round(v, 3) for k, v in breakdown.items()
-                if v >= 0.001}
         if trace is not None:
+            entry["breakdown_s"] = {
+                name: round(secs, 3)
+                for name, (_n, secs) in trace.name_totals.items()
+                if secs >= 0.001}
             entry["span_summary"] = trace.span_summary()
         result["detail"]["host_pipeline"] = entry
     except Exception as exc:
@@ -1738,6 +1748,7 @@ def main() -> int:
             SchedulerConfig as _SConf
         from kai_scheduler_tpu.scheduler import Scheduler
         from kai_scheduler_tpu.utils.metrics import METRICS
+        from kai_scheduler_tpu.utils.tracing import TRACER
 
         api = InMemoryKubeAPI()
         for i in range(st_nodes):
@@ -1773,8 +1784,11 @@ def main() -> int:
             t_it = time.perf_counter()
             ssn = sched.run_once()
             warm.append(time.perf_counter() - t_it)
-            packs.append(ssn.phase_timings.get("snapshot_pack", 0.0))
-            uploads.append(ssn.phase_timings.get("arena_upload", 0.0))
+            trace = TRACER.get_trace(ssn.trace_id)
+            packs.append(_span_seconds(trace, "snapshot_delta"))
+            uploads.append(_span_seconds(
+                trace, "arena_scatter", "dispatch:arena_state_upload",
+                "dispatch:arena_static_upload"))
         placed = sum(1 for pg in ssn.cluster.podgroups.values()
                      for t in pg.pods.values() if t.node_name)
         # In-run reference: a from-scratch pack of the same cluster

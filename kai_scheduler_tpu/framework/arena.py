@@ -610,16 +610,12 @@ class ClusterArena:
             # sit on the dead/wrong side of the fallback boundary.
             self.drop_device("device-guard transition "
                              f"({guard.breaker.state})")
-        t0 = time.perf_counter()
         alloc, labels, taints = self.device_static(snap, session)
         idle, rel, room = self.state.arrays(session)
         # The arena's own guarded uploads may themselves have fallen
         # back; absorbing them here keeps a degraded steady state from
         # re-invalidating (and re-uploading) on every call.
         self.guard_watch.resync(guard)
-        dt = time.perf_counter() - t0
-        session.phase_timings["arena_upload"] = \
-            session.phase_timings.get("arena_upload", 0.0) + dt
         return (alloc, idle, rel, labels, taints, room)
 
     # -- observability -----------------------------------------------------

@@ -186,12 +186,6 @@ class Session:
             self.mesh = cluster_mesh(d)  # raises when JAX has fewer
             base = pad or max(len(cluster.nodes), 1)
             pad = -(-base // d) * d
-        # Per-phase cycle timing (the e2e_scheduling_latency breakdown the
-        # reference gets from per-plugin/action histograms,
-        # metrics/metrics.go:65): filled here and by open()/run_once.
-        import time as _time
-        self.phase_timings: dict[str, float] = {}
-        _t = _time.perf_counter()
         # Persistent arena (framework/arena.py): when the cache carries
         # one (ClusterCache does), the pack is incremental against the
         # previous cycle's arrays and the device tensors stay resident
@@ -226,7 +220,6 @@ class Session:
         else:
             self.snapshot: SnapshotTensors = pack(
                 cluster, queue_usage=pack_usage, pad_nodes_to=pad)
-        self.phase_timings["snapshot_pack"] = _time.perf_counter() - _t
         snap = self.snapshot
         table, dirty_rows, node_index = (
             host.carried(snap) if host is not None else (None, None, None))
@@ -320,21 +313,12 @@ class Session:
 
     # -- lifecycle ---------------------------------------------------------
     def open(self) -> "Session":
-        import time as _time
-
         from ..plugins import build_plugins
-        t0 = _time.perf_counter()
         self.plugins = build_plugins(self.config)
         for plugin in self.plugins:
-            t = _time.perf_counter()
             with TRACER.span(f"plugin:{plugin.name}", kind="plugin",
                              plugin=plugin.name):
                 plugin.on_session_open(self)
-            dt = _time.perf_counter() - t
-            if dt >= 0.005:  # only phases that matter in the breakdown
-                self.phase_timings[f"plugin_{plugin.name}"] = \
-                    self.phase_timings.get(f"plugin_{plugin.name}", 0.0) + dt
-        self.phase_timings["plugins_open"] = _time.perf_counter() - t0
         return self
 
     def close(self) -> None:

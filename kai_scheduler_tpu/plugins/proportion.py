@@ -379,8 +379,6 @@ class ProportionPlugin(Plugin):
         - ``levels``: the pre-forest per-level dispatch loop, kept as
           the A/B baseline and parity reference.
         """
-        import time as _time
-
         from ..utils.metrics import METRICS
         qids = sorted(self.queues)
         index = {qid: i for i, qid in enumerate(qids)}
@@ -399,7 +397,6 @@ class ProportionPlugin(Plugin):
         request, usage = stack("request"), stack("usage")
         mode = getattr(ssn.config, "fused_fairshare", "forest")
         validate = lambda r: getattr(r, "shape", (0,))[0] >= n
-        t_step = _time.perf_counter()
         # Guarded like every other device dispatch: session open must
         # degrade to the CPU fallback on a dead device, not wedge the
         # cycle before its first action.
@@ -439,10 +436,6 @@ class ProportionPlugin(Plugin):
                         self.total, ssn.config.k_value, hier, deserved,
                         limit, oqw, request, usage),
                     label="fair_share", validate=validate)
-        # The fair-share STEP cost (prep + division dispatch, not the
-        # attribute stacking above): the number the churn bench's A/B
-        # rows and the fleet-budget ceiling gate on.
-        ssn.phase_timings["fairshare"] = _time.perf_counter() - t_step
         store = self._qattr_store(ssn.cache)
         gauges = store["gauges"] if store is not None else {}
         deduped = 0

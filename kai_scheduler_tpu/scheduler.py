@@ -230,19 +230,11 @@ class Scheduler:
                     except (DeviceGuardError, Fenced) as exc:
                         _abort(f"action {action.name}", exc)
                         break
-                    dt = time.perf_counter() - ta
-                    ssn.phase_timings[f"action_{action.name}"] = dt
                     METRICS.observe(
                         f"action_scheduling_latency_{action.name}",
-                        dt * 1000.0)
+                        (time.perf_counter() - ta) * 1000.0)
         finally:
             ssn.close()
-        # Per-phase breakdown on /metrics: where the cycle budget goes
-        # (snapshot pack, each plugin's open, each action) — the
-        # e2e_scheduling_latency breakdown the host-pipeline work is
-        # measured by.
-        for phase, secs in ssn.phase_timings.items():
-            METRICS.observe(f"cycle_phase_latency_{phase}", secs * 1000.0)
         cycle_ms = (time.perf_counter() - t0) * 1000.0
         METRICS.observe("e2e_scheduling_latency_milliseconds", cycle_ms)
         # SLO accounting: burn the cycle budget counter when over, and

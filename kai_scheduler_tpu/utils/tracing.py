@@ -2,7 +2,7 @@
 
 The per-cycle hot loop (snapshot -> plugin opens -> actions -> kernel
 dispatches -> commit) is the paper's latency-critical contribution, yet
-``phase_timings`` averages cannot answer the two questions that matter
+latency averages cannot answer the two questions that matter
 after an incident: *which span burned the budget of cycle N* and *why is
 this PodGroup still pending*.  This module gives every cycle a structured
 trace — nested spans with monotonic durations, attributes, and error
@@ -69,11 +69,19 @@ One clock: while a cycle is live every span is also a
 session started by anyone holds the scheduler's phases on the device
 trace's own clock.  A process that never imported jax (the apiserver
 child) records spans without it.
+
+The cyclic collector (PR 40): from the first ``begin_cycle`` on, one
+``gc.callbacks`` entry of the process-wide ``TRACER`` counts every
+collection and its pause by generation, and a collection of the oldest
+generation that lands on a thread with a live cycle is a ``gc:full`` span
+under that thread's innermost open span.  ``end_cycle`` folds the counts
+into ``METRICS``; the callback itself takes no lock (``Tracer._on_gc``).
 """
 
 from __future__ import annotations
 
 import contextlib
+import gc
 import itertools
 import json
 import os
@@ -254,9 +262,14 @@ class _HandOff:
     ``writer`` is the ident of the one thread that may record at the
     moment: each adoption takes it over, and leaving the ``with`` block
     (the dispatch span is about to close) clears it for good.  Both
-    happen under the tracer's ring lock, as an adopter's record does."""
+    happen under the tracer's ring lock, as an adopter's record does.
 
-    __slots__ = ("_tracer", "trace", "stack", "writer")
+    ``pending`` holds the ``gc:full`` spans that closed on the writer's
+    thread: the collector's callback may not take that lock, so it queues
+    them here and the next holder of the lock records them
+    (``record_pending``)."""
+
+    __slots__ = ("_tracer", "trace", "stack", "writer", "pending")
 
     def __init__(self, tracer: "Tracer", trace: "CycleTrace | None",
                  stack: list):
@@ -264,6 +277,7 @@ class _HandOff:
         self.trace = trace
         self.stack = stack
         self.writer: int | None = None
+        self.pending: list = []
 
     def __enter__(self):
         return self
@@ -271,7 +285,14 @@ class _HandOff:
     def __exit__(self, exc_type, exc, tb):
         with self._tracer._lock:
             self.writer = None
+            self.record_pending()
         return False
+
+    def record_pending(self) -> None:
+        """Under the ring lock: record what the collector queued.  A span
+        queued after the hand-off was revoked stays here, on no trace."""
+        while self.pending:
+            self.trace.record(self.pending.pop(0))
 
     def adopting(self, thunk):
         """``thunk``, recording into this trace on the thread that calls
@@ -449,6 +470,14 @@ class Tracer:
         # podgroup -> latest rejection record ({"cycle", "trace_id",
         # "reasons"}); bounded like ClusterCache._warned_selectors.
         self._explain_latest: dict = {}
+        # The cyclic collector, as _on_gc keeps it (the process-wide
+        # TRACER's alone ever move): [collections, pause seconds] so far
+        # by generation, what end_cycle has folded into METRICS of each,
+        # and the clock and the span of the collection under way.
+        self._gc_totals = [[0, 0.0], [0, 0.0], [0, 0.0]]
+        self._gc_folded = [[0, 0.0], [0, 0.0], [0, 0.0]]
+        self._gc_t0 = 0.0
+        self._gc_span: Span | None = None
 
     # -- cycle lifecycle ---------------------------------------------------
     def _state(self) -> dict:
@@ -467,6 +496,9 @@ class Tracer:
             # end_cycle ran: finalize the dangling trace as aborted so
             # the recorder never loses it (and the stack never leaks).
             self.end_cycle(aborted="trace abandoned by next cycle")
+        if TRACER._on_gc not in gc.callbacks:
+            # Once a process: a process that opens no cycle never pays.
+            gc.callbacks.append(TRACER._on_gc)
         trace_id = f"t{next(self._ids):06d}"
         trace = CycleTrace(trace_id, cycle, self.max_spans_per_trace)
         self._annotation = _trace_annotation()
@@ -527,6 +559,7 @@ class Tracer:
                 METRICS.observe(f"cycle_span_{sp.kind}_latency_ms",
                                 sp.duration_s * 1e3)
         with self._lock:
+            gc_moved = self._gc_unfolded()
             self._ring.append(trace)
             for name in resolved:
                 self._explain_latest.pop(name, None)
@@ -538,6 +571,9 @@ class Tracer:
                 self._explain_latest[podgroup] = {
                     "podgroup": podgroup, "cycle": trace.cycle,
                     "trace_id": trace.trace_id, "reasons": list(reasons)}
+        for gen, (collections, pause_s) in enumerate(gc_moved):
+            METRICS.inc("gc_collections_total", collections, generation=gen)
+            METRICS.inc("gc_pause_seconds_total", pause_s, generation=gen)
         self._maybe_dump(trace)
         return trace
 
@@ -605,9 +641,71 @@ class Tracer:
         with self._lock:
             live = not self._superseded(st)
             if live:
+                st["hand_off"].record_pending()
                 trace.record(span)
         if not live:
             METRICS.inc("trace_spans_revoked_total")
+
+    # -- the cyclic collector ----------------------------------------------
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """The process's one ``gc.callbacks`` entry: every collection and
+        its pause into the totals by generation, and a collection of the
+        oldest generation on a thread with a live cycle as a ``gc:full``
+        span under that thread's innermost open span.  The two younger
+        generations open nothing: thousands a cycle would fill the trace.
+
+        This takes NO lock and calls nothing that does.  A due collection
+        starts between any two bytecodes of the thread whose allocation
+        crossed the threshold, also inside a ``with lock:`` block that
+        thread holds: ``METRICS.inc`` under its data lock, an adopter's
+        ``_close_span`` under the ring lock.  Neither lock is reentrant, so
+        a callback that took either would deadlock there.  Hence the totals
+        are plain lists that ``end_cycle`` folds into ``METRICS`` (CPython
+        runs one collection at a time under the GIL and both phases inside
+        it: one writer), the cycle thread records its span as it records
+        every span, without the lock, and an adopter queues it on the
+        hand-off for the next holder of the ring lock."""
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+            if info["generation"] == 2:
+                st = getattr(self._local, "state", None)
+                if st is not None and st["trace"] is not None \
+                        and not self._superseded(st):
+                    span = self._gc_span = self._open(
+                        st, st["trace"], "gc:full", "gc", {"generation": 2})
+                    span.start_s = self._gc_t0 - st["trace"].t0
+            return
+        pause_s = time.perf_counter() - self._gc_t0
+        total = self._gc_totals[info["generation"]]
+        total[0] += 1
+        total[1] += pause_s
+        span = self._gc_span
+        if span is None:
+            return
+        self._gc_span = None
+        st = self._local.state
+        if st["stack"] and st["stack"][-1] is span:
+            st["stack"].pop()
+        self._end_annotation(span)
+        span.attrs["collected"] = info["collected"]
+        span.duration_s = pause_s  # to the digit what the counter gained
+        hand_off = st["hand_off"]
+        if hand_off is None:  # the cycle thread, the trace's owner
+            st["trace"].record(span)
+        elif hand_off.writer == st["ident"]:
+            hand_off.pending.append(span)
+
+    def _gc_unfolded(self) -> list:
+        """Under the ring lock (cycles of two schedulers may end at once):
+        (collections, pause seconds) of each generation since the last
+        call.  Each total is read once, so a collection that falls between
+        the reads is counted whole by the next call."""
+        moved = []
+        for total, folded in zip(self._gc_totals, self._gc_folded):
+            collections, pause_s = total
+            moved.append((collections - folded[0], pause_s - folded[1]))
+            folded[:] = collections, pause_s
+        return moved
 
     # -- across a thread seam (the device guard's worker) ------------------
     def hand_off(self):
@@ -920,7 +1018,8 @@ class Tracer:
             return sorted(self._explain_latest)
 
     def reset(self) -> None:
-        """Drop all recorded state (tests)."""
+        """Drop all recorded state (tests).  The collector's callback and
+        its totals are the process's and stay."""
         with self._lock:
             self._ring.clear()
             self._explain_latest.clear()

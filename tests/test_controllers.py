@@ -571,10 +571,12 @@ class TestAdmissionRuntimeAndMetrics:
         assert 'queue_fair_share_gpu{queue="q"}' in text
         assert "e2e_scheduling_latency_milliseconds" in text
         # Per-phase cycle breakdown (the host-pipeline profiling surface):
-        # snapshot pack, plugin opens, each action.
-        assert "cycle_phase_latency_snapshot_pack" in text
-        assert "cycle_phase_latency_plugins_open" in text
-        assert "cycle_phase_latency_action_allocate" in text
+        # the span histograms of the snapshot, the plugin opens and the
+        # actions, one clock a phase.
+        assert "cycle_span_snapshot_latency_ms" in text
+        assert "cycle_span_plugin_latency_ms" in text
+        assert "cycle_span_action_latency_ms" in text
+        assert "cycle_phase_latency" not in text
 
 
 class TestMixedWorkloadScenario:

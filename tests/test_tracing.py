@@ -697,6 +697,285 @@ class TestProfilerClock:
         assert [s.name for s in trace.spans] == ["s", "cycle"]
 
 
+# -- the cyclic collector (PR 40) ---------------------------------------------
+
+def gc_counters():
+    """(collections, pause seconds) folded into METRICS so far, by
+    generation."""
+    return [(METRICS.counters.get(
+                f'gc_collections_total{{generation="{g}"}}', 0.0),
+             METRICS.counters.get(
+                f'gc_pause_seconds_total{{generation="{g}"}}', 0.0))
+            for g in range(3)]
+
+
+def gc_moved(before):
+    return [(n1 - n0, s1 - s0)
+            for (n0, s0), (n1, s1) in zip(before, gc_counters())]
+
+
+def within(seconds, body):
+    """``body`` on a thread of its own, given ``seconds``: a deadlock
+    fails the test and does not hang the run."""
+    import threading
+    out = []
+    thread = threading.Thread(target=lambda: out.append(body()),
+                              daemon=True)
+    thread.start()
+    thread.join(seconds)
+    assert not thread.is_alive(), f"no return within {seconds} s"
+    assert out, "the body raised"
+    return out[0]
+
+
+class TestCollector:
+    @pytest.fixture(autouse=True)
+    def only_the_collections_a_test_asks_for(self):
+        import gc
+        gc.disable()
+        # One cycle installs the callback and folds what earlier tests
+        # left, so that a test's counters move by its own collections.
+        TRACER.begin_cycle(0)
+        TRACER.end_cycle()
+        yield
+        gc.enable()
+
+    def test_full_collection_is_a_span_under_the_open_span(self):
+        import gc
+        before = gc_counters()
+        TRACER.begin_cycle(1)
+        with TRACER.span("propose:operands", kind="propose"):
+            gc.collect()
+        trace = TRACER.end_cycle()
+        spans = by_name(trace)
+        (full,), (operands,) = spans["gc:full"], spans["propose:operands"]
+        assert full.kind == "gc" and full.parent_id == operands.span_id
+        assert full.attrs["generation"] == 2
+        assert isinstance(full.attrs["collected"], int)
+        assert operands.start_s <= full.start_s and full.duration_s > 0
+        assert full.start_s + full.duration_s \
+            <= operands.start_s + operands.duration_s
+        young, middle, old = gc_moved(before)
+        assert old[0] == 1 and old[1] == pytest.approx(full.duration_s,
+                                                       abs=1e-9)
+        assert young[0] == 0 and middle[0] == 0
+        # Kind gc keeps its histogram: the operator's pause quantile.
+        assert METRICS.histograms["cycle_span_gc_latency_ms"].n >= 1
+
+    def test_what_the_walk_collected_is_on_the_span(self):
+        import gc
+
+        class Node:
+            pass
+        TRACER.begin_cycle(1)
+        for _ in range(10):
+            a, b = Node(), Node()
+            a.other, b.other = b, a
+        del a, b
+        gc.collect()
+        trace = TRACER.end_cycle()
+        (full,) = by_name(trace)["gc:full"]
+        assert full.attrs["collected"] >= 20
+        assert full.parent_id == trace.root.span_id
+
+    @pytest.mark.parametrize("generation", (0, 1))
+    def test_young_collection_opens_nothing_and_is_counted(self,
+                                                           generation):
+        import gc
+        before = gc_counters()
+        TRACER.begin_cycle(1)
+        with TRACER.span("statement:apply", kind="allocate"):
+            gc.collect(generation)
+        trace = TRACER.end_cycle()
+        assert [sp.name for sp in trace.spans] == ["statement:apply",
+                                                   "cycle"]
+        moved = gc_moved(before)
+        assert [n for n, _s in moved] == [1.0 if g == generation else 0.0
+                                          for g in range(3)]
+        assert moved[generation][1] > 0
+
+    def test_collection_on_a_thread_with_no_cycle_is_counted(self):
+        import gc
+        before = gc_counters()
+        TRACER.begin_cycle(1)
+        with TRACER.span("statement:apply", kind="allocate"):
+            assert within(30.0, gc.collect) >= 0  # a status worker
+        trace = TRACER.end_cycle()
+        assert "gc:full" not in by_name(trace)
+        old = gc_moved(before)[2]
+        assert old[0] == 1 and old[1] > 0
+
+    def test_collection_between_cycles_is_in_the_cycle_that_follows(self):
+        import gc
+        before = gc_counters()
+        gc.collect()  # the client, between two cycles
+        assert gc_moved(before)[2] == (0, 0)
+        TRACER.begin_cycle(1)
+        trace = TRACER.end_cycle()
+        assert "gc:full" not in by_name(trace)
+        old = gc_moved(before)[2]
+        assert old[0] == 1 and old[1] > 0
+        TRACER.begin_cycle(2)
+        TRACER.end_cycle()
+        assert gc_moved(before)[2] == old  # folded once
+
+    def test_callback_is_installed_once_a_process(self):
+        import gc
+
+        def installed():
+            return [cb for cb in gc.callbacks
+                    if getattr(cb, "__func__", None) is Tracer._on_gc]
+        for cycle in range(5):
+            TRACER.begin_cycle(cycle)
+            TRACER.end_cycle()
+        TRACER.reset()
+        other = Tracer(capacity=2)  # a private recorder adds none
+        other.begin_cycle(1)
+        other.end_cycle()
+        TRACER.begin_cycle(9)
+        gc.collect()
+        trace = TRACER.end_cycle()
+        assert len(installed()) == 1 and installed()[0].__self__ is TRACER
+        assert len(by_name(trace)["gc:full"]) == 1
+
+    def test_full_collection_is_an_annotation_on_the_profilers_clock(
+            self, tmp_path):
+        import gc
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.enable_hlo_proto = False
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            TRACER.begin_cycle(1)
+            with TRACER.span("propose:operands", kind="propose"):
+                gc.collect()
+            trace = TRACER.end_cycle()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                                / "*.xplane.pb"))
+        found = {ev.name: ev.duration_ns
+                 for plane in ProfileData.from_file(path).planes
+                 for line in plane.lines for ev in line.events
+                 if ev.name.startswith("kai:")}
+        (full,) = by_name(trace)["gc:full"]
+        assert found["kai:gc:full"] / 1e9 == pytest.approx(
+            full.duration_s, abs=1e-3)
+        assert found["kai:gc:full"] <= found["kai:propose:operands"]
+
+    # -- the callback takes no lock: each case under its own time limit ----
+    def test_collection_under_the_metrics_lock_returns(self):
+        """A due collection starts between two bytecodes, also inside
+        ``METRICS.inc``'s ``with self._data_lock`` on the same thread."""
+        import gc
+        before = gc_counters()
+
+        def body():
+            TRACER.begin_cycle(1)
+            with TRACER.span("statement:commit", kind="allocate"):
+                with METRICS._data_lock:
+                    gc.collect()
+                    gc.collect(0)
+            return TRACER.end_cycle()
+        trace = within(30.0, body)
+        assert len(by_name(trace)["gc:full"]) == 1
+        moved = gc_moved(before)
+        assert moved[2][0] == 1 and moved[0][0] == 1
+
+    @pytest.mark.parametrize("recorded_by", ("next_close", "hand_off_exit"))
+    def test_collection_under_the_ring_lock_on_an_adopter_returns(
+            self, recorded_by):
+        """An adopting worker closes its spans under the ring lock; a
+        collection that lands there queues its span on the hand-off, and
+        the worker's next close, or the cycle thread leaving the hand-off,
+        records it."""
+        import gc
+        TRACER.begin_cycle(1)
+
+        def thunk():
+            if recorded_by == "next_close":
+                with TRACER.span("seam:stage", kind="seam"):
+                    with TRACER._lock:
+                        gc.collect()
+            else:
+                with TRACER._lock:
+                    gc.collect()
+            return 7
+        with TRACER.span("dispatch:probe", kind="kernel"), \
+                TRACER.hand_off() as seam:
+            assert within(30.0, seam.adopting(thunk)) == 7
+            if recorded_by == "hand_off_exit":
+                assert seam.pending and "gc:full" not in \
+                    TRACER._state()["trace"].name_totals
+        trace = TRACER.end_cycle()
+        spans = by_name(trace)
+        (full,) = spans["gc:full"]
+        parent = "seam:stage" if recorded_by == "next_close" \
+            else "dispatch:probe"
+        assert full.parent_id == spans[parent][0].span_id
+        assert full.attrs["generation"] == 2 and full.duration_s > 0
+        assert not seam.pending and trace.dropped_spans == 0
+
+    def test_collection_on_a_live_guard_worker_lands_under_its_seam(self):
+        import gc
+        configure_device_guard(deadline_s=5.0, retries=0,
+                               breaker_threshold=100)
+        ssn = cycle_session()
+
+        def thunk():
+            with TRACER.span("seam:stage", kind="seam"):
+                gc.collect()
+            return 1
+        assert ssn.dispatch_kernel(thunk, label="probe") == 1
+        spans = by_name(TRACER.end_cycle())
+        (full,), (stage,) = spans["gc:full"], spans["seam:stage"]
+        assert full.parent_id == stage.span_id
+        assert stage.parent_id == spans["dispatch:probe"][0].span_id
+
+    def test_collection_on_an_abandoned_worker_records_nothing(
+            self, monkeypatch):
+        """A worker the guard abandoned at its deadline: its collection
+        is counted, opens no span in its own cycle's trace nor in the
+        next one's, and raises nothing (CPython hands what a callback
+        raises to ``sys.unraisablehook``)."""
+        import gc
+        import sys
+        import threading
+
+        from kai_scheduler_tpu.utils.deviceguard import DeviceGuardError
+        configure_device_guard(deadline_s=0.1, retries=0,
+                               breaker_threshold=100,
+                               fallback_enabled=False)
+        ssn = cycle_session()
+        before = gc_counters()
+        release, finished = threading.Event(), threading.Event()
+        raised = []
+        monkeypatch.setattr(sys, "unraisablehook", raised.append)
+
+        def thunk():
+            try:
+                assert release.wait(30.0)
+                gc.collect()
+            finally:
+                finished.set()
+            return 1
+        with pytest.raises(DeviceGuardError):
+            ssn.dispatch_kernel(thunk, label="slow")
+        first = TRACER.end_cycle()
+        TRACER.begin_cycle(2)
+        release.set()
+        assert finished.wait(30.0)
+        second = TRACER.end_cycle()
+        assert "gc:full" not in by_name(first)
+        assert "gc:full" not in by_name(second)
+        assert gc_moved(before)[2][0] == 1
+        assert raised == []
+
+
 class TestProfileEndpoints:
     """``--enable-profiler`` arms the one sampling profiler there is
     (tests/test_server.py starts the daemon with it); the three paths it
