@@ -109,12 +109,16 @@ class ClusterInfo:
         self.arena_stamp: int | None = None
         # Columnar fast-path hints (controllers/cache_builder.py
         # _snapshot_columnar): exact facts about the pod population
-        # ("no pod carries a selector/affinity term/host port",
-        # precomputed max toleration width) that let pack() and the
-        # per-cycle plugin scans skip their O(pods) walks with identical
-        # results.  None on every other construction path (clones,
-        # filters, tests) — consumers must treat absence as "walk".
+        # ("no pod carries a selector/host port", precomputed max
+        # toleration width) that let pack() and the per-cycle plugin
+        # scans skip their O(pods) walks with identical results.  None on
+        # every other construction path (clones, filters, tests) —
+        # consumers must treat absence as "walk".
         self.columnar_hints: dict | None = None
+        # The pods that carry an inter-pod term, where the builder of
+        # this object has proven them (the columnar build: none); None
+        # where nobody has.  Read through ``Session.term_carriers``.
+        self.term_carriers: list | None = None
         # Stable orderings for tensor packing.
         self.node_order: list[str] = sorted(self.nodes)
         for i, name in enumerate(self.node_order):

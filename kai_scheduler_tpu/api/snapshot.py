@@ -143,11 +143,35 @@ def build_codec(cluster: ClusterInfo,
     return codec
 
 
-def vocabulary_signature(cluster: ClusterInfo) -> tuple:
-    """What of the cluster the label codec, the codec-derived widths and
-    the label and taint rows of the nodes are made from, in the order
-    ``pack`` meets it: every pod that carries a selector or a toleration,
-    and every node that carries a taint or a label some pod selects on.
+def survey_pods(cluster: ClusterInfo) -> tuple[list, list]:
+    """``(vocabulary pods, term carriers)`` from one walk over every pod.
+
+    The first is the pods' part of ``vocabulary_signature``.  The term
+    carriers are the pods that carry an inter-pod term (required or
+    preferred, affinity or anti-affinity; any status, any node), in walk
+    order: what ``Session.term_carriers`` hands the pod-affinity gate so
+    that it asks and does not list every running pod."""
+    pods, carriers = [], []
+    for pg in cluster.podgroups.values():
+        for t in pg.pods.values():
+            if t.node_selector or t.tolerations:
+                pods.append((t.uid, tuple(t.node_selector.items()),
+                             tuple(sorted(t.tolerations))))
+            if (t.affinity_terms or t.anti_affinity_terms
+                    or t.preferred_affinity_terms
+                    or t.preferred_anti_affinity_terms):
+                carriers.append(t)
+    return pods, carriers
+
+
+def vocabulary_signature(cluster: ClusterInfo) -> tuple[tuple, list]:
+    """``(signature, term carriers)``: what of the cluster the label
+    codec, the codec-derived widths and the label and taint rows of the
+    nodes are made from, in the order ``pack`` meets it: every pod that
+    carries a selector or a toleration, and every node that carries a
+    taint or a label some pod selects on; and, since the walk over every
+    pod is made here before a pack knows whether it patches, what else
+    ``survey_pods`` found on it.
 
     Two packs of one cluster whose signatures are equal share the codec,
     ``max_tols``, ``max_taints``, ``node_labels`` and ``node_taints``:
@@ -156,10 +180,7 @@ def vocabulary_signature(cluster: ClusterInfo) -> tuple:
     selects on nothing and taints nothing reads ``((), ())`` after two
     plain walks; an unequal signature costs a full pack, never a wrong
     one."""
-    pods = [
-        (t.uid, tuple(t.node_selector.items()), tuple(sorted(t.tolerations)))
-        for pg in cluster.podgroups.values() for t in pg.pods.values()
-        if t.node_selector or t.tolerations]
+    pods, carriers = survey_pods(cluster)
     keys = {k for _uid, selector, _tols in pods for k, _v in selector}
     # Without a selector anywhere no label has a column: taints alone.
     nodes = [
@@ -169,7 +190,7 @@ def vocabulary_signature(cluster: ClusterInfo) -> tuple:
         if node.taints or node.labels] if keys else [
         (name, (), tuple(node.taints))
         for name, node in cluster.nodes.items() if node.taints]
-    return pods, nodes
+    return (pods, nodes), carriers
 
 
 def _select_jobs(cluster: ClusterInfo,

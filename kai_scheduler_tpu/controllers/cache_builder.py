@@ -1781,12 +1781,14 @@ class ClusterCache:
         # Exact pod-population facts for pack()'s and the plugins'
         # O(pods) scans (identical results, no walk).
         cluster.columnar_hints = {
-            "no_affinity_terms": True,
             "no_host_ports": True,
             "no_selectors": not bool(np.any(flags & FLAG_SELECTOR)),
             "max_tols": int(max(1, store.tol_len[wrows].max()))
             if wrows.size else 1,
         }
+        # A pod with an inter-pod term is FLAG_COMPLEX, and this path runs
+        # only where the store holds none (``_columnar_verdict``).
+        cluster.term_carriers = []
         # Memoized queue aggregates (same accumulation order as the
         # object walk); statement mutations invalidate and recompute
         # from the materialized objects as usual.  Summed in turn, not

@@ -315,6 +315,9 @@ class HostArena:
         # What the sessions' plugins keep from one session to the next
         # (``Session.products``): a full pack starts it empty.
         self.products: dict = {}
+        # The pods that carry an inter-pod term, as the last pack's walk
+        # over every pod found them (``Session.term_carriers``).
+        self.term_carriers: list = []
         self._table = None            # the sessions' NativeNodeTable
         self._node_index: dict | None = None
 
@@ -377,7 +380,7 @@ class HostArena:
         verdict rides on the caller's ``snapshot`` span as attributes
         (``Session.pack_stats``; docs/OBSERVABILITY.md)."""
         t0 = time.perf_counter()
-        vocab = vocabulary_signature(cluster)
+        vocab, self.term_carriers = vocabulary_signature(cluster)
         reason, rows = self._reason_and_rows(cluster, pad_nodes_to, vocab)
         dirty = () if rows is None else [cluster.node_order[i] for i in rows]
         # Task, job and queue arrays are rebuilt: podgroups, pod statuses

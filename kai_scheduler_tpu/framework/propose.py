@@ -204,17 +204,9 @@ def wave_filter(ssn):
     # pods the bulk kernel knows nothing about.  Collect the active terms
     # once and gate only jobs a term could actually match — a single guard
     # pod must not knock every labeled job off the fleet path.
-    hints = getattr(ssn.cluster, "columnar_hints", None)
-    if hints and hints.get("no_affinity_terms"):
-        # Columnar snapshot: the store proved no pod carries an
-        # anti-affinity term — identical result, no O(pods) walk.
-        repeller_terms = []
-    else:
-        repeller_terms = [
-            term
-            for pg in ssn.cluster.podgroups.values()
-            for t in pg.pods.values() if t.is_active_allocated()
-            for term in t.anti_affinity_terms]
+    repeller_terms = [
+        term for t in ssn.term_carriers if t.is_active_allocated()
+        for term in t.anti_affinity_terms]
 
     def takes(pg, tasks) -> bool:
         host_side = (

@@ -21,7 +21,7 @@ import numpy as np
 from ..api.cluster_info import ClusterInfo
 from ..api.pod_info import PodInfo
 from ..api.podgroup_info import PodGroupInfo
-from ..api.snapshot import SnapshotTensors, pack
+from ..api.snapshot import SnapshotTensors, pack, survey_pods
 from ..ops.scoring import BINPACK
 from ..utils.metrics import METRICS
 from ..utils.tracing import TRACER
@@ -220,6 +220,12 @@ class Session:
         else:
             self.snapshot: SnapshotTensors = pack(
                 cluster, queue_usage=pack_usage, pad_nodes_to=pad)
+        # ``term_carriers`` (below): the host arena's walk over every pod
+        # has just found them; a snapshot builder that proves them says so
+        # on the cluster (``ClusterCache``'s columnar build); a session
+        # nobody told (None) walks once, when first asked.
+        self._term_carriers: list | None = (
+            cluster.term_carriers if host is None else host.term_carriers)
         snap = self.snapshot
         table, dirty_rows, node_index = (
             host.carried(snap) if host is not None else (None, None, None))
@@ -550,6 +556,17 @@ class Session:
             if not res.schedulable:
                 return res
         return SchedulableResult()
+
+    @property
+    def term_carriers(self) -> list:
+        """The pods of the cluster that carry an inter-pod term (required
+        or preferred, affinity or anti-affinity; any status, any node), as
+        the snapshot layer found them for this session (docs/DESIGN.md
+        section 8).  Terms do not change inside a session; status and node
+        do, so a reader filters at the call."""
+        if self._term_carriers is None:
+            self._term_carriers = survey_pods(self.cluster)[1]
+        return self._term_carriers
 
     def compute_hard_mask(self, tasks) -> "np.ndarray | None":
         """AND of every hard_node_mask_fns contribution: [T,N] bool or

@@ -31,6 +31,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from ..utils.metrics import METRICS
+from ..utils.tracing import TRACER
 from .base import Plugin, register_plugin
 
 AFFINITY_SCORE = 50.0  # between placement (<=9+10) and availability (100)
@@ -49,6 +51,7 @@ class PodAffinityPlugin(Plugin):
         self.ssn = ssn
         self._domain_cache: dict = {}
         self._pods_cache = (-1, None)  # (mutation_count, pods)
+        METRICS.inc("podaffinity_pod_walks_total", 0)
         ssn.extra_score_fns.append(self.extra_scores)
         ssn.hard_node_mask_fns.append(self.hard_masks)
         ssn.anti_domain_fns.append(self.anti_domains)
@@ -84,10 +87,13 @@ class PodAffinityPlugin(Plugin):
     def _active_pods(self):
         """(labels, namespace, node_idx, anti_terms, job_id) for every
         active allocated pod on a snapshot node; memoized per session
-        mutation tick (statements bump it on every state change)."""
+        mutation tick (statements bump it on every state change).  For
+        the terms of a chunk's own tasks, which match on every pod's
+        labels; the symmetry gate asks ``_carrier_repellers``."""
         tick = self.ssn.mutation_count
         if self._pods_cache[0] == tick:
             return self._pods_cache[1]
+        METRICS.inc("podaffinity_pod_walks_total")
         out = []
         for pg in self.ssn.cluster.podgroups.values():
             for task in pg.pods.values():
@@ -100,6 +106,22 @@ class PodAffinityPlugin(Plugin):
                             getattr(task, "anti_affinity_terms", []),
                             task.job_id))
         self._pods_cache = (tick, out)
+        return out
+
+    def _carrier_repellers(self) -> list:
+        """``(node_idx, term)`` for each required anti-affinity term of an
+        active allocated pod on a snapshot node, from the pods the
+        snapshot layer found carrying a term (``Session.term_carriers``),
+        filtered at the call because statements change status inside a
+        session."""
+        out = []
+        for task in self.ssn.term_carriers:
+            if not (task.anti_affinity_terms and task.is_active_allocated()
+                    and task.node_name):
+                continue
+            idx = self.ssn.node_index(task.node_name)
+            if idx >= 0:
+                out.extend((idx, term) for term in task.anti_affinity_terms)
         return out
 
     def _term_mask(self, term, pods) -> np.ndarray:
@@ -137,17 +159,21 @@ class PodAffinityPlugin(Plugin):
             getattr(t, "affinity_terms", None)
             or getattr(t, "anti_affinity_terms", None)
             for t in tasks)
-        pods = self._active_pods()
-        if not has_own_terms and not any(
-                anti for _l, _n, _i, anti, _j in pods):
+        # Anti-affinity symmetry: existing pods' anti terms repel a
+        # matching incoming task from their domains.
+        sym_repellers = self._carrier_repellers()
+        TRACER.stamp("propose:operands", affinity=(
+            "walked" if has_own_terms
+            else "carriers" if self.ssn.term_carriers else "none"))
+        if not has_own_terms and not sym_repellers:
             return None
+        # Only a chunk with terms of its own needs every running pod's
+        # labels.
+        pods = self._active_pods() if has_own_terms else None
 
         n = self.ssn.node_idle.shape[0]
         out = np.ones((len(tasks), n), bool)
         touched = False
-        sym_repellers = [
-            (labels, ns, idx, term)
-            for labels, ns, idx, anti, _j in pods for term in anti]
         selected = self._selected_in_gang_affinity(tasks)
         for i, task in enumerate(tasks):
             row = out[i]
@@ -170,9 +196,7 @@ class PodAffinityPlugin(Plugin):
             for term in getattr(task, "anti_affinity_terms", []) or []:
                 row &= ~self._term_mask(term, pods)
                 touched = True
-            # Anti-affinity symmetry: existing pods' anti terms repel a
-            # matching incoming task from their domains.
-            for _labels, _ns, idx, term in sym_repellers:
+            for idx, term in sym_repellers:
                 if term.matches(task.labels, task.namespace):
                     dom, n_dom = self._domains(term.topology_key)
                     if dom[idx] >= 0:
