@@ -349,9 +349,14 @@ def _prescreen_verdict(ssn, tasks, builder: "ScenarioBuilder", sp):
         rows.task_req, rows.task_job, rows.task_sel, rows.task_tol,
         ssn.gpu_strategy, ssn.cpu_strategy)
     sp.set(prefixes=num_prefixes, steps=len(steps), rows=m_pad,
-           t_pad=int(rows.task_req.shape[0]), form=form)
+           t_pad=int(rows.task_req.shape[0]), form=form,
+           strategy=propose.strategy_name(ssn))
     if form == "grouped":
         sp.set(runs=scan_steps)
+    elif form == "scanned":
+        # No mask reaches this call (the hard-mask gate above), so rows
+        # that differ are scanned for the strategy and nothing else.
+        propose.declined("prescreen_runs", "strategy")
 
     from ..utils.deviceguard import CycleDeadlineExceeded, DeviceGuardError
     METRICS.inc("device_kernel_calls")

@@ -26,7 +26,7 @@ from ..api.pod_status import PodStatus
 from ..ops import allocate_grouped as ag
 from ..ops.allocate import allocate_jobs_kernel
 from ..ops.allocate_grouped import _next_pow2
-from ..ops.scoring import BINPACK
+from ..ops.scoring import BINPACK, SPREAD
 from ..utils.metrics import METRICS
 from ..utils.tracing import TRACER
 
@@ -156,11 +156,45 @@ def _stage(*operands):
     return staged
 
 
-# -- what the grouped fill can take -----------------------------------------
+# -- the batched forms, and why a call does not take one ---------------------
+# ``batched_form_declined_total{form, reason}``: a call that one of the
+# batched forms (each proven under bin-pack alone) could not take, counted
+# once a call where the form is chosen, never once a task.  ``wave``: the
+# bulk wave of many jobs (``wave_filter``); ``grouped_fill``: the fill plan
+# of one homogeneous chunk (``_grouped_fill_rows``); ``prescreen_runs``:
+# the scenario prescreen's run loop (actions/solvers.py
+# ``_prescreen_verdict``, where ``dispatched_form`` reads ``scanned``).
+DECLINES = (("wave", "strategy"),
+            ("grouped_fill", "strategy"), ("grouped_fill", "domain_rows"),
+            ("grouped_fill", "rows"), ("grouped_fill", "extras"),
+            ("grouped_fill", "mask"), ("prescreen_runs", "strategy"))
+
+
+def declined(form: str, reason: str, count: int = 1) -> None:
+    METRICS.inc("batched_form_declined_total", count, form=form,
+                reason=reason)
+
+
+def register_declines() -> None:
+    """Every series of the family at 0, when a session opens: a shard that
+    never declines reads 0 and not absent."""
+    for form, reason in DECLINES:
+        declined(form, reason, 0)
+
+
+def strategy_name(ssn) -> str:
+    """The session's placement strategies as a span says them:
+    ``binpack``, ``spread``, or ``mixed`` where the two axes differ."""
+    if ssn.gpu_strategy != ssn.cpu_strategy:
+        return "mixed"
+    return "spread" if ssn.gpu_strategy == SPREAD else "binpack"
+
+
 def _grouped_fill_rows(ssn, rows: TaskOperands, extra, mask, subset,
                        domain_rows: bool):
     """``(row_extra, row_mask)`` when ONE chunk can take the grouped
-    fill-plan kernel (one scan step instead of one per task), else None.
+    fill-plan kernel (one scan step instead of one per task), else None
+    with the first reason found counted (``declined``).
 
     The chunk must be homogeneous: tasks identical in request, selector
     and tolerations, bin-pack on both axes, no domain rows.  Extra score
@@ -169,23 +203,25 @@ def _grouped_fill_rows(ssn, rows: TaskOperands, extra, mask, subset,
     10) for the fill plan's ordering invariance (allocate_groups_kernel);
     a node subset becomes a hard mask row."""
     t = rows.t
-    if not (t > 1 and not domain_rows
-            and ssn.gpu_strategy == BINPACK
-            and ssn.cpu_strategy == BINPACK
+    if ssn.gpu_strategy != BINPACK or ssn.cpu_strategy != BINPACK:
+        return declined("grouped_fill", "strategy")
+    if domain_rows:
+        return declined("grouped_fill", "domain_rows")
+    if not (t > 1
             and (rows.task_req[1:t] == rows.task_req[0]).all()
             and (rows.task_sel[1:t] == rows.task_sel[0]).all()
             and (rows.task_tol[1:t] == rows.task_tol[0]).all()):
-        return None
+        return declined("grouped_fill", "rows")
     row_extra = row_mask = None
     if extra is not None and extra.any():
         row = extra if extra.ndim == 1 else extra[0]
         if not ((extra.ndim == 1 or (extra[1:] == row).all()) and bool(
                 np.all(np.remainder(row, 10.0) == 0.0))):
-            return None
+            return declined("grouped_fill", "extras")
         row_extra = row[None, :]
     if mask is not None:
         if not (mask[1:] == mask[0]).all():
-            return None
+            return declined("grouped_fill", "mask")
         row_mask = mask[:1] if subset is None else mask[:1] & subset
     elif subset is not None:
         row_mask = subset[None, :]
@@ -198,7 +234,7 @@ def wave_filter(ssn):
     masks and no host-side state — or None when it can place none (the
     grouped kernel implements bin-pack only)."""
     if ssn.gpu_strategy != BINPACK or ssn.cpu_strategy != BINPACK:
-        return None
+        return declined("wave", "strategy")
 
     # Anti-affinity symmetry: existing pods' anti terms can repel incoming
     # pods the bulk kernel knows nothing about.  Collect the active terms
@@ -416,7 +452,7 @@ def propose(ssn, chunks, kind: str, pipeline_only: bool,
                  "row" if ndims else "none",
                  "mask": "dense" if mask is not None else
                  "none" if subset is None else "row"}
-        operands_span.set(**forms)
+        operands_span.set(strategy=strategy_name(ssn), **forms)
         METRICS.inc("propose_operand_form_total", **forms)
 
         fill_rows = (_grouped_fill_rows(ssn, rows, extras[0], mask, subset,

@@ -282,6 +282,7 @@ class Session:
             n: i for i, n in enumerate(self.snapshot.node_names)}
         self.gpu_strategy = BINPACK
         self.cpu_strategy = BINPACK
+        propose.register_declines()
         # Sessions are scheduler-thread-owned end to end: statements
         # mutate mirrors on the cycle path only (commit I/O ships OUT of
         # the session to the executor; it never writes back in).

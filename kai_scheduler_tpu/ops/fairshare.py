@@ -59,6 +59,33 @@ def _requestable(request, limit):
     return np.where(limit == UNLIMITED, request, np.minimum(limit, request))
 
 
+def restore_exact(fair: np.ndarray, deserved: np.ndarray,
+                  limit: np.ndarray, request: np.ndarray) -> np.ndarray:
+    """``fair`` [Q,R] as the host holds quantities (f64), with what a
+    32-bit device rounded away put back where it is known.
+
+    Without x64 the kernels take the queues' deserved, limit and request
+    as f32, and a sum past 2**24 units that is no multiple of a power of
+    two is rounded there (a department that asks 590,852,000 milli-cores
+    reads 590,851,968).  A queue that is given all it may ask, or exactly
+    its deserved share, then reads half a unit in the last place beside
+    what the host's own roll-up (f64, exact) holds for it, and every
+    comparison of the two that upstream makes in one precision (reclaim's
+    ``allocated / fair_share > 1``, ``allocated + request <= fair_share``)
+    is decided by the rounding: a reclaimer that stands exactly at its
+    share was refused.  So where the device's answer IS one of those two
+    landing values at the device's precision, the answer is that value as
+    the host has it.  No tolerance: an answer that is neither stays what
+    the device said."""
+    fair = np.asarray(fair)
+    if fair.dtype == np.float64:
+        return fair
+    out = fair.astype(np.float64)
+    for exact in (deserved, _requestable(request, limit)):
+        out = np.where(fair == exact.astype(fair.dtype), exact, out)
+    return out
+
+
 def set_resources_share_np(total: np.ndarray, k_value: float,
                            deserved: np.ndarray, limit: np.ndarray,
                            over_quota_weight: np.ndarray,

@@ -436,6 +436,7 @@ class ProportionPlugin(Plugin):
                         self.total, ssn.config.k_value, hier, deserved,
                         limit, oqw, request, usage),
                     label="fair_share", validate=validate)
+        fair = fsops.restore_exact(fair, deserved, limit, request)
         store = self._qattr_store(ssn.cache)
         gauges = store["gauges"] if store is not None else {}
         deduped = 0

@@ -480,7 +480,8 @@ class TestAllocateSpans:
         operands = spans["propose:operands"][0].attrs
         assert operands == {"t": 3, "t_pad": 4, "nodes": 8,
                             "path": "exact", "extras": "row",
-                            "mask": "row", "affinity": "none"}
+                            "mask": "row", "affinity": "none",
+                            "strategy": "binpack"}
         assert spans["topology:subset_nodes"][0].attrs["nodes_in_first"] == 2
         # One [N] row for the gang, not a row a task.
         assert spans["extra_scores:topology"][0].attrs["bytes"] == 8 * 8
