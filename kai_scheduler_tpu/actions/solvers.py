@@ -345,18 +345,15 @@ def _prescreen_verdict(ssn, tasks, builder: "ScenarioBuilder", sp):
     if rows is None:
         return declined("no-task-rows")
     # The kernel picks its form from these rows by the same predicates.
+    # No mask reaches this call (the hard-mask gate above), so the form is
+    # never ``scanned`` here, whatever the strategies.
     form, scan_steps = dispatched_form(
-        rows.task_req, rows.task_job, rows.task_sel, rows.task_tol,
-        ssn.gpu_strategy, ssn.cpu_strategy)
+        rows.task_req, rows.task_job, rows.task_sel, rows.task_tol)
     sp.set(prefixes=num_prefixes, steps=len(steps), rows=m_pad,
            t_pad=int(rows.task_req.shape[0]), form=form,
            strategy=propose.strategy_name(ssn))
     if form == "grouped":
         sp.set(runs=scan_steps)
-    elif form == "scanned":
-        # No mask reaches this call (the hard-mask gate above), so rows
-        # that differ are scanned for the strategy and nothing else.
-        propose.declined("prescreen_runs", "strategy")
 
     from ..utils.deviceguard import CycleDeadlineExceeded, DeviceGuardError
     METRICS.inc("device_kernel_calls")

@@ -158,12 +158,18 @@ def _stage(*operands):
 
 # -- the batched forms, and why a call does not take one ---------------------
 # ``batched_form_declined_total{form, reason}``: a call that one of the
-# batched forms (each proven under bin-pack alone) could not take, counted
-# once a call where the form is chosen, never once a task.  ``wave``: the
-# bulk wave of many jobs (``wave_filter``); ``grouped_fill``: the fill plan
-# of one homogeneous chunk (``_grouped_fill_rows``); ``prescreen_runs``:
-# the scenario prescreen's run loop (actions/solvers.py
-# ``_prescreen_verdict``, where ``dispatched_form`` reads ``scanned``).
+# batched forms could not take, counted once a call where the form is
+# chosen, never once a task.  ``wave``: the bulk wave of many jobs
+# (``wave_filter``); ``grouped_fill``: the fill plan of one homogeneous
+# chunk (``_grouped_fill_rows``).  Both place pods that claim idle, where
+# spread round-robins as nodes fill, and are proven under bin-pack alone.
+# ``prescreen_runs``: the scenario prescreen's run loop, which since PR 43
+# lands a run by either strategy's key (ops/scenario_batch.py: nothing
+# claims idle in a pipeline-only attempt) and declines nothing.  Nothing
+# increments the series; it stays registered at 0 because the benchmark's
+# ``strategy_declines`` (benchmark/layer_metrics/strategy_declines.json)
+# reads it in every cell, and a series that vanishes reads null there.
+# It goes when a ``benchmark`` PR retires that metric (ROADMAP S8).
 DECLINES = (("wave", "strategy"),
             ("grouped_fill", "strategy"), ("grouped_fill", "domain_rows"),
             ("grouped_fill", "rows"), ("grouped_fill", "extras"),

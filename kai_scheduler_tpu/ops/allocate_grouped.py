@@ -33,7 +33,10 @@ Equivalence to the sequential greedy (tested against the exact kernel):
 - gang failure (demand exceeds total capacity) rolls the job back at the
   next job boundary, exactly like the per-task kernel.
 
-Spread strategy round-robins as nodes fill and must use the exact kernel.
+Spread strategy round-robins as nodes fill and must use the exact kernel:
+true of this fill, whose placements claim idle, the quantity spread scores;
+the scenario prescreen's pipeline-only runs claim none, and land by either
+strategy's key through this module's fill (ops/scenario_batch.py).
 
 The per-step row + fill implementation is a static three-rung ladder
 (docs/DESIGN.md §3.2b): TPU-Pallas node-tile row kernel -> fused-jnp

@@ -27,23 +27,31 @@ static arguments, never by a flag:
   gang fits iff the hard-feasible nodes' ``c_n`` sum to its size.  One
   elementwise pass over the pools and one reduction over N.
 * **grouped** — a gang of several runs of identical adjacent rows (a
-  master beside its workers) under bin-pack on both axes: one dependent
-  step a RUN, for all prefixes at once.  A count is exact for one run
-  only, because a second run's fit depends on where the first landed;
-  so between two runs the first run's pods are taken from the carried
-  pool and the pod room where the exact kernel would have put them.
-  That landing is the grouped kernel's fill plan (``allocate_grouped``:
-  under bin-pack, greedy fills the best-scoring node to capacity before
-  moving on, so the sequence is "sort by initial score, fill in order",
-  a threshold select with no sort), with its row and fill functions and
-  the count's capacity.  Nothing claims idle in a pipeline-only attempt,
-  so only the releasing pool and the room are carried.  The last run
-  needs no landing: it is a count.
+  master beside its workers), under either strategy on either axis: one
+  dependent step a RUN, for all prefixes at once.  A count is exact for
+  one run only, because a second run's fit depends on where the first
+  landed; so between two runs the first run's pods are taken from the
+  carried pool and the pod room where the exact kernel would have put
+  them.  Nothing claims idle in a pipeline-only attempt (the exact
+  kernel's step zeroes ``fit_now``, so a placement takes ``req`` from the
+  releasing pool and one pod of the room), and the strategy's score
+  reads idle alone: spread's ``idle / allocatable`` is the same row at
+  every step of a run, bin-pack's is monotone in the same idle whatever
+  the min/max span does.  So under EITHER strategy the exact kernel's
+  ``argmax`` picks the best-scoring feasible node and keeps picking it
+  until its capacity is spent, then the next: "sort by initial score,
+  fill in order", the grouped kernel's fill plan (``allocate_grouped``,
+  a threshold select with no sort) keyed by the score of the strategies
+  the call was compiled for, with the count's capacity.  Spread
+  round-robins where a placement claims idle (the bind, the grouped
+  fill of ``framework/propose.py``): not here.  Only the releasing pool
+  and the room are carried.  The last run needs no landing: it is a
+  count.
 * **scanned** — the pending job's pipeline-only placement attempt,
   ``allocate_jobs_kernel``, vmapped over the prefixes: one dependent step
-  a POD.  It stays where the fill plan is no proof: a spread strategy on
-  either axis (spread round-robins as nodes fill) and any call with a
-  ``task_node_mask`` (rows that differ by task).
+  a POD.  It is the form of a call with a ``task_node_mask`` (rows that
+  differ by task, where a run's pods no longer share one feasible set)
+  and of no other.
 
 All read the same dense per-prefix pools (scatter-add of the release
 rows, running sum over the prefix axis).  The counted form could be had
@@ -63,7 +71,7 @@ import jax.numpy as jnp
 from .allocate import NEG, allocate_jobs_kernel
 from .allocate_grouped import _fill_by_score_descent, _score_keys
 from .predicates import EPS, hard_row
-from .scoring import BINPACK, score_row_selected
+from .scoring import BINPACK, score_row, score_row_selected
 
 
 def uniform_gang(task_req, task_job, task_selector, task_tolerations):
@@ -96,24 +104,19 @@ def gang_runs(task_req, task_job, task_selector, task_tolerations):
 
 
 def dispatched_form(task_req, task_job, task_selector, task_tolerations,
-                    gpu_strategy: int = BINPACK,
-                    cpu_strategy: int = BINPACK, masked: bool = False):
+                    masked: bool = False):
     """(form, dependent steps over the pools) of the call that
     ``batch_prefix_feasibility`` makes of these rows: ``counted`` 0,
-    ``grouped`` its runs, ``scanned`` the padded rows.  The host's reading
-    of what it sends, by the predicates the program applies."""
+    ``grouped`` its runs, ``scanned`` (a masked call, and no other) the
+    padded rows.  The host's reading of what it sends, by the predicates
+    the program applies; the strategies choose the run's key, never the
+    form."""
     rows = (task_req, task_job, task_selector, task_tolerations)
-    if not masked and bool(uniform_gang(*rows)):
+    if masked:
+        return "scanned", int(task_req.shape[0])
+    if bool(uniform_gang(*rows)):
         return "counted", 0
-    if not masked and _fill_plan_holds(gpu_strategy, cpu_strategy):
-        return "grouped", int(gang_runs(*rows))
-    return "scanned", int(task_req.shape[0])
-
-
-def _fill_plan_holds(gpu_strategy: int, cpu_strategy: int) -> bool:
-    """Static: the grouped kernel's fill equals the exact kernel's
-    sequence under bin-pack (spread round-robins as nodes fill)."""
-    return gpu_strategy == BINPACK and cpu_strategy == BINPACK
+    return "grouped", int(gang_runs(*rows))
 
 
 def corrected_count(quotient, req, total):
@@ -174,11 +177,13 @@ def count_prefixes(prefix_rel, node_idle, node_labels, node_taints,
 def group_prefixes(prefix_rel, node_allocatable, node_idle, node_labels,
                    node_taints, node_room, task_req, task_job,
                    task_selector, task_tolerations,
+                   gpu_strategy: int = BINPACK, cpu_strategy: int = BINPACK,
                    f32_keys: bool = False):
     """The grouped form: [K] bool from ``prefix_rel`` [K,N,R] for a gang
-    of runs of identical rows, bin-pack on both axes.  A ``while`` over
-    the runs, outside the prefix axis; ``f32_keys`` orders scores at the
-    chip's precision on any backend (``_score_keys``)."""
+    of runs of identical rows, each run landed by the key of the (static)
+    strategies.  A ``while`` over the runs, outside the prefix axis;
+    ``f32_keys`` orders scores at the chip's precision on any backend
+    (``_score_keys``)."""
     k, n, _ = prefix_rel.shape
     t = task_req.shape[0]
     dtype = node_idle.dtype
@@ -190,6 +195,9 @@ def group_prefixes(prefix_rel, node_allocatable, node_idle, node_labels,
     run_size = jax.ops.segment_sum(real.astype(dtype),
                                    jnp.cumsum(opens) - 1, num_segments=t)
     never_now = jnp.zeros(n, bool)    # pipeline-only: nothing claims idle
+    # One scored column where the axes agree, both where they differ.
+    score_of = score_row_selected if gpu_strategy == cpu_strategy \
+        else score_row
 
     def capacity_of(run, rel, room):
         row = first_row[run]
@@ -208,9 +216,9 @@ def group_prefixes(prefix_rel, node_allocatable, node_idle, node_labels,
         capacity = capacity_of(i, rel, room)
         feasible = capacity >= 1.0
         score = jax.vmap(
-            lambda fits: score_row_selected(
+            lambda fits: score_of(
                 node_allocatable, node_idle, req, fits, never_now,
-                BINPACK, BINPACK))(feasible)
+                gpu_strategy, cpu_strategy))(feasible)
         key, levels, utype = _score_keys(jnp.where(feasible, score, NEG),
                                          f32_keys)
         take = jax.vmap(
@@ -276,9 +284,9 @@ def batch_prefix_feasibility(node_allocatable, node_idle, node_releasing,
     O(victim tasks), never O(prefixes x nodes).  node_room is
     prefix-invariant (evicted pods stay on their node as Releasing).
 
-    A gang of identical pods is counted, any other stepped over run by
-    run under bin-pack and scanned pod by pod otherwise (module
-    docstring); a ``task_node_mask`` is static and goes to the scan.
+    A gang of identical pods is counted and any other stepped over run
+    by run, each run landed by the strategies' key (module docstring); a
+    ``task_node_mask`` is static and goes to the scan, pod by pod.
     """
     tasks = (task_req, task_job, task_selector, task_tolerations)
 
@@ -289,14 +297,11 @@ def batch_prefix_feasibility(node_allocatable, node_idle, node_releasing,
                                                          mode="drop")
         return node_releasing[None, :, :] + jnp.cumsum(delta, axis=0)
 
-    def scanned():
+    if task_node_mask is not None:
         return scan_prefixes(
             pools(), node_allocatable, node_idle, node_labels,
             node_taints, node_room, *tasks, task_node_mask, gpu_strategy,
             cpu_strategy)
-
-    if task_node_mask is not None:
-        return scanned()
 
     def counted():
         return count_prefixes(pools(), node_idle, node_labels,
@@ -304,11 +309,10 @@ def batch_prefix_feasibility(node_allocatable, node_idle, node_releasing,
 
     def grouped():
         return group_prefixes(pools(), node_allocatable, node_idle,
-                              node_labels, node_taints, node_room, *tasks)
+                              node_labels, node_taints, node_room, *tasks,
+                              gpu_strategy=gpu_strategy,
+                              cpu_strategy=cpu_strategy)
 
     # At the top level, outside any vmap, where a cond would turn into a
     # select and run both.
-    return jax.lax.cond(
-        uniform_gang(*tasks), counted,
-        grouped if _fill_plan_holds(gpu_strategy, cpu_strategy)
-        else scanned)
+    return jax.lax.cond(uniform_gang(*tasks), counted, grouped)

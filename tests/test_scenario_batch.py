@@ -1,10 +1,11 @@
 """The scenario prescreen's three forms (``ops/scenario_batch.py``): a
 gang of identical pods is counted in one pass over the prefix pools, a gang
 of several runs of identical pods is stepped over run by run with the
-grouped kernel's fill between two runs, any other call is scanned by the
-exact kernel, and all give the same bits where they apply.  The choice is
-made from the task rows and the static arguments, on the device and, for
-the span and the counters, on the host."""
+grouped kernel's fill between two runs, keyed by the strategies the call is
+compiled for, a call with a ``task_node_mask`` is scanned by the exact
+kernel, and all give the same bits where they apply.  The choice is made
+from the task rows and the mask, on the device and, for the span and the
+counters, on the host."""
 
 import functools
 
@@ -69,29 +70,49 @@ def counted(pool, nodes, tasks):
     return sb.count_prefixes(pool, idle, labels, taints, room, *tasks)
 
 
-@functools.partial(jax.jit, static_argnames=("masked", "strategy"))
-def scanned(pool, nodes, tasks, mask=None, masked=False, strategy=BINPACK):
+# (gpu_strategy, cpu_strategy), as the kernels take them.
+PAIRS = ((BINPACK, BINPACK), (SPREAD, SPREAD), (SPREAD, BINPACK),
+         (BINPACK, SPREAD))
+PAIR_IDS = ("binpack", "spread", "spread-gpus", "spread-cpus")
+
+
+@functools.partial(jax.jit, static_argnames=("pair",))
+def scanned(pool, nodes, tasks, mask=None, pair=PAIRS[0]):
     alloc, idle, _rel, labels, taints, room = nodes
     return sb.scan_prefixes(pool, alloc, idle, labels, taints, room, *tasks,
-                            mask if masked else None, strategy, strategy)
+                            mask, *pair)
 
 
-@jax.jit
-def grouped(pool, nodes, tasks):
-    """Keys at the chip's precision (``_score_keys`` ``force_f32``)."""
+@functools.partial(jax.jit, static_argnames=("pair", "f32_keys"))
+def grouped(pool, nodes, tasks, pair=PAIRS[0], f32_keys=True):
+    """By default, keys at the chip's precision (``_score_keys``
+    ``force_f32``)."""
     alloc, idle, _rel, labels, taints, room = nodes
     return sb.group_prefixes(pool, alloc, idle, labels, taints, room, *tasks,
-                             f32_keys=True)
+                             gpu_strategy=pair[0], cpu_strategy=pair[1],
+                             f32_keys=f32_keys)
 
 
-def whole(nodes, release, tasks, k=K, mask=None, strategy=BINPACK):
+def whole(nodes, release, tasks, k=K, mask=None, pair=PAIRS[0]):
     return np.asarray(sb.batch_prefix_feasibility(
         *nodes, *release, *tasks, num_prefixes=k, task_node_mask=mask,
-        gpu_strategy=strategy, cpu_strategy=strategy))
+        gpu_strategy=pair[0], cpu_strategy=pair[1]))
 
 
-def form_of(tasks, strategy=BINPACK, masked=False):
-    return sb.dispatched_form(*tasks, strategy, strategy, masked)
+def traced_forms(monkeypatch):
+    """The list that the three forms' kernels add their names to as
+    ``batch_prefix_feasibility`` traces them (clear its cache around the
+    call, so that it traces)."""
+    traced = []
+    for name in ("count_prefixes", "group_prefixes", "scan_prefixes"):
+        kernel = getattr(sb, name)
+        monkeypatch.setattr(sb, name, lambda *a, _n=name, _f=kernel, **kw: (
+            traced.append(_n), _f(*a, **kw))[1])
+    return traced
+
+
+def form_of(tasks, masked=False):
+    return sb.dispatched_form(*tasks, masked=masked)
 
 
 @pytest.mark.parametrize("seed", range(24))
@@ -198,8 +219,8 @@ def test_a_call_with_a_task_node_mask_is_scanned():
     mask = np.ones((4, 4), bool)
     mask[:, 2] = False
     pool = pools(nodes[2], *release, k=k)
-    want = np.asarray(scanned(pool, nodes, tasks, jnp.asarray(mask),
-                              masked=True)).tolist()
+    want = np.asarray(scanned(pool, nodes, tasks,
+                              jnp.asarray(mask))).tolist()
     assert want == [False, False, False, True]
     assert whole(nodes, release, tasks, k=k,
                  mask=jnp.asarray(mask)).tolist() == want
@@ -386,40 +407,169 @@ def test_the_first_run_can_land_where_the_second_never_could():
 
 
 @pytest.mark.parametrize("exact", ("spread", "mask"))
-def test_a_spread_strategy_and_a_mask_keep_the_exact_scan(monkeypatch,
-                                                          exact):
-    """The fill plan is bin-pack's and a run's rows share one mask row at
-    most: either way the program traced holds the exact scan and no run
-    loop, and its answer is the exact scan's under that strategy."""
-    traced = []
-    for name in ("group_prefixes", "scan_prefixes"):
-        form = getattr(sb, name)
-        monkeypatch.setattr(sb, name, lambda *a, _n=name, _f=form, **kw: (
-            traced.append(_n), _f(*a, **kw))[1])
-    nodes, release, tasks, _ = mixed_gang(5)
+def test_a_mask_keeps_the_exact_scan_and_a_spread_strategy_does_not(
+        monkeypatch, exact):
+    """A run's rows share one mask row at most, so a masked call traces
+    the exact scan and no run loop; a spread strategy (scanned until PR 43)
+    traces the run loop and no scan.  Either way the answer is the exact
+    scan's under that strategy."""
+    traced = traced_forms(monkeypatch)
+    nodes, release, tasks, pattern = mixed_gang(5)
     pool = pools(nodes[2], *release)
     sb.batch_prefix_feasibility.clear_cache()
     try:
         if exact == "spread":
-            got = whole(nodes, release, tasks, strategy=SPREAD)
-            assert traced == ["scan_prefixes"]
-            want = scanned(pool, nodes, tasks, strategy=SPREAD)
-            assert form_of(tasks, SPREAD) == ("scanned", len(tasks[0]))
+            got = whole(nodes, release, tasks, pair=(SPREAD, SPREAD))
+            assert sorted(traced) == ["count_prefixes", "group_prefixes"]
+            want = scanned(pool, nodes, tasks, pair=(SPREAD, SPREAD))
+            assert form_of(tasks) == ("grouped", len(pattern))
         else:
             mask = np.ones((len(tasks[0]), N), bool)
             mask[:, ::3] = False
             got = whole(nodes, release, tasks, mask=jnp.asarray(mask))
             assert traced == ["scan_prefixes"]
-            want = scanned(pool, nodes, tasks, jnp.asarray(mask),
-                           masked=True)
+            want = scanned(pool, nodes, tasks, jnp.asarray(mask))
             assert form_of(tasks, masked=True) == ("scanned",
                                                    len(tasks[0]))
         assert got.tolist() == np.asarray(want).tolist()
         traced.clear()
         whole(nodes, release, tasks)
-        assert traced == ["group_prefixes"]
+        assert sorted(traced) == ["count_prefixes", "group_prefixes"]
     finally:
         sb.batch_prefix_feasibility.clear_cache()
+
+
+# -- the run loop under each pair of strategies (PR 43) ----------------------
+def other(pair):
+    """The pair that keys every run by the strategy ``pair`` does not."""
+    return tuple(SPREAD + BINPACK - axis for axis in pair)
+
+
+def strategy_fleet(seed: int):
+    """``mixed_gang(seed)`` (score ties, nodes of no GPU, nodes of no room,
+    pods of no GPU beside pods of some) on nodes of three sizes, so that
+    spread's free share orders them otherwise than bin-pack's free amount
+    does.  Every share is a ratio of small whole numbers: two that differ
+    differ in f32 too."""
+    (alloc, *state), release, tasks, pattern = mixed_gang(seed)
+    size = np.random.default_rng(5000 + seed).choice([4.0, 8.0, 16.0], N)
+    alloc = np.where(alloc > 0, size[:, None] * POD, 0.0)
+    return (alloc, *state), release, tasks, pattern
+
+
+STRATEGY_SEEDS = range(24)
+
+
+@pytest.mark.parametrize("f32_keys", (False, True), ids=("f64", "f32"))
+@pytest.mark.parametrize("pair", PAIRS, ids=PAIR_IDS)
+@pytest.mark.parametrize("seed", STRATEGY_SEEDS)
+def test_grouped_equals_scanned_under_each_pair_of_strategies(seed, pair,
+                                                              f32_keys):
+    """A pipeline-only attempt never writes idle, so a run of identical
+    pods lands by its first score under either strategy: the run loop keyed
+    by the call's strategies answers as the exact scan under them does."""
+    nodes, release, tasks, pattern = strategy_fleet(seed)
+    pool = pools(nodes[2], *release)
+    want = np.asarray(scanned(pool, nodes, tasks, pair=pair)).tolist()
+    assert np.asarray(grouped(pool, nodes, tasks, pair,
+                                 f32_keys)).tolist() == want
+    assert form_of(tasks) == ("grouped", len(pattern))
+    if f32_keys:
+        assert whole(nodes, release, tasks, pair=pair).tolist() == want
+
+
+@pytest.mark.parametrize("pair", PAIRS, ids=PAIR_IDS)
+def test_the_strategy_fleets_tell_the_pairs_apart(pair):
+    """No vacuous agreement under any pair: both bits occur, a run's
+    landing decides a later run's fit (counting the runs alone answers
+    otherwise), landing by the other strategy's key answers otherwise, and
+    so does changing the strategy of either axis alone."""
+    bits, unlike, by_other, by_axis = [], 0, 0, [0, 0]
+    for seed in STRATEGY_SEEDS:
+        nodes, release, tasks, _ = strategy_fleet(seed)
+        pool = pools(nodes[2], *release)
+        got = np.asarray(grouped(pool, nodes, tasks, pair))
+        bits += got.tolist()
+        alone = runs_alone(pool, nodes, tasks)
+        assert not (got & ~alone).any()
+        unlike += int((alone != got).sum())
+        by_other += int((np.asarray(
+            grouped(pool, nodes, tasks, other(pair))) != got).sum())
+        for axis in (0, 1):
+            one = tuple(other(pair)[a] if a == axis else pair[a]
+                        for a in (0, 1))
+            by_axis[axis] += int((np.asarray(
+                grouped(pool, nodes, tasks, one)) != got).sum())
+    assert 0.15 < np.mean(bits) < 0.85
+    assert unlike >= 10
+    assert by_other >= 5 and min(by_axis) >= 2
+
+
+def two_sized_nodes(axis: str):
+    """``two_nodes([1, 0], [1, 1], 1)`` with node 1 half the size, asked by
+    a gang of GPU pods (``axis`` "gpu") or of pods of no GPU, the same
+    numbers in cpu ("cpu"): a worker of one unit, then a master of two.
+    Bin-pack sends the worker to node 1, the fuller, and the master has
+    node 0's two units: [True, True].  Spread sends it to node 0, the
+    freer share, and each node is left one unit until prefix 1 releases a
+    second on node 1: [False, True]."""
+    unit = np.array([1000.0, 2.0 ** 30, 1.0])
+    ask = unit * (1.0 if axis == "gpu" else np.array([1.0, 1.0, 0.0]))
+    none = np.full((2, 1), -1, np.int32)
+    nodes = (np.outer([8.0, 4.0], unit), np.outer([1.0, 0.0], unit),
+             np.outer([1.0, 1.0], unit), none, none, np.full(2, 2.0))
+    release = (np.array([1], np.int32), np.array([1], np.int32), unit[None])
+    task_req = np.array([ask, 2 * ask, 0 * ask, 0 * ask])
+    task_job = np.array([0, 0, 1, 1], np.int32)
+    none = np.full((4, 1), -1, np.int32)
+    return nodes, release, (task_req, task_job, none, none)
+
+
+@pytest.mark.parametrize("pair", PAIRS, ids=PAIR_IDS)
+def test_landing_by_the_other_strategys_key_flips_a_bit(pair):
+    """The control of the comparison above, an axis at a time: on these
+    two nodes the exact scan's verdict under ``pair`` is the run loop's
+    under ``pair`` and is NOT the run loop's under the other key, for the
+    gang that the GPU strategy scores and for the one the CPU's does."""
+    by_strategy = {BINPACK: [True, True], SPREAD: [False, True]}
+    for axis, strategy in zip(("gpu", "cpu"), pair):
+        nodes, release, tasks = two_sized_nodes(axis)
+        pool = pools(nodes[2], *release, k=2)
+        want = np.asarray(scanned(pool, nodes, tasks, pair=pair)).tolist()
+        assert want == by_strategy[strategy]
+        for f32_keys in (False, True):
+            assert np.asarray(grouped(pool, nodes, tasks, pair,
+                                         f32_keys)).tolist() == want
+            wrong = np.asarray(grouped(pool, nodes, tasks, other(pair),
+                                          f32_keys)).tolist()
+            assert wrong == by_strategy[other(pair)[axis == "cpu"]] != want
+        assert whole(nodes, release, tasks, k=2, pair=pair).tolist() == want
+
+
+@pytest.mark.parametrize("pair", PAIRS, ids=PAIR_IDS)
+@pytest.mark.parametrize("rows", ("uniform", "mixed", "no-pods"))
+def test_no_rows_are_scanned_without_a_mask(monkeypatch, rows, pair):
+    """``scanned`` is the mask's form alone: whatever the rows, the host
+    reads another form of an unmasked call, and the program traced for it
+    under any pair of strategies holds no exact scan."""
+    nodes, release, tasks = fleet(3) if rows == "uniform" \
+        else mixed_gang(3)[:3]
+    if rows == "no-pods":
+        tasks = (tasks[0], np.ones_like(tasks[1]), *tasks[2:])
+    form, steps = form_of(tasks)
+    # No pod differs from row 0 where there is none.
+    assert form == {"uniform": "counted", "mixed": "grouped",
+                    "no-pods": "counted"}[rows]
+    assert steps == int(sb.gang_runs(*map(jnp.asarray, tasks))) \
+        * (form == "grouped")
+    assert form_of(tasks, masked=True) == ("scanned", len(tasks[0]))
+    traced = traced_forms(monkeypatch)
+    sb.batch_prefix_feasibility.clear_cache()
+    try:
+        whole(nodes, release, tasks, pair=pair)
+    finally:
+        sb.batch_prefix_feasibility.clear_cache()
+    assert sorted(traced) == ["count_prefixes", "group_prefixes"]
 
 
 def defrag_fleet(seed: int):
@@ -515,7 +665,7 @@ SPREAD_GPUS = SchedulerConfig(gpu_placement_strategy="spread")
     ("grouped", [{"gpu": 3}, {"gpu": 1}], None),
     ("grouped", [{"gpu": 2, "cpu": "2"}, {"gpu": 2, "cpu": "1"}], None),
     ("grouped", [{"gpu": 1}, {"gpu": 2}, {"gpu": 1}], None),
-    ("scanned", [{"gpu": 3}, {"gpu": 1}], SPREAD_GPUS),
+    ("grouped", [{"gpu": 3}, {"gpu": 1}], SPREAD_GPUS),
     ("counted", [{"gpu": 1}] * 3, SPREAD_GPUS),
 ], ids=("one-pod", "three-alike-and-a-pad", "master-and-worker",
         "cpu-differs", "master-between-workers", "spread-gpus",
@@ -558,5 +708,4 @@ def test_host_and_device_name_the_same_form(monkeypatch, form,
     assert ("runs" in span.attrs) == (form == "grouped")
     if form == "grouped":
         assert span.attrs["runs"] == runs > 1
-    assert steps_moved == {"counted": 0, "grouped": runs,
-                           "scanned": span.attrs["t_pad"]}[form]
+    assert steps_moved == {"counted": 0, "grouped": runs}[form]

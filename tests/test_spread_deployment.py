@@ -8,9 +8,11 @@ plain reference the chip's ``correct`` uses
 the program) finds all twelve numbers 0: the reclaim cell's eleven, and
 every pod on the node upstream's spread order gives it.  The same run
 under bin-pack settings is told apart by that twelfth number alone.  And
-what the program says of the batched forms a spread strategy declines: the
-counter family ``batched_form_declined_total{form, reason}`` and the
-``strategy`` attribute (docs/OBSERVABILITY.md "Span model")."""
+what the program says of the batched forms a spread strategy declines (the
+fill and the wave, which claim idle) and of the one it does not (the
+prescreen's run loop, since PR 43): the counter family
+``batched_form_declined_total{form, reason}`` and the ``strategy``
+attribute (docs/OBSERVABILITY.md "Span model")."""
 
 import os
 import types
@@ -25,6 +27,7 @@ from kai_scheduler_tpu.ops.allocate import allocate_jobs_kernel
 from kai_scheduler_tpu.ops.scoring import BINPACK, SPREAD
 from kai_scheduler_tpu.utils.metrics import METRICS, _key
 from kai_scheduler_tpu.utils.tracing import TRACER
+from tests.test_scenario_batch import traced_forms
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(ROOT, "benchmark")
@@ -167,34 +170,28 @@ def test_the_placements_are_the_references_pod_by_pod(driven):
     assert checked == 4
 
 
-def test_the_mixed_gang_is_scanned_under_spread_and_grouped_under_binpack(
+def test_the_mixed_gang_is_grouped_under_spread_and_under_binpack(
         driven, monkeypatch):
     """``dispatched_form`` of the rows the solver sent, by the host; and
     the branch the program traces for the same rows and strategies, by
-    the device: one form on both sides."""
+    the device: one form on both sides, the run loop under either strategy
+    (under spread the exact scan, until PR 43)."""
     strategy = STRATEGIES[driven.strategy]
     gang = WIDTHS[driven.nodes]["gang"]
     call = driven.sent[-1]
     rows = call.operands[3:]
     t_pad = len(rows[0])
     assert t_pad >= gang and t_pad & (t_pad - 1) == 0
-    want = ("scanned", t_pad) if driven.strategy == "spread" \
-        else ("grouped", 2)
-    assert sb.dispatched_form(*rows, strategy, strategy) == want
+    assert sb.dispatched_form(*rows) == ("grouped", 2)
     assert call.static["gpu_strategy"] == call.static["cpu_strategy"] \
         == strategy
-    # A mask sends any rows to the scan, whatever the strategy.
-    assert sb.dispatched_form(*rows, strategy, strategy, masked=True) == (
-        "scanned", t_pad)
+    # A mask sends any rows to the scan, and nothing else does.
+    assert sb.dispatched_form(*rows, masked=True) == ("scanned", t_pad)
     (span,) = [s for s in driven.trace.spans if s.name == "solve:prescreen"]
-    assert span.attrs["form"] == want[0]
+    assert span.attrs["form"] == "grouped"
     assert span.attrs["strategy"] == driven.strategy
-    assert span.attrs.get("runs") == (None if want[0] == "scanned" else 2)
-    traced = []
-    for name in ("group_prefixes", "scan_prefixes", "count_prefixes"):
-        form = getattr(sb, name)
-        monkeypatch.setattr(sb, name, lambda *a, _n=name, _f=form, **kw: (
-            traced.append(_n), _f(*a, **kw))[1])
+    assert span.attrs["runs"] == 2
+    traced = traced_forms(monkeypatch)
     sb.batch_prefix_feasibility.clear_cache()
     try:
         again = sb.batch_prefix_feasibility(
@@ -203,10 +200,9 @@ def test_the_mixed_gang_is_scanned_under_spread_and_grouped_under_binpack(
             gpu_strategy=strategy, cpu_strategy=strategy)
     finally:
         sb.batch_prefix_feasibility.clear_cache()
-    # The cond holds the counted branch and the one the strategies leave.
-    assert sorted(traced) == sorted(
-        ["count_prefixes", "scan_prefixes" if want[0] == "scanned"
-         else "group_prefixes"])
+    # The cond holds the counted branch and the run loop, keyed by the
+    # call's strategies.
+    assert sorted(traced) == ["count_prefixes", "group_prefixes"]
     assert np.asarray(again).tolist() == call.verdict.tolist()
 
 
@@ -240,23 +236,37 @@ def test_the_verdict_bits_equal_as_many_sequential_simulations(driven):
 
 def test_the_family_counts_once_a_call_by_form_and_reason(driven):
     """A spread shard declines the grouped fill twice a cycle (the bind,
-    and the attempt that finds the fleet full) and the prescreen's run
-    loop once, by its strategy; under bin-pack the same two calls decline
-    the fill by their rows (a master beside its workers) and the run loop
-    answers.  Never once a task, and every series is there from the first
-    session on."""
+    and the attempt that finds the fleet full), by its strategy; under
+    bin-pack the same two calls decline the fill by their rows (a master
+    beside its workers).  The prescreen's run loop answers under both, in
+    two steps (since PR 43; a spread shard declined it once a cycle and
+    took a step a padded row).  Never once a task, and every series is
+    there from the first session on."""
     spread = driven.strategy == "spread"
-    t_pad = len(driven.sent[-1].operands[3])
     for rec in driven.client.records[1:]:
         want = {declined(*pair): 0 for pair in propose.DECLINES}
         want[declined("grouped_fill", "strategy" if spread else "rows")] = 2
-        want[declined("prescreen_runs", "strategy")] = int(spread)
-        want["scenario_prescreen_scan_steps_total"] = t_pad if spread else 2
+        want["scenario_prescreen_scan_steps_total"] = 2
         want["device_kernel_calls"] = 5
         assert rec.counters == want
     first = driven.client.records[0].counters
     assert set(first) == set(COUNTERS)
     assert first[declined("wave", "strategy")] == 0
+
+
+def test_the_prescreens_series_is_registered_at_0_and_a_solve_moves_it_not(
+        driven):
+    """``prescreen_runs``/``strategy`` stays in the family for the
+    benchmark's ``strategy_declines``, which reads it in every cell: there
+    from the first session on, at 0, and 0 after every cycle's solve under
+    either strategy, each of which asked the prescreen."""
+    key = declined("prescreen_runs", "strategy")
+    assert ("prescreen_runs", "strategy") in propose.DECLINES
+    assert [rec.counters[key] for rec in driven.client.records] == [0] * 5
+    assert len(driven.sent) == 5                 # a prescreen a cycle
+    before = METRICS.counters[key]
+    propose.register_declines()
+    assert METRICS.counters[key] == before
 
 
 def test_the_spans_say_the_strategy(driven):
@@ -278,15 +288,27 @@ def test_the_strategy_attribute_names_both_axes(gpu, cpu, name):
     ssn = types.SimpleNamespace(gpu_strategy=STRATEGIES[gpu],
                                 cpu_strategy=STRATEGIES[cpu])
     assert propose.strategy_name(ssn) == name
-    # A master beside three workers: a spread strategy on either axis
-    # sends the rows to the scan.
+    # A master beside three workers: two runs under any pair, which
+    # chooses the key each run lands by and never the form.
     req = np.tile([4000.0, 2.0 ** 35, 1.0], (4, 1))
     req[0] *= 2
     rows = (req, np.zeros(4, np.int32), np.full((4, 1), -1, np.int32),
             np.full((4, 1), -1, np.int32))
-    assert sb.dispatched_form(
-        *rows, ssn.gpu_strategy, ssn.cpu_strategy) == (
-        ("grouped", 2) if name == "binpack" else ("scanned", 4))
+    assert sb.dispatched_form(*rows) == ("grouped", 2)
+    n = 6
+    none = np.full((n, 1), -1, np.int32)
+    pool = np.tile(2 * req[1], (2, n, 1))
+    pool[0, 1:] = 0.0                  # prefix 0: one node, of two workers
+    fleet = tuple(map(jnp.asarray, (np.tile(8 * req[1], (n, 1)),
+                                    np.zeros((n, 3)), none, none,
+                                    np.full(n, 4.0), *rows)))
+    strategies = (ssn.gpu_strategy, ssn.cpu_strategy)
+    want = sb.scan_prefixes(jnp.asarray(pool), *fleet, None, *strategies)
+    assert np.asarray(want).tolist() == [False, True]
+    got = sb.group_prefixes(
+        jnp.asarray(pool), *fleet, gpu_strategy=strategies[0],
+        cpu_strategy=strategies[1])
+    assert np.asarray(got).tolist() == [False, True]
 
 
 def test_the_wave_declines_a_spread_shard_once_a_call():
