@@ -59,10 +59,17 @@ class ConsolidationAction:
             # inventory is per-profile and host-checked in simulation.
             total_req = np.sum([t.res_req.to_vec(mig_as_gpu=False)
                                 for t in tasks], axis=0) if tasks else None
+            headroom = np.zeros(ssn.node_idle.shape[1])
+            headroom[rs.RES_GPU] = fractional_headroom(ssn)
             total_free = ssn.node_idle.sum(axis=0) \
-                + ssn.node_releasing.sum(axis=0)
-            total_free[rs.RES_GPU] += fractional_headroom(ssn)
-            if total_req is None or np.any(total_req > total_free + 1e-9):
+                + ssn.node_releasing.sum(axis=0) + headroom
+            # The same bound on the nodes the job's static constraints
+            # admit (a selector, a required node affinity, taints): what
+            # is idle on a node it may not use seats nothing, and a pod
+            # pinned inside those nodes frees nothing there by moving.
+            if total_req is None or np.any(total_req > total_free + 1e-9) \
+                    or not ssn.relocation_can_seat(job, tasks,
+                                                   total_req - headroom):
                 if ssn.config.use_scheduling_signatures:
                     failed_signatures.add(sig)
                 order.requeue_queue(job.queue_id)

@@ -60,8 +60,10 @@ class ReclaimAction:
                         sv.set(victims=len(survey))
                 victims = [pg for pg in survey
                            if pg.queue_id != job.queue_id]
+                surveyed = len(victims)
                 victims = ssn.filter_reclaim_victims(job, victims)
-                sp.set(victims=len(victims), success=False)
+                sp.set(victims=len(victims),
+                       filtered=surveyed - len(victims), success=False)
                 result = None
                 if victims:
                     result = solve_job(ssn, job, victims,

@@ -263,13 +263,19 @@ def _prefix_prescreen(ssn, tasks, builder: "ScenarioBuilder"):
     or None when the pending job needs state the batch cannot model.
 
     Soundness: a False must mean the sequential simulation would also
-    fail.  That holds only when the pending job's feasibility depends
-    solely on capacity (evictions can then only ADD releasing capacity):
-    host-state tasks (fractional/MIG/DRA) and any hard-mask / in-gang
-    domain contribution disqualify, because eviction order could change
-    those (conservatively: masks only relax after evictions, but a
-    current-state mask may be stricter than a post-eviction one — we must
-    not over-prune).
+    fail.  That holds when the pending job's feasibility depends on
+    capacity (evictions can then only ADD releasing capacity) and on
+    nothing else that an eviction changes.  Host-state tasks
+    (fractional/MIG/DRA) disqualify.  So does a STATE-DEPENDENT hard mask
+    (host ports in use, bound PVCs, storage, inter-pod terms) and any
+    in-gang domain row: such a mask only relaxes as victims leave, so the
+    current state's may be stricter than the one the simulation would
+    meet, and the batch must not over-prune.  A STATIC mask (required
+    node affinity: node labels and names alone, ``Session
+    .compute_static_mask``) does not disqualify: the sequential
+    simulation applies the same rows to the same nodes and no eviction
+    changes a label, so it goes to the kernel as ``task_node_mask`` and
+    the verdict stays exact.
     """
     with TRACER.span("solve:prescreen", kind="solver") as sp:
         verdict = _prescreen_verdict(ssn, tasks, builder, sp)
@@ -305,7 +311,7 @@ def _prescreen_verdict(ssn, tasks, builder: "ScenarioBuilder", sp):
     # unsoundly skip feasible prefixes.
     if any(t.is_fractional for _v, vtasks in steps for t in vtasks):
         return declined("fractional-victim")
-    if ssn.compute_hard_mask(tasks) is not None:
+    if ssn.compute_state_mask(tasks) is not None:
         return declined("hard-mask")
     for fn in ssn.anti_domain_fns + ssn.affinity_domain_fns:
         if fn(tasks) is not None:
@@ -344,13 +350,18 @@ def _prescreen_verdict(ssn, tasks, builder: "ScenarioBuilder", sp):
         ssn, [(builder.scenario.pending_job, tasks)])
     if rows is None:
         return declined("no-task-rows")
-    # The kernel picks its form from these rows by the same predicates.
-    # No mask reaches this call (the hard-mask gate above), so the form is
-    # never ``scanned`` here, whatever the strategies.
+    # A static mask (the state-dependent ones declined above) goes with
+    # the rows, all-true for the padding tasks, and makes the call the
+    # ``scanned`` form; otherwise the kernel picks its form from the rows
+    # by the same predicates, whatever the strategies.
+    mask = propose._pad_rows(ssn.compute_static_mask(tasks), rows.t_pad,
+                             True)
     form, scan_steps = dispatched_form(
-        rows.task_req, rows.task_job, rows.task_sel, rows.task_tol)
+        rows.task_req, rows.task_job, rows.task_sel, rows.task_tol,
+        masked=mask is not None)
     sp.set(prefixes=num_prefixes, steps=len(steps), rows=m_pad,
-           t_pad=int(rows.task_req.shape[0]), form=form,
+           t_pad=rows.t_pad, form=form,
+           mask="none" if mask is None else "static",
            strategy=propose.strategy_name(ssn))
     if form == "grouped":
         sp.set(runs=scan_steps)
@@ -362,6 +373,7 @@ def _prescreen_verdict(ssn, tasks, builder: "ScenarioBuilder", sp):
     # rows counts none, one whose gangs are all alike takes no step.
     METRICS.inc("scenario_prescreen_counted_total", int(form == "counted"))
     METRICS.inc("scenario_prescreen_scan_steps_total", scan_steps)
+    METRICS.inc("scenario_prescreen_masked_total", int(mask is not None))
     try:
         feasible = propose.run_on_nodes(
             ssn, batch_prefix_feasibility,
@@ -369,6 +381,10 @@ def _prescreen_verdict(ssn, tasks, builder: "ScenarioBuilder", sp):
              rows.task_job, rows.task_sel, rows.task_tol),
             label="scenario_prescreen",
             validate=lambda r: getattr(r, "shape", (0,))[0] >= len(steps),
+            # By name only where there is one: a call that names a None
+            # is another program to jit than the one that leaves it out,
+            # which is how the unmasked cells prime theirs.
+            named=None if mask is None else {"task_node_mask": mask},
             num_prefixes=num_prefixes, gpu_strategy=ssn.gpu_strategy,
             cpu_strategy=ssn.cpu_strategy)
     except CycleDeadlineExceeded:

@@ -467,6 +467,9 @@ def propose(ssn, chunks, kind: str, pipeline_only: bool,
         program, label = choose_program(ssn, kind, fill_rows, domain_rows,
                                         pipeline_only, extras=bool(ndims))
         operands_span.set(path="multi" if kind == "multi" else program)
+        if mask is not None and program != "grouped":
+            # What the [t_pad,N] bool mask uploads (the fill takes a row).
+            operands_span.set(mask_bytes=t_pad * n_nodes)
 
         task_rows = (rows.task_req, rows.task_job, rows.task_sel,
                      rows.task_tol, rows.job_allowed)
@@ -549,14 +552,19 @@ def place_wave(ssn, chunks, job_allowed):
     return _proposals(ssn, chunks, *answer, reorder=True)
 
 
-def run_on_nodes(ssn, kernel, operands, label: str, validate, **static):
-    """``kernel(*node arrays, *operands, **static)`` through the guard,
-    the host operands crossing at ``_stage`` like every other kernel's
+def run_on_nodes(ssn, kernel, operands, label: str, validate, named=None,
+                 **static):
+    """``kernel(*node arrays, *operands, **named, **static)`` through the
+    guard, the host operands (``named``: those the kernel takes by name,
+    None stays None) crossing at ``_stage`` like every other kernel's
     (the scenario prescreen's call, actions/solvers.py)."""
     node_arrays = ssn._device_arrays()
-    return ssn.dispatch_kernel(
-        lambda: kernel(*node_arrays, *_stage(*operands), **static),
-        label=label, validate=validate)
+
+    def thunk():
+        *positional, by_name = _stage(*operands, named or {})
+        return kernel(*node_arrays, *positional, **by_name, **static)
+
+    return ssn.dispatch_kernel(thunk, label=label, validate=validate)
 
 
 def score_nodes(ssn, task) -> np.ndarray:

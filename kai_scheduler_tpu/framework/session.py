@@ -72,6 +72,17 @@ class InMemoryCache:
         self.events.append((kind, message))
 
 
+def _and_masks(fns, tasks) -> "np.ndarray | None":
+    """AND of the [T,N] bool contributions of ``fns`` for ``tasks``, None
+    where none of them constrains anything."""
+    mask = None
+    for fn in fns:
+        contrib = fn(tasks)
+        if contrib is not None:
+            mask = contrib if mask is None else (mask & contrib)
+    return mask
+
+
 def _unpack_allocation(result, t: int):
     """(placed [t], piped [t], success [J]) from an AllocationResult.
 
@@ -151,6 +162,10 @@ class Session:
         self.preempt_scenario_validators: list[Callable] = []
         self.reclaim_victim_filters: list[Callable] = []
         self.preempt_victim_filters: list[Callable] = []
+        # ``fn(job, tasks) -> [R] | None``: the most that relocating
+        # running pods can leave free on the nodes the tasks' static
+        # constraints admit (the consolidation action's bound).
+        self.relocation_bound_fns: list[Callable] = []
         self.allocate_handlers: list[Callable] = []
         self.deallocate_handlers: list[Callable] = []
         self.subset_nodes_fns: list[Callable] = []
@@ -161,8 +176,12 @@ class Session:
         # the topology plugin; consulted only on paths that proved the
         # chunk homogeneous (grouped fast path, bulk action).
         self.rank_assign_fns: list[Callable] = []
-        # Hard [T,N] feasibility contributions (podaffinity terms,
-        # upstream predicates) and self-anti-affinity domain rows.
+        # Hard [T,N] feasibility contributions and self-anti-affinity
+        # domain rows.  ``static_node_mask_fns``: what depends on node
+        # labels and names alone (required node affinity), which no
+        # eviction changes; ``hard_node_mask_fns``: what depends on what
+        # runs where (host ports, bound PVCs, storage, inter-pod terms).
+        self.static_node_mask_fns: list[Callable] = []
         self.hard_node_mask_fns: list[Callable] = []
         self.anti_domain_fns: list[Callable] = []
         self.affinity_domain_fns: list[Callable] = []
@@ -570,16 +589,22 @@ class Session:
         return self._term_carriers
 
     def compute_hard_mask(self, tasks) -> "np.ndarray | None":
-        """AND of every hard_node_mask_fns contribution: [T,N] bool or
-        None when unconstrained.  Host-side allocation paths (fractional,
-        MIG, DRA) consult this too — the kernel and host paths must agree
-        on feasibility."""
-        mask = None
-        for fn in self.hard_node_mask_fns:
-            contrib = fn(tasks)
-            if contrib is not None:
-                mask = contrib if mask is None else (mask & contrib)
-        return mask
+        """AND of every static and state-dependent hard-mask
+        contribution: [T,N] bool or None when unconstrained.  Host-side
+        allocation paths (fractional, MIG, DRA) consult this too — the
+        kernel and host paths must agree on feasibility."""
+        return _and_masks(
+            self.static_node_mask_fns + self.hard_node_mask_fns, tasks)
+
+    def compute_static_mask(self, tasks) -> "np.ndarray | None":
+        """The part of ``compute_hard_mask`` that node labels and names
+        alone decide: the same [T,N] before and after any eviction."""
+        return _and_masks(self.static_node_mask_fns, tasks)
+
+    def compute_state_mask(self, tasks) -> "np.ndarray | None":
+        """The part of ``compute_hard_mask`` that depends on what runs
+        where, which an eviction may relax."""
+        return _and_masks(self.hard_node_mask_fns, tasks)
 
     def check_pre_predicates(self, tasks) -> SchedulableResult:
         """Run cluster-level PreFilter predicates over a job's tasks
@@ -611,6 +636,15 @@ class Session:
         for fn in self.reclaim_victim_filters:
             victims = fn(reclaimer, victims)
         return victims
+
+    def relocation_can_seat(self, job, tasks, total_req) -> bool:
+        """False where some registered bound shows that no relocation can
+        free ``total_req`` [R] on the nodes ``tasks`` may use."""
+        for fn in self.relocation_bound_fns:
+            bound = fn(job, tasks)
+            if bound is not None and np.any(total_req > bound + 1e-9):
+                return False
+        return True
 
     def filter_preempt_victims(self, preemptor, victims) -> list:
         for fn in self.preempt_victim_filters:

@@ -177,7 +177,8 @@ def test_the_span_tree_under_the_consolidation_action(driven):
     assert prescreen.attrs == {
         "prefixes": cut["victims"], "steps": scored,
         "rows": 2 * cut["victims"],
-        "t_pad": gang, "form": "grouped", "strategy": "binpack", "runs": 2,
+        "t_pad": gang, "form": "grouped", "mask": "none",
+        "strategy": "binpack", "runs": 2,
         "feasible": scored - (gang - 2), "first_feasible": gang - 2}
     dispatch = only(children(trace, prescreen),
                     "dispatch:scenario_prescreen")

@@ -228,6 +228,66 @@ def test_a_call_with_a_task_node_mask_is_scanned():
         == [False, False, True, True]
 
 
+def counted_under_a_row(nodes, release, tasks, row):
+    """[K] bool in numpy for a gang of identical pods that all carry the
+    same mask ``row`` [N] (a required node affinity of one template): the
+    count's closed form among the nodes the row admits, with the label
+    and taint compare of ``hard_row`` spelled out."""
+    _alloc, idle, rel, labels, taints, room = nodes
+    task_req, task_job, task_sel, task_tol = tasks
+    need = int((task_job == 0).sum())
+    req, sel, tol = task_req[0], task_sel[0], task_tol[0]
+    tolerated = (taints[:, :, None] == tol[None, None, :]).any(axis=-1)
+    hard = (np.all((sel == -1) | (sel == labels), axis=1)
+            & np.all((taints == -1) | tolerated, axis=1) & (room >= 1) & row)
+    out = []
+    for pool in pools(rel, *release):
+        seats = np.floor(room)
+        for res in np.flatnonzero(req > 0):
+            seats = np.minimum(seats, np.floor(
+                (idle[:, res] + pool[:, res] + 1e-9) / req[res]))
+        out.append(need > 0 and float(
+            np.minimum(np.where(hard, seats, 0), need).sum()) >= need)
+    return out
+
+
+@pytest.mark.parametrize("pair", PAIRS[:2], ids=PAIR_IDS[:2])
+@pytest.mark.parametrize("seed", range(24))
+def test_a_masked_gang_is_the_count_among_the_admitted_nodes(seed, pair):
+    """The scanned form under one static row for every pod (PR 44: what
+    the solver sends for a gang with a required node affinity), on fleets
+    whose label and taint tables are not empty: the verdict is the numpy
+    count among the admitted nodes, under either strategy, and the host
+    labels the call ``scanned`` with ``t_pad`` steps."""
+    nodes, release, tasks = fleet(seed)
+    rng = np.random.default_rng([seed, 44])
+    row = rng.random(N) < 0.6
+    t_pad = len(tasks[0])
+    mask = np.ones((t_pad, N), bool)
+    mask[tasks[1] == 0] = row
+    want = counted_under_a_row(nodes, release, tasks, row)
+    got = whole(nodes, release, tasks, mask=jnp.asarray(mask), pair=pair)
+    assert got.tolist() == want
+    assert form_of(tasks, masked=True) == ("scanned", t_pad)
+    # An all-true mask is the unmasked count.
+    assert whole(nodes, release, tasks, pair=pair).tolist() \
+        == counted_under_a_row(nodes, release, tasks, np.ones(N, bool))
+
+
+def test_the_masked_fleets_hold_both_answers_and_the_mask_matters():
+    flipped = fits = fails = 0
+    for seed in range(24):
+        nodes, release, tasks = fleet(seed)
+        row = np.random.default_rng([seed, 44]).random(N) < 0.6
+        masked = counted_under_a_row(nodes, release, tasks, row)
+        plain = counted_under_a_row(nodes, release, tasks,
+                                    np.ones(N, bool))
+        fits += sum(masked)
+        fails += len(masked) - sum(masked)
+        flipped += sum(a != b for a, b in zip(masked, plain))
+    assert fits > 20 and fails > 20 and flipped > 10
+
+
 PATTERNS = {2: ("MW", "WM", "WM"), 3: ("MWX", "WMW", "WXM"),
             4: ("MWXW", "WMXW", "WXWM")}
 DIFFERS = ("request", "selector", "tolerations", "any")
