@@ -811,18 +811,18 @@ def stage_b_fairshare(n_queues=10000, bands=1) -> dict:
 
 
 def stage_b_prescreen(quotients=512) -> dict:
-    """The scenario prescreen's three forms on exact-integer cpu quotients.
+    """The scenario prescreen's two forms on exact-integer cpu quotients.
 
     TPU f32 division hands ``floor(k * 4000 / 4000)`` back one low for
-    some k (ROADMAP D12), and the counted form divides.  Prefix k leaves
-    room for exactly k pods on node k and ``quotients - k`` on node 0, so
-    a gang of ``quotients`` fits every prefix only if both counts are
-    exact, and one pod more fits none only if neither is high.  The same
-    rows under a mask that refuses no node go to the exact scan, which
-    divides nowhere; with one pod tolerating a taint no node carries they
-    are a gang of two runs, which the kernel steps over with the count's
-    capacity and the grouped fill between them: the forms must agree bit
-    for bit."""
+    some k (ROADMAP D12), and both forms divide.  Prefix k leaves room
+    for exactly k pods on node k and ``quotients - k`` on node 0, so a
+    gang of ``quotients`` fits every prefix only if both counts are
+    exact, and one pod more fits none only if neither is high.  With one
+    pod tolerating a taint no node carries the rows are a gang of two
+    runs, which the kernel steps over with the count's capacity and the
+    grouped fill between them; under a static mask that refuses no node
+    either gang keeps its form (the mask row is one more row set of the
+    same predicates): all four must give that answer bit for bit."""
     import numpy as np
 
     from kai_scheduler_tpu.ops.scenario_batch import (
@@ -850,15 +850,15 @@ def stage_b_prescreen(quotients=512) -> dict:
     release_vec[:len(amount), 0] = amount
     t_pad = 1 << int(q).bit_length()         # room for quotients + 1 pods
 
-    def verdict(gang: int, form: str):
+    def verdict(gang: int, form: str, masked: bool = False):
         job = np.where(np.arange(t_pad) < gang, 0, 1).astype(np.int32)
         req = np.where((job == 0)[:, None], [cpu, 0.0, 0.0], 0.0)
         sel = np.full((t_pad, 1), -1, np.int32)
         tol = np.full((t_pad, 1), -1, np.int32)
         if form == "grouped":
             tol[0, 0] = 5
-        mask = np.ones((t_pad, n), bool) if form == "scanned" else None
-        reads = dispatched_form(req, job, sel, tol, masked=mask is not None)
+        mask = np.ones((t_pad, n), bool) if masked else None
+        reads = dispatched_form(req, job, sel, tol, mask)
         _check(reads[0] == form, f"a gang made for the {form} form reads "
                                  f"{reads}")
         return np.asarray(batch_prefix_feasibility(
@@ -872,14 +872,14 @@ def stage_b_prescreen(quotients=512) -> dict:
     verdict(q, "counted")                    # ends in the host fetch
     run_ms = (time.perf_counter() - t0) * 1e3
     over = verdict(q + 1, "counted")
-    exact = verdict(q, "scanned"), verdict(q + 1, "scanned")
     differ = 0
-    for form, got in (("counted", (fits, over)),
-                      ("grouped", (verdict(q, "grouped"),
-                                   verdict(q + 1, "grouped")))):
-        off = int(sum((a != b).sum() for a, b in zip(got, exact)))
+    for form, masked in (("counted", False), ("grouped", False),
+                         ("counted", True), ("grouped", True)):
+        off = int((~verdict(q, form, masked)).sum()
+                  + verdict(q + 1, form, masked).sum())
         _check(off == 0,
-               f"{form} and scanned disagree on {off} of {2 * q} prefixes")
+               f"{form}{' under a mask' * masked} and the exact answer "
+               f"disagree on {off} of {2 * q} prefixes")
         differ += off
     low, high = int((~fits).sum()), int(over.sum())
     _check(low == 0 and high == 0,

@@ -351,14 +351,13 @@ def _prescreen_verdict(ssn, tasks, builder: "ScenarioBuilder", sp):
     if rows is None:
         return declined("no-task-rows")
     # A static mask (the state-dependent ones declined above) goes with
-    # the rows, all-true for the padding tasks, and makes the call the
-    # ``scanned`` form; otherwise the kernel picks its form from the rows
-    # by the same predicates, whatever the strategies.
+    # the rows, all-true for the padding tasks, as one more row set: the
+    # kernel picks its form from the rows and the mask's by the same
+    # predicates, whatever the strategies.
     mask = propose._pad_rows(ssn.compute_static_mask(tasks), rows.t_pad,
                              True)
     form, scan_steps = dispatched_form(
-        rows.task_req, rows.task_job, rows.task_sel, rows.task_tol,
-        masked=mask is not None)
+        rows.task_req, rows.task_job, rows.task_sel, rows.task_tol, mask)
     sp.set(prefixes=num_prefixes, steps=len(steps), rows=m_pad,
            t_pad=rows.t_pad, form=form,
            mask="none" if mask is None else "static",

@@ -15,12 +15,15 @@ exact-confirms only the smallest feasible prefix through the ordinary
 statement path (validators, victim re-placement, masks), so semantics
 stay identical to the sequential search.
 
-A prefix is answered in one of three forms, chosen in the program from
-what the task rows hold (``uniform_gang``, ``continues_run``) and from the
-static arguments, never by a flag:
+A prefix is answered in one of two forms, chosen in the program from
+what the task rows hold (``uniform_gang``, ``continues_run``), never by a
+flag.  Two rows are the same pod when request, selector, tolerations and,
+where the call carries a static ``task_node_mask`` (a required node
+affinity: node labels and names alone, which no eviction changes), their
+``[N]`` mask rows agree: the mask row is part of a run's identity, and
+ANDs into the run's hard feasibility.
 
-* **counted** — the gang's real rows (job 0) are all one pod: same
-  request, selector and tolerations, and no ``task_node_mask``.  A
+* **counted** — the gang's real rows (job 0) are all one pod.  A
   pipeline-only placement takes ``req`` from the chosen node's releasing
   pool and one pod of its room and nothing anywhere else, so node n
   takes exactly ``c_n`` pods whatever the order or the score, and the
@@ -42,18 +45,23 @@ static arguments, never by a flag:
   until its capacity is spent, then the next: "sort by initial score,
   fill in order", the grouped kernel's fill plan (``allocate_grouped``,
   a threshold select with no sort) keyed by the score of the strategies
-  the call was compiled for, with the count's capacity.  Spread
-  round-robins where a placement claims idle (the bind, the grouped
-  fill of ``framework/propose.py``): not here.  Only the releasing pool
-  and the room are carried.  The last run needs no landing: it is a
-  count.
-* **scanned** — the pending job's pipeline-only placement attempt,
-  ``allocate_jobs_kernel``, vmapped over the prefixes: one dependent step
-  a POD.  It is the form of a call with a ``task_node_mask`` (rows that
-  differ by task, where a run's pods no longer share one feasible set)
-  and of no other.
+  the call was compiled for, with the count's capacity.  The argument
+  asks of a run only that its pods share one feasible set, not which
+  nodes are in it: a mask row that every pod of the run carries narrows
+  the set and the run lands as before.  Spread round-robins where a
+  placement claims idle (the bind, the grouped fill of
+  ``framework/propose.py``): not here.  Only the releasing pool and the
+  room are carried.  The last run needs no landing: it is a count.
 
-All read the same dense per-prefix pools (scatter-add of the release
+Rows that differ open a new run, whichever of the four differs, and a run
+of ONE pod landed by ``land`` is the exact kernel's step (the best-scoring
+feasible node takes the pod): a gang whose every pod carries another mask
+row is ``t`` runs of one, the pod-by-pod scan in this form.  So no third
+form is needed; the exact kernel vmapped over the prefixes
+(``allocate_jobs_kernel``, one dependent step a POD) is the oracle the
+tests hold both forms to (``tests/prescreen_oracle.py``).
+
+Both read the same dense per-prefix pools (scatter-add of the release
 rows, running sum over the prefix axis).  The counted form could be had
 from the touched nodes alone without ever materialising them; the pools
 stay dense because the benchmark's byte count for this program
@@ -68,52 +76,64 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .allocate import NEG, allocate_jobs_kernel
+from .allocate import NEG
 from .allocate_grouped import _fill_by_score_descent, _score_keys
 from .predicates import EPS, hard_row
 from .scoring import BINPACK, score_row, score_row_selected
 
 
-def uniform_gang(task_req, task_job, task_selector, task_tolerations):
+def _row_sets(task_req, task_selector, task_tolerations, task_node_mask):
+    """The row sets that make two task rows one pod; the mask's only where
+    the call has one (a trace-time fact: an unmasked call compares what
+    it always did)."""
+    rows = (task_req, task_selector, task_tolerations)
+    return rows if task_node_mask is None else (*rows, task_node_mask)
+
+
+def uniform_gang(task_req, task_job, task_selector, task_tolerations,
+                 task_node_mask=None):
     """Scalar bool: is every row of job 0 the same pod as row 0?  The
     predicate behind the counted form: the device evaluates it on its
     operands, the host (numpy rows) on what it sends, to label the call."""
     real = task_job == 0
     same = real
-    for rows in (task_req, task_selector, task_tolerations):
+    for rows in _row_sets(task_req, task_selector, task_tolerations,
+                          task_node_mask):
         same = same & (rows == rows[0]).all(axis=-1)
     return (same == real).all()
 
 
-def continues_run(task_req, task_job, task_selector, task_tolerations):
+def continues_run(task_req, task_job, task_selector, task_tolerations,
+                  task_node_mask=None):
     """[T-1] bool: is row t+1 the pod of row t, both of job 0?  The
     predicate behind the grouped form's runs, for jax and numpy rows
     alike, as ``uniform_gang`` is."""
     real = task_job == 0
     same = real[1:] & real[:-1]
-    for rows in (task_req, task_selector, task_tolerations):
+    for rows in _row_sets(task_req, task_selector, task_tolerations,
+                          task_node_mask):
         same = same & (rows[1:] == rows[:-1]).all(axis=-1)
     return same
 
 
-def gang_runs(task_req, task_job, task_selector, task_tolerations):
+def gang_runs(task_req, task_job, task_selector, task_tolerations,
+              task_node_mask=None):
     """Scalar: the runs of identical adjacent rows job 0 is made of."""
     # Every real row opens a run or continues one.
     return (task_job == 0).sum() - continues_run(
-        task_req, task_job, task_selector, task_tolerations).sum()
+        task_req, task_job, task_selector, task_tolerations,
+        task_node_mask).sum()
 
 
 def dispatched_form(task_req, task_job, task_selector, task_tolerations,
-                    masked: bool = False):
+                    task_node_mask=None):
     """(form, dependent steps over the pools) of the call that
     ``batch_prefix_feasibility`` makes of these rows: ``counted`` 0,
-    ``grouped`` its runs, ``scanned`` (a masked call, and no other) the
-    padded rows.  The host's reading of what it sends, by the predicates
-    the program applies; the strategies choose the run's key, never the
-    form."""
-    rows = (task_req, task_job, task_selector, task_tolerations)
-    if masked:
-        return "scanned", int(task_req.shape[0])
+    ``grouped`` its runs, with a mask as without.  The host's reading of
+    what it sends, by the predicates the program applies; the strategies
+    choose the run's key, never the form."""
+    rows = (task_req, task_job, task_selector, task_tolerations,
+            task_node_mask)
     if bool(uniform_gang(*rows)):
         return "counted", 0
     return "grouped", int(gang_runs(*rows))
@@ -162,13 +182,15 @@ def _planes(prefix_rel):
 
 def count_prefixes(prefix_rel, node_idle, node_labels, node_taints,
                    node_room, task_req, task_job, task_selector,
-                   task_tolerations):
+                   task_tolerations, task_node_mask=None):
     """The counted form: [K] bool from ``prefix_rel`` [K,N,R] for a gang
     of ``sum(task_job == 0)`` pods, each the pod of row 0."""
     need = jnp.sum(task_job == 0).astype(node_idle.dtype)
     # Prefix-invariant, [N]: evicted pods stay on their node as Releasing.
     hard = hard_row(node_labels, node_taints, node_room, task_selector[0],
                     task_tolerations[0])
+    if task_node_mask is not None:
+        hard = hard & task_node_mask[0]
     capacity = run_capacity(_planes(prefix_rel), node_idle, hard,
                             node_room, task_req[0])
     return (need > 0) & _seats(capacity, need)
@@ -176,20 +198,21 @@ def count_prefixes(prefix_rel, node_idle, node_labels, node_taints,
 
 def group_prefixes(prefix_rel, node_allocatable, node_idle, node_labels,
                    node_taints, node_room, task_req, task_job,
-                   task_selector, task_tolerations,
+                   task_selector, task_tolerations, task_node_mask=None,
                    gpu_strategy: int = BINPACK, cpu_strategy: int = BINPACK,
                    f32_keys: bool = False):
     """The grouped form: [K] bool from ``prefix_rel`` [K,N,R] for a gang
     of runs of identical rows, each run landed by the key of the (static)
-    strategies.  A ``while`` over the runs, outside the prefix axis;
-    ``f32_keys`` orders scores at the chip's precision on any backend
-    (``_score_keys``)."""
+    strategies among the nodes its mask row admits.  A ``while`` over the
+    runs, outside the prefix axis; ``f32_keys`` orders scores at the
+    chip's precision on any backend (``_score_keys``)."""
     k, n, _ = prefix_rel.shape
     t = task_req.shape[0]
     dtype = node_idle.dtype
     real = task_job == 0
     opens = jnp.concatenate([real[:1], real[1:] & ~continues_run(
-        task_req, task_job, task_selector, task_tolerations)])
+        task_req, task_job, task_selector, task_tolerations,
+        task_node_mask)])
     runs = opens.sum()
     first_row = jnp.nonzero(opens, size=t, fill_value=0)[0]
     run_size = jax.ops.segment_sum(real.astype(dtype),
@@ -205,6 +228,8 @@ def group_prefixes(prefix_rel, node_allocatable, node_idle, node_labels,
         # room test is implied by the capacity's.
         hard = hard_row(node_labels, node_taints, node_room,
                         task_selector[row], task_tolerations[row])
+        if task_node_mask is not None:
+            hard = hard & task_node_mask[row]
         return run_capacity(rel, node_idle, hard, room, task_req[row])
 
     def land(state):
@@ -239,30 +264,6 @@ def group_prefixes(prefix_rel, node_allocatable, node_idle, node_labels,
                                     run_size[last])
 
 
-def scan_prefixes(prefix_rel, node_allocatable, node_idle, node_labels,
-                  node_taints, node_room, task_req, task_job,
-                  task_selector, task_tolerations, task_node_mask,
-                  gpu_strategy: int, cpu_strategy: int):
-    """The scanned form: [K] bool from ``prefix_rel`` [K,N,R], the exact
-    kernel's pipeline-only attempt at each prefix."""
-    # Job 1 holds the caller's padding task rows; gate it off so the
-    # kernel skips their placement work entirely (same convention as
-    # session.propose_placements padding).
-    job_allowed = jnp.array([True, False])
-
-    def one(prefix):
-        result = allocate_jobs_kernel(
-            node_allocatable, node_idle, prefix, node_labels,
-            node_taints, node_room, task_req, task_job, task_selector,
-            task_tolerations, job_allowed,
-            task_node_mask=task_node_mask,
-            gpu_strategy=gpu_strategy, cpu_strategy=cpu_strategy,
-            pipeline_only=True)
-        return result.job_success[0]
-
-    return jax.vmap(one)(prefix_rel)
-
-
 @functools.partial(jax.jit,
                    static_argnames=("num_prefixes", "gpu_strategy",
                                     "cpu_strategy"))
@@ -285,10 +286,13 @@ def batch_prefix_feasibility(node_allocatable, node_idle, node_releasing,
     prefix-invariant (evicted pods stay on their node as Releasing).
 
     A gang of identical pods is counted and any other stepped over run
-    by run, each run landed by the strategies' key (module docstring); a
-    ``task_node_mask`` is static and goes to the scan, pod by pod.
+    by run, each run landed by the strategies' key (module docstring).  A
+    ``task_node_mask`` [T,N] bool, all-true on the padding rows, is static
+    (no eviction changes it): its rows tell pods apart as the other rows
+    do, and a run's row bounds where the run may land.
     """
-    tasks = (task_req, task_job, task_selector, task_tolerations)
+    tasks = (task_req, task_job, task_selector, task_tolerations,
+             task_node_mask)
 
     def pools():
         n, r = node_releasing.shape
@@ -296,12 +300,6 @@ def batch_prefix_feasibility(node_allocatable, node_idle, node_releasing,
         delta = delta.at[release_step, release_node].add(release_vec,
                                                          mode="drop")
         return node_releasing[None, :, :] + jnp.cumsum(delta, axis=0)
-
-    if task_node_mask is not None:
-        return scan_prefixes(
-            pools(), node_allocatable, node_idle, node_labels,
-            node_taints, node_room, *tasks, task_node_mask, gpu_strategy,
-            cpu_strategy)
 
     def counted():
         return count_prefixes(pools(), node_idle, node_labels,

@@ -247,11 +247,13 @@ def test_a_static_mask_goes_to_the_prescreen(strategy):
     span, trace = prescreen_span(ssn)
     assert "declined" not in span.attrs
     assert span.attrs["mask"] == "static"
-    assert span.attrs["form"] == "scanned"
+    # A master beside its workers under one row: two runs, two steps
+    # (``scanned`` and ``t_pad`` steps until PR 45).
+    assert span.attrs["form"] == "grouped" and span.attrs["runs"] == 2
     assert span.attrs["strategy"] == strategy
     assert span.attrs["t_pad"] == 32
     assert [METRICS.counters[c] - b for c, b in zip(
-        (masked, steps), before)] == [1, 32]
+        (masked, steps), before)] == [1, 2]
     (job,) = [s for s in trace.spans if s.name == "solve:job"]
     assert job.attrs["solved"] and job.attrs["tried"] == 2
     assert job.attrs["skipped"] == span.attrs["first_feasible"] > 0
@@ -638,11 +640,12 @@ def test_the_span_tree_of_a_masked_reclaim(driven):
         "solve:precheck", "solve:scenario", "solve:prescreen",
         "solve:scenario", "statement:commit"]
     prescreen = only(inside, "solve:prescreen")
-    assert prescreen.attrs["form"] == "scanned"
+    assert prescreen.attrs["form"] == "grouped"
+    assert prescreen.attrs["runs"] == 2
     assert prescreen.attrs["mask"] == "static"
     assert prescreen.attrs["strategy"] == "binpack"
     assert prescreen.attrs["first_feasible"] == steps - 2
-    assert "runs" not in prescreen.attrs and "declined" not in prescreen.attrs
+    assert "declined" not in prescreen.attrs
     only(children(trace, prescreen), "dispatch:scenario_prescreen")
     commit = only(inside, "statement:commit")
     assert commit.attrs == {"binds": 0, "evictions": gang}
@@ -674,11 +677,11 @@ def test_the_node_affinity_mask_is_built_once_a_session(driven):
 def test_the_counters_of_a_masked_cycle(driven):
     cut = CUTS[driven.nodes]
     share = driven.cell.config["occupancy"]["preemptible_nodes_share"]
-    t_pad = 1 << (cut["gang"] - 1).bit_length()
     for rec in driven.client.records[1:]:
         assert rec.counters[FILTERED] == int(cut["pools"][0] * share) * 2
         assert rec.counters["scenario_prescreen_masked_total"] == 1
-        assert rec.counters["scenario_prescreen_scan_steps_total"] == t_pad
+        # The master's run and the workers', whatever the gang's size.
+        assert rec.counters["scenario_prescreen_scan_steps_total"] == 2
         assert rec.counters["scenarios_skipped_by_prescreen_total"] \
             == cut["gang"] // 2 - 2
 

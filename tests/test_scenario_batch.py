@@ -1,11 +1,12 @@
-"""The scenario prescreen's three forms (``ops/scenario_batch.py``): a
+"""The scenario prescreen's two forms (``ops/scenario_batch.py``): a
 gang of identical pods is counted in one pass over the prefix pools, a gang
 of several runs of identical pods is stepped over run by run with the
 grouped kernel's fill between two runs, keyed by the strategies the call is
-compiled for, a call with a ``task_node_mask`` is scanned by the exact
-kernel, and all give the same bits where they apply.  The choice is made
-from the task rows and the mask, on the device and, for the span and the
-counters, on the host."""
+compiled for; a ``task_node_mask`` row is part of what makes two rows one
+pod and bounds where its run may land; and both give the bits of the exact
+kernel scanned pod by pod over every prefix (``tests/prescreen_oracle.py``),
+with a mask and without.  The choice is made from the task rows and the
+mask's, on the device and, for the span and the counters, on the host."""
 
 import functools
 
@@ -20,6 +21,7 @@ from kai_scheduler_tpu.framework.conf import SchedulerConfig
 from kai_scheduler_tpu.utils.metrics import METRICS
 from kai_scheduler_tpu.utils.tracing import TRACER
 from tests.fixtures import build_session, run_action
+from tests.prescreen_oracle import scan_prefixes
 
 N, K, M, T_PAD = 24, 16, 32, 8
 POD = np.array([4000.0, 2.0 ** 35, 1.0])     # the benchmark's worker
@@ -65,9 +67,9 @@ def pools(rel, step, node, vec, k=K):
 
 
 @jax.jit
-def counted(pool, nodes, tasks):
+def counted(pool, nodes, tasks, mask=None):
     _alloc, idle, _rel, labels, taints, room = nodes
-    return sb.count_prefixes(pool, idle, labels, taints, room, *tasks)
+    return sb.count_prefixes(pool, idle, labels, taints, room, *tasks, mask)
 
 
 # (gpu_strategy, cpu_strategy), as the kernels take them.
@@ -79,18 +81,18 @@ PAIR_IDS = ("binpack", "spread", "spread-gpus", "spread-cpus")
 @functools.partial(jax.jit, static_argnames=("pair",))
 def scanned(pool, nodes, tasks, mask=None, pair=PAIRS[0]):
     alloc, idle, _rel, labels, taints, room = nodes
-    return sb.scan_prefixes(pool, alloc, idle, labels, taints, room, *tasks,
-                            mask, *pair)
+    return scan_prefixes(pool, alloc, idle, labels, taints, room, *tasks,
+                         mask, *pair)
 
 
 @functools.partial(jax.jit, static_argnames=("pair", "f32_keys"))
-def grouped(pool, nodes, tasks, pair=PAIRS[0], f32_keys=True):
+def grouped(pool, nodes, tasks, pair=PAIRS[0], f32_keys=True, mask=None):
     """By default, keys at the chip's precision (``_score_keys``
     ``force_f32``)."""
     alloc, idle, _rel, labels, taints, room = nodes
     return sb.group_prefixes(pool, alloc, idle, labels, taints, room, *tasks,
-                             gpu_strategy=pair[0], cpu_strategy=pair[1],
-                             f32_keys=f32_keys)
+                             mask, gpu_strategy=pair[0],
+                             cpu_strategy=pair[1], f32_keys=f32_keys)
 
 
 def whole(nodes, release, tasks, k=K, mask=None, pair=PAIRS[0]):
@@ -100,29 +102,95 @@ def whole(nodes, release, tasks, k=K, mask=None, pair=PAIRS[0]):
 
 
 def traced_forms(monkeypatch):
-    """The list that the three forms' kernels add their names to as
+    """The list that the two forms' kernels add their names to as
     ``batch_prefix_feasibility`` traces them (clear its cache around the
     call, so that it traces)."""
     traced = []
-    for name in ("count_prefixes", "group_prefixes", "scan_prefixes"):
+    for name in ("count_prefixes", "group_prefixes"):
         kernel = getattr(sb, name)
         monkeypatch.setattr(sb, name, lambda *a, _n=name, _f=kernel, **kw: (
             traced.append(_n), _f(*a, **kw))[1])
     return traced
 
 
-def form_of(tasks, masked=False):
-    return sb.dispatched_form(*tasks, masked=masked)
+def form_of(tasks, mask=None):
+    return sb.dispatched_form(*tasks, mask)
 
 
-@pytest.mark.parametrize("seed", range(24))
-def test_counted_equals_scanned_on_random_fleets(seed):
+MASKS = ("one-row", "row-a-run", "split", "all-true", "no-node")
+
+
+def gang_mask(kind: str, tasks, seed: int):
+    """``(mask, runs_more)``: a static ``[t_pad,N]`` bool mask for the gang
+    of ``tasks``, all-true on the padding rows as the solver pads it, and
+    the runs it adds to the gang's.  ``one-row``: every pod the same random
+    row (one template's required affinity); ``row-a-run``: each run of
+    identical pods its own; ``split``: one row, but for the first run of
+    two pods or more, whose pods after the first carry another, which
+    must open a run; ``all-true`` and ``no-node``; ``none``: no mask."""
+    if kind == "none":
+        return None, 0
+    real = tasks[1] == 0
+    opens = np.r_[real[:1], real[1:] & ~sb.continues_run(*tasks)]
+    run_of = np.cumsum(opens) - 1
+    rows = np.random.default_rng([seed, 45]).random(
+        (int(opens.sum()) + 1, N)) < 0.7
+    assert len({row.tobytes() for row in rows}) == len(rows)
+    mask = np.ones((len(real), N), bool)
+    if kind == "one-row":
+        mask[real] = rows[-1]
+    elif kind == "row-a-run":
+        mask[real] = rows[run_of[real]]
+    elif kind == "split":
+        mask[real] = rows[-1]
+        sizes = np.bincount(run_of[real])
+        first = int(np.flatnonzero(sizes >= 2)[0])
+        mask[real & (run_of == first) & ~opens] = rows[first]
+        return mask, 1
+    elif kind == "no-node":
+        mask[real] = False
+    return mask, 0
+
+
+def mask_cases(seeds, masked_seeds, no_split=()):
+    """``(seed, mask kind)``: every seed unmasked, as before PR 45, and
+    ``masked_seeds`` under each kind of ``MASKS``; the seeds of
+    ``no_split`` have no run of two pods to split."""
+    return [(seed, "none") for seed in seeds] + [
+        (seed, kind) for seed in masked_seeds for kind in MASKS
+        if kind != "split" or seed not in no_split]
+
+
+# Gangs of one pod.
+@pytest.mark.parametrize("seed, kind", mask_cases(
+    range(24), range(24), no_split=(5, 13, 21)))
+def test_counted_equals_scanned_on_random_fleets(seed, kind):
+    """A gang of identical pods is counted, under any mask that gives
+    every pod the same row; a row that changes inside it makes two runs.
+    Either way the bits are the exact scan's under that mask."""
     nodes, release, tasks = fleet(seed)
     pool = pools(nodes[2], *release)
-    want = np.asarray(scanned(pool, nodes, tasks))
-    assert np.asarray(counted(pool, nodes, tasks)).tolist() == want.tolist()
-    assert bool(sb.uniform_gang(*map(jnp.asarray, tasks)))
-    assert whole(nodes, release, tasks).tolist() == want.tolist()
+    mask, more = gang_mask(kind, tasks, seed)
+    device_mask = None if mask is None else jnp.asarray(mask)
+    want = np.asarray(scanned(pool, nodes, tasks, device_mask))
+    uniform = bool(sb.uniform_gang(*map(jnp.asarray, tasks), device_mask))
+    assert uniform == (more == 0)
+    if uniform:
+        assert form_of(tasks, mask) == ("counted", 0)
+        assert np.asarray(counted(pool, nodes, tasks,
+                                  device_mask)).tolist() == want.tolist()
+    else:
+        assert form_of(tasks, mask) == ("grouped", 2)
+        assert np.asarray(grouped(pool, nodes, tasks,
+                                  mask=device_mask)).tolist() \
+            == want.tolist()
+    assert whole(nodes, release, tasks,
+                 mask=device_mask).tolist() == want.tolist()
+    if kind == "all-true":
+        assert want.tolist() == np.asarray(
+            scanned(pool, nodes, tasks)).tolist()
+    if kind == "no-node":
+        assert not want.any()
 
 
 def test_the_random_fleets_hold_both_answers():
@@ -209,7 +277,9 @@ def test_a_gang_of_two_distinct_rows_is_scanned():
     assert np.asarray(counted(pool, nodes, tasks)).tolist() != want
 
 
-def test_a_call_with_a_task_node_mask_is_scanned():
+def test_a_call_with_a_task_node_mask_is_counted_under_its_row():
+    """Scanned until PR 45.  One row for every pod leaves the gang uniform:
+    the count among the nodes the row admits, and the exact scan's bits."""
     nodes, release, tasks, k = mixed_fleet()
     task_req, task_job, none, _ = tasks
     tasks = (np.where((task_job == 0)[:, None], task_req[1], 0.0), task_job,
@@ -218,14 +288,30 @@ def test_a_call_with_a_task_node_mask_is_scanned():
     # Identical workers, but none may use node 2.
     mask = np.ones((4, 4), bool)
     mask[:, 2] = False
+    assert bool(sb.uniform_gang(*map(jnp.asarray, tasks),
+                                jnp.asarray(mask)))
+    assert form_of(tasks, mask) == ("counted", 0)
     pool = pools(nodes[2], *release, k=k)
     want = np.asarray(scanned(pool, nodes, tasks,
                               jnp.asarray(mask))).tolist()
     assert want == [False, False, False, True]
     assert whole(nodes, release, tasks, k=k,
                  mask=jnp.asarray(mask)).tolist() == want
+    assert np.asarray(counted(pool, nodes, tasks,
+                              jnp.asarray(mask))).tolist() == want
     assert np.asarray(counted(pool, nodes, tasks)).tolist() \
         == [False, False, True, True]
+    # The last worker alone may use node 2 as well: another pod, a second
+    # run, and the scan's bits still.
+    mask[2, 2] = True
+    assert not bool(sb.uniform_gang(*map(jnp.asarray, tasks),
+                                    jnp.asarray(mask)))
+    assert form_of(tasks, mask) == ("grouped", 2)
+    want = np.asarray(scanned(pool, nodes, tasks,
+                              jnp.asarray(mask))).tolist()
+    assert want == [False, False, True, True]
+    assert whole(nodes, release, tasks, k=k,
+                 mask=jnp.asarray(mask)).tolist() == want
 
 
 def counted_under_a_row(nodes, release, tasks, row):
@@ -254,11 +340,11 @@ def counted_under_a_row(nodes, release, tasks, row):
 @pytest.mark.parametrize("pair", PAIRS[:2], ids=PAIR_IDS[:2])
 @pytest.mark.parametrize("seed", range(24))
 def test_a_masked_gang_is_the_count_among_the_admitted_nodes(seed, pair):
-    """The scanned form under one static row for every pod (PR 44: what
-    the solver sends for a gang with a required node affinity), on fleets
-    whose label and taint tables are not empty: the verdict is the numpy
-    count among the admitted nodes, under either strategy, and the host
-    labels the call ``scanned`` with ``t_pad`` steps."""
+    """One static row for every pod (PR 44: what the solver sends for a
+    gang with a required node affinity), on fleets whose label and taint
+    tables are not empty: the verdict is the numpy count among the admitted
+    nodes, under either strategy, and the host labels the call ``counted``
+    (``scanned`` with ``t_pad`` steps until PR 45)."""
     nodes, release, tasks = fleet(seed)
     rng = np.random.default_rng([seed, 44])
     row = rng.random(N) < 0.6
@@ -268,7 +354,10 @@ def test_a_masked_gang_is_the_count_among_the_admitted_nodes(seed, pair):
     want = counted_under_a_row(nodes, release, tasks, row)
     got = whole(nodes, release, tasks, mask=jnp.asarray(mask), pair=pair)
     assert got.tolist() == want
-    assert form_of(tasks, masked=True) == ("scanned", t_pad)
+    assert form_of(tasks, mask) == ("counted", 0)
+    pool = pools(nodes[2], *release)
+    assert np.asarray(scanned(pool, nodes, tasks, jnp.asarray(mask),
+                              pair=pair)).tolist() == want
     # An all-true mask is the unmasked count.
     assert whole(nodes, release, tasks, pair=pair).tolist() \
         == counted_under_a_row(nodes, release, tasks, np.ones(N, bool))
@@ -373,17 +462,39 @@ def runs_alone(pool, nodes, tasks):
 
 
 MIXED_SEEDS = range(36)
+NO_SPLIT = (33,)          # every run of its gang is one pod
 
 
-@pytest.mark.parametrize("seed", MIXED_SEEDS)
-def test_grouped_equals_scanned_on_random_fleets(seed):
-    nodes, release, tasks, pattern = mixed_gang(seed)
+def grouped_equals_scanned(nodes, release, tasks, runs, kind, seed,
+                           pair=PAIRS[0], f32_keys=True):
+    """The run loop and the whole call against the exact scan under the
+    same mask (``gang_mask``); the scan's bits."""
     pool = pools(nodes[2], *release)
-    want = np.asarray(scanned(pool, nodes, tasks)).tolist()
-    assert form_of(tasks) == ("grouped", len(pattern))
-    assert int(sb.gang_runs(*map(jnp.asarray, tasks))) == len(pattern)
-    assert np.asarray(grouped(pool, nodes, tasks)).tolist() == want
-    assert whole(nodes, release, tasks).tolist() == want
+    mask, more = gang_mask(kind, tasks, seed)
+    device_mask = None if mask is None else jnp.asarray(mask)
+    want = np.asarray(scanned(pool, nodes, tasks, device_mask,
+                              pair=pair)).tolist()
+    assert form_of(tasks, mask) == ("grouped", runs + more)
+    assert int(sb.gang_runs(*map(jnp.asarray, tasks),
+                            device_mask)) == runs + more
+    assert np.asarray(grouped(pool, nodes, tasks, pair, f32_keys,
+                              device_mask)).tolist() == want
+    if f32_keys:
+        assert whole(nodes, release, tasks, mask=device_mask,
+                     pair=pair).tolist() == want
+    if kind == "all-true":
+        assert want == np.asarray(scanned(pool, nodes, tasks,
+                                          pair=pair)).tolist()
+    if kind == "no-node":
+        assert not any(want)
+    return want, device_mask
+
+
+@pytest.mark.parametrize("seed, kind", mask_cases(
+    MIXED_SEEDS, MIXED_SEEDS, NO_SPLIT))
+def test_grouped_equals_scanned_on_random_fleets(seed, kind):
+    nodes, release, tasks, pattern = mixed_gang(seed)
+    grouped_equals_scanned(nodes, release, tasks, len(pattern), kind, seed)
 
 
 def test_the_mixed_fleets_hold_both_answers_and_need_the_fill():
@@ -401,6 +512,30 @@ def test_the_mixed_fleets_hold_both_answers_and_need_the_fill():
         unlike += int((alone != got).sum())
     assert 0.15 < np.mean(bits) < 0.85
     assert unlike >= 10
+
+
+def test_the_masked_gangs_hold_both_answers_and_every_row_matters():
+    """No vacuous agreement under a mask either: both bits occur, the mask
+    moves bits, and landing a split run whole under its first pod's row
+    (the row change ignored) answers otherwise."""
+    bits, moved, unsplit = [], 0, 0
+    for seed in MIXED_SEEDS:
+        nodes, release, tasks, _ = mixed_gang(seed)
+        pool = pools(nodes[2], *release)
+        plain = np.asarray(grouped(pool, nodes, tasks))
+        under = {}
+        for kind in ("one-row", "row-a-run", "split"):
+            if kind == "split" and seed in NO_SPLIT:
+                continue
+            mask, _ = gang_mask(kind, tasks, seed)
+            under[kind] = np.asarray(grouped(pool, nodes, tasks,
+                                             mask=jnp.asarray(mask)))
+            bits += under[kind].tolist()
+            moved += int((under[kind] != plain).sum())
+        if "split" in under:
+            unsplit += int((under["split"] != under["one-row"]).sum())
+    assert 0.2 < np.mean(bits) < 0.8
+    assert moved >= 100 and unsplit >= 20
 
 
 @pytest.mark.parametrize("seed", range(4))
@@ -467,12 +602,14 @@ def test_the_first_run_can_land_where_the_second_never_could():
 
 
 @pytest.mark.parametrize("exact", ("spread", "mask"))
-def test_a_mask_keeps_the_exact_scan_and_a_spread_strategy_does_not(
+def test_neither_a_mask_nor_a_spread_strategy_keeps_the_exact_scan(
         monkeypatch, exact):
-    """A run's rows share one mask row at most, so a masked call traces
-    the exact scan and no run loop; a spread strategy (scanned until PR 43)
-    traces the run loop and no scan.  Either way the answer is the exact
-    scan's under that strategy."""
+    """A spread strategy (scanned until PR 43) and a masked call (scanned
+    until PR 45) both trace the count and the run loop, and the program has
+    no exact scan to trace.  Either way the answer is the exact scan's
+    under that strategy and that mask."""
+    assert not hasattr(sb, "scan_prefixes")
+    assert not hasattr(sb, "allocate_jobs_kernel")
     traced = traced_forms(monkeypatch)
     nodes, release, tasks, pattern = mixed_gang(5)
     pool = pools(nodes[2], *release)
@@ -480,17 +617,15 @@ def test_a_mask_keeps_the_exact_scan_and_a_spread_strategy_does_not(
     try:
         if exact == "spread":
             got = whole(nodes, release, tasks, pair=(SPREAD, SPREAD))
-            assert sorted(traced) == ["count_prefixes", "group_prefixes"]
             want = scanned(pool, nodes, tasks, pair=(SPREAD, SPREAD))
             assert form_of(tasks) == ("grouped", len(pattern))
         else:
             mask = np.ones((len(tasks[0]), N), bool)
             mask[:, ::3] = False
             got = whole(nodes, release, tasks, mask=jnp.asarray(mask))
-            assert traced == ["scan_prefixes"]
             want = scanned(pool, nodes, tasks, jnp.asarray(mask))
-            assert form_of(tasks, masked=True) == ("scanned",
-                                                   len(tasks[0]))
+            assert form_of(tasks, mask) == ("grouped", len(pattern))
+        assert sorted(traced) == ["count_prefixes", "group_prefixes"]
         assert got.tolist() == np.asarray(want).tolist()
         traced.clear()
         whole(nodes, release, tasks)
@@ -522,20 +657,22 @@ STRATEGY_SEEDS = range(24)
 
 @pytest.mark.parametrize("f32_keys", (False, True), ids=("f64", "f32"))
 @pytest.mark.parametrize("pair", PAIRS, ids=PAIR_IDS)
-@pytest.mark.parametrize("seed", STRATEGY_SEEDS)
-def test_grouped_equals_scanned_under_each_pair_of_strategies(seed, pair,
-                                                              f32_keys):
+@pytest.mark.parametrize("seed, kind",
+                         mask_cases(STRATEGY_SEEDS, STRATEGY_SEEDS[:8]))
+def test_grouped_equals_scanned_under_each_pair_of_strategies(seed, kind,
+                                                              pair, f32_keys):
     """A pipeline-only attempt never writes idle, so a run of identical
-    pods lands by its first score under either strategy: the run loop keyed
-    by the call's strategies answers as the exact scan under them does."""
+    pods lands by its first score under either strategy, among the nodes
+    its mask row admits: the run loop keyed by the call's strategies
+    answers as the exact scan under them and that mask does, in 64 bits
+    and, the whole call, in 32 (as the chip runs it)."""
     nodes, release, tasks, pattern = strategy_fleet(seed)
-    pool = pools(nodes[2], *release)
-    want = np.asarray(scanned(pool, nodes, tasks, pair=pair)).tolist()
-    assert np.asarray(grouped(pool, nodes, tasks, pair,
-                                 f32_keys)).tolist() == want
-    assert form_of(tasks) == ("grouped", len(pattern))
-    if f32_keys:
-        assert whole(nodes, release, tasks, pair=pair).tolist() == want
+    want, mask = grouped_equals_scanned(nodes, release, tasks, len(pattern),
+                                        kind, seed, pair, f32_keys)
+    if f32_keys and kind != "none":
+        with jax.enable_x64(False):
+            narrow = whole(nodes, release, tasks, mask=mask, pair=pair)
+        assert narrow.tolist() == want
 
 
 @pytest.mark.parametrize("pair", PAIRS, ids=PAIR_IDS)
@@ -608,10 +745,10 @@ def test_landing_by_the_other_strategys_key_flips_a_bit(pair):
 
 @pytest.mark.parametrize("pair", PAIRS, ids=PAIR_IDS)
 @pytest.mark.parametrize("rows", ("uniform", "mixed", "no-pods"))
-def test_no_rows_are_scanned_without_a_mask(monkeypatch, rows, pair):
-    """``scanned`` is the mask's form alone: whatever the rows, the host
-    reads another form of an unmasked call, and the program traced for it
-    under any pair of strategies holds no exact scan."""
+def test_no_rows_are_scanned_with_a_mask_or_without(monkeypatch, rows, pair):
+    """Whatever the rows, the host reads ``counted`` or ``grouped``, of a
+    call whose mask tells no two pods apart as of an unmasked one, and the
+    program traced under any pair of strategies holds the two forms."""
     nodes, release, tasks = fleet(3) if rows == "uniform" \
         else mixed_gang(3)[:3]
     if rows == "no-pods":
@@ -622,14 +759,40 @@ def test_no_rows_are_scanned_without_a_mask(monkeypatch, rows, pair):
                     "no-pods": "counted"}[rows]
     assert steps == int(sb.gang_runs(*map(jnp.asarray, tasks))) \
         * (form == "grouped")
-    assert form_of(tasks, masked=True) == ("scanned", len(tasks[0]))
+    mask = np.ones((len(tasks[0]), N), bool)
+    assert form_of(tasks, mask) == (form, steps)
     traced = traced_forms(monkeypatch)
     sb.batch_prefix_feasibility.clear_cache()
     try:
-        whole(nodes, release, tasks, pair=pair)
+        plain = whole(nodes, release, tasks, pair=pair)
+        assert sorted(traced) == ["count_prefixes", "group_prefixes"]
+        traced.clear()
+        assert whole(nodes, release, tasks, pair=pair,
+                     mask=jnp.asarray(mask)).tolist() == plain.tolist()
     finally:
         sb.batch_prefix_feasibility.clear_cache()
     assert sorted(traced) == ["count_prefixes", "group_prefixes"]
+
+
+def lowered(nodes, release, tasks, mask=None):
+    return sb.batch_prefix_feasibility.lower(
+        *nodes, *release, *tasks, num_prefixes=K, task_node_mask=mask)
+
+
+def test_an_unmasked_lowering_has_no_mask_operand():
+    """``task_node_mask=None`` is a trace-time fact: the program lowered
+    without a mask takes no ``[T,N]`` predicate (three cells run that
+    program, and it is the parent's), where the masked one takes exactly
+    that operand more."""
+    nodes, release, tasks, _ = mixed_gang(5)
+    t_pad = len(tasks[0])
+    operands = [[(a.shape, a.dtype) for a in
+                 jax.tree_util.tree_leaves(low.in_avals)]
+                for low in (lowered(nodes, release, tasks),
+                            lowered(nodes, release, tasks,
+                                    np.ones((t_pad, N), bool)))]
+    assert not [o for o in operands[0] if o[1] == bool]
+    assert operands[1] == operands[0] + [((t_pad, N), np.dtype(bool))]
 
 
 def defrag_fleet(seed: int):

@@ -27,6 +27,7 @@ from kai_scheduler_tpu.ops.allocate import allocate_jobs_kernel
 from kai_scheduler_tpu.ops.scoring import BINPACK, SPREAD
 from kai_scheduler_tpu.utils.metrics import METRICS, _key
 from kai_scheduler_tpu.utils.tracing import TRACER
+from tests.prescreen_oracle import scan_prefixes
 from tests.test_scenario_batch import traced_forms
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -185,8 +186,14 @@ def test_the_mixed_gang_is_grouped_under_spread_and_under_binpack(
     assert sb.dispatched_form(*rows) == ("grouped", 2)
     assert call.static["gpu_strategy"] == call.static["cpu_strategy"] \
         == strategy
-    # A mask sends any rows to the scan, and nothing else does.
-    assert sb.dispatched_form(*rows, masked=True) == ("scanned", t_pad)
+    # A mask that tells no two pods apart changes neither the form nor
+    # the runs (it sent any rows to the exact scan until PR 45).
+    n = len(call.nodes[0])
+    assert sb.dispatched_form(*rows, np.ones((t_pad, n), bool)) \
+        == ("grouped", 2)
+    masters = np.ones((t_pad, n), bool)
+    masters[:2, 0] = False           # the master and the first worker
+    assert sb.dispatched_form(*rows, masters) == ("grouped", 3)
     (span,) = [s for s in driven.trace.spans if s.name == "solve:prescreen"]
     assert span.attrs["form"] == "grouped"
     assert span.attrs["strategy"] == driven.strategy
@@ -303,7 +310,7 @@ def test_the_strategy_attribute_names_both_axes(gpu, cpu, name):
                                     np.zeros((n, 3)), none, none,
                                     np.full(n, 4.0), *rows)))
     strategies = (ssn.gpu_strategy, ssn.cpu_strategy)
-    want = sb.scan_prefixes(jnp.asarray(pool), *fleet, None, *strategies)
+    want = scan_prefixes(jnp.asarray(pool), *fleet, None, *strategies)
     assert np.asarray(want).tolist() == [False, True]
     got = sb.group_prefixes(
         jnp.asarray(pool), *fleet, gpu_strategy=strategies[0],
