@@ -45,17 +45,41 @@ class BindRequest:
 
 class QueueAggregates(NamedTuple):
     """Per-leaf-queue sums of one pod walk (``ClusterInfo._aggregates``).
-    The last two are there only where the sums were counted, which proves
+    The last three are there only where the sums were counted, which proves
     them equal to the additions in turn to the bit."""
     allocated: dict
     requested: dict
     non_preemptible: dict | None = None   # active pods of guaranteed groups
     adds: dict | None = None              # additions a pod-by-pod roll-up makes
+    unit: dict | None = None              # [R] a leaf: what its requests are whole multiples of
 
 
-# Sums of whole non-negative float64 numbers are exact, in any order, while
-# they stay under this.
+# A sum of non-negative whole multiples of a power of two ``unit`` is exact
+# in float64, in any order, while it stays under ``EXACT_BELOW * unit``:
+# every partial sum is a multiple of the unit with fewer than 2**53 of them,
+# hence representable.  The unit is taken column by column (milli-cores,
+# bytes, whole GPUs), so 2**53 multiples of 32 Gi count where 2**53 bytes
+# did not.
 EXACT_BELOW = 2.0 ** 53
+_ONE = np.ones(rs.NUM_RES)
+_ONE.flags.writeable = False
+
+
+def sums_exact(total: np.ndarray, unit: np.ndarray) -> bool:
+    """Whether ``total``, summed from non-negative whole multiples of
+    ``unit`` column by column, is proven exact (``EXACT_BELOW``)."""
+    return bool((total < EXACT_BELOW * unit).all())
+
+
+def _unit_of(vectors) -> np.ndarray:
+    """Column by column the largest power of two that divides every
+    non-zero entry of the whole-number ``vectors`` [D,R]; inf for a column
+    of zeros, which proves nothing wrong."""
+    mantissa, exponent = np.frexp(np.asarray(vectors, float))
+    bits = np.ldexp(mantissa, 53).astype(np.int64)   # the 53 significant
+    lowest = bits & -bits
+    unit = np.ldexp(lowest.astype(float), exponent - 53)
+    return np.where(lowest == 0, np.inf, unit).min(axis=0)
 
 
 class ClusterInfo:
@@ -222,19 +246,23 @@ class ClusterInfo:
         gang share a handful of requirement objects, so the walk counts
         pods per object and adds ``count * vector`` once.  That equals the
         additions in turn to the bit only while every vector is made of
-        whole non-negative numbers and every total stays under 2**53
-        (milli-cores, bytes, whole GPUs); a fractional or gpu-memory
-        request anywhere returns None and the sums are taken in turn.
+        non-negative whole multiples of a power of two and every total
+        stays under 2**53 of them, column by column (``EXACT_BELOW``: 2**53
+        milli-cores, 2**53 times 32 Gi where every pod asks whole multiples
+        of 32 Gi).  A fractional or gpu-memory request anywhere, or a leaf's
+        total past that, returns None and the sums are taken in turn.
 
         Counted, the walk also gives what the proportion plugin rolls up
         the queue tree: the active pods of non-preemptible PodGroups (a
-        PodGroup's property, so the same count) and how many additions a
-        pod-by-pod roll-up would have made at each leaf."""
+        PodGroup's property, so the same count), how many additions a
+        pod-by-pod roll-up would have made at each leaf, and each leaf's
+        unit, by which the plugin proves its ancestors' totals."""
         pending = PodStatus.PENDING
         allocated = {qid: rs.zeros() for qid in self.queues}
         requested = {qid: rs.zeros() for qid in self.queues}
         non_preemptible = {qid: rs.zeros() for qid in self.queues}
         adds = dict.fromkeys(self.queues, 0)
+        asked: dict = {}      # (leaf, id(requirements)) -> their vector
         for pg in self.podgroups.values():
             qid = pg.queue_id
             if qid not in allocated:
@@ -259,14 +287,27 @@ class ClusterInfo:
                 if req.gpu_memory_bytes > 0.0 or (vec < 0.0).any() \
                         or (vec != np.floor(vec)).any():
                     return None
+                asked[qid, id(req)] = vec
                 allocated[qid] += active * vec
                 requested[qid] += (active + waiting) * vec
                 if guaranteed:
                     non_preemptible[qid] += active * vec
                 adds[qid] += (3 if guaranteed else 2) * active + waiting
-        if any((total >= EXACT_BELOW).any() for total in requested.values()):
-            return None
-        return QueueAggregates(allocated, requested, non_preemptible, adds)
+        # No queue asks more than all the leaves together: while that is
+        # under 2**53 the unit 1 of whole numbers proves every total, and
+        # the requests' own is looked for only past it.
+        unit = dict.fromkeys(self.queues, _ONE)
+        if not sums_exact(sum(requested.values(), rs.zeros()), _ONE):
+            by_leaf: dict = {}
+            for (qid, _), vec in asked.items():
+                by_leaf.setdefault(qid, []).append(vec)
+            for qid, vectors in by_leaf.items():
+                unit[qid] = _unit_of(vectors)
+            if not all(sums_exact(requested[qid], unit[qid])
+                       for qid in unit):
+                return None
+        return QueueAggregates(allocated, requested, non_preemptible, adds,
+                               unit)
 
     def min_node_gpu_memory(self) -> float:
         """Smallest per-GPU memory across nodes that report one — the

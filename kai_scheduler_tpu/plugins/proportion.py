@@ -20,7 +20,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..api import resources as rs
-from ..api.cluster_info import EXACT_BELOW
+from ..api.cluster_info import sums_exact
 from ..api.podgroup_info import PodGroupInfo
 from ..framework.session import SchedulableResult
 from ..ops import fairshare as fsops
@@ -231,7 +231,10 @@ class ProportionPlugin(Plugin):
             METRICS.inc("queue_attrs_rebuilt_total", rebuilt)
         # Roll allocated/non-preemptible/request up the parent chain
         # (proportion.go:347-401), by the cheapest way that is proven to
-        # give the pod-by-pod walk's sums to the bit; the span says which.
+        # give the pod-by-pod walk's sums to the bit; the span says which,
+        # and the counter how often it was the walk (registered first, so a
+        # fleet that never walks reads 0 and not nothing).
+        METRICS.inc("proportion_rollup_walked_total", 0)
         self.min_gpu_mem = cluster.min_node_gpu_memory()
         batch = getattr(cluster, "columnar_batch", None)
         if batch is not None and self._roll_up_columnar(batch):
@@ -241,6 +244,7 @@ class ProportionPlugin(Plugin):
         else:
             self._roll_up_walked(cluster)
             self.rollup = "walked"
+            METRICS.inc("proportion_rollup_walked_total")
         TRACER.stamp(f"plugin:{self.name}", rollup=self.rollup)
 
     def _roll_up_walked(self, cluster) -> None:
@@ -273,31 +277,34 @@ class ProportionPlugin(Plugin):
         took (``ClusterInfo.queue_rollup``, which the pack has just asked
         for): each leaf's three vectors go to the leaf and its ancestors.
         The cluster hands them over only where it has proven them the
-        additions in turn to the bit (whole non-negative vectors, no
-        gpu-memory request, so every normalisation above is the identity);
-        the same proof is asked of every ancestor's total here.  False,
-        with nothing written, where either is missing."""
+        additions in turn to the bit (non-negative whole multiples of a
+        power of two, under 2**53 of them, no gpu-memory request, so every
+        normalisation above is the identity); the same proof is asked of
+        every ancestor's total here, in the smallest unit of the leaves
+        beneath it.  False, with nothing written, where either is missing."""
         if counted is None:
             return False
-        totals: dict = {}   # qid -> [attributes, the three sums [3,R], adds]
+        totals: dict = {}   # qid -> [attributes, three sums [3,R], adds, unit]
         for leaf, adds in counted.adds.items():
             if not adds:
                 continue
             sums = np.stack((counted.allocated[leaf], counted.requested[leaf],
                              counted.non_preemptible[leaf]))
+            unit = counted.unit[leaf]
             q = self.queues.get(leaf)
             while q is not None:
                 entry = totals.get(q.uid)
                 if entry is None:
-                    totals[q.uid] = [q, sums, adds]
+                    totals[q.uid] = [q, sums, adds, unit]
                 else:
                     entry[1] = entry[1] + sums
                     entry[2] += adds
+                    entry[3] = np.minimum(entry[3], unit)
                 q = self.queues.get(q.parent) if q.parent else None
-        if any((sums[1] >= EXACT_BELOW).any() for _q, sums, _n in
-               totals.values()):
+        if not all(sums_exact(sums[1], unit)
+                   for _q, sums, _n, unit in totals.values()):
             return False
-        for q, sums, adds in totals.values():
+        for q, sums, adds, _u in totals.values():
             # The accumulators were zeroed above, as the walk finds them.
             q.allocated = q.allocated + sums[0]
             q.request = q.request + sums[1]

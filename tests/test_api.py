@@ -316,7 +316,9 @@ def test_queue_aggregates_by_count_equal_those_in_turn(seed):
     ResourceRequirements.from_spec("1", "1Gi", 0, gpu_fraction=0.3),
     ResourceRequirements.from_spec("1", "1Gi", 0, gpu_memory="4Gi"),
     ResourceRequirements.from_spec("0.0005", "1Gi", 0),
-    ResourceRequirements(base=np.array([1000.0, 2.0 ** 53, 0.0])),
+    # An odd byte count: with the queue's whole Gi beside it the total is
+    # 2**53 of its unit, one byte, or more.
+    ResourceRequirements(base=np.array([1000.0, 2.0 ** 53 - 1.0, 0.0])),
 ], ids=["fraction", "gpu_memory", "half_a_millicore", "past_2_53"])
 def test_queue_aggregates_take_turns_where_a_count_is_not_exact(spoiler):
     ci = _aggregate_cluster(5, spoiler)
@@ -325,3 +327,20 @@ def test_queue_aggregates_take_turns_where_a_count_is_not_exact(spoiler):
     want = ci._aggregates_in_turn()
     _same_bits(got[0], want[0])
     _same_bits(got[1], want[1])
+
+
+@pytest.mark.parametrize("bytes_asked", [2.0 ** 53, 2.0 ** 53 + 2.0 ** 35,
+                                         2.0 ** 62],
+                         ids=["2_53", "2_53_and_32gi", "2_62"])
+def test_queue_aggregates_count_past_2_53_bytes_in_whole_gi(bytes_asked):
+    """Every request a whole multiple of 2**30 bytes and fewer than 2**53
+    of them a queue: counted, and the additions in turn to the bit."""
+    ci = _aggregate_cluster(5, ResourceRequirements(
+        base=np.array([1000.0, bytes_asked, 0.0])))
+    counted = ci._aggregates_by_count()
+    assert counted is not None
+    assert counted.requested["qa"][1] >= 2.0 ** 53
+    assert counted.unit["qa"][1] >= 2.0 ** 30
+    in_turn = ci._aggregates_in_turn()
+    _same_bits(counted[0], in_turn[0])
+    _same_bits(counted[1], in_turn[1])
