@@ -328,493 +328,6 @@ def fleet_phase(n_nodes=2000, n_jobs=8, gang=100, waves=2,
     return result
 
 
-def burst_phase(n_nodes=400, over=2.0, cycles=4, pipelined=False,
-                gpu_per_node=8, baseline=False):
-    """System-level burst: ``over``x GPU-oversubscribed single-pod
-    workloads through the WHOLE fleet (admission -> grouper -> scheduler
-    -> binder -> status updater).  Exactly the GPU capacity binds; the
-    other half is a standing backlog whose re-attempt + status churn is
-    what the steady cycle measures — the shape where commit I/O, status
-    writes, and watch fanout dominate, i.e. what the overlapped pipeline
-    (DESIGN §10) and the coalescing/dedupe satellites attack."""
-    from kai_scheduler_tpu.controllers import (ShardSpec, System,
-                                               SystemConfig, make_pod)
-    from kai_scheduler_tpu.framework.conf import SchedulerConfig
-    from kai_scheduler_tpu.utils.metrics import METRICS
-
-    capacity = n_nodes * gpu_per_node
-    n_pods = int(capacity * over)
-    # Allocate-only: the burst row measures the backlog's re-attempt +
-    # status/fanout churn (the write-path costs this PR targets), not
-    # scenario-simulation depth — the reclaim ring measures that.
-    cfg = SchedulerConfig(actions=["allocate"])
-    system = System(SystemConfig(shards=[ShardSpec(config=cfg)],
-                                 pipelined_cycles=pipelined))
-    api = system.api
-    if baseline:
-        # Pre-PR10 behavior: rewrite every backlog group's Unschedulable
-        # condition every cycle (the A/B baseline, like PR9's "looped"
-        # fair-share mode).
-        for s_ in system.schedulers:
-            s_.cache.status_dedupe = False
-    for i in range(n_nodes):
-        api.create({"kind": "Node",
-                    "metadata": {"name": f"bn{i:05d}"}, "spec": {},
-                    "status": {"allocatable": {
-                        "cpu": "64", "memory": "512Gi",
-                        "nvidia.com/gpu": gpu_per_node, "pods": 110}}})
-    for q in range(4):
-        api.create({"kind": "Queue", "metadata": {"name": f"bq{q}"},
-                    "spec": {}})
-    for i in range(n_pods):
-        api.create(make_pod(f"burst-{i:06d}", queue=f"bq{i % 4}", gpu=1))
-    system.drain()
-    coalesced0 = METRICS.counters.get("watch_events_coalesced_total", 0)
-    deduped0 = METRICS.counters.get("status_writes_deduped_total", 0)
-    ts = []
-    for _ in range(cycles):
-        t0 = time.perf_counter()
-        system.run_cycle()
-        ts.append(time.perf_counter() - t0)
-    system.flush_pipeline()
-    system.drain()
-    bound = len([p for p in api.list("Pod")
-                 if p["spec"].get("nodeName")])
-    result = {
-        "config": f"{n_nodes}nodes_{n_pods}pods_burst",
-        "pipelined": bool(pipelined),
-        "status_dedupe": not baseline,
-        "first_cycle_s": round(ts[0], 3),
-        "steady_cycle_s": round(float(np.median(ts[1:] or ts)), 3),
-        "cycles": cycles,
-        "pods_bound": bound,
-        "expected_bound": capacity,
-        "capacity_note": (
-            f"capacity-bound: {n_nodes} nodes x {gpu_per_node} GPUs = "
-            f"{capacity} slots vs {n_pods} one-GPU pods "
-            f"({over:g}x demand)"),
-        "watch_events_coalesced": int(METRICS.counters.get(
-            "watch_events_coalesced_total", 0) - coalesced0),
-        "status_writes_deduped": int(METRICS.counters.get(
-            "status_writes_deduped_total", 0) - deduped0),
-    }
-    if pipelined and system.pipeline_stats:
-        ratios = [row["overlap_ratio"] for row in system.pipeline_stats]
-        result["overlap_ratio_mean"] = round(float(np.mean(ratios)), 3)
-    system.stop_pipeline()
-    return result
-
-
-def reclaim_system_phase(n_nodes=200, starved_jobs=16, starved_gpu=8,
-                         batched=True, gpu_per_node=8,
-                         substrate="memory"):
-    """System-level reclaim: queue q0 hogs the whole GPU pool (4x its
-    deserved share), then a starved queue's jobs arrive and the reclaim
-    action evicts victims — ``starved_jobs * starved_gpu`` serialized
-    eviction writes on the commit path.  ``batched=False`` forces the
-    per-victim synchronous write train (the A/B baseline);
-    ``batched=True`` routes the batch through the async status updater
-    with one flush per gang batch (``ClusterCache.evict_many``).
-
-    ``substrate="http"`` runs the whole fleet against a real
-    ``KubeAPIServer`` over loopback HTTP — eviction writes then cost
-    genuine round trips, which is the regime the batching targets (on
-    the in-memory store a patch is microseconds and thread-pool
-    coordination costs more than it saves; ``evict_write_ms`` reports
-    the write train either way so the row is apples-to-apples)."""
-    from kai_scheduler_tpu.controllers import (System, SystemConfig,
-                                               make_pod)
-
-    capacity = n_nodes * gpu_per_node
-    server = client = None
-    if substrate == "http":
-        from kai_scheduler_tpu.controllers.apiserver import KubeAPIServer
-        from kai_scheduler_tpu.controllers.httpclient import HTTPKubeAPI
-        server = KubeAPIServer().start()
-        client = HTTPKubeAPI(server.url)
-        system = System(SystemConfig(), api=client)
-    else:
-        system = System(SystemConfig())
-    api = system.api
-    per_queue = capacity // 4
-    for i in range(n_nodes):
-        api.create({"kind": "Node",
-                    "metadata": {"name": f"rn{i:05d}"}, "spec": {},
-                    "status": {"allocatable": {
-                        "cpu": "64", "memory": "512Gi",
-                        "nvidia.com/gpu": gpu_per_node, "pods": 110}}})
-    for q in range(4):
-        api.create({"kind": "Queue", "metadata": {"name": f"rq{q}"},
-                    "spec": {"deserved": {
-                        "cpu": str(64 * n_nodes // 4),
-                        "memory": f"{512 * n_nodes // 4}Gi",
-                        "gpu": per_queue}}})
-    for i in range(capacity):
-        api.create(make_pod(f"hog-{i:06d}", queue="rq0", gpu=1))
-    system.drain()
-    for _ in range(4):
-        system.run_cycle()
-        if len([p for p in api.list("Pod")
-                if p["spec"].get("nodeName")]) >= capacity:
-            break
-    # The starved queue's work arrives into the full cluster.
-    for j in range(starved_jobs):
-        api.create(make_pod(f"starved-{j:03d}", queue="rq1",
-                            gpu=starved_gpu))
-    system.drain()
-    caches = [s.cache for s in system.schedulers] + [system.cache]
-    for cache in caches:
-        cache.evict_batching = batched
-        cache.last_evict_write_s = 0.0
-    try:
-        t0 = time.perf_counter()
-        system.run_cycle()
-        reclaim_s = time.perf_counter() - t0
-        evicted = len([p for p in api.list("Pod")
-                       if p["metadata"].get("deletionTimestamp")])
-    finally:
-        if client is not None:
-            client.close()
-        if server is not None:
-            server.stop()
-    return {
-        "config": f"{n_nodes}nodes_{capacity}hogs_"
-                  f"{starved_jobs}x{starved_gpu}gpu_reclaim",
-        "substrate": substrate,
-        "evict_batched": bool(batched),
-        "reclaim_cycle_s": round(reclaim_s, 3),
-        # The write train alone (the part batching targets; the rest of
-        # the cycle is scenario-solver work already measured elsewhere).
-        "evict_write_ms": round(sum(c.last_evict_write_s
-                                    for c in caches) * 1000.0, 2),
-        "evictions": evicted,
-        "nodes": n_nodes,
-    }
-
-
-def reclaim_ab_main() -> int:
-    """Same-commit reclaim A/B (satellite): per-victim synchronous
-    eviction writes vs the batched ``evict_many`` path, recorded as two
-    ``reclaim-ab`` rows in results.jsonl."""
-    enable_compile_cache()
-    import jax
-
-    backend = jax.default_backend()
-    # Warmup pass (in-memory, small): pays the reclaim solver's XLA
-    # compiles so the A/B pair measures writes, not compilation.
-    reclaim_system_phase(n_nodes=20, starved_jobs=4, batched=True)
-    rows = {}
-    for batched in (False, True):
-        r = reclaim_system_phase(n_nodes=48, starved_jobs=16,
-                                 starved_gpu=8, batched=batched,
-                                 substrate="http")
-        rows[batched] = r
-        _log(f"reclaim A/B batched={batched}: cycle "
-             f"{r['reclaim_cycle_s']}s, write train "
-             f"{r['evict_write_ms']}ms, {r['evictions']} evictions")
-        _append_result_row({"scenario": "reclaim-ab",
-                            "backend": backend, **r})
-    speedup = rows[False]["evict_write_ms"] / max(
-        rows[True]["evict_write_ms"], 1e-9)
-    _log(f"reclaim evict-write-train speedup: {speedup:.2f}x "
-         f"(evictions {rows[False]['evictions']} vs "
-         f"{rows[True]['evictions']})")
-    return 0
-
-
-def pipeline_ab_main() -> int:
-    """The tentpole's committed artifact (one commit, one machine):
-    serial-vs-pipelined A/B pairs on the fleet (2000n/4000p) and burst
-    (400n, 2x oversubscribed) shapes — identical ``pods_bound`` is
-    asserted, the steady-cycle ratio is the headline — plus the
-    pipelined churn ring carrying p99 submit→bound."""
-    enable_compile_cache()
-    import jax
-
-    backend = jax.default_backend()
-    # Warmup: a small fleet + burst pass pays the XLA compiles so the
-    # A/B pairs below measure the scheduler, not compilation order.
-    fleet_phase(200, 4, 50)
-    burst_phase(24, cycles=2)
-    # --- fleet A/B, both substrates ---------------------------------------
-    # "memory" runs the headline 2000n/4000p shape — writes are
-    # pure-Python microseconds there, so the interpreter lock bounds
-    # what the commit thread can overlap.  "http" is the daemon's
-    # production regime — commit I/O is real network round trips the
-    # executor thread genuinely overlaps with host prep.  The http leg
-    # runs BOTH the historical 400n/800p daemon shape (the @d78375f
-    # 11.6s-pipelined baseline this PR's transport work is measured
-    # against) and the full 2000n/4000p fleet shape — previously
-    # infeasible over the wire (410s serial cycles before the pooled
-    # dispatcher + preserialized frames + watch-mode cache + bulk
-    # endpoints).  All pairs commit.
-    for substrate, shape in (("memory", (2000, 8, 500)),
-                             ("http", (400, 4, 200)),
-                             ("http", (2000, 8, 500))):
-        fleet = {}
-        for pipelined in (False, True):
-            r = fleet_phase(*shape, pipelined=pipelined,
-                            substrate=substrate)
-            fleet[pipelined] = r
-            _log(f"fleet A/B {substrate} pipelined={pipelined}: warm "
-                 f"{r['warm_cycle_s']}s, bound "
-                 f"{r['pod_latency'].get('bound_pods')}")
-            row = {"scenario": "fleet-pipeline-ab", "backend": backend,
-                   "mode": "pipelined" if pipelined else "serial",
-                   "substrate": substrate,
-                   "config": r["config"],
-                   "warm_cycle_s": r["warm_cycle_s"],
-                   "warm_wave_s": r.get("warm_wave_s"),
-                   "cold_wave_s": r["cold_wave_s"],
-                   "pods_bound": r["pod_latency"].get("bound_pods"),
-                   "p50_submit_bound_ms":
-                       r["pod_latency"].get("submit_to_bound_p50_ms"),
-                   "p99_submit_bound_ms":
-                       r["pod_latency"].get("submit_to_bound_p99_ms"),
-                   "wire": r.get("wire"),
-                   "fragmentation": r.get("fragmentation")}
-            if "pipeline" in r:
-                row["overlap_ratio_mean"] = \
-                    r["pipeline"]["overlap_ratio_mean"]
-            _append_result_row(row)
-        assert fleet[False]["pod_latency"].get("bound_pods") == \
-            fleet[True]["pod_latency"].get("bound_pods"), \
-            "pipelined fleet bound a different pod count than serial"
-        _log(f"fleet steady-cycle [{substrate}]: "
-             f"serial {fleet[False]['warm_cycle_s']}s "
-             f"-> pipelined {fleet[True]['warm_cycle_s']}s "
-             f"({fleet[False]['warm_cycle_s'] / max(fleet[True]['warm_cycle_s'], 1e-9):.2f}x)")
-
-    # --- burst 400n, 2x oversubscribed -----------------------------------
-    # Three rungs, one commit: "baseline" re-creates the pre-PR10 cycle
-    # (serial, Unschedulable conditions rewritten every cycle — the
-    # self-inflicted O(backlog) churn), "serial" is the new write path
-    # without overlap, "pipelined" is the shipped mode.
-    burst = {}
-    for mode, pipelined, baseline in (("baseline", False, True),
-                                      ("serial", False, False),
-                                      ("pipelined", True, False)):
-        r = burst_phase(400, pipelined=pipelined, baseline=baseline)
-        burst[mode] = r
-        _log(f"burst A/B {mode}: steady {r['steady_cycle_s']}s, "
-             f"bound {r['pods_bound']}")
-        _append_result_row({"scenario": "burst-pipeline-ab",
-                            "backend": backend, "mode": mode, **r})
-    assert burst["baseline"]["pods_bound"] == \
-        burst["pipelined"]["pods_bound"] == \
-        burst["serial"]["pods_bound"], \
-        "burst A/B rungs bound different pod counts"
-    _log(f"burst steady-cycle: baseline "
-         f"{burst['baseline']['steady_cycle_s']}s -> pipelined "
-         f"{burst['pipelined']['steady_cycle_s']}s "
-         f"({burst['baseline']['steady_cycle_s'] / max(burst['pipelined']['steady_cycle_s'], 1e-9):.2f}x)")
-
-    # --- pipelined churn ring (p99 submit→bound headline) -----------------
-    row = churn_phase(pipelined=True)
-    _append_result_row({"scenario": "churn-ring", "backend": backend,
-                        "pipelined": True, **row})
-    _log(f"pipelined churn ring: cycle {row['cycle_s']}s, p99 "
-         f"submit→bound "
-         f"{row['pod_latency'].get('submit_to_bound_p99_ms')}ms")
-    return 0
-
-
-def columnar_ab_main() -> int:
-    """Columnar host-state A/B (DESIGN §11), one commit, one machine:
-    object-path vs array-native snapshot pairs on the fleet
-    (2000n/4000p) shape and the churn ring.  Identical ``pods_bound``
-    is asserted on the fleet pair; the acceptance artifacts are the
-    ``snapshotted``/``grouped`` phase medians and the direct
-    ``snapshot_build_latency_ms`` median per mode, with
-    ``columnar_fallback_total`` required to stay flat (0 new fallbacks)
-    across the columnar legs."""
-    enable_compile_cache()
-    import jax
-
-    from kai_scheduler_tpu.utils.metrics import METRICS
-
-    backend = jax.default_backend()
-
-    def _snapshot_build_median(before_counts):
-        h = METRICS.histograms.get("snapshot_build_latency_ms")
-        if h is None:
-            return None
-        delta = {b: h.counts.get(b, 0) - before_counts.get(b, 0)
-                 for b in h.buckets}
-        n = sum(delta.values())
-        if n <= 0:
-            return None
-        target = max(1, -(-n // 2))
-        acc = 0
-        for b in h.buckets:
-            acc += delta[b]
-            if acc >= target:
-                return b
-        return h.buckets[-1]
-
-    def _hist_counts():
-        h = METRICS.histograms.get("snapshot_build_latency_ms")
-        return dict(h.counts) if h is not None else {}
-
-    # Warmup: pay the XLA compiles outside the measured pairs.
-    fleet_phase(200, 4, 50)
-
-    # --- fleet 2000n/4000p pair -------------------------------------------
-    fleet = {}
-    for columnar in (False, True):
-        os.environ["KAI_COLUMNAR"] = "1" if columnar else "0"
-        mode = "columnar" if columnar else "object"
-        fb0 = METRICS.counters.get("columnar_fallback_total", 0)
-        h0 = _hist_counts()
-        r = fleet_phase(2000, 8, 500)
-        fleet[columnar] = r
-        fallbacks = METRICS.counters.get(
-            "columnar_fallback_total", 0) - fb0
-        medians = r["pod_latency"].get("phase_median_ms", {})
-        row = {"scenario": "fleet-columnar-ab", "backend": backend,
-               "mode": mode, "config": r["config"],
-               "warm_cycle_s": r["warm_cycle_s"],
-               "cold_wave_s": r["cold_wave_s"],
-               "warm_wave_s": r.get("warm_wave_s"),
-               "pods_bound": r["pod_latency"].get("bound_pods"),
-               "snapshotted_median_ms": medians.get("snapshotted"),
-               "grouped_median_ms": medians.get("grouped"),
-               "snapshot_build_median_ms": _snapshot_build_median(h0),
-               "p50_submit_bound_ms":
-                   r["pod_latency"].get("submit_to_bound_p50_ms"),
-               "p99_submit_bound_ms":
-                   r["pod_latency"].get("submit_to_bound_p99_ms"),
-               "columnar_fallbacks": fallbacks,
-               "wire": r.get("wire"),
-               "fragmentation": r.get("fragmentation")}
-        _append_result_row(row)
-        _log(f"fleet columnar A/B {mode}: warm {r['warm_cycle_s']}s, "
-             f"snapshotted {medians.get('snapshotted')}ms, grouped "
-             f"{medians.get('grouped')}ms, fallbacks {fallbacks}")
-        if columnar:
-            assert fallbacks == 0, \
-                f"columnar fleet leg took {fallbacks} fallback(s)"
-    assert fleet[False]["pod_latency"].get("bound_pods") == \
-        fleet[True]["pod_latency"].get("bound_pods"), \
-        "columnar fleet bound a different pod count than object path"
-    m0 = fleet[False]["pod_latency"]["phase_median_ms"]
-    m1 = fleet[True]["pod_latency"]["phase_median_ms"]
-    _log(f"fleet snapshotted median: object {m0.get('snapshotted')}ms "
-         f"-> columnar {m1.get('snapshotted')}ms "
-         f"({m0.get('snapshotted', 0) / max(m1.get('snapshotted', 1), 1e-9):.2f}x); "
-         f"grouped {m0.get('grouped')}ms -> {m1.get('grouped')}ms")
-
-    # --- fleet steady-state pair, interleaved ------------------------------
-    # The wave pair above binds its 4000 pods in one or two mega-cycles,
-    # so its phase medians carry 1-2 samples each and the noise of a
-    # shared host.  The steady pair is the controlled experiment: both
-    # Systems live in ONE process, 2000n/4000p bound, and the cycles
-    # interleave object/columnar sample by sample — host drift and GC
-    # spikes land on both modes equally, and every number is an exact
-    # perf_counter median over the interleaved samples.
-    def _build_steady(columnar):
-        os.environ["KAI_COLUMNAR"] = "1" if columnar else "0"
-        from kai_scheduler_tpu.controllers import (System, SystemConfig,
-                                                   make_pod, owner_ref)
-        system = System(SystemConfig())
-        api = system.api
-        for i in range(2000):
-            api.create({"kind": "Node",
-                        "metadata": {"name": f"sn{i:05d}"}, "spec": {},
-                        "status": {"allocatable": {
-                            "cpu": "32", "memory": "256Gi",
-                            "nvidia.com/gpu": 8, "pods": 110}}})
-        for q in range(8):
-            api.create({"kind": "Queue",
-                        "metadata": {"name": f"fq{q}"}, "spec": {}})
-        for j in range(8):
-            name = f"steady-j{j}"
-            api.create({
-                "kind": "PyTorchJob", "apiVersion": "kubeflow.org/v1",
-                "metadata": {"name": name, "uid": f"{name}-uid",
-                             "labels": {"kai.scheduler/queue":
-                                        f"fq{j % 8}"}},
-                "spec": {"pytorchReplicaSpecs": {
-                    "Worker": {"replicas": 500}}}})
-            ref = owner_ref("PyTorchJob", name, uid=f"{name}-uid",
-                            api_version="kubeflow.org/v1")
-            for k in range(500):
-                api.create(make_pod(
-                    f"{name}-worker-{k:04d}", owner=ref,
-                    gpu=1 if j % 2 == 0 else 0,
-                    labels={"training.kubeflow.org/replica-type":
-                            "worker"}))
-        for _ in range(8):
-            system.run_cycle()
-        bound = sum(1 for p in api.list("Pod")
-                    if p["spec"].get("nodeName"))
-        return system, bound
-
-    systems = {}
-    for columnar in (False, True):
-        systems[columnar] = _build_steady(columnar)
-    assert systems[False][1] == systems[True][1] == 4000, \
-        "steady A/B: both modes must bind the full 4000-pod fleet"
-    samples = {False: {"snap": [], "cycle": []},
-               True: {"snap": [], "cycle": []}}
-    # NOTE: the mode is fixed at ClusterCache construction (the env var
-    # is read once in _build_steady); nothing mode-dependent happens per
-    # rep here — the two Systems simply interleave their samples.
-    for _rep in range(9):
-        for columnar in (False, True):
-            system, _ = systems[columnar]
-            cache = system.schedulers[0].cache
-            t0 = time.perf_counter()
-            cache.snapshot()
-            samples[columnar]["snap"].append(time.perf_counter() - t0)
-            t0 = time.perf_counter()
-            system.run_cycle()
-            samples[columnar]["cycle"].append(time.perf_counter() - t0)
-    steady = {}
-    for columnar in (False, True):
-        mode = "columnar" if columnar else "object"
-        snap_ms = float(np.median(samples[columnar]["snap"])) * 1000.0
-        cyc_ms = float(np.median(samples[columnar]["cycle"])) * 1000.0
-        steady[columnar] = (snap_ms, cyc_ms)
-        _append_result_row({
-            "scenario": "fleet-steady-columnar-ab", "backend": backend,
-            "mode": mode, "config": "2000nodes_4000pods_steady",
-            "samples": len(samples[columnar]["snap"]),
-            "interleaved": True,
-            "snapshot_build_median_ms": round(snap_ms, 1),
-            "steady_cycle_median_ms": round(cyc_ms, 1),
-            "pods_bound": systems[columnar][1]})
-    _log(f"fleet steady (interleaved): snapshot build "
-         f"{steady[False][0]:.0f}ms -> {steady[True][0]:.0f}ms "
-         f"({steady[False][0] / max(steady[True][0], 1e-9):.2f}x); "
-         f"cycle {steady[False][1]:.0f}ms -> {steady[True][1]:.0f}ms "
-         f"({steady[False][1] / max(steady[True][1], 1e-9):.2f}x)")
-    del systems
-
-    # --- churn ring pair ---------------------------------------------------
-    for columnar in (False, True):
-        os.environ["KAI_COLUMNAR"] = "1" if columnar else "0"
-        mode = "columnar" if columnar else "object"
-        fb0 = METRICS.counters.get("columnar_fallback_total", 0)
-        h0 = _hist_counts()
-        row = churn_phase()
-        fallbacks = METRICS.counters.get(
-            "columnar_fallback_total", 0) - fb0
-        _append_result_row({
-            "scenario": "churn-columnar-ab", "backend": backend,
-            "mode": mode,
-            "snapshot_build_median_ms": _snapshot_build_median(h0),
-            "columnar_fallbacks": fallbacks, **row})
-        _log(f"churn columnar A/B {mode}: cycle {row['cycle_s']}s, p99 "
-             f"{row['pod_latency'].get('submit_to_bound_p99_ms')}ms, "
-             f"fallbacks {fallbacks}")
-        if columnar:
-            assert fallbacks == 0, \
-                f"columnar churn leg took {fallbacks} fallback(s)"
-    os.environ.pop("KAI_COLUMNAR", None)
-    return 0
-
-
 def forest_parent_indices(n_queues, roots=16, fanouts=(2, 2, 2, 2, 2, 8)):
     """Parent index per queue (-1 = root) for the multi-tenant org
     forest: ``roots`` top-level tenants, breadth-first fanout per depth
@@ -888,16 +401,12 @@ def fairshare_inputs(n_queues=10000, roots=16,
 
 def fairshare_microbench(n_queues=10000, roots=16,
                          fanouts=(2, 2, 2, 2, 2, 8), bands=1,
-                         mode="forest", iters=7, seed=0):
+                         iters=7, seed=0):
     """The fair-share STEP alone at scale: what one cycle of the
-    proportion plugin's division costs in each mode.
-
-    ``looped`` measures what every cycle paid before the forest kernel:
-    a fresh ``QueueHierarchy.build`` (the plugin rebuilt it per cycle)
-    plus one ``divide_groups_jax`` dispatch per level.  ``forest``
-    measures the shipped path: the prep-cache hash plus ONE fused
-    dispatch (ops/fairshare.fair_share_forest).  Both paths produce
-    bit-identical shares (asserted here; property-tested in
+    proportion plugin's division costs — the prep-cache hash plus ONE
+    fused dispatch (ops/fairshare.fair_share_forest).  Its shares are
+    held bit-identical to the per-level reference ``fair_share_levels``
+    on this instance (asserted here; property-tested in
     tests/test_fairshare_forest.py)."""
     from kai_scheduler_tpu.ops import fairshare as fs
     from kai_scheduler_tpu.utils.metrics import METRICS
@@ -908,23 +417,15 @@ def fairshare_microbench(n_queues=10000, roots=16,
                                         inst["creation"], inst["uids"])
     deserved, limit, oqw = inst["deserved"], inst["limit"], inst["oqw"]
     request, usage, total = inst["request"], inst["usage"], inst["total"]
-    hier_depth = int(max(
-        len(fs.QueueHierarchy.build(parent, priority, creation,
-                                    uids).levels), 1)) - 1
+    hier = fs.QueueHierarchy.build(parent, priority, creation, uids)
+    hier_depth = max(len(hier.levels), 1) - 1
 
-    def step_looped():
-        h = fs.QueueHierarchy.build(parent, priority, creation, uids)
-        # kailint: disable=KAI004 — offline micro-bench, no Session to dispatch through
-        return fs.fair_share_levels(total, 1.0, h, deserved, limit, oqw,
-                                    request, usage)
-
-    def step_forest():
+    def step():
         prep = fs.prepared_forest(parent, priority, creation, uids,
                                   deserved, limit, oqw)
         # kailint: disable=KAI004 — offline micro-bench, no Session to dispatch through
         return fs.fair_share_forest(total, 1.0, prep, request, usage)
 
-    step = step_forest if mode == "forest" else step_looped
     reuse0 = METRICS.counters.get("fairshare_prep_reuse_total", 0)
     disp0 = METRICS.counters.get("fairshare_dispatch_total", 0)
     out = step()  # warm (compiles; fills the prep cache)
@@ -937,7 +438,6 @@ def fairshare_microbench(n_queues=10000, roots=16,
         "queues": q,
         "depth": hier_depth,
         "bands": bands,
-        "mode": mode,
         "fairshare_step_ms": round(float(np.median(ts)), 2),
         "prep_reuse": int(METRICS.counters.get(
             "fairshare_prep_reuse_total", 0) - reuse0),
@@ -945,15 +445,18 @@ def fairshare_microbench(n_queues=10000, roots=16,
             "fairshare_dispatch_total", 0) - disp0),
         "iters": iters,
     }
-    if mode == "forest":
-        # Cross-mode bit-parity on THIS instance, not just the suite's.
-        assert np.array_equal(out, step_looped()), \
-            "forest fair share diverged from per-level path"
+    # Bit-parity with the reference on THIS instance, not just the
+    # suite's.
+    # kailint: disable=KAI004 — offline micro-bench, no Session to dispatch through
+    levels = fs.fair_share_levels(total, 1.0, hier, deserved, limit, oqw,
+                                  request, usage)
+    assert np.array_equal(out, levels), \
+        "forest fair share diverged from per-level path"
     return result
 
 
 def churn_phase(n_nodes=256, n_queues=10000, cycles=8,
-                submit_per_cycle=400, mode="forest", seed=0,
+                submit_per_cycle=400, seed=0,
                 gpu_per_node=8, pipelined=False, substrate="memory"):
     """The heavy-traffic multi-tenant churn ring (ROADMAP item 3).
 
@@ -963,7 +466,7 @@ def churn_phase(n_nodes=256, n_queues=10000, cycles=8,
     random slice of bound pods, and evicts a few more (the kubelet
     analog then finalizes terminations) — not a one-shot fill.  Reports
     p99 submit→bound pod latency from the lifecycle tracker alongside
-    cycle time and the fair-share step median for the selected mode.
+    cycle time and the fair-share step median.
 
     Capacity math (the burst-row convention): the stream is
     GPU-throughput-bound.  Cumulative submissions exceed the
@@ -979,7 +482,7 @@ def churn_phase(n_nodes=256, n_queues=10000, cycles=8,
     from kai_scheduler_tpu.utils.tracing import TRACER
 
     rng = np.random.default_rng(seed)
-    cfg = SchedulerConfig(actions=["allocate"], fused_fairshare=mode)
+    cfg = SchedulerConfig(actions=["allocate"])
     server = client = None
     if substrate == "http":
         # The wire ring: the whole churn stream (submits, completes,
@@ -1113,7 +616,6 @@ def churn_phase(n_nodes=256, n_queues=10000, cycles=8,
                   f"{submit_per_cycle}per_cycle",
         "pipelined": bool(pipelined),
         "substrate": substrate,
-        "fairshare_mode": mode,
         "queues": n_queues,
         "leaves": len(leaves),
         "cycles": cycles,
@@ -1143,40 +645,23 @@ def churn_phase(n_nodes=256, n_queues=10000, cycles=8,
     return result
 
 
-def churn_main(iters: int = 7) -> int:
-    """The committed churn-ring artifact (one commit, one machine):
-
-    1. same-commit fair-share A/B at 10k queues / depth 8 — the looped
-       (per-level, per-cycle prep) step vs the fused single-dispatch
-       forest step, appended as two ``fairshare-10k-ab`` rows;
-    2. the churn ring itself at O(10k) queues with the fused path,
-       appended as a ``churn-ring`` row carrying p99 submit→bound.
-    """
+def churn_main() -> int:
+    """The churn-ring rows (one commit, one machine): the ring itself
+    at O(10k) queues, appended as a ``churn-ring`` row carrying p99
+    submit→bound, in memory and then over the wire."""
     enable_compile_cache()
     import jax
 
     backend = jax.default_backend()
-    ab = {}
-    for mode in ("looped", "forest"):
-        r = fairshare_microbench(mode=mode, iters=iters)
-        ab[mode] = r
-        _log(f"fairshare A/B {mode}: {r['fairshare_step_ms']}ms")
-        _append_result_row({"scenario": "fairshare-10k-ab",
-                            "backend": backend, **r})
-    speedup = ab["looped"]["fairshare_step_ms"] / max(
-        ab["forest"]["fairshare_step_ms"], 1e-9)
-    _log(f"fair-share step speedup: {speedup:.2f}x")
-
     row = churn_phase()
     _append_result_row({"scenario": "churn-ring", "backend": backend,
-                        "fairshare_speedup_vs_looped": round(speedup, 2),
                         **row})
 
     # The churn ring OVER THE WIRE (DESIGN §12): the same continuous
     # stream driven through a real loopback apiserver — submits,
     # completions, evictions, and the kubelet analog all pay transport,
     # with the driver's per-cycle queries pushed down as field
-    # selectors.  Committed next to the in-memory row as the A/B.
+    # selectors.
     wrow = churn_phase(pipelined=True, substrate="http")
     _append_result_row({"scenario": "churn-ring", "backend": backend,
                         **wrow})
@@ -1359,6 +844,7 @@ def _append_result_row(row: dict) -> None:
     # full disk) the measurement of a potentially hours-long run still
     # reaches stdout instead of dying inside open().
     print(json.dumps(entry), flush=True)
+    os.makedirs(os.path.dirname(RESULTS_FILE), exist_ok=True)
     with open(RESULTS_FILE, "a") as f:
         f.write(json.dumps(entry) + "\n")
 
@@ -1435,48 +921,6 @@ def north_star_main(prime_only: bool = False, iters: int = 3,
         }
         if append:
             _append_result_row(row)
-    return 0
-
-
-def large_gang_ab_main(iters: int = 5) -> int:
-    """Same-commit before/after pair at the committed large-gang CPU
-    shape (8192 nodes / 32768 pods, gang 256): the legacy grouped kernel
-    vs the fused ladder's resolved mode, both appended to
-    docs/scale-tests/results.jsonl.  The pair is the acceptance artifact
-    for the fused-kernel speedup — one commit, one machine, two modes."""
-    enable_compile_cache()
-    import jax
-
-    backend = jax.default_backend()
-    from kai_scheduler_tpu.ops.allocate_grouped import (_resolve_fused_mode,
-                                                        allocate_grouped)
-    nodes_n, jobs_n, gang_n = 8192, 128, 256
-    big = build_arrays(nodes_n, jobs_n, gang_n, placeable=True)
-    nodes, tasks = big[:6], big[6:10]
-    # "auto" (NOT None): the A/B pair must ignore a KAI_FUSED_ALLOC env
-    # pin — a pinned "legacy" would silently record legacy twice and
-    # pass it off as the fused 'after' row.
-    for mode in ("legacy", _resolve_fused_mode("auto", nodes_n)):
-        t_c = time.perf_counter()
-        out = allocate_grouped(nodes, *tasks, big[10], fused_mode=mode)
-        compile_s = time.perf_counter() - t_c
-        placed = int((np.asarray(out.placements) >= 0).sum())
-        times = []
-        for _ in range(iters):
-            t_it = time.perf_counter()
-            allocate_grouped(nodes, *tasks, big[10], fused_mode=mode)
-            times.append((time.perf_counter() - t_it) * 1000.0)
-        _append_result_row({
-            "scenario": "large-gang-cpu",
-            "backend": backend,
-            "fused_mode": mode,
-            "nodes": nodes_n,
-            "pods": jobs_n * gang_n,
-            "gang": gang_n,
-            "cycle_ms": round(float(np.median(times)), 1),
-            "pods_placed": placed,
-            "warm_compile_s": round(compile_s, 1),
-        })
     return 0
 
 
@@ -1878,37 +1322,15 @@ if __name__ == "__main__":
         # One warm execution per north-star shape: populates the
         # compile cache so later runs skip the compile, records nothing.
         sys.exit(north_star_main(prime_only=True))
-    elif "--large-gang-ab" in sys.argv:
-        # Same-commit legacy-vs-fused pair at the committed large-gang
-        # CPU shape, appended to results.jsonl.
-        sys.exit(large_gang_ab_main())
     elif "--churn" in sys.argv:
-        # Multi-tenant churn ring at O(10k) queues: same-commit
-        # looped-vs-forest fair-share A/B rows + the continuous
+        # Multi-tenant churn ring at O(10k) queues: the continuous
         # submit/complete/evict stream with p99 submit→bound, appended
         # to results.jsonl.
         sys.exit(churn_main())
-    elif "--pipeline-ab" in sys.argv:
-        # Overlapped-cycle A/B (DESIGN §10): serial-vs-pipelined pairs
-        # on the fleet (2000n/4000p) and burst (400n) shapes with
-        # identical pods_bound asserted, plus the pipelined churn ring
-        # carrying p99 submit→bound, appended to results.jsonl.
-        sys.exit(pipeline_ab_main())
-    elif "--columnar-ab" in sys.argv:
-        # Columnar host-state A/B (DESIGN §11): object-path vs
-        # array-native snapshot pairs on the fleet (2000n/4000p) shape
-        # and the churn ring, identical pods_bound asserted, appended
-        # to results.jsonl.
-        sys.exit(columnar_ab_main())
     elif "--churn-wire-faults" in sys.argv:
         # The churn ring under the composite wire-fault spec (PR 15):
         # p99 submit→bound with the wire lying the whole run, annotated
         # @wire-faults, appended to results.jsonl.
         sys.exit(churn_wire_faults_main())
-    elif "--reclaim-ab" in sys.argv:
-        # Same-commit reclaim eviction-write A/B: per-victim synchronous
-        # writes vs the batched evict_many path, appended to
-        # results.jsonl.
-        sys.exit(reclaim_ab_main())
     else:
         sys.exit(main())

@@ -113,16 +113,3 @@ def survey_reclaim_victims(ssn) -> list[PodGroupInfo]:
         out.append(job)
         order.requeue_queue(job.queue_id)
     return out
-
-
-def collect_reclaim_victims(ssn, reclaimer: PodGroupInfo
-                            ) -> list[PodGroupInfo]:
-    """Compatibility helper: per-reclaimer view of the survey."""
-    return [pg for pg in survey_reclaim_victims(ssn)
-            if pg.queue_id != reclaimer.queue_id]
-
-
-def ssn_job_rank(ssn, pg) -> float:
-    """Higher rank = stronger claim = evicted later.  Approximates the
-    reverse of the job order: priority, then age."""
-    return pg.priority * 1e12 - pg.creation_ts

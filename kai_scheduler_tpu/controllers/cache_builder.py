@@ -428,17 +428,6 @@ class ClusterCache:
         # operator; Statement.commit journals intents through it and
         # startup_reconcile replays it after a restart.
         self.commitlog = None
-        # Batched eviction writes (evict_many): False forces the
-        # per-victim synchronous path — the A/B baseline for the
-        # reclaim bench (bench.py --reclaim-ab).  last_evict_write_s
-        # accumulates the write-train wall time either way (the bench's
-        # apples-to-apples number).
-        self.evict_batching = True
-        self.last_evict_write_s = 0.0
-        # Unschedulable-condition dedupe in update_job_statuses: False
-        # restores the rewrite-every-cycle behavior — the pre-PR10 A/B
-        # baseline for the burst bench.
-        self.status_dedupe = True
         # Watch-gap recovery: after the HTTP client re-lists past a 410
         # GONE, derived caches keyed on resourceVersions it may have
         # missed must be rebuilt.  Registered through a weakref: shard
@@ -2316,13 +2305,11 @@ class ClusterCache:
         if not tasks:
             return 0
         updater = self.status_updater
-        if not self.evict_batching or updater is None \
-                or not hasattr(updater, "submit_patch"):
+        if updater is None or not hasattr(updater, "submit_patch"):
             t0 = _time.perf_counter()
             for task in tasks:
                 self.evict(task)
             dt = _time.perf_counter() - t0
-            self.last_evict_write_s += dt
             METRICS.observe("evict_write_latency_ms", dt * 1000.0)
             return len(tasks)
         fk = self._fence_kwargs()
@@ -2384,7 +2371,6 @@ class ClusterCache:
             # serialized round-trip cost.
             updater.flush()
         dt = _time.perf_counter() - t0
-        self.last_evict_write_s += dt
         METRICS.observe("evict_write_latency_ms", dt * 1000.0)
         # Lifecycle attempts close only for evictions that actually
         # landed — vanished pods stay a no-op and failed writes stay
@@ -2445,7 +2431,7 @@ class ClusterCache:
                 (c for c in obj.get("status", {}).get("conditions", [])
                  if c.get("type") == "Unschedulable"
                  and c.get("status") == "True"), None)
-            if self.status_dedupe and current is not None \
+            if current is not None \
                     and current.get("message") == pg.fit_errors[-1]:
                 # Same verdict as last cycle: rewriting it (with only a
                 # fresh traceId) is churn, not information — /explain

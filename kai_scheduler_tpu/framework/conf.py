@@ -73,12 +73,6 @@ class SchedulerConfig:
     # round (job order fixed per round) instead of one call per job.
     # 0 disables bulk mode.
     bulk_allocation_threshold: int = 32
-    # Fair-share division path: "forest" runs the whole queue hierarchy
-    # as ONE jitted dispatch with cached host prep (ops/fairshare.py
-    # fair_share_forest, DESIGN §2b); "levels" keeps the per-level
-    # dispatch loop (the pre-forest baseline, kept for A/B benches and
-    # as the parity reference).
-    fused_fairshare: str = "forest"
     # Rank-aware gang placement (ops/rankplace.py): permute
     # interchangeable gang members so consecutive MPI ranks land
     # topology-adjacent.  Pure post-fill permutation — placements'
@@ -151,16 +145,9 @@ class SchedulerConfig:
                     "max_scenarios_per_job", "max_victims_considered",
                     "scenario_prescreen_max", "scenario_prescreen_after",
                     "batched_scenario_confirm", "cycle_deadline_s",
-                    "fused_fairshare", "rank_aware_placement"):
+                    "rank_aware_placement"):
             if key in d:
                 setattr(config, key, d[key])
-        if config.fused_fairshare not in ("forest", "levels"):
-            # Loud, not silent: a typo'd mode would otherwise fall into
-            # the slow per-level loop on a 10k-queue cluster (the
-            # operator's args validation surfaces this rejection).
-            raise ValueError(
-                f"fused_fairshare must be 'forest' or 'levels', got "
-                f"{config.fused_fairshare!r}")
         if "queue_depth_per_action" in d:
             config.queue_depth_per_action = dict(d["queue_depth_per_action"])
         gates = d.get("feature_gates", d.get("featureGates"))

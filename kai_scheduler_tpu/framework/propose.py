@@ -354,7 +354,7 @@ def _run_grouped(ssn, label: str, program: str, n_jobs: int,
     """One guarded call of the fill plan over the real rows (the wrappers
     group and pad for themselves).  ``fused_dispatch_span`` stamps the
     guard verdict on the cycle thread, the wrapper the rung it resolved;
-    the sharded kernel has no ladder, so a mesh dispatch emits no
+    the sharded kernel has no rungs, so a mesh dispatch emits no
     ``allocate_fused`` span."""
     t = rows.t
     if program == "sharded_grouped":

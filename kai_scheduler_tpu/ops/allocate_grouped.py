@@ -38,21 +38,18 @@ true of this fill, whose placements claim idle, the quantity spread scores;
 the scenario prescreen's pipeline-only runs claim none, and land by either
 strategy's key through this module's fill (ops/scenario_batch.py).
 
-The per-step row + fill implementation is a static three-rung ladder
-(docs/DESIGN.md §3.2b): TPU-Pallas node-tile row kernel -> fused-jnp
-single-pass row with the masked-sum radix-descent fill -> the legacy
-feasibility_row/score_row/histogram composition.  All rungs are
-bit-identical in placements (tests/test_fused_parity.py,
-tools/kernel_parity.py); the wrapper resolves the rung per backend/shape
-(env pin: KAI_FUSED_ALLOC) and counts it in
-``allocate_fused_taken_total``.
+The per-step row pass has two rungs (docs/DESIGN.md §3.2b): the
+TPU-Pallas node-tile row kernel and the fused-jnp single-pass row, both
+feeding the masked-sum radix-descent fill.  Both are bit-identical in
+placements to the exact per-task kernel (tests/test_fused_parity.py,
+tools/kernel_parity.py); the wrapper resolves the rung from the backend
+and the node bucket and counts it in ``allocate_fused_taken_total``.
 """
 
 from __future__ import annotations
 
 import contextlib
 import functools
-import os
 from typing import NamedTuple
 
 import jax
@@ -61,15 +58,13 @@ import numpy as np
 
 from ..utils.tracing import TRACER
 from .allocate import NEG, AllocationResult
-from .predicates import feasibility_caps_row, feasibility_row
+from .predicates import feasibility_caps_row
 from .scoring import AVAILABILITY, BINPACK, score_row, score_row_selected
 
-# Fused-path selection (docs/DESIGN.md fused-kernel section).  The ladder
-# is TPU-Pallas -> fused-jnp -> legacy: ``auto`` resolves per backend and
-# shape; KAI_FUSED_ALLOC pins a rung (parity suites pin ``legacy`` to diff
-# the ladder against the original formulation).
-FUSED_MODES = ("auto", "pallas", "jnp", "legacy")
-_FUSED_ENV = "KAI_FUSED_ALLOC"
+# Fused-path selection (docs/DESIGN.md fused-kernel section): ``auto``
+# resolves TPU-Pallas or fused-jnp from the backend and the node bucket;
+# the two rung names are what a caller may ask for outright.
+FUSED_MODES = ("auto", "pallas", "jnp")
 
 # Digit width (bits) of the fused fill's radix descent.  Each level costs
 # one in-prefix mask pass plus (2^W - 1) masked-sum reductions that XLA
@@ -208,89 +203,31 @@ def _score_keys(score, force_f32: bool = False):
     return key, 4, jnp.uint32
 
 
-def _histogram(capw, digit, bins):
-    """Capacity histogram over radix digits WITHOUT materializing a
-    one-hot: the broadcast-compare feeds straight into the axis-0 sum, so
-    XLA's reduce fusion reads ``capw``/``digit`` once per lane tile
-    instead of writing+reading an [N, bins] f32 one-hot through HBM (the
-    previous matmul formulation's dominant per-step cost at 98k nodes).
-    Accumulation stays in ``capw.dtype``.  In f32 a bin's capacity sum
-    (and the cumsum over bins) can exceed 2^24 at large shapes — e.g.
-    98k nodes with per-node caps clipped to the gang count — so the sums
-    themselves are not guaranteed exact there.  The threshold decision
-    stays correct because ``need <= count`` keeps the compared region
-    (cumulative capacity up to the threshold digit vs the remaining
-    need) within the exactly-representable range: the select only reads
-    the histogram where the running total is still below ``need``."""
-    ar = jnp.arange(bins)
-    return jnp.sum(jnp.where(digit[:, None] == ar[None, :],
-                             capw[:, None], jnp.zeros((), capw.dtype)),
-                   axis=0)
-
-
-def _fill_by_score(key, levels, utype, cap, count):
+def _fill_by_score_descent(key, levels, utype, cap, count):
     """Exact greedy fill WITHOUT sorting: distribute ``count`` units over
-    nodes in descending-score order (ascending index among ties), each
-    node bounded by ``cap``.
+    items in descending-key order (ascending index among ties), each item
+    bounded by ``cap``.
 
     The fill is monotone in score, so it is fully described by a threshold
-    key: nodes strictly above it take their whole capacity, nodes at it
-    split the remainder in index order.  The threshold is found by
-    radix-select — per 8-bit digit, a fused capacity histogram (no sort,
-    no top_k, no scatter, no materialized one-hot) and a 256-wide scan.
-    Replaces the per-step ``lax.top_k`` over the full node axis, which
-    lowers to a full sort per scan step and dominated large-cluster cycle
-    latency.
-    """
-    n_bits = levels * 8
-    prefix = jnp.zeros((), utype)
-    above = jnp.zeros((), cap.dtype)
-    for level in range(levels):
-        shift = n_bits - 8 * (level + 1)
-        digit = ((key >> utype(shift)) & utype(0xFF)).astype(jnp.int32)
-        if level == 0:
-            capw = cap
-        else:
-            in_prefix = (key >> utype(n_bits - 8 * level)) == prefix
-            capw = jnp.where(in_prefix, cap, 0.0)
-        hist = _histogram(capw, digit, 256)
-        ge = jnp.cumsum(hist[::-1])[::-1]          # capacity(digit >= d)
-        gt = ge - hist                             # capacity(digit >  d)
-        need = count - above                       # invariant: need > 0
-        crossing = (gt < need) & (need <= ge)
-        # Unique crossing digit when total capacity suffices; else fall to
-        # digit 0 (everything ends up full-taken, clipped by cap).
-        d_star = jnp.where(crossing.any(), jnp.argmax(crossing),
-                           0).astype(jnp.int32)
-        above = above + gt[d_star]
-        prefix = (prefix << utype(8)) | d_star.astype(utype)
-    take_full = jnp.where(key > prefix, cap, 0.0)
-    eqcap = jnp.where(key == prefix, cap, 0.0)
-    rem = jnp.maximum(count - above, 0.0)
-    pref = jnp.cumsum(eqcap)
-    take_eq = jnp.clip(rem - (pref - eqcap), 0.0, eqcap)
-    # count <= 0 (gated/fully-satisfied): the no-crossing fallback above
-    # would otherwise full-take everything.
-    return jnp.where(count > 0, take_full + take_eq, 0.0)
+    key: items strictly above it take their whole capacity, items at it
+    split the remainder in index order.  The threshold is found by a
+    radix descent: per W-bit level the threshold digit falls out of 2^W
+    masked capacity sums — XLA multi-output-fuses them over a single read
+    of (key, cap) — so the whole select is O(items x levels) with no
+    scatter, no sort, no top_k, no materialized one-hot.
 
-
-def _fill_by_score_descent(key, levels, utype, cap, count):
-    """Exact greedy fill with the same take semantics as
-    ``_fill_by_score``, built from fused masked-sum reductions instead of
-    the 256-wide capacity histogram.
-
-    The histogram formulation pays O(items x 256) broadcast-compare work
-    per level; on CPU (and for the Pallas row outputs on TPU) the same
-    threshold digit falls out of 2^W masked capacity sums per W-bit
-    level — XLA multi-output-fuses them over a single read of
-    (key, cap) — so the whole select is O(items x levels) with no
-    scatter, no sort, no materialized one-hot.  Every per-digit sum is
-    computed FRESH from the current in-prefix mask (never derived by
-    subtracting a carried total, which would drag early >2^24-scale f32
-    rounding error into the deep levels where the in-prefix set — and
-    the legacy histogram's sums — have shrunk back to exact range), so
-    the compared region stays exact for the same reason documented on
-    ``_histogram``.
+    Accumulation stays in ``cap.dtype``.  In f32 a digit's capacity sum
+    can exceed 2^24 at large shapes — e.g. 98k nodes with per-node caps
+    clipped to the gang count — so the sums themselves are not guaranteed
+    exact there.  The threshold decision stays correct because ``need <=
+    count`` keeps the compared region (cumulative capacity up to the
+    threshold digit vs the remaining need) within the exactly-
+    representable range: the select only reads the sums where the running
+    total is still below ``need``.  Every per-digit sum is computed FRESH
+    from the current in-prefix mask (never derived by subtracting a
+    carried total, which would drag early >2^24-scale f32 rounding error
+    into the deep levels where the in-prefix set has shrunk back to exact
+    range).
     """
     w = SELECT_DIGIT_BITS
     n_bits = levels * 8
@@ -318,8 +255,9 @@ def _fill_by_score_descent(key, levels, utype, cap, count):
                                jnp.zeros((), cap.dtype)))
              for d in range(1 << w)]
         # ge[d] = capacity(digit >= d); threshold digit d* is the unique
-        # crossing gt(d) < need <= ge(d) (first match mirrors the
-        # histogram form's argmax; fall to 0 when capacity is short).
+        # crossing gt(d) < need <= ge(d) (first match; fall to 0 when
+        # capacity is short: everything ends up full-taken, clipped by
+        # cap).
         ge = [None] * (1 << w)
         acc = jnp.zeros((), cap.dtype)
         for d in reversed(range(1 << w)):
@@ -347,6 +285,8 @@ def _fill_by_score_descent(key, levels, utype, cap, count):
     rem = jnp.maximum(count - above, 0.0)
     pref = jnp.cumsum(eqcap)
     take_eq = jnp.clip(rem - (pref - eqcap), 0.0, eqcap)
+    # count <= 0 (gated/fully-satisfied): the no-crossing fallback above
+    # would otherwise full-take everything.
     return jnp.where(count > 0, take_full + take_eq, 0.0)
 
 
@@ -364,7 +304,7 @@ def _fused_row(node_allocatable, idle, rel, node_labels, node_taints,
     (scoring.score_row_selected) so the whole row is one elementwise DAG
     plus the two binpack min/max reductions — no [N]-wide intermediate
     crosses a fusion boundary more than once.  Formula-identical to the
-    legacy step's feasibility_row + score_row + capacity composition.
+    exact kernel's feasibility_row + score_row composition.
     """
     fit_now, fit_future, cap_now_f, cap_tot_f = feasibility_caps_row(
         idle, None if releasing_empty else rel,
@@ -415,7 +355,7 @@ def allocate_groups_kernel(node_allocatable, node_idle, node_releasing,
                            allow_pipeline: bool = True,
                            pipeline_only: bool = False,
                            single_group_jobs: bool = False,
-                           fused_mode: str = "legacy",
+                           fused_mode: str = "jnp",
                            releasing_empty: bool = False,
                            f32_keys: bool = False):
     """Scan over groups; per group emit up to max_group fill segments.
@@ -442,37 +382,32 @@ def allocate_groups_kernel(node_allocatable, node_idle, node_releasing,
     before routing (framework/session.py).
 
     ``fused_mode`` picks the per-step row implementation (static, decided
-    by the host wrapper — docs/DESIGN.md fused-kernel section):
-    ``legacy`` keeps the original feasibility_row + score_row + histogram
-    composition; ``jnp`` runs the fused single-pass row
-    (predicates.feasibility_caps_row + scoring.score_row_selected) with
-    the masked-sum radix-descent fill; ``pallas`` swaps the row pass for
-    the Pallas node-tile kernel (ops/pallas_kernels.group_step_pallas).
-    ``releasing_empty`` (fused modes only) declares the releasing pool
-    all-zero, which provably collapses the pipeline item tier: fit_future
-    == fit_now, cap_rel == 0, so the step skips the pipe keys, the
-    interleave, and the releasing update entirely.  The wrapper only sets
-    it from a host-verified hint and never under ``pipeline_only`` (a
-    pipeline-only fill mutates releasing below zero, invalidating the
-    premise mid-scan)."""
+    by the host wrapper — docs/DESIGN.md fused-kernel section): ``jnp``
+    runs the fused single-pass row (predicates.feasibility_caps_row +
+    scoring.score_row_selected) with the masked-sum radix-descent fill;
+    ``pallas`` swaps the row pass for the Pallas node-tile kernel
+    (ops/pallas_kernels.group_step_pallas).
+    ``releasing_empty`` declares the releasing pool all-zero, which
+    provably collapses the pipeline item tier: fit_future == fit_now,
+    cap_rel == 0, so the step skips the pipe keys, the interleave, and the
+    releasing update entirely.  The wrapper only sets it from a
+    host-verified hint and never under ``pipeline_only`` (a pipeline-only
+    fill mutates releasing below zero, invalidating the premise
+    mid-scan)."""
     G = group_req.shape[0]
     N = node_allocatable.shape[0]
     K = max_group
     if group_indep is None:
         group_indep = jnp.zeros(G, bool)
-    assert fused_mode in ("legacy", "jnp", "pallas"), fused_mode
+    assert fused_mode in ("jnp", "pallas"), fused_mode
     # A pipeline-only fill mutates releasing below zero mid-scan, which
     # invalidates the all-zero premise the specialization rests on; the
     # wrapper never combines them, direct callers must not either.
     assert not (releasing_empty and pipeline_only), \
         "releasing_empty is unsound under pipeline_only"
-    fused = fused_mode != "legacy"
     # Pipe (phase-B) items exist unless the releasing tier is provably
-    # dead; legacy always interleaves them (zero-capacity items are
-    # harmless there and keep the original code byte-for-byte).
-    pipe_items = (not fused) or pipeline_only \
-        or (allow_pipeline and not releasing_empty)
-    rel_static = fused and releasing_empty
+    # dead.
+    pipe_items = pipeline_only or (allow_pipeline and not releasing_empty)
 
     class Carry(NamedTuple):
         idle: jnp.ndarray
@@ -486,10 +421,10 @@ def allocate_groups_kernel(node_allocatable, node_idle, node_releasing,
 
     zero = jnp.zeros(())
     init = Carry(node_idle,
-                 zero if rel_static else node_releasing,
+                 zero if releasing_empty else node_releasing,
                  node_pod_room,
                  zero if single_group_jobs else node_idle,
-                 zero if (single_group_jobs or rel_static)
+                 zero if (single_group_jobs or releasing_empty)
                  else node_releasing,
                  zero if single_group_jobs else node_pod_room,
                  jnp.array(-1, jnp.int32), jnp.array(False))
@@ -514,99 +449,43 @@ def allocate_groups_kernel(node_allocatable, node_idle, node_releasing,
         req = group_req[g]
         count = jnp.where(ok, group_count[g], 0.0)
 
-        if fused:
-            extra_row = group_extra[j] if group_extra is not None else None
-            mask_row = group_mask[j] if group_mask is not None else None
-            row_args = (node_allocatable, idle,
-                        None if rel_static else rel,
-                        node_labels, node_taints, room, req,
-                        group_sel[g], group_tol[g], extra_row, mask_row)
-            row_kw = dict(gpu_strategy=gpu_strategy,
-                          cpu_strategy=cpu_strategy,
-                          allow_pipeline=allow_pipeline,
-                          pipeline_only=pipeline_only,
-                          releasing_empty=rel_static,
-                          pipe_items=pipe_items)
-            if fused_mode == "pallas" and gpu_strategy == cpu_strategy:
-                # (Pallas computes at f32 natively — f32_keys is a no-op
-                # there; mixed per-axis strategies keep the two-axis
-                # canonical scorer, which only the jnp row implements.)
-                from .pallas_kernels import group_step_pallas
-                (key_now, key_pipe, cap_now, cap_tot,
-                 levels, utype) = group_step_pallas(*row_args, **row_kw)
-            else:
-                (key_now, key_pipe, cap_now, cap_tot,
-                 levels, utype) = _fused_row(*row_args, f32_keys=f32_keys,
-                                             **row_kw)
-            cap_now = jnp.clip(cap_now, 0.0, count)
-            if pipe_items:
-                cap_rel = jnp.clip(cap_tot - cap_now, 0.0, count)
-                key2 = jnp.stack([key_now, key_pipe], axis=1).reshape(-1)
-                cap2 = jnp.stack([cap_now, cap_rel], axis=1).reshape(-1)
-            else:
-                # Releasing tier provably dead: items ARE nodes — same
-                # ascending-index tie-break, half the fill width.
-                key2, cap2 = key_now, cap_now
-            take2 = jax.lax.cond(
-                count > 0,
-                lambda: _fill_by_score_descent(key2, levels, utype, cap2,
-                                               count),
-                lambda: jnp.zeros_like(cap2))
+        extra_row = group_extra[j] if group_extra is not None else None
+        mask_row = group_mask[j] if group_mask is not None else None
+        row_args = (node_allocatable, idle,
+                    None if releasing_empty else rel,
+                    node_labels, node_taints, room, req,
+                    group_sel[g], group_tol[g], extra_row, mask_row)
+        row_kw = dict(gpu_strategy=gpu_strategy,
+                      cpu_strategy=cpu_strategy,
+                      allow_pipeline=allow_pipeline,
+                      pipeline_only=pipeline_only,
+                      releasing_empty=releasing_empty,
+                      pipe_items=pipe_items)
+        if fused_mode == "pallas" and gpu_strategy == cpu_strategy:
+            # (Pallas computes at f32 natively — f32_keys is a no-op
+            # there; mixed per-axis strategies keep the two-axis
+            # canonical scorer, which only the jnp row implements.)
+            from .pallas_kernels import group_step_pallas
+            (key_now, key_pipe, cap_now, cap_tot,
+             levels, utype) = group_step_pallas(*row_args, **row_kw)
         else:
-            fit_now, fit_future = feasibility_row(
-                idle, rel, node_labels, node_taints, room, req,
-                group_sel[g], group_tol[g])
-            if group_mask is not None:
-                mask_row = group_mask[j]
-                fit_now = fit_now & mask_row
-                fit_future = fit_future & mask_row
-            if pipeline_only:
-                fit_now = jnp.zeros_like(fit_now)
-            feasible = fit_now | (fit_future
-                                  if (allow_pipeline or pipeline_only)
-                                  else jnp.zeros_like(fit_future))
-            score = score_row(node_allocatable, idle, req, feasible,
-                              fit_now, gpu_strategy, cpu_strategy)
-            if group_extra is not None:
-                score = score + group_extra[j]
-            score = jnp.where(feasible, score, NEG)
-            # Pipeline items score without the availability boost (the
-            # exact kernel's fit_now term vanishes once a node's idle is
-            # spent).
-            score_pipe = score - jnp.where(fit_now, AVAILABILITY, 0.0)
-            key_now, levels, utype = _score_keys(score, f32_keys)
-            key_pipe, _, _ = _score_keys(score_pipe, f32_keys)
-
-            safe_req = jnp.where(req > 0, req, 1.0)
-            cap_now_f = jnp.min(jnp.where(
-                req[None, :] > 0, jnp.floor(idle / safe_req[None, :]),
-                jnp.inf), axis=1)
-            cap_tot_f = jnp.min(jnp.where(
-                req[None, :] > 0,
-                jnp.floor((idle + rel) / safe_req[None, :]), jnp.inf),
-                axis=1)
-            cap_now = jnp.where(fit_now, jnp.minimum(cap_now_f, room), 0.0)
-            cap_tot = jnp.where(feasible, jnp.minimum(cap_tot_f, room),
-                                0.0)
-            cap_now = jnp.clip(cap_now, 0.0, count)
+            (key_now, key_pipe, cap_now, cap_tot,
+             levels, utype) = _fused_row(*row_args, f32_keys=f32_keys,
+                                         **row_kw)
+        cap_now = jnp.clip(cap_now, 0.0, count)
+        if pipe_items:
             cap_rel = jnp.clip(cap_tot - cap_now, 0.0, count)
-            if not (allow_pipeline or pipeline_only):
-                cap_rel = jnp.zeros_like(cap_rel)
-
-            # ONE exact greedy fill, sort-free, over the interleaved 2N
-            # (node, phase) items — item 2n is node n's idle capacity at
-            # its full score, item 2n+1 its releasing capacity without
-            # the availability boost.  Interleaving keeps equal-key ties
-            # resolved by ascending node index, matching the exact
-            # kernel's argmax.  The lax.cond skips the radix select
-            # entirely for satisfied demands (padded/gated groups) —
-            # most of a backlog cycle's step cost.
             key2 = jnp.stack([key_now, key_pipe], axis=1).reshape(-1)
             cap2 = jnp.stack([cap_now, cap_rel], axis=1).reshape(-1)
-            take2 = jax.lax.cond(
-                count > 0,
-                lambda: _fill_by_score(key2, levels, utype, cap2, count),
-                lambda: jnp.zeros_like(cap2))
+        else:
+            # Releasing tier provably dead: items ARE nodes — same
+            # ascending-index tie-break, half the fill width.
+            key2, cap2 = key_now, cap_now
+        take2 = jax.lax.cond(
+            count > 0,
+            lambda: _fill_by_score_descent(key2, levels, utype, cap2,
+                                           count),
+            lambda: jnp.zeros_like(cap2))
 
         if pipe_items:
             take_a = take2[0::2]
@@ -627,7 +506,7 @@ def allocate_groups_kernel(node_allocatable, node_idle, node_releasing,
                 take_b = jnp.where(gang_ok, take_b, 0.0)
 
         idle = idle - take_a[:, None] * req[None, :]
-        if not rel_static:
+        if not releasing_empty:
             rel = rel - (take_b if take_b is not None
                          else jnp.zeros_like(take_a))[:, None] * req[None, :]
         room = room - take_a - (take_b if take_b is not None else 0.0)
@@ -657,7 +536,7 @@ def allocate_groups_kernel(node_allocatable, node_idle, node_releasing,
     else:
         idle = jnp.where(carry.cur_ok, carry.idle, carry.ck_idle)
         rel = jnp.where(carry.cur_ok, carry.rel, carry.ck_rel)
-    if rel_static:
+    if releasing_empty:
         # The scan never touched releasing (cap_rel proven 0): the input
         # array IS the output, with no per-step carry copies paid.
         rel = node_releasing
@@ -739,25 +618,13 @@ def _allocate_groups_packed(node_allocatable, node_idle, node_releasing,
 
 
 def _resolve_fused_mode(requested: str | None, n_nodes: int) -> str:
-    """Resolve the fallback ladder TPU-Pallas -> fused-jnp -> legacy.
-
-    Explicit request (session config / tests) wins, then the
-    KAI_FUSED_ALLOC env pin, then ``auto``: the Pallas node-tile kernel
-    on a TPU backend whose node bucket tiles evenly, the fused jnp
-    formulation everywhere else.  ``legacy`` is only ever an explicit
-    choice — it exists for the parity suites and as the operator's
-    escape hatch, not as an automatic fallback target."""
-    mode = (requested or os.environ.get(_FUSED_ENV) or "auto").strip()
+    """Resolve the rung: an explicit request (tests, tools) wins, else
+    ``auto``: the Pallas node-tile kernel on a TPU backend whose node
+    bucket tiles evenly, the fused jnp formulation everywhere else."""
+    mode = requested or "auto"
     if mode not in FUSED_MODES:
-        # An unrecognized pin (case typo mid-incident) must be LOUD, not
-        # silently coerced back onto the rung the operator tried to
-        # escape.
-        from ..utils.logging import LOG
-        from ..utils.metrics import METRICS
-        LOG.warning("allocate_grouped: unrecognized %s=%r (valid: %s); "
-                    "using auto", _FUSED_ENV, mode, "|".join(FUSED_MODES))
-        METRICS.inc("allocate_fused_invalid_mode_total")
-        mode = "auto"
+        raise ValueError(f"fused_mode must be one of {FUSED_MODES}, "
+                         f"got {mode!r}")
     if mode == "auto":
         if jax.default_backend() == "tpu":
             from .pallas_kernels import NODE_TILE
@@ -765,7 +632,7 @@ def _resolve_fused_mode(requested: str | None, n_nodes: int) -> str:
                 return "pallas"
         return "jnp"
     if mode == "pallas":
-        # An explicitly pinned Pallas rung still needs a tileable node
+        # An explicitly requested Pallas rung still needs a tileable node
         # bucket; downgrade one rung (loudly, via the downgrade counter)
         # instead of crashing mid-dispatch.
         from .pallas_kernels import NODE_TILE
@@ -788,7 +655,7 @@ def allocate_grouped(node_arrays, task_req, task_job, task_selector,
                      node_mask=None,
                      fused_mode: str | None = None,
                      has_releasing: bool | None = None,
-                     f32_keys: bool | None = None) -> AllocationResult:
+                     f32_keys: bool = False) -> AllocationResult:
     """Host wrapper: group prep -> group-scan kernel (with on-device
     per-task expansion).
 
@@ -805,8 +672,8 @@ def allocate_grouped(node_arrays, task_req, task_job, task_selector,
     feasibility rows.  Jobs with either disable group merging across job
     boundaries (rows differ) but still fill in one step per group.
 
-    ``fused_mode``: pallas | jnp | legacy | auto (default: the
-    KAI_FUSED_ALLOC env pin, else auto — see ``_resolve_fused_mode``).
+    ``fused_mode``: pallas | jnp | auto (default auto — see
+    ``_resolve_fused_mode``).
     ``has_releasing``: host-verified hint that the releasing pool has any
     nonzero entry; callers holding host mirrors (the session via the
     arena state cache) pass it so the no-releasing fused specialization
@@ -892,7 +759,7 @@ def allocate_grouped(node_arrays, task_req, task_job, task_selector,
     n_nodes_padded = int(node_arrays[0].shape[0])
     mode = _resolve_fused_mode(fused_mode, n_nodes_padded)
     releasing_empty = False
-    if mode != "legacy" and not pipeline_only:
+    if not pipeline_only:
         if has_releasing is None:
             # Off-TPU the releasing array is host-adjacent (CPU backend)
             # so the hint is one cheap scan; on TPU assume releasing
@@ -900,14 +767,9 @@ def allocate_grouped(node_arrays, task_req, task_job, task_selector,
             has_releasing = True if jax.default_backend() == "tpu" \
                 else bool(np.asarray(node_arrays[2]).any())
         releasing_empty = not has_releasing
-    if f32_keys is None:
-        # KAI_F32_SCORE_KEYS=1 simulates the TPU key downcast on any
-        # backend (the precision-split suite's end-to-end hook).
-        f32_keys = os.environ.get("KAI_F32_SCORE_KEYS") == "1"
 
     from ..utils.metrics import METRICS
-    if mode != "legacy":
-        METRICS.inc("allocate_fused_taken_total", mode=mode)
+    METRICS.inc("allocate_fused_taken_total", mode=mode)
     TRACER.stamp("allocate_fused", mode=mode, groups=n_real_groups,
                  nodes=n_nodes_padded, releasing_empty=releasing_empty)
     packed, idle, rel = _allocate_groups_packed(

@@ -1,20 +1,15 @@
-"""Pallas TPU kernels for the allocation hot row ops.
+"""Pallas TPU kernel for the grouped allocation's hot row pass.
 
-Two generations of kernels live here:
-
-- ``task_row_pallas`` — the original per-TASK row pass (feasibility +
-  capacity for one task against all nodes), kept as the escape hatch for
-  the exact per-task kernel's largest shapes;
-- ``group_step_pallas`` — the fused per-GROUP-STEP row pass the grouped
-  fill-plan kernel (ops/allocate_grouped, fused_mode="pallas") runs
-  inside its scan.  One ``pallas_call`` with a (phase, node-tile) grid
-  sweeps the resident node state twice, entirely in VMEM per tile:
-  phase 0 accumulates the bin-pack min/max over the task's valid nodes
-  into SMEM scratch; phase 1 emits the fill keys (sign-flipped f32 score
-  bitcasts, ready for the radix-descent fill) and the idle/total
-  whole-task capacities.  That is TWO HBM reads of the node tensors per
-  group step and zero materialized [N]-wide intermediates, versus the
-  ~dozen reduction-separated passes of the unfused composition.
+``group_step_pallas`` is the fused per-GROUP-STEP row pass the grouped
+fill-plan kernel (ops/allocate_grouped, fused_mode="pallas") runs inside
+its scan.  One ``pallas_call`` with a (phase, node-tile) grid sweeps the
+resident node state twice, entirely in VMEM per tile: phase 0 accumulates
+the bin-pack min/max over the task's valid nodes into SMEM scratch;
+phase 1 emits the fill keys (sign-flipped f32 score bitcasts, ready for
+the radix-descent fill) and the idle/total whole-task capacities.  That
+is TWO HBM reads of the node tensors per group step and zero materialized
+[N]-wide intermediates, versus the ~dozen reduction-separated passes of
+the unfused composition.
 
 Semantics match ops.predicates.feasibility_caps_row +
 ops.scoring.score_row_selected at f32 (parity-tested in interpret mode);
@@ -24,112 +19,11 @@ backends or when the node bucket doesn't tile.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 NODE_TILE = 512
 NEG = -1e18
-
-
-def _row_kernel(req_ref, sel_ref, tol_ref, idle_ref, rel_ref, labels_ref,
-                taints_ref, room_ref, alloc_ref,
-                fit_now_ref, fit_fut_ref, cap_now_ref, cap_tot_ref):
-    """One node tile: feasibility masks + whole-task capacities.
-
-    Shapes per tile: idle/rel/alloc [TILE, R]; labels [TILE, L];
-    taints [TILE, Tt]; room [TILE]; req [R]; sel [L]; tol [Tl].
-    """
-    req = req_ref[...]            # [1, R]
-    sel = sel_ref[...]            # [1, L]
-    tol = tol_ref[...]            # [1, Tl]
-    idle = idle_ref[...]          # [TILE, R]
-    rel = rel_ref[...]
-    labels = labels_ref[...]      # [TILE, L]
-    taints = taints_ref[...]      # [TILE, Tt]
-    room = room_ref[...]          # [TILE, 1]
-
-    sel_ok = jnp.all((sel == -1) | (sel == labels), axis=-1,
-                     keepdims=True)                    # [TILE,1]
-    tolerated = jnp.any(taints[:, :, None] == tol[0][None, None, :],
-                        axis=-1)                       # [TILE,Tt]
-    taint_ok = jnp.all((taints == -1) | tolerated, axis=-1,
-                       keepdims=True)
-    hard = sel_ok & taint_ok & (room >= 1.0)
-
-    fits_idle = jnp.all(req <= idle + 1e-9, axis=-1, keepdims=True)
-    fits_total = jnp.all(req <= idle + rel + 1e-9, axis=-1, keepdims=True)
-    fit_now = hard & fits_idle
-    fit_fut = hard & fits_total
-
-    safe_req = jnp.where(req > 0, req, 1.0)
-    per_res_now = jnp.where(req > 0, jnp.floor(idle / safe_req), jnp.inf)
-    per_res_tot = jnp.where(req > 0, jnp.floor((idle + rel) / safe_req),
-                            jnp.inf)
-    cap_now = jnp.minimum(jnp.min(per_res_now, axis=-1, keepdims=True),
-                          room)
-    cap_tot = jnp.minimum(jnp.min(per_res_tot, axis=-1, keepdims=True),
-                          room)
-
-    fit_now_ref[...] = fit_now.astype(jnp.float32)
-    fit_fut_ref[...] = fit_fut.astype(jnp.float32)
-    cap_now_ref[...] = jnp.where(fit_now, cap_now, 0.0).astype(jnp.float32)
-    cap_tot_ref[...] = jnp.where(fit_fut, cap_tot, 0.0).astype(jnp.float32)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def task_row_pallas(req, sel, tol, node_idle, node_releasing, node_labels,
-                    node_taints, node_room, node_allocatable,
-                    interpret: bool | None = None):
-    """Fused per-task row pass: (fit_now, fit_future, cap_now, cap_tot)
-    each [N] — the Pallas version of feasibility_row + capacity math.
-
-    ``interpret`` defaults to True off-TPU (the Pallas CPU interpreter,
-    used by the test suite); on TPU the kernel compiles to Mosaic."""
-    from jax.experimental import pallas as pl
-
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    n = node_idle.shape[0]
-    tile = min(NODE_TILE, n)
-    if n % tile != 0:
-        raise ValueError(f"node count {n} must tile by {tile}")
-    grid = (n // tile,)
-    r = node_idle.shape[1]
-    L = node_labels.shape[1]
-    tt = node_taints.shape[1]
-
-    def node_block(shape_cols):
-        return pl.BlockSpec((tile, shape_cols), lambda i: (i, 0))
-
-    out_shape = [jax.ShapeDtypeStruct((n, 1), jnp.float32)] * 4
-    fit_now, fit_fut, cap_now, cap_tot = pl.pallas_call(
-        _row_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, r), lambda i: (0, 0)),      # req
-            pl.BlockSpec((1, L), lambda i: (0, 0)),      # sel
-            pl.BlockSpec((1, tol.shape[0]), lambda i: (0, 0)),  # tol
-            node_block(r),                                # idle
-            node_block(r),                                # releasing
-            node_block(L),                                # labels
-            node_block(tt),                               # taints
-            node_block(1),                                # room
-            node_block(r),                                # allocatable
-        ],
-        out_specs=[node_block(1)] * 4,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(req[None, :].astype(jnp.float32), sel[None, :].astype(jnp.int32),
-      tol[None, :].astype(jnp.int32),
-      node_idle.astype(jnp.float32), node_releasing.astype(jnp.float32),
-      node_labels.astype(jnp.int32), node_taints.astype(jnp.int32),
-      node_room.astype(jnp.float32)[:, None],
-      node_allocatable.astype(jnp.float32))
-    return (fit_now[:, 0] > 0.5, fit_fut[:, 0] > 0.5,
-            cap_now[:, 0], cap_tot[:, 0])
 
 
 def _where_s(flag, if_true, if_false):
@@ -376,24 +270,3 @@ def group_step_pallas(node_allocatable, idle, rel, node_labels,
     key_pipe = as_key(outs[2]) if pipe_items else None
     cap_tot = outs[3][0] if pipe_items else None
     return key_now, key_pipe, cap_now, cap_tot, 4, jnp.uint32
-
-
-def task_row_reference(req, sel, tol, node_idle, node_releasing,
-                       node_labels, node_taints, node_room):
-    """jnp reference for parity tests (mirrors feasibility_row + the
-    grouped kernel's capacity computation)."""
-    from .predicates import feasibility_row
-    fit_now, fit_fut = feasibility_row(
-        node_idle, node_releasing, node_labels, node_taints, node_room,
-        req, sel, tol)
-    safe_req = jnp.where(req > 0, req, 1.0)
-    cap_now = jnp.min(jnp.where(req[None, :] > 0,
-                                jnp.floor(node_idle / safe_req[None, :]),
-                                jnp.inf), axis=1)
-    cap_tot = jnp.min(jnp.where(
-        req[None, :] > 0,
-        jnp.floor((node_idle + node_releasing) / safe_req[None, :]),
-        jnp.inf), axis=1)
-    cap_now = jnp.where(fit_now, jnp.minimum(cap_now, node_room), 0.0)
-    cap_tot = jnp.where(fit_fut, jnp.minimum(cap_tot, node_room), 0.0)
-    return fit_now, fit_fut, cap_now, cap_tot

@@ -79,8 +79,8 @@ def feasibility_caps_row(idle, releasing, labels, taints, room,
 
     ``releasing=None`` declares the caller has proven the releasing pool
     empty: fit_future and cap_tot_f alias the fit-now outputs (with
-    releasing == 0 the legacy formulas reduce to exactly that, including
-    EPS behaviour).
+    releasing == 0 ``feasibility_row``'s formulas reduce to exactly
+    that, including EPS behaviour).
     """
     hard = hard_row(labels, taints, room, selector, tolerations)
 

@@ -30,11 +30,10 @@ randomized instances under KAI_FAULT_SEED):
 
 - ``rank_place_kernel``: one jitted dispatch — a stable ``lax.sort`` of
   (topology-rank, slot-index) pairs plus the per-level hop fold — the
-  in-kernel scoring home the fused per-group-step ladder feeds;
+  in-kernel scoring home the fused per-group-step fill feeds;
 - ``rank_place_np``: the host reference (``np.lexsort`` is the same
-  stable sort), kept verbatim as the legacy rung for bit-parity A/B and
-  as the small-gang fast path (a 4-wide gang is cheaper on host than a
-  dispatch).
+  stable sort), which is also the small-gang fast path (a 4-wide gang is
+  cheaper on host than a dispatch).
 
 Hop metric: hop(a, b) = 0 for the same node, else 1 + the number of
 topology levels whose domains differ (a missing label counts as
@@ -45,7 +44,6 @@ tree distance in boundary crossings.
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
 
 import jax
@@ -54,10 +52,9 @@ import numpy as np
 
 from .topology import ROOT_LEVEL, TopologyTree
 
-# Mode pin: "kernel" | "host" | "auto" (auto = kernel for gangs of at
-# least _KERNEL_MIN_GANG slots, host below — both paths bit-identical,
-# the threshold is purely a dispatch-overhead choice).
-_MODE_ENV = "KAI_RANKPLACE"
+# Gangs of at least this many slots take the kernel, smaller ones the
+# host reference — both paths bit-identical, the threshold is purely a
+# dispatch-overhead choice.
 _KERNEL_MIN_GANG = 32
 
 
@@ -123,7 +120,7 @@ def _hops_np(nodes_by_rank: np.ndarray, level_segs: np.ndarray
 def rank_place_np(slot_nodes: np.ndarray, topo_rank: np.ndarray,
                   level_segs: np.ndarray
                   ) -> tuple[np.ndarray, np.ndarray]:
-    """Host reference (the legacy parity rung).
+    """Host reference (and the small-gang path).
 
     ``slot_nodes``: [T] packed node index per gang slot.  Returns
     (perm [T] int32 — slot index for rank position k, hops [T-1] int32
@@ -186,12 +183,9 @@ def rank_place_padded(slot_nodes: np.ndarray, topo_rank, level_segs
     return perm[:t], hops[:max(t - 1, 0)]
 
 
-def resolve_mode(requested: str | None, gang_size: int) -> str:
-    """kernel | host, honoring the KAI_RANKPLACE pin."""
-    mode = (requested or os.environ.get(_MODE_ENV) or "auto").strip()
-    if mode not in ("kernel", "host"):
-        mode = "kernel" if gang_size >= _KERNEL_MIN_GANG else "host"
-    return mode
+def resolve_mode(gang_size: int) -> str:
+    """kernel | host, by the gang's size."""
+    return "kernel" if gang_size >= _KERNEL_MIN_GANG else "host"
 
 
 def mean_hop(nodes_by_rank: np.ndarray, order: TopoOrder) -> float:

@@ -372,7 +372,7 @@ class TopologySession:
             if idx < 0:
                 return None
             slot_nodes[i] = idx
-        mode = rp.resolve_mode(None, len(placements))
+        mode = rp.resolve_mode(len(placements))
         with TRACER.span("rankplace", kind="rankplace",
                          gang=len(placements), tree=tree.name,
                          mode=mode) as sp:

@@ -198,7 +198,8 @@ def sharded_allocate_groups_kernel(mesh, node_allocatable, node_idle,
 
             # Sort-free distributed fill: the score threshold comes from
             # radix-select over psum-merged capacity histograms (the
-            # multi-chip form of ops/allocate_grouped._fill_by_score),
+            # multi-chip form of the single-chip fill's threshold
+            # search, ops/allocate_grouped._fill_by_score_descent),
             # replacing the per-step local+global top_k sorts.
             key, levels, utype = _score_keys(score)
             take_a = _fill_by_score_sharded(key, levels, utype, cap_now,

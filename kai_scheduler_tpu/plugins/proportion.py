@@ -374,18 +374,13 @@ class ProportionPlugin(Plugin):
             q = self.queues.get(q.parent) if q.parent else None
 
     def _set_fair_share(self, ssn) -> None:
-        """Run the hierarchical division kernel (proportion.go:403-440).
-
-        Two paths behind ``config.fused_fairshare`` (bit-identical,
-        property-tested):
-        - ``forest`` (default): ONE jitted dispatch for the whole queue
-          hierarchy, with the host prep (hierarchy build, dense level
-          layout, weight-tensor upload) cached across cycles keyed on
-          the queue set + weights (ops/fairshare.prepared_forest) — a
-          steady 10k-queue forest pays one hash and one dispatch;
-        - ``levels``: the pre-forest per-level dispatch loop, kept as
-          the A/B baseline and parity reference.
-        """
+        """Run the hierarchical division kernel (proportion.go:403-440):
+        ONE jitted dispatch for the whole queue hierarchy, with the host
+        prep (hierarchy build, dense level layout, weight-tensor upload)
+        cached across cycles keyed on the queue set + weights
+        (ops/fairshare.prepared_forest) — a steady 10k-queue forest pays
+        one hash and one dispatch.  Property-tested bit-identical to the
+        per-level reference ``fair_share_levels``."""
         from ..utils.metrics import METRICS
         qids = sorted(self.queues)
         index = {qid: i for i, qid in enumerate(qids)}
@@ -402,47 +397,35 @@ class ProportionPlugin(Plugin):
         deserved, limit = stack("deserved"), stack("limit")
         oqw = stack("over_quota_weight")
         request, usage = stack("request"), stack("usage")
-        mode = getattr(ssn.config, "fused_fairshare", "forest")
         validate = lambda r: getattr(r, "shape", (0,))[0] >= n
         # Guarded like every other device dispatch: session open must
         # degrade to the CPU fallback on a dead device, not wedge the
         # cycle before its first action.
-        with TRACER.span("fairshare", kind="fairshare", queues=n,
-                         mode=mode) as sp:
-            if mode == "forest":
-                # The prep (hierarchy build + layout/weight uploads)
-                # lives INSIDE the guarded thunk: its jnp.asarray calls
-                # touch the device, and on a guard fallback the thunk
-                # re-runs on the CPU backend AFTER fallback_calls
-                # bumped — so prepared_forest's GuardWatch drops the
-                # dead-device cache entry and rebuilds host-side.
-                info: dict = {}
+        with TRACER.span("fairshare", kind="fairshare", queues=n) as sp:
+            # The prep (hierarchy build + layout/weight uploads) lives
+            # INSIDE the guarded thunk: its jnp.asarray calls touch the
+            # device, and on a guard fallback the thunk re-runs on the
+            # CPU backend AFTER fallback_calls bumped — so
+            # prepared_forest's GuardWatch drops the dead-device cache
+            # entry and rebuilds host-side.
+            info: dict = {}
 
-                def forest_thunk():
-                    prep = fsops.prepared_forest(
-                        parent, priority, creation, qids, deserved,
-                        limit, oqw, out_info=info)
-                    info["prep"] = prep
-                    return fsops.fair_share_forest(
-                        self.total, ssn.config.k_value, prep, request,
-                        usage)
+            def forest_thunk():
+                prep = fsops.prepared_forest(
+                    parent, priority, creation, qids, deserved,
+                    limit, oqw, out_info=info)
+                info["prep"] = prep
+                return fsops.fair_share_forest(
+                    self.total, ssn.config.k_value, prep, request,
+                    usage)
 
-                fair = ssn.dispatch_kernel(forest_thunk,
-                                           label="fair_share",
-                                           validate=validate)
-                prep = info.get("prep")
-                if prep is not None:
-                    sp.set(levels=prep.spec.num_levels,
-                           bands=prep.spec.num_bands,
-                           prep_reused=bool(info.get("reused")))
-            else:
-                hier = fsops.QueueHierarchy.build(parent, priority,
-                                                  creation, qids)
-                fair = ssn.dispatch_kernel(
-                    lambda: fsops.fair_share_levels(
-                        self.total, ssn.config.k_value, hier, deserved,
-                        limit, oqw, request, usage),
-                    label="fair_share", validate=validate)
+            fair = ssn.dispatch_kernel(forest_thunk, label="fair_share",
+                                       validate=validate)
+            prep = info.get("prep")
+            if prep is not None:
+                sp.set(levels=prep.spec.num_levels,
+                       bands=prep.spec.num_bands,
+                       prep_reused=bool(info.get("reused")))
         fair = fsops.restore_exact(fair, deserved, limit, request)
         store = self._qattr_store(ssn.cache)
         gauges = store["gauges"] if store is not None else {}

@@ -47,8 +47,8 @@ LATENCY_TESTS = ["tests/test_lifecycle.py"]
 INCREMENTAL_TESTS = ["tests/test_incremental_cache.py"]
 # --fused: the fused-allocation parity ring — each seed regenerates the
 # randomized workloads (tests/test_fused_parity.py reads KAI_FAULT_SEED
-# into its instance generator) and re-proves legacy/jnp/Pallas
-# bit-identity plus the breaker-open fallback.
+# into its instance generator) and re-proves jnp/Pallas bit-identity
+# to the exact kernel plus the breaker-open fallback.
 FUSED_TESTS = ["tests/test_fused_parity.py"]
 # --shards: the concurrent-sharded-schedulers churn ring — each seed
 # reshuffles the submit/complete stream while two shards cycle in real
@@ -189,7 +189,8 @@ def main(argv=None) -> int:
                     help="fused mode: sweep the fused-allocation parity "
                          f"ring ({FUSED_TESTS}) — each seed regenerates "
                          "the randomized workloads and re-proves "
-                         "legacy/jnp/Pallas placement bit-identity")
+                         "jnp/Pallas placement bit-identity to the "
+                         "exact kernel")
     ap.add_argument("--shards", action="store_true",
                     help="shards mode: sweep the concurrent-shards churn "
                          f"ring ({SHARDS_TESTS}) — each seed reshuffles "

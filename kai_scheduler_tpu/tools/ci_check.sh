@@ -27,7 +27,7 @@
 #   conformance   tools/conformance.py --smoke: every proof in one
 #                 command — all three analyzers, every chaos-matrix
 #                 mode definition, and a real 1-seed wire-faults sweep
-#   kernel parity fused-allocation ladder (Pallas/jnp/legacy) vs the
+#   kernel parity the grouped fill's two rungs (Pallas, jnp) vs the
 #                 exact kernel: placements must be bit-identical
 #                 (tools/kernel_parity.py --smoke)
 #   stackprof     continuous-profiler smoke: profile a short embedded
@@ -107,7 +107,7 @@ JAX_PLATFORMS=cpu python -m kai_scheduler_tpu.tools.conformance --smoke \
     || fail=1
 
 echo
-echo "== kernel-parity smoke (fused ladder vs legacy vs exact) =="
+echo "== kernel-parity smoke (jnp and pallas rungs vs exact) =="
 JAX_PLATFORMS=cpu python -m kai_scheduler_tpu.tools.kernel_parity \
     --smoke || fail=1
 

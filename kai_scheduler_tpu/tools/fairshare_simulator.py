@@ -62,13 +62,15 @@ def simulate(payload: dict, backend: str = "numpy") -> dict:
     request = fsops.roll_up_requests(parent, leaf_request)
 
     if backend == "jax":
-        hier = fsops.QueueHierarchy.build(parent, priority, creation, names)
-        # Offline CLI: there is no Session (and no device-guard) here —
-        # the simulator exists to diff the jax kernel against the
-        # sequential reference below, so the call is direct by design.
+        # What the scheduler runs (plugins/proportion._set_fair_share):
+        # the prepared forest, one dispatch.  Offline CLI: there is no
+        # Session (and no device-guard) here — the simulator exists to
+        # diff the product's kernel against the sequential reference
+        # below, so the call is direct by design.
+        prep = fsops.prepared_forest(parent, priority, creation, names,
+                                     deserved, limit, oqw)
         # kailint: disable=KAI004 — offline simulator, no Session to dispatch through
-        fair = fsops.fair_share_levels(total, k, hier, deserved, limit, oqw,
-                                       request, usage)
+        fair = fsops.fair_share_forest(total, k, prep, request, usage)
     else:
         # Sequential reference, level by level (proportion.go:410-425).
         fair = np.zeros((q, rs.NUM_RES))

@@ -14,7 +14,7 @@ result against ``docs/scale-tests/fleet_budget.json``:
   the cycle count), the podgrouper's owner-resolution memo must see
   hits, and the GROUPED ALLOCATION path must actually take the fused
   kernel (``allocate_fused_taken_total`` counts per wrapper dispatch —
-  a silent fall-back-to-legacy regression zeroes it while every
+  a silent fall-back to the per-job path zeroes it while every
   wall-clock gate still passes on a fast machine);
 - **allocate-kernel ceiling**: the grouped kernel itself is re-measured
   at a small committed shape (``allocate_shape``) and its median must

@@ -1,17 +1,17 @@
-"""Kernel-parity smoke: diff the fused allocation ladder against its
-references in one command.
+"""Kernel-parity smoke: diff the grouped fill's two rungs against the
+reference in one command.
 
 For each seed, a randomized gang workload runs through:
 
-- the legacy grouped kernel (the committed reference formulation),
 - the fused-jnp rung (``fused_mode="jnp"``),
 - the Pallas rung in interpreter mode (``fused_mode="pallas"``),
-- the exact per-task kernel (``ops/allocate.allocate_jobs_kernel``),
+- the exact per-task kernel (``ops/allocate.allocate_jobs_kernel``), the
+  reference,
 
-and every pairing must agree bit-for-bit on placements, pipelined flags
-and job success.  This is the ci_check.sh gate that catches a fused-path
-drift WITHOUT waiting for the full pytest ring; at `--seeds N` it doubles
-as a longer offline sweep.
+and both rungs must agree with the reference bit-for-bit on placements,
+pipelined flags and job success.  This is the ci_check.sh gate that
+catches a fused-path drift WITHOUT waiting for the full pytest ring; at
+`--seeds N` it doubles as a longer offline sweep.
 
 Usage (ci_check.sh runs --smoke):
 
@@ -71,28 +71,21 @@ def run_seed(seed: int, n_nodes: int, n_jobs: int) -> list[str]:
         # kailint: disable=KAI004 — offline parity sweep, no Session to dispatch through
         mode: allocate_grouped(nodes, req, job, sel, tol, allowed,
                                fused_mode=mode)
-        for mode in ("legacy", "jnp", "pallas")
+        for mode in ("jnp", "pallas")
     }
     # kailint: disable=KAI004 — offline parity sweep, no Session to dispatch through
     exact = allocate_jobs_kernel(*nodes, jnp.asarray(req),
                                  jnp.asarray(job), jnp.asarray(sel),
                                  jnp.asarray(tol), jnp.asarray(allowed))
     problems = []
-    ref = outs["legacy"]
-    for mode in ("jnp", "pallas"):
+    for mode, out in outs.items():
         for field in ("placements", "pipelined", "job_success"):
-            a = np.asarray(getattr(ref, field))
-            b = np.asarray(getattr(outs[mode], field))
+            a = np.asarray(getattr(exact, field))
+            b = np.asarray(getattr(out, field))
             if not (a == b).all():
                 problems.append(
-                    f"seed {seed}: {mode} != legacy on {field} "
+                    f"seed {seed}: {mode} != exact kernel on {field} "
                     f"({int((a != b).sum())} rows)")
-    for field in ("placements", "pipelined", "job_success"):
-        a = np.asarray(getattr(exact, field))
-        b = np.asarray(getattr(ref, field))
-        if not (a == b).all():
-            problems.append(
-                f"seed {seed}: legacy grouped != exact kernel on {field}")
     return problems
 
 
@@ -114,7 +107,7 @@ def main(argv=None) -> int:
     for seed in seeds:
         problems = run_seed(seed, args.nodes, args.jobs)
         status = "ok  " if not problems else "FAIL"
-        print(f"{status} seed {seed}  (legacy/jnp/pallas/exact agree)"
+        print(f"{status} seed {seed}  (jnp/pallas/exact agree)"
               if not problems else f"{status} seed {seed}", flush=True)
         for p in problems:
             print("     " + p, flush=True)
@@ -124,8 +117,8 @@ def main(argv=None) -> int:
         print(f"kernel parity: FAILED ({len(failures)} mismatch(es) "
               f"in {dt:.1f}s)")
         return 1
-    print(f"kernel parity: all rungs bit-identical over "
-          f"{len(list(seeds))} seed(s) in {dt:.1f}s")
+    print(f"kernel parity: both rungs bit-identical to the exact kernel "
+          f"over {len(list(seeds))} seed(s) in {dt:.1f}s")
     return 0
 
 

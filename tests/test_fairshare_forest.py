@@ -55,8 +55,8 @@ def structured_forest(seed, q=10000, roots=16, fanouts=(2, 2, 2, 2, 2, 8),
                       bands=1):
     """A multi-tenant org tree at scale: ``roots`` top-level tenants,
     breadth-first fanout per depth, depth >= len(fanouts).  The topology
-    comes from bench.forest_parent_indices — the same forest the
-    committed ``fairshare-10k-ab``/``churn-ring`` rows measure."""
+    comes from bench.forest_parent_indices — the same forest
+    ``bench.fairshare_microbench`` and the churn ring run."""
     import bench
     rng = np.random.default_rng(SEED_BASE + seed)
     parent = bench.forest_parent_indices(q, roots, fanouts)
@@ -273,7 +273,8 @@ class TestPrepCache:
 
 class TestPluginIntegration:
     def test_forest_and_levels_modes_agree_end_to_end(self):
-        from kai_scheduler_tpu.framework import SchedulerConfig
+        """The session's fair shares (the forest dispatch) against the
+        per-level reference called on the plugin's own inputs."""
         from tests.fixtures import build_session
 
         spec = {
@@ -288,18 +289,30 @@ class TestPluginIntegration:
                      for i, q in enumerate(
                          ["team-a", "team-a", "team-b", "solo"])},
         }
-        shares = {}
-        for mode in ("forest", "levels"):
-            ssn = build_session(spec, config=SchedulerConfig(
-                fused_fairshare=mode))
-            shares[mode] = {
-                qid: attrs.fair_share.copy()
-                for qid, attrs in ssn.proportion.queues.items()}
-        assert shares["forest"].keys() == shares["levels"].keys()
-        for qid in shares["forest"]:
+        ssn = build_session(spec)
+        prop = ssn.proportion
+        qids = sorted(prop.queues)
+        index = {qid: i for i, qid in enumerate(qids)}
+        col = lambda attr: np.stack(
+            [getattr(prop.queues[q], attr) for q in qids])
+        parent = np.array([index.get(prop.queues[q].parent, -1)
+                           for q in qids], np.int64)
+        hier = fs.QueueHierarchy.build(
+            parent, np.array([prop.queues[q].priority for q in qids]),
+            np.array([prop.queues[q].creation_ts for q in qids]), qids)
+        deserved, limit, request = \
+            col("deserved"), col("limit"), col("request")
+        levels = fs.restore_exact(
+            fs.fair_share_levels(prop.total, ssn.config.k_value, hier,
+                                 deserved, limit, col("over_quota_weight"),
+                                 request, col("usage")),
+            deserved, limit, request)
+        assert {"org", "team-a", "team-b", "solo"} <= set(qids)
+        for qid, i in index.items():
             np.testing.assert_array_equal(
-                shares["forest"][qid], shares["levels"][qid],
-                err_msg=f"queue {qid} fair share differs across modes")
+                prop.queues[qid].fair_share, levels[i],
+                err_msg=f"queue {qid} fair share differs from the "
+                        f"per-level reference")
 
     def test_session_open_counts_single_dispatch_and_span(self):
         from kai_scheduler_tpu.utils.tracing import TRACER
@@ -322,4 +335,4 @@ class TestPluginIntegration:
         spans = [s for s in trace.spans if s.kind == "fairshare"]
         assert len(spans) == 1
         assert spans[0].attrs["queues"] == 3
-        assert spans[0].attrs["mode"] == "forest"
+        assert "mode" not in spans[0].attrs

@@ -1,13 +1,9 @@
 """Pallas kernel parity tests (run in interpreter mode on CPU; the same
-kernels compile for real TPU)."""
+kernel compiles for real TPU)."""
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-from kai_scheduler_tpu.ops.pallas_kernels import (task_row_pallas,
-                                                  task_row_reference)
 
 
 def make_inputs(seed, n=512):
@@ -27,21 +23,6 @@ def make_inputs(seed, n=512):
     return (jnp.asarray(req), jnp.asarray(sel), jnp.asarray(tol),
             jnp.asarray(idle), jnp.asarray(rel), jnp.asarray(labels),
             jnp.asarray(taints), jnp.asarray(room), jnp.asarray(alloc))
-
-
-@pytest.mark.parametrize("seed", range(4))
-def test_pallas_row_matches_reference(seed):
-    req, sel, tol, idle, rel, labels, taints, room, alloc = \
-        make_inputs(seed)
-    ref = task_row_reference(req, sel, tol, idle, rel, labels, taints,
-                             room)
-    out = task_row_pallas(req, sel, tol, idle, rel, labels, taints, room,
-                          alloc)
-    for name, a, b in zip(("fit_now", "fit_future", "cap_now", "cap_tot"),
-                          ref, out):
-        np.testing.assert_allclose(
-            np.asarray(a, dtype=np.float32), np.asarray(b, np.float32),
-            err_msg=name, atol=1e-5)
 
 
 class TestGroupStepPallas:

@@ -236,14 +236,15 @@ class TestEndToEnd:
                     if str(k).startswith("rank_place_assignments_total"))
         assert after > before
 
-    def test_kernel_and_host_modes_agree_end_to_end(self):
-        os.environ["KAI_RANKPLACE"] = "kernel"
-        try:
-            _ssn_k, idx_k, _ = _mpi_session(True)
-        finally:
-            os.environ["KAI_RANKPLACE"] = "host"
-        try:
-            _ssn_h, idx_h, _ = _mpi_session(True)
-        finally:
-            del os.environ["KAI_RANKPLACE"]
-        assert np.array_equal(idx_k, idx_h)
+    def test_kernel_and_host_modes_agree_end_to_end(self, monkeypatch):
+        from kai_scheduler_tpu.utils.metrics import METRICS
+        idx = {}
+        # The 16-rank gang stands on one side of the threshold, then the
+        # other: gang size is the only thing the choice reads.
+        for mode, min_gang in (("kernel", 16), ("host", 17)):
+            monkeypatch.setattr(rp, "_KERNEL_MIN_GANG", min_gang)
+            key = f'rank_place_assignments_total{{mode="{mode}"}}'
+            before = METRICS.counters.get(key, 0)
+            _ssn, idx[mode], _ = _mpi_session(True)
+            assert METRICS.counters.get(key, 0) > before
+        assert np.array_equal(idx["kernel"], idx["host"])
