@@ -9,6 +9,8 @@ preempt validators (minruntime) approve.
 from __future__ import annotations
 
 from ..api.podgroup_info import PodGroupInfo
+from ..utils.metrics import METRICS
+from ..utils.tracing import TRACER
 from .solvers import solve_job
 from .utils import INFINITE, JobsOrderByQueues
 
@@ -41,7 +43,12 @@ class PreemptAction:
                 order.requeue_queue(job.queue_id)
                 continue
             if survey is None:
-                survey = survey_preempt_victims(ssn)
+                # The first preemptor of the cycle pays the walk over
+                # every PodGroup; the others reuse it.
+                with TRACER.span("preempt:survey", kind="preempt") as sv:
+                    survey = survey_preempt_victims(ssn)
+                    sv.set(queues=len(survey),
+                           victims=sum(len(v) for v in survey.values()))
             victims = [pg for pg in survey.get(job.queue_id, [])
                        if pg.priority < job.priority and pg.uid != job.uid]
             victims = ssn.filter_preempt_victims(job, victims)
@@ -50,6 +57,12 @@ class PreemptAction:
                 continue
             result = solve_job(ssn, job, victims,
                                ssn.validate_preempt_scenario, self.name)
+            # Both series move, one of them by 0, so that a process whose
+            # preemptors were all solved reads 0 unsolved and not nothing.
+            METRICS.inc("preemptors_solved_total", int(result.success),
+                        result="solved")
+            METRICS.inc("preemptors_solved_total", int(not result.success),
+                        result="unsolved")
             if result.success:
                 gone = {uid for uid in result.evicted_jobs
                         if ssn.cluster.podgroups[uid]

@@ -367,7 +367,9 @@ def _prescreen_verdict(ssn, tasks, builder: "ScenarioBuilder", sp):
 
     from ..utils.deviceguard import CycleDeadlineExceeded, DeviceGuardError
     METRICS.inc("device_kernel_calls")
-    # Both families move by 0 where the form adds nothing, so that they
+    # Every dispatched prescreen, whatever its form.
+    METRICS.inc("scenario_prescreen_calls_total")
+    # The next families move by 0 where the form adds nothing, so that they
     # read 0 and are not absent: a process whose gangs are all of mixed
     # rows counts none, one whose gangs are all alike takes no step.
     METRICS.inc("scenario_prescreen_counted_total", int(form == "counted"))

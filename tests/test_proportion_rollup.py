@@ -531,7 +531,8 @@ def _metric():
 
 def test_the_benchmarks_metric_is_this_counter():
     bench, entry, doc = _metric()
-    assert bench["per_layer"][-1] is entry
+    # Asked by name: later PRs append their metrics after this one.
+    assert entry in bench["per_layer"]
     assert entry["workloads"] == [w["name"] for w in bench["workloads"]]
     assert doc.pop("reader") == {"kind": "counter_delta", "counter": WALKED}
     assert doc == {k: v for k, v in entry.items() if k != "workloads"}
