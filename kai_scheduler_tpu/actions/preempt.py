@@ -8,11 +8,20 @@ preempt validators (minruntime) approve.
 
 from __future__ import annotations
 
+from bisect import bisect_left
+
 from ..api.podgroup_info import PodGroupInfo
 from ..utils.metrics import METRICS
 from ..utils.tracing import TRACER
-from .solvers import solve_job
+from .solvers import VictimOffers, solve_job, victim_offer
 from .utils import INFINITE, JobsOrderByQueues
+
+# The victim PodGroups the action read to give its preemptors their
+# candidates and their budgets; the one survey's walk is not among them.
+EXAMINED = "preempt_victims_examined_total"
+# The least the victim filters are handed at a time once a first chunk of
+# ``max_victims_considered`` came back short.
+FILTER_CHUNK = 64
 
 
 class PreemptAction:
@@ -29,9 +38,10 @@ class PreemptAction:
             ssn, pending,
             ssn.config.queue_depth_per_action.get(self.name, INFINITE))
         failed_signatures: set[str] = set()
-        # Per-queue victim survey, maintained incrementally (the per-job
-        # rescan of every podgroup dominates cycle time at scale).
-        survey: dict | None = None
+        # One ledger a queue, built from the cycle's one survey (the
+        # per-job rescan of every podgroup dominates cycle time at scale)
+        # and patched by every commit.
+        ledgers: dict | None = None
 
         while not order.empty():
             job = order.pop_next_job()
@@ -42,21 +52,24 @@ class PreemptAction:
                     and sig in failed_signatures:
                 order.requeue_queue(job.queue_id)
                 continue
-            if survey is None:
+            if ledgers is None:
                 # The first preemptor of the cycle pays the walk over
                 # every PodGroup; the others reuse it.
                 with TRACER.span("preempt:survey", kind="preempt") as sv:
-                    survey = survey_preempt_victims(ssn)
-                    sv.set(queues=len(survey),
-                           victims=sum(len(v) for v in survey.values()))
-            victims = [pg for pg in survey.get(job.queue_id, [])
-                       if pg.priority < job.priority and pg.uid != job.uid]
-            victims = ssn.filter_preempt_victims(job, victims)
+                    ledgers = {queue: VictimLedger(jobs) for queue, jobs
+                               in survey_preempt_victims(ssn).items()}
+                    sv.set(queues=len(ledgers),
+                           victims=sum(len(led.jobs)
+                                       for led in ledgers.values()))
+            ledger = ledgers.get(job.queue_id)
+            victims, offers = (ledger.candidates(ssn, job) if ledger
+                               else ([], None))
             if not victims:
                 order.requeue_queue(job.queue_id)
                 continue
             result = solve_job(ssn, job, victims,
-                               ssn.validate_preempt_scenario, self.name)
+                               ssn.validate_preempt_scenario, self.name,
+                               offers=offers)
             # Both series move, one of them by 0, so that a process whose
             # preemptors were all solved reads 0 unsolved and not nothing.
             METRICS.inc("preemptors_solved_total", int(result.success),
@@ -64,15 +77,75 @@ class PreemptAction:
             METRICS.inc("preemptors_solved_total", int(not result.success),
                         result="unsolved")
             if result.success:
-                gone = {uid for uid in result.evicted_jobs
-                        if ssn.cluster.podgroups[uid]
-                        .num_active_allocated() == 0}
-                survey[job.queue_id] = [
-                    pg for pg in survey.get(job.queue_id, [])
-                    if pg.uid not in gone]
+                ledger.committed(ssn, job, result.evicted_jobs)
             elif ssn.config.use_scheduling_signatures:
                 failed_signatures.add(sig)
             order.requeue_queue(job.queue_id)
+
+
+class VictimLedger:
+    """One queue's victims for one cycle of the preempt action: what its
+    preemptors, one after another, take their candidates from.
+
+    ``jobs`` is the survey's list, weakest first, and stays the survey's:
+    a job leaves it when a commit took its last active pod, and none
+    joins.  ``priorities`` stands beside it so that "strictly lower
+    priority than this preemptor" (preempt.go:126-155) is a bisect and a
+    slice; a preemptor that runs and is preemptible is in the list too,
+    at its own priority, and so never inside its own slice.  ``_offers``
+    holds what a victim offers a solve (``solvers.victim_offer``), read
+    off its pods when a preemptor first needs it and again only after a
+    commit touched the job."""
+
+    def __init__(self, jobs: list):
+        self.jobs = list(jobs)
+        self.priorities = [pg.priority for pg in jobs]
+        self._offers: dict = {}
+
+    def candidates(self, ssn, preemptor) -> tuple[list, VictimOffers]:
+        """The first ``max_victims_considered`` victims of strictly lower
+        priority that the session's filters admit, and their offers:
+        ``filter(whole slice)[:cap]``, found from the head in chunks (the
+        filters' contract, ``Session.filter_preempt_victims``)."""
+        cap = ssn.config.max_victims_considered
+        end = bisect_left(self.priorities, preemptor.priority)
+        victims: list = []
+        pos = examined = 0
+        while len(victims) < cap and pos < end:
+            # The cap's worth first: all there is to read where no filter
+            # drops a victim.
+            step = cap if pos == 0 else max(cap - len(victims), FILTER_CHUNK)
+            chunk = self.jobs[pos:min(end, pos + step)]
+            pos += len(chunk)
+            examined += len(chunk)
+            victims.extend(ssn.filter_preempt_victims(preemptor, chunk))
+        del victims[cap:]
+        offers = VictimOffers()
+        for pg in victims:
+            offer = self._offers.get(pg.uid)
+            if offer is None:
+                offer = self._offers[pg.uid] = victim_offer(pg)
+                examined += 1
+            offers.append(*offer)
+        METRICS.inc(EXAMINED, examined)
+        return victims, offers
+
+    def committed(self, ssn, preemptor, evicted_jobs) -> None:
+        """Patch after ``preemptor``'s solve committed: the jobs it took
+        from leave with their last active pod or offer what is left (a
+        job that shed only its surplus), and the preemptor's own pods are
+        placed now.  A solve that fails discards its statement and
+        patches nothing."""
+        self._offers.pop(preemptor.uid, None)
+        gone = []
+        for uid in evicted_jobs:
+            self._offers.pop(uid, None)
+            pg = ssn.cluster.podgroups[uid]
+            if pg.num_active_allocated() == 0:
+                gone.append(self.jobs.index(pg))
+        for i in sorted(gone, reverse=True):
+            del self.jobs[i], self.priorities[i]
+        METRICS.inc(EXAMINED, len(evicted_jobs))
 
 
 def survey_preempt_victims(ssn) -> dict:

@@ -22,6 +22,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..api import resources as rs
+from ..api.cluster_info import EXACT_BELOW
 from ..api.podgroup_info import PodGroupInfo
 from ..framework import propose
 from ..ops.allocate_grouped import _next_pow2
@@ -50,11 +51,14 @@ class ScenarioBuilder:
     """
 
     def __init__(self, pending_job: PodGroupInfo, pending_tasks: list,
-                 ordered_victims: list[PodGroupInfo]):
+                 ordered_victims: list[PodGroupInfo],
+                 offers: "VictimOffers | None" = None):
         self.scenario = Scenario(pending_job, pending_tasks)
         self._steps: list = []
-        for victim in ordered_victims:
-            elastic, core = _split_victim_tasks(victim)
+        if offers is None:
+            offers = VictimOffers.read(ordered_victims)
+        for victim, elastic, core in zip(ordered_victims, offers.elastic,
+                                         offers.core):
             if elastic:
                 self._steps.append((victim, elastic))
             if core:
@@ -88,6 +92,48 @@ def _split_victim_tasks(victim: PodGroupInfo):
         else:
             core.extend(active)
     return elastic, core
+
+
+def victim_offer(victim: PodGroupInfo) -> tuple:
+    """What one victim job offers a solve, read off its pods in one walk:
+    ``(elastic, core, reqs)``, the split ``ScenarioBuilder`` steps through
+    and the ``[pods, R]`` rows the victim adds to ``_within_budget``.  It
+    holds until a statement that touched the job commits (a discarded
+    statement puts every pod back as it was)."""
+    elastic, core = _split_victim_tasks(victim)
+    # The split holds every active pod of the job once (each pod is
+    # indexed in one pod set), so its requests are the job's.
+    reqs = np.array([t.res_req.to_vec(mig_as_gpu=False)
+                     for t in elastic + core]).reshape(-1, rs.NUM_RES)
+    # An empty part is the one empty tuple: a thousand empty lists kept
+    # for a solve's length are a thousand objects the collector carries.
+    return elastic or (), core or (), reqs
+
+
+class VictimOffers:
+    """The offers of one solve's victims, in the victims' order: a column
+    each of ``victim_offer``'s three parts and no object a victim, so that
+    a solve leaves the collector the lists the split always made and
+    nothing more.  ``solve_job`` reads them off the victims itself; a
+    caller that solves several jobs a cycle over the same victims keeps
+    each victim's offer and hands the solver these columns."""
+
+    def __init__(self):
+        self.elastic: list = []
+        self.core: list = []
+        self.reqs: list = []
+
+    def append(self, elastic: list, core: list, reqs: np.ndarray) -> None:
+        self.elastic.append(elastic)
+        self.core.append(core)
+        self.reqs.append(reqs)
+
+    @classmethod
+    def read(cls, victims) -> "VictimOffers":
+        offers = cls()
+        for victim in victims:
+            offers.append(*victim_offer(victim))
+        return offers
 
 
 @dataclass
@@ -125,9 +171,15 @@ def solve_job(ssn, pending_job: PodGroupInfo,
               ordered_victims: list[PodGroupInfo],
               validate, action_name: str,
               require_all_victims_replaced: bool = False,
-              try_replace_victims: bool = True) -> SolverResult:
+              try_replace_victims: bool = True,
+              offers: VictimOffers | None = None) -> SolverResult:
     """Find the smallest victim prefix whose eviction lets pending_job
-    schedule, validated by ``validate(scenario)``.  Commits on success."""
+    schedule, validated by ``validate(scenario)``.  Commits on success.
+
+    ``offers``: those of ``ordered_victims``, which is then no longer
+    than ``max_victims_considered``, from a caller that already holds
+    them (the preempt action's ledger); absent, they are read off the
+    victims here."""
     tasks = pending_job.tasks_to_allocate(
         subgroup_order_fn=ssn.pod_set_order_key,
         task_order_fn=ssn.task_order_key, real_allocation=False)
@@ -139,8 +191,8 @@ def solve_job(ssn, pending_job: PodGroupInfo,
     with TRACER.span("solve:job", kind="solver", job=pending_job.name,
                      action=action_name, tasks=len(tasks),
                      victims=len(ordered_victims)) as sp:
-        result = _solve(ssn, pending_job, tasks, ordered_victims, validate,
-                        action_name, require_all_victims_replaced,
+        result = _solve(ssn, pending_job, tasks, ordered_victims, offers,
+                        validate, action_name, require_all_victims_replaced,
                         try_replace_victims, sp)
         sp.set(tried=result.scenarios_tried,
                skipped=result.scenarios_skipped, solved=result.success,
@@ -151,37 +203,65 @@ def solve_job(ssn, pending_job: PodGroupInfo,
     return result
 
 
-def _within_budget(ssn, tasks, ordered_victims) -> bool:
+def _within_budget(ssn, tasks, offers) -> bool:
     """Cheap infeasibility precheck: even evicting every candidate victim
     cannot create more than (idle + releasing + victim resources +
     repackable fraction headroom); a pending job larger than that can
     never be solved — skip simulating.  The headroom term matters
     because a fractional victim's request vector (0.4 GPU) understates
     what its relocation can free (the WHOLE backing device empties once
-    the sharing group drains)."""
-    with TRACER.span("solve:precheck", kind="solver"):
-        total_req = np.sum([t.res_req.to_vec(mig_as_gpu=False)
-                            for t in tasks], axis=0)
-        budget = ssn.node_idle.sum(axis=0) + ssn.node_releasing.sum(axis=0)
-        budget[rs.RES_GPU] += fractional_headroom(ssn)
-        for vjob in ordered_victims:
-            for t in vjob.pods.values():
-                if t.is_active_allocated():
-                    budget = budget + t.res_req.to_vec(mig_as_gpu=False)
-        return not np.any(total_req > budget + 1e-9)
+    the sharing group drains).  It is never negative and its walk visits
+    every node, so it is asked for only where the budget without it
+    refuses: a budget that covers the job covers it with more."""
+    total_req = np.sum([t.res_req.to_vec(mig_as_gpu=False)
+                        for t in tasks], axis=0)
+    base = ssn.node_idle.sum(axis=0) + ssn.node_releasing.sum(axis=0)
+    if _covers(base, offers, total_req):
+        return True
+    base[rs.RES_GPU] += fractional_headroom(ssn)
+    return _covers(base, offers, total_req)
 
 
-def _solve(ssn, pending_job, tasks, ordered_victims, validate,
+def _covers(base, offers, total_req) -> bool:
+    """Whether ``base`` and what the victims offer cover ``total_req``.
+
+    The victims' term is one sum over the offers' rows, a pod each, where
+    it used to be added to ``base`` pod by pod.  In a column whose every
+    addend is a whole number and whose total stays under 2**53 every
+    partial sum is a whole number a float64 holds, whatever the order:
+    there the budget is the pod-by-pod one to the bit, and so is the
+    verdict.  In any other
+    column (fractional GPUs, an idle fleet's bytes past 2**53) two orders
+    may round apart, each by at most n ulps of the total over n addends;
+    ``room`` covers both, so there the test may let through a job that
+    the pod-by-pod sum would have refused by a rounding (it is then
+    simulated and fails), and never refuses one that sum admits."""
+    if not offers.reqs:
+        return not np.any(total_req > base + 1e-9)
+    rows = np.concatenate(offers.reqs)
+    offered = rows.sum(axis=0)
+    size = np.abs(base) + offered
+    exact = ((base == np.floor(base)) & (size < EXACT_BELOW)
+             & (rows == np.floor(rows)).all(axis=0))
+    room = np.where(exact, 0.0, 2.0 * (len(rows) + 1)
+                    * np.finfo(np.float64).eps * size)
+    return not np.any(total_req > base + offered + room + 1e-9)
+
+
+def _solve(ssn, pending_job, tasks, ordered_victims, offers, validate,
            action_name: str, require_all_victims_replaced: bool,
            try_replace_victims: bool, sp) -> SolverResult:
     """``solve_job`` under its span ``sp``."""
-    if not _within_budget(ssn, tasks, ordered_victims):
-        return SolverResult(False)
+    with TRACER.span("solve:precheck", kind="solver"):
+        if offers is None:
+            offers = VictimOffers.read(ordered_victims)
+        if not _within_budget(ssn, tasks, offers):
+            return SolverResult(False)
 
     # Let plugins snapshot pre-simulation state for their validators.
     ssn.on_job_solution_start()
 
-    builder = ScenarioBuilder(pending_job, tasks, ordered_victims)
+    builder = ScenarioBuilder(pending_job, tasks, ordered_victims, offers)
     sp.set(steps=len(builder._steps))
     # LAZY batched pre-screen: the common reclaim succeeds on its first
     # or second scenario, where a prescreen kernel call is pure overhead

@@ -302,6 +302,9 @@ class Session:
         self.gpu_strategy = BINPACK
         self.cpu_strategy = BINPACK
         propose.register_declines()
+        # At 0 from a session's opening, like the families above: a cycle
+        # with no preemptor reads 0 and not absent (actions/preempt.py).
+        METRICS.inc("preempt_victims_examined_total", 0)
         # Sessions are scheduler-thread-owned end to end: statements
         # mutate mirrors on the cycle path only (commit I/O ships OUT of
         # the session to the executor; it never writes back in).
@@ -647,6 +650,18 @@ class Session:
         return True
 
     def filter_preempt_victims(self, preemptor, victims) -> list:
+        """The victims every registered filter admits for ``preemptor``.
+
+        The contract of ``preempt_victim_filters``: a filter takes
+        ``(preemptor, victims)`` and returns the victims it admits IN THE
+        ORDER IT WAS GIVEN THEM, EACH JUDGED ALONE, by what the preemptor
+        and that one victim are and by nothing else of the list
+        (upstream builds one predicate a preemptor and asks it job by
+        job, preempt.go:126-155).  So ``filter(a + b) == filter(a) +
+        filter(b)``, and the preempt action filters its ordered victims
+        from the head in chunks until the solver has as many as it reads,
+        never the whole list.  A filter may hand back the list it was
+        given when it drops nothing; nobody writes to either."""
         for fn in self.preempt_victim_filters:
             victims = fn(preemptor, victims)
         return victims

@@ -32,8 +32,8 @@ class MinRuntimePlugin(Plugin):
             return False
         return (self.ssn.cluster.now - job.last_start_ts) < min_runtime
 
-    def _min_runtime(self, job, kind: str) -> float:
-        q = self.ssn.cluster.queues.get(job.queue_id)
+    def _min_runtime(self, queue_id: str, kind: str) -> float:
+        q = self.ssn.cluster.queues.get(queue_id)
         # Queue-level override wins over the shard default (:148-205).
         while q is not None:
             val = (q.preempt_min_runtime if kind == "preempt"
@@ -44,18 +44,31 @@ class MinRuntimePlugin(Plugin):
         return self.default_preempt if kind == "preempt" \
             else self.default_reclaim
 
-    def filter_preempt(self, preemptor, victims):
+    def _unprotected(self, victims, kind: str):
+        """The victims no minimum runtime protects, in their order, each
+        judged alone.  The minimum depends on a victim's queue and the
+        kind alone: it is looked up once a queue a call, and where none
+        is positive nothing can be protected and the list itself goes
+        back."""
+        minimum = {qid: self._min_runtime(qid, kind)
+                   for qid in {v.queue_id for v in victims}}
+        if all(m <= 0 for m in minimum.values()):
+            return victims
         return [v for v in victims
-                if not self._protected(v, self._min_runtime(v, "preempt"))]
+                if not self._protected(v, minimum[v.queue_id])]
+
+    def filter_preempt(self, preemptor, victims):
+        return self._unprotected(victims, "preempt")
 
     def filter_reclaim(self, reclaimer, victims):
-        return [v for v in victims
-                if not self._protected(v, self._min_runtime(v, "reclaim"))]
+        return self._unprotected(victims, "reclaim")
+
+    def _none_protected(self, scenario, kind: str) -> bool:
+        victims = [v for v, _ in scenario.victims]
+        return len(self._unprotected(victims, kind)) == len(victims)
 
     def validate_preempt(self, scenario) -> bool:
-        return all(not self._protected(v, self._min_runtime(v, "preempt"))
-                   for v, _ in scenario.victims)
+        return self._none_protected(scenario, "preempt")
 
     def validate_reclaim(self, scenario) -> bool:
-        return all(not self._protected(v, self._min_runtime(v, "reclaim"))
-                   for v, _ in scenario.victims)
+        return self._none_protected(scenario, "reclaim")
