@@ -27,15 +27,42 @@ class PriorityQueue:
     invoking the comparator per comparison — pairwise DRF comparators cost
     tens of microseconds each, which dominated steady-state cycles with
     thousands of pending jobs (the burst scale scenario).
+    ``largest_key_first`` pops by descending key (the victim order of a
+    leaf) with no wrapper a key; ``less`` comes reversed from the caller.
+    ``items`` are there from the start, as if pushed one by one in their
+    order, but ordered in bulk: in key mode one key an item and one C
+    sort of the key tuples (a sorted list is a heap), in comparator mode
+    one ``heapify``, or one sort where ``max_size`` keeps the best only.
     """
 
     def __init__(self, less: Callable, max_size: int = INFINITE,
-                 key: Callable | None = None):
+                 key: Callable | None = None,
+                 largest_key_first: bool = False, items: Iterable = ()):
         self.less = less
         self.key = key
         self.max_size = max_size
-        self._items: list = []
-        self._counter = itertools.count()
+        self._keyed = (self._DescendingEntry if largest_key_first
+                       else self._KeyedEntry)
+        items = list(items)
+        bounded = max_size != INFINITE
+        if key is not None:
+            keys = [key(item) for item in items]
+            # Stable in either direction: equal keys stay in the order
+            # given, which is the entries' ``seq``.
+            ranked = sorted(range(len(items)), key=keys.__getitem__,
+                            reverse=largest_key_first)
+            if bounded:
+                del ranked[max_size:]
+            self._items = [self._keyed(items[i], keys[i], i) for i in ranked]
+        else:
+            entries = [self._Entry(item, less, seq)
+                       for seq, item in enumerate(items)]
+            if bounded:
+                entries = sorted(entries)[:max_size]
+            else:
+                heapq.heapify(entries)
+            self._items = entries
+        self._counter = itertools.count(len(items))
 
     class _Entry:
         __slots__ = ("item", "less", "seq")
@@ -61,10 +88,17 @@ class PriorityQueue:
                 return self.k < other.k
             return self.seq < other.seq
 
+    class _DescendingEntry(_KeyedEntry):
+        __slots__ = ()
+
+        def __lt__(self, other):
+            if self.k != other.k:
+                return other.k < self.k
+            return self.seq < other.seq
+
     def push(self, item) -> None:
         if self.key is not None:
-            entry = self._KeyedEntry(item, self.key(item),
-                                     next(self._counter))
+            entry = self._keyed(item, self.key(item), next(self._counter))
         else:
             entry = self._Entry(item, self.less, next(self._counter))
         if self.max_size != INFINITE and len(self._items) >= self.max_size:
@@ -120,9 +154,9 @@ class _QueueNode:
 
 
 class _Rev:
-    """Reverses the sort order of a key tuple (victim-mode key form:
-    pairwise-comparator reversal would abandon the O(1)-comparison key
-    fast path that keeps 1000s-of-jobs ordering cheap)."""
+    """Reverses the sort order of a queue's key tuple (victim-mode key
+    form: pairwise-comparator reversal would abandon the O(1)-comparison
+    key fast path that keeps 1000s-of-jobs ordering cheap)."""
 
     __slots__ = ("k",)
 
@@ -182,16 +216,14 @@ class JobsOrderByQueues:
         # (one key computation per push) instead of running the pairwise
         # DRF comparators per heap comparison.  An unpaired registration
         # (order fn without key fn) disables it, preserving exact
-        # comparator semantics.  Victim mode reverses the keys via _Rev
-        # (the reference's VictimQueue "!order" with the fast path kept —
-        # a 3200-victim survey must not pay pairwise DRF comparisons).
+        # comparator semantics.  Victim mode reverses the keys (the
+        # reference's VictimQueue "!order" with the fast path kept — a
+        # 3200-victim survey must not pay pairwise DRF comparisons): a
+        # leaf's jobs pop largest key first, a queue's key goes in a _Rev.
         self._job_key = None
         if (getattr(ssn, "job_keys_complete", False)
                 and len(ssn.job_key_fns) == len(ssn.job_order_fns)):
-            if victim_mode:
-                self._job_key = lambda j: _Rev(ssn.job_sort_key(j))
-            else:
-                self._job_key = ssn.job_sort_key
+            self._job_key = ssn.job_sort_key
         self._queue_key = None
         if (ssn.queue_key_fn is not None
                 and len(ssn.queue_order_fns) == 1
@@ -203,17 +235,23 @@ class JobsOrderByQueues:
                 self._queue_key = ssn.queue_key_fn
         self._nodes: dict[str, _QueueNode] = {}
         self._roots: list = []      # heap of _NodeEntry
-        # Bulk build: fill job heaps first, then attach each node ONCE
-        # (bottom-up by construction order: leaves insert before the
-        # parents they create), instead of re-keying ancestors per job.
+        # Bulk build: order each leaf's jobs in one call, then attach
+        # each node ONCE (bottom-up by construction order: leaves insert
+        # before the parents they create), instead of a heap push a job
+        # and re-keying ancestors per job.
+        by_leaf: dict[str, list] = {}
         for job in jobs:
-            self._leaf(job.queue_id).jobs.push(job)
+            by_leaf.setdefault(job.queue_id, []).append(job)
+        for qid, leaf_jobs in by_leaf.items():
+            self._leaf(qid, leaf_jobs)
         for node in list(self._nodes.values()):
             if node.live():
                 self._attach(node)
 
     # -- tree construction -------------------------------------------------
-    def _leaf(self, qid: str) -> _QueueNode:
+    def _leaf(self, qid: str, jobs: Iterable = ()) -> _QueueNode:
+        """The leaf node of ``qid``, made on first use with ``jobs`` as
+        the jobs it starts with."""
         node = self._nodes.get(qid)
         if node is None:
             node = _QueueNode(qid, is_leaf=True)
@@ -223,8 +261,9 @@ class JobsOrderByQueues:
                 job_less = lambda a, b: self.ssn.compare_jobs(a, b) > 0
             else:
                 job_less = lambda a, b: self.ssn.compare_jobs(a, b) < 0
-            node.jobs = PriorityQueue(job_less, self._max_jobs,
-                                      key=self._job_key)
+            node.jobs = PriorityQueue(
+                job_less, self._max_jobs, key=self._job_key,
+                largest_key_first=self.victim_mode, items=jobs)
             self._nodes[qid] = node
             self._link_parent(node)
         return node
@@ -355,3 +394,16 @@ class JobsOrderByQueues:
                 and not node.jobs.empty():
             self._attach(node)
         self._refresh_ancestors(node)
+
+    def release(self) -> None:
+        """Drop the tree of an order that is done with.  Nodes, heap
+        entries and the comparators they hold refer to one another and to
+        this object, so an order abandoned with jobs still on its heaps
+        (a victim stream is read a fiftieth of the way) would keep every
+        entry and key until the cyclic collector's next full pass; taken
+        apart, they go with their last reference."""
+        for node in self._nodes.values():
+            node.parent = node.jobs = None
+            node.children = []
+        self._nodes = {}
+        self._roots = []

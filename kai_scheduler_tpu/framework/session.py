@@ -303,8 +303,10 @@ class Session:
         self.cpu_strategy = BINPACK
         propose.register_declines()
         # At 0 from a session's opening, like the families above: a cycle
-        # with no preemptor reads 0 and not absent (actions/preempt.py).
+        # with no preemptor, or no reclaimer past its gates, reads 0 and
+        # not absent (actions/preempt.py, actions/reclaim.py).
         METRICS.inc("preempt_victims_examined_total", 0)
+        METRICS.inc("reclaim_victims_examined_total", 0)
         # Sessions are scheduler-thread-owned end to end: statements
         # mutate mirrors on the cycle path only (commit I/O ships OUT of
         # the session to the executor; it never writes back in).
@@ -636,6 +638,19 @@ class Session:
         return all(fn(scenario) for fn in self.preempt_scenario_validators)
 
     def filter_reclaim_victims(self, reclaimer, victims) -> list:
+        """The victims every registered filter admits for ``reclaimer``.
+
+        ``reclaim_victim_filters`` carry the contract of
+        ``preempt_victim_filters`` (``filter_preempt_victims``): a filter
+        takes ``(reclaimer, victims)`` and returns the victims it admits
+        IN THE ORDER IT WAS GIVEN THEM, EACH JUDGED ALONE, by what the
+        reclaimer and that one victim are and by nothing else of the
+        list.  So ``filter(a + b) == filter(a) + filter(b)``, and the
+        reclaim action filters its victim stream from the head in chunks
+        until the solver has as many as it reads
+        (``actions/reclaim.py`` ``VictimStream.candidates``), never the
+        whole survey.  A filter may hand back the list it was given when
+        it drops nothing; nobody writes to either."""
         for fn in self.reclaim_victim_filters:
             victims = fn(reclaimer, victims)
         return victims

@@ -135,8 +135,10 @@ def test_the_span_tree_under_the_reclaim_action(driven):
     assert job.kind == "reclaim" and job.attrs["success"] is True
     assert job.attrs["queue"] == driven.client.reclaimer
     survey = only(children(trace, job), "reclaim:survey")
-    # The survey holds the reclaimer's own running gangs too.
-    assert survey.attrs["victims"] >= job.attrs["victims"] > cut["victims"]
+    # The survey holds every victim, the reclaimer's own running gangs
+    # too; the reclaimer read its stream as far as the solver reads.
+    assert survey.attrs["victims"] > job.attrs["victims"] == cut["victims"]
+    assert job.attrs["filtered"] == 0
     solve = only(children(trace, job), "solve:job")
     assert solve.kind == "solver"
     steps = gang // 2                  # two pods a step, one GPU a pod
