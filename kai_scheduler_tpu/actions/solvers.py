@@ -23,6 +23,7 @@ import numpy as np
 
 from ..api import resources as rs
 from ..api.cluster_info import EXACT_BELOW
+from ..api.pod_status import PodStatus
 from ..api.podgroup_info import PodGroupInfo
 from ..framework import propose
 from ..ops.allocate_grouped import _next_pow2
@@ -356,6 +357,14 @@ def _prefix_prescreen(ssn, tasks, builder: "ScenarioBuilder"):
     simulation applies the same rows to the same nodes and no eviction
     changes a label, so it goes to the kernel as ``task_node_mask`` and
     the verdict stays exact.
+
+    A job-level REQUIRED topology level is state-dependent too, and does
+    not disqualify: its candidate domains only grow as victims leave, and
+    the kernel applies ``subset_nodes``' own rule to every prefix's state
+    (``ops/scenario_batch.py`` ``domain_verdicts``).  A preferred level
+    alone changes scores and not feasibility, and a podset's own
+    constraint is not modelled: both keep the fleet-wide verdict, which
+    is sound for them (a gang that fits a domain fits the fleet).
     """
     with TRACER.span("solve:prescreen", kind="solver") as sp:
         verdict = _prescreen_verdict(ssn, tasks, builder, sp)
@@ -400,6 +409,23 @@ def _prescreen_verdict(ssn, tasks, builder: "ScenarioBuilder", sp):
     from ..ops.scenario_batch import (batch_prefix_feasibility,
                                       dispatched_form)
 
+    # The domains of the job's required topology level, where it has one
+    # (None: the fleet-wide verdict, sound for every job).
+    job = builder.scenario.pending_job
+    domains = None
+    if not any(ps.has_own_topology_constraint()
+               for ps in job.pod_sets.values()):
+        for fn in ssn.required_domain_fns:
+            domains = fn(job)
+            if domains is not None:
+                break
+    level, n_domains = "none", 0
+    pool_nodes = ssn.node_idle.shape[0]
+    if domains is not None and len(domains[1]) > 2 * pool_nodes:
+        # Domains of very unequal sizes: their table would hold more
+        # padding than fleet, a prefix each.
+        return declined("ragged-domains")
+
     steps = steps[:cap]
     # Sparse victim-release rows; padding (step index == num_prefixes)
     # drops in the device-side scatter.  Pow2 buckets keep the jit cache
@@ -436,13 +462,28 @@ def _prescreen_verdict(ssn, tasks, builder: "ScenarioBuilder", sp):
     # predicates, whatever the strategies.
     mask = propose._pad_rows(ssn.compute_static_mask(tasks), rows.t_pad,
                              True)
+    named = {} if mask is None else {"task_node_mask": mask}
+    static = {}
     form, scan_steps = dispatched_form(
         rows.task_req, rows.task_job, rows.task_sel, rows.task_tol, mask)
+    if domains is not None and domains[4] and form != "counted":
+        # A preferred level's boosts move where a run lands, and the
+        # domain form lands runs by the strategies' scores alone: a gang
+        # of several runs that also prefers a level keeps the fleet-wide
+        # verdict, sound for every gang (a counted gang has no order to
+        # move, and stays exact in the domain form).
+        domains = None
+    if domains is not None:
+        level, slot_node, domain_ok, n_domains, _preferred = domains
+        pool_nodes = len(slot_node)
+        named.update(slot_node=slot_node, domain_ok=domain_ok)
+        static.update(num_domains=len(domain_ok))
     sp.set(prefixes=num_prefixes, steps=len(steps), rows=m_pad,
            t_pad=rows.t_pad, form=form,
            mask="none" if mask is None else "static",
-           strategy=propose.strategy_name(ssn))
-    if form == "grouped":
+           strategy=propose.strategy_name(ssn), level=level,
+           domains=n_domains)
+    if form != "counted":
         sp.set(runs=scan_steps)
 
     from ..utils.deviceguard import CycleDeadlineExceeded, DeviceGuardError
@@ -455,19 +496,25 @@ def _prescreen_verdict(ssn, tasks, builder: "ScenarioBuilder", sp):
     METRICS.inc("scenario_prescreen_counted_total", int(form == "counted"))
     METRICS.inc("scenario_prescreen_scan_steps_total", scan_steps)
     METRICS.inc("scenario_prescreen_masked_total", int(mask is not None))
+    METRICS.inc("scenario_prescreen_domain_calls_total",
+                int(domains is not None))
+    # The [K, N] cells of the pools the call builds (N the slots of the
+    # domains' table in the domain form): what its bytes follow.
+    METRICS.inc("scenario_prescreen_pool_cells_total",
+                num_prefixes * pool_nodes)
     try:
         feasible = propose.run_on_nodes(
             ssn, batch_prefix_feasibility,
             (release_step, release_node, release_vec, rows.task_req,
              rows.task_job, rows.task_sel, rows.task_tol),
             label="scenario_prescreen",
-            validate=lambda r: getattr(r, "shape", (0,))[0] >= len(steps),
+            validate=lambda r: getattr(r, "shape", (0,))[-1] >= len(steps),
             # By name only where there is one: a call that names a None
             # is another program to jit than the one that leaves it out,
             # which is how the unmasked cells prime theirs.
-            named=None if mask is None else {"task_node_mask": mask},
+            named=named or None,
             num_prefixes=num_prefixes, gpu_strategy=ssn.gpu_strategy,
-            cpu_strategy=ssn.cpu_strategy)
+            cpu_strategy=ssn.cpu_strategy, **static)
     except CycleDeadlineExceeded:
         raise
     except DeviceGuardError:
@@ -479,7 +526,17 @@ def _prescreen_verdict(ssn, tasks, builder: "ScenarioBuilder", sp):
         # step-index lookup skips it naturally).
         sp.set(unavailable=True)
         return ()
-    return np.asarray(feasible)[:len(steps)]
+    feasible = np.asarray(feasible)
+    if domains is None:
+        return feasible[:len(steps)]
+    # [2,K]: some one domain seats the gang; the fleet as one domain
+    # holds it.  What the second passes and the first refuses is what the
+    # domain axis pruned.
+    seated, fleet = feasible[0, :len(steps)], feasible[1, :len(steps)]
+    pruned = int(np.count_nonzero(fleet & ~seated))
+    sp.set(pruned=pruned)
+    METRICS.inc("scenario_prescreen_domain_pruned_total", pruned)
+    return seated
 
 
 def _unevicted_tasks(scenario: Scenario, stmt) -> list:
@@ -513,11 +570,15 @@ def _simulate_attempt(ssn, stmt, scenario: Scenario,
 
     all_replaced = True
     if try_replace_victims:
+        placed_again = []
         for vjob, vtasks in scenario.victims:
             replaced = attempt_to_allocate_job(ssn, vjob, pipeline_only=True,
                                                stmt=stmt, commit=False)
-            if not replaced:
+            if replaced:
+                placed_again.append((vjob, vtasks))
+            else:
                 all_replaced = False
+        _attempt_the_rest(ssn, stmt, placed_again)
     else:
         all_replaced = False
     if require_all_victims_replaced and not all_replaced:
@@ -525,22 +586,89 @@ def _simulate_attempt(ssn, stmt, scenario: Scenario,
     return True
 
 
-def _plain_chunk(ssn, job):
+def _plain_chunk(ssn, job, pending: bool = False):
     """tasks_to_allocate when the job is expressible in one concatenated
     kernel call; None routes the scenario to the sequential path (the
-    same state classes attempt_to_allocate_job handles host-side)."""
-    if (job.required_topology_level or job.preferred_topology_level
-            or any(ps.has_own_topology_constraint()
-                   for ps in job.pod_sets.values())):
+    same state classes attempt_to_allocate_job handles host-side).  The
+    ``pending`` job may carry a job-level topology constraint: its
+    candidate domains become its chunk's node subset
+    (``_batched_confirm``).  A victim with one, and any job with a
+    podset's own, still go the sequential way."""
+    if any(ps.has_own_topology_constraint()
+           for ps in job.pod_sets.values()):
+        propose.declined("confirm", "podset-topology")
+        return None
+    if not pending and (job.required_topology_level
+                        or job.preferred_topology_level):
+        propose.declined("confirm", "victim-topology")
         return None
     tasks = job.tasks_to_allocate(
         subgroup_order_fn=ssn.pod_set_order_key,
         task_order_fn=ssn.task_order_key, real_allocation=False)
-    for t in tasks:
-        if (t.is_fractional or t.resource_claims or t.res_req.mig_resources
-                or t.host_ports or t.needs_storage_scheduling()):
-            return None
-    return tasks
+    return None if _host_state(tasks) else tasks
+
+
+def _host_state(tasks) -> bool:
+    """Whether a task needs state the concatenated kernel call lacks."""
+    return any(t.is_fractional or t.resource_claims
+               or t.res_req.mig_resources or t.host_ports
+               or t.needs_storage_scheduling() for t in tasks)
+
+
+def _the_rest(ssn, vtasks) -> list:
+    """The pods of ``vtasks`` (what a scenario evicted of one victim)
+    that no placement of the statement has put back yet, in the tasks'
+    order."""
+    return sorted((t for t in vtasks if t.status == PodStatus.RELEASING),
+                  key=ssn.task_order_key)
+
+
+def _attempt_the_rest(ssn, stmt, placed_again) -> None:
+    """The second pass of a scenario's re-placement, attempt by attempt.
+
+    The first pass is upstream's: one ``attempt_to_allocate_job`` a
+    victim, which places its NEXT CHUNK again (its gang chunk where the
+    scenario left it below its minimum, one pod where it did not).  An
+    elastic victim of a prefix that runs on past the pending job's need
+    (a gang held to one rack, whose prefix runs through eight) then lost
+    the rest of what the scenario took of it, whatever room there was.
+    This pass goes over the victims whose first attempt succeeded
+    (``placed_again``: ``(job, evicted tasks)``), in their order, and
+    places the rest a pod an attempt, as the allocate action grows an
+    elastic job, until one finds no room.  Where the first pass left no
+    room, every cell's case but the rack-bound reclaimer's, it does
+    nothing."""
+    for vjob, vtasks in placed_again:
+        for _ in range(len(vtasks)):
+            if not _the_rest(ssn, vtasks) or not attempt_to_allocate_job(
+                    ssn, vjob, pipeline_only=True, stmt=stmt, commit=False):
+                break
+
+
+def _place_the_rest(ssn, stmt, placed_again) -> None:
+    """``_attempt_the_rest`` in ONE multi-job call: every pod left, a
+    chunk of its own, a victim's chunks a chain (each tried only where
+    the one before it succeeded, ``allocate_jobs_kernel(job_follows=)``),
+    applied in order under the queue-capacity gate.  No call where
+    nothing is left; the attempts where the call cannot be made."""
+    chunks = [(vjob, [t]) for vjob, vtasks in placed_again
+              for t in _the_rest(ssn, vtasks)]
+    if not chunks:
+        return
+    proposals = None if _host_state(t for _j, ts in chunks for t in ts) \
+        else ssn.propose_placements_multi(chunks, pipeline_only=True)
+    if proposals is None:
+        return _attempt_the_rest(ssn, stmt, placed_again)
+    stopped = set()
+    for (job, tasks), prop in zip(chunks, proposals):
+        if job.uid in stopped:
+            continue
+        if not prop.success or not ssn.is_job_over_queue_capacity(
+                job, tasks).schedulable:
+            stopped.add(job.uid)
+            continue
+        stmt.apply_bulk((task, node, True)
+                        for task, node, _p in prop.placements)
 
 
 def _batched_confirm(ssn, stmt, scenario: Scenario,
@@ -550,9 +678,16 @@ def _batched_confirm(ssn, stmt, scenario: Scenario,
     (solvers/by_pod_solver.go runs these as N sequential AllocateJob
     calls — the dominant per-scenario cost at contention).
 
+    A pending job with a topology level goes in under a node subset, a
+    candidate domain of ``subset_nodes`` on the statement's state, in the
+    candidates' order as ``_place_gated_job`` tries them: the call is
+    made again with the next candidate only where the gang did not fit
+    the one before, and a victim may land anywhere either time.
+
     Returns (ok, all_replaced), or None to fall back to the sequential
     path when any involved job needs host-side state."""
-    pending_tasks = _plain_chunk(ssn, scenario.pending_job)
+    pending_job = scenario.pending_job
+    pending_tasks = _plain_chunk(ssn, pending_job, pending=True)
     if pending_tasks is None or not pending_tasks:
         return None
     # Same admission gates attempt_to_allocate_job applies.
@@ -581,11 +716,19 @@ def _batched_confirm(ssn, stmt, scenario: Scenario,
 
     for job, _tasks in chunks:
         ssn.pre_job_allocation(job)
-    proposals = ssn.propose_placements_multi(chunks, pipeline_only=True)
-    if proposals is None:
-        return None
-    pending_prop = proposals[scenario.pending_job.uid]
-    if not pending_prop.success:
+    subsets = [None]
+    if pending_job.required_topology_level \
+            or pending_job.preferred_topology_level:
+        subsets = ssn.subset_nodes(pending_job, pending_tasks)
+    for subset in subsets:
+        proposals = ssn.propose_placements_multi(
+            chunks, pipeline_only=True, node_subset=subset)
+        if proposals is None:
+            return None
+        if proposals[0].success:
+            break
+    else:
+        # No candidate domain, or the gang fits none of them.
         return (False, False)
     # Apply job by job, re-checking the queue-capacity gate against the
     # statement state accumulated so far — the kernel models NODE
@@ -595,10 +738,11 @@ def _batched_confirm(ssn, stmt, scenario: Scenario,
     # gated-out job only frees node capacity the kernel had charged, so
     # the retained placements remain feasible.
     stmt.apply_bulk((task, node, True)
-                    for task, node, _p in pending_prop.placements)
+                    for task, node, _p in proposals[0].placements)
     all_replaced = try_replace_victims and not skipped_victim
-    for job, tasks in chunks[1:]:
-        prop = proposals[job.uid]
+    evicted_of = {vjob.uid: vtasks for vjob, vtasks in scenario.victims}
+    placed_again = []
+    for (job, tasks), prop in zip(chunks[1:], proposals[1:]):
         if not prop.success:
             all_replaced = False
             continue
@@ -607,4 +751,7 @@ def _batched_confirm(ssn, stmt, scenario: Scenario,
             continue
         stmt.apply_bulk((task, node, True)
                         for task, node, _p in prop.placements)
+        placed_again.append((job, evicted_of[job.uid]))
+    # What else the scenario took of the victims that stand again.
+    _place_the_rest(ssn, stmt, placed_again)
     return (True, all_replaced)

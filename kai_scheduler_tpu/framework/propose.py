@@ -173,7 +173,8 @@ def _stage(*operands):
 DECLINES = (("wave", "strategy"),
             ("grouped_fill", "strategy"), ("grouped_fill", "domain_rows"),
             ("grouped_fill", "rows"), ("grouped_fill", "extras"),
-            ("grouped_fill", "mask"), ("prescreen_runs", "strategy"))
+            ("grouped_fill", "mask"), ("prescreen_runs", "strategy"),
+            ("confirm", "podset-topology"), ("confirm", "victim-topology"))
 
 
 def declined(form: str, reason: str, count: int = 1) -> None:
@@ -327,6 +328,35 @@ def _route_extras(rows: TaskOperands, chunks, extras, n_nodes: int):
     return job_extra, task_extra
 
 
+def _chunk_extras(ssn, chunks) -> list:
+    """Each chunk's summed extra scores.  Consecutive chunks of ONE job (a
+    victim a scenario places again, its gang chunk and then its surplus a
+    pod a chunk) are scored by one call of the registered fns over all
+    their tasks, and the answer cut by chunk: a fn scores a task by the
+    task and its job, never by the tasks beside it in the chunk, so a row
+    is the row the chunk's own call gives."""
+    extras = []
+    i = 0
+    while i < len(chunks):
+        job = chunks[i][0]
+        j = i + 1
+        while job is not None and j < len(chunks) and chunks[j][0] is job:
+            j += 1
+        if j == i + 1:
+            extras.append(ssn._sum_extra_scores(chunks[i][1]))
+        else:
+            group = chunks[i:j]
+            extra = ssn._sum_extra_scores(
+                [t for _job, tasks in group for t in tasks])
+            row = 0
+            for _job, tasks in group:
+                extras.append(extra if extra is None or extra.ndim == 1
+                              else extra[row:row + len(tasks)])
+                row += len(tasks)
+        i = j
+    return extras
+
+
 def _first_rows(fns, tasks):
     """The first registered fn's domain rows for these tasks, or None."""
     for fn in fns:
@@ -381,11 +411,12 @@ def _run_grouped(ssn, label: str, program: str, n_jobs: int,
             np.asarray(result.job_success))
 
 
-def _proposals(ssn, chunks, placed, piped, success, subset=None,
+def _proposals(ssn, chunks, placed, piped, success, subsets=None,
                reorder: bool = False) -> list:
     """One ``Proposal`` a chunk, in chunk order, from a kernel's answer.
     A chunk fails whole: its job gated out or rolled back, a task left
-    unplaced, or a task outside the node subset.  ``reorder``: the chunks
+    unplaced, or a task outside its node subset (``subsets``: one a
+    chunk, None where the chunk has none).  ``reorder``: the chunks
     may be rank-reordered (the caller proved them homogeneous, or the
     registered fns re-verify it, ops/rankplace.py)."""
     names = ssn.snapshot.node_names
@@ -395,6 +426,7 @@ def _proposals(ssn, chunks, placed, piped, success, subset=None,
         nodes = placed[row:row + len(tasks)]
         pipes = piped[row:row + len(tasks)]
         row += len(tasks)
+        subset = None if subsets is None else subsets[j]
         if not bool(success[j]) or (nodes < 0).any() or (
                 subset is not None and not subset[nodes].all()):
             out.append(Proposal(False, []))
@@ -415,9 +447,13 @@ def propose(ssn, chunks, kind: str, pipeline_only: bool,
 
     ``kind``: ``single`` (one chunk; takes a ``node_subset`` and domain
     rows, goes to any program) or ``multi`` (several chunks, the exact
-    kernel only).  None when a task cannot be encoded, or a ``multi``
-    call's tasks bring domain rows (per-job machinery the concatenated
-    call cannot express)."""
+    kernel only; a ``node_subset`` there holds the FIRST chunk alone, the
+    scenario confirm's pending job in its topology domain, and the
+    victims behind it go anywhere; consecutive chunks of ONE job there
+    are a chain, each tried only where the one before it succeeded).
+    None when a task cannot be encoded,
+    or a ``multi`` call's tasks bring domain rows (per-job machinery the
+    concatenated call cannot express)."""
     METRICS.inc("device_kernel_calls")
     all_tasks = [t for _job, tasks in chunks for t in tasks]
     t = len(all_tasks)
@@ -440,7 +476,7 @@ def propose(ssn, chunks, kind: str, pipeline_only: bool,
 
         # Each chunk's extra scores are its own job's: None, one [N] row
         # for the whole chunk, or [len(tasks), N].
-        extras = [ssn._sum_extra_scores(tasks) for _job, tasks in chunks]
+        extras = _chunk_extras(ssn, chunks)
         # Hard per-task node masks (inter-pod affinity terms, upstream
         # predicate verdicts): False = infeasible, enforced in-kernel.
         mask = ssn.compute_hard_mask(all_tasks)
@@ -451,6 +487,8 @@ def propose(ssn, chunks, kind: str, pipeline_only: bool,
         # per-task mask wherever both exist, on every path.
         subset = (None if node_subset is None
                   else np.asarray(node_subset, bool))
+        subsets = [subset] + [None if kind == "multi" else subset] * (
+            len(chunks) - 1)
         # Said on the span, and counted: the form the score and hard-mask
         # operands took, none, row (one [N] row a job) or dense ([T,N]).
         ndims = {extra.ndim for extra in extras if extra is not None}
@@ -485,7 +523,7 @@ def propose(ssn, chunks, kind: str, pipeline_only: bool,
             job_extra, task_extra = _route_extras(rows, chunks, extras,
                                                   n_nodes)
             job_mask = None
-            if subset is not None:
+            if subset is not None and kind == "single":
                 job_mask = np.ones((rows.j_pad, n_nodes), bool)
                 job_mask[:len(chunks)] = subset
             named = {"task_node_mask": _pad_rows(mask, t_pad, True),
@@ -493,6 +531,16 @@ def propose(ssn, chunks, kind: str, pipeline_only: bool,
                      "task_aff_domain": _pad_domain_rows(aff_dom, t_pad),
                      "job_extra_scores": job_extra,
                      "job_node_mask": job_mask}
+            # The next two named only where there is one (a call that
+            # names a None is another program to jit).
+            if subset is not None and kind == "multi":
+                named["first_job_node_mask"] = subset
+            follows = [j > 0 and chunks[j][0] is not None
+                       and chunks[j][0] is chunks[j - 1][0]
+                       for j in range(len(chunks))]
+            if any(follows):
+                named["job_follows"] = _pad_rows(
+                    np.array(follows), rows.j_pad, False)
             host = (*task_rows, task_extra, named)
 
     if program == "grouped":
@@ -525,7 +573,7 @@ def propose(ssn, chunks, kind: str, pipeline_only: bool,
     with TRACER.span("propose:unpack", kind="propose", t=t):
         # The grouped fill proved its chunk's tasks interchangeable — the
         # one precondition rank reorder needs.
-        return _proposals(ssn, chunks, *answer, subset=subset,
+        return _proposals(ssn, chunks, *answer, subsets=subsets,
                           reorder=program == "grouped")
 
 

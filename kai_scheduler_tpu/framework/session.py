@@ -169,6 +169,10 @@ class Session:
         self.allocate_handlers: list[Callable] = []
         self.deallocate_handlers: list[Callable] = []
         self.subset_nodes_fns: list[Callable] = []
+        # ``fn(job) -> ops.topology.TopologySession.required_domains``:
+        # the domains of a job's required topology level, for the
+        # scenario prescreen's domain form (the topology plugin's).
+        self.required_domain_fns: list[Callable] = []
         self.extra_score_fns: list[Callable] = []
         # Rank-aware placement (ops/rankplace.py): post-fill permutation
         # of an interchangeable gang chunk's (task, node, piped) pairs so
@@ -307,6 +311,10 @@ class Session:
         # not absent (actions/preempt.py, actions/reclaim.py).
         METRICS.inc("preempt_victims_examined_total", 0)
         METRICS.inc("reclaim_victims_examined_total", 0)
+        # The scenario prescreen's domain form (actions/solvers.py).
+        METRICS.inc("scenario_prescreen_domain_calls_total", 0)
+        METRICS.inc("scenario_prescreen_domain_pruned_total", 0)
+        METRICS.inc("scenario_prescreen_pool_cells_total", 0)
         # Sessions are scheduler-thread-owned end to end: statements
         # mutate mirrors on the cycle path only (commit I/O ships OUT of
         # the session to the executor; it never writes back in).
@@ -768,22 +776,24 @@ class Session:
         return acc
 
     def propose_placements_multi(self, job_chunks,
-                                 pipeline_only: bool = True):
+                                 pipeline_only: bool = True,
+                                 node_subset=None):
         """Place SEVERAL jobs' chunks in ONE kernel call (the scenario
         confirm pass: pending job + victim re-placements together instead
         of one device round trip per job).
 
-        ``job_chunks``: [(job, tasks)].  Returns {job_uid: Proposal} with
-        per-job gang atomicity (the kernel's per-job success gating), or
-        None when any chunk needs per-job machinery the concatenated call
-        cannot express (domain rows from anti/affinity plugins) or holds
-        a task that cannot be encoded."""
-        out = propose.propose(self, job_chunks, "multi",
-                              pipeline_only=pipeline_only)
-        if out is None:
-            return None
-        return {job.uid: prop for (job, _tasks), prop
-                in zip(job_chunks, out)}
+        ``job_chunks``: [(job, tasks)]; a job may bring several chunks in
+        a row (a victim placed again pod for pod), each tried only where
+        the one before it succeeded.  Returns a ``Proposal`` a chunk, in
+        the chunks' order, with per-chunk gang atomicity (the kernel's
+        per-job success gating), or None when any chunk needs per-job
+        machinery the concatenated call cannot express (domain rows from
+        anti/affinity plugins) or holds a task that cannot be encoded.
+        ``node_subset`` holds the FIRST chunk alone (a topology domain
+        for the pending job)."""
+        return propose.propose(self, job_chunks, "multi",
+                               pipeline_only=pipeline_only,
+                               node_subset=node_subset)
 
     def propose_placements(self, tasks: list[PodInfo],
                            pipeline_only: bool = False,

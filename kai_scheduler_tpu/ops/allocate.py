@@ -61,7 +61,8 @@ def allocate_jobs_kernel(node_allocatable, node_idle, node_releasing,
                          job_allowed, task_extra_scores=None,
                          task_node_mask=None, task_anti_domain=None,
                          task_aff_domain=None, job_extra_scores=None,
-                         job_node_mask=None,
+                         job_node_mask=None, first_job_node_mask=None,
+                         job_follows=None,
                          gpu_strategy: int = BINPACK,
                          cpu_strategy: int = BINPACK,
                          allow_pipeline: bool = True,
@@ -81,6 +82,16 @@ def allocate_jobs_kernel(node_allocatable, node_idle, node_releasing,
     ``task_job[t]``, added to / ANDed with the per-task row where both
     are given.  A padding job's row is read and never used: its job is
     gated out.
+    first_job_node_mask: optional [N] bool row that holds for the tasks
+    of job 0 alone (a scenario confirm's pending job held to its topology
+    domain while the victims behind it go anywhere): one row where
+    ``job_node_mask`` would be J of which J - 1 say nothing.
+    job_follows: optional [J] bool, true on a job that is the next chunk
+    of the job before it (a victim a scenario places again, its gang
+    chunk and then its surplus a pod a chunk): it is tried only where
+    that one succeeded, as a sequence of attempts stops at the first that
+    fails, so a chunk that would never be applied takes no room from the
+    jobs behind it.
     task_anti_domain: optional (dom [T,N] int32, marks [T] bool,
     avoids [T] bool) — in-gang REQUIRED anti-affinity for ONE term.
     ``dom`` maps nodes to the term's topology domains (-1 = no domain);
@@ -149,6 +160,10 @@ def allocate_jobs_kernel(node_allocatable, node_idle, node_releasing,
         ck_rel = jnp.where(new_job, rel, carry.ck_rel)
         ck_room = jnp.where(new_job, room, carry.ck_room)
         ok = jnp.where(new_job, job_allowed[j], carry.cur_ok)
+        if job_follows is not None:
+            # At a boundary ``carry.cur_ok`` is the verdict on the job
+            # before, whole.
+            ok = jnp.where(new_job & job_follows[j], ok & carry.cur_ok, ok)
         blocked_avoiders = jnp.where(new_job, False, carry.blocked_avoiders)
         blocked_markers = jnp.where(new_job, False, carry.blocked_markers)
         aff_union = jnp.where(new_job, False, carry.aff_union)
@@ -166,6 +181,8 @@ def allocate_jobs_kernel(node_allocatable, node_idle, node_releasing,
             feasible = feasible & task_node_mask[t]
         if job_node_mask is not None:
             feasible = feasible & job_node_mask[j]
+        if first_job_node_mask is not None:
+            feasible = feasible & (first_job_node_mask | (j != 0))
         if task_anti_domain is not None:
             feasible = feasible \
                 & ~(anti_avoids[t] & blocked_avoiders) \

@@ -61,6 +61,16 @@ form is needed; the exact kernel vmapped over the prefixes
 (``allocate_jobs_kernel``, one dependent step a POD) is the oracle the
 tests hold both forms to (``tests/prescreen_oracle.py``).
 
+A gang with a REQUIRED topology level is answered domain by domain
+(``domain_verdicts``): the same two forms vmapped over the level's
+domains, each a small fleet, under the rule the host's ``subset_nodes``
+applies to one state (``ops/topology.py`` ``domain_holds``); a prefix
+passes where some one domain seats the gang.  Where the job also PREFERS
+a level, its boosts change where a run lands and this module does not
+model them: a counted gang stays exact, because a count has no order,
+and a gang of several runs is left to the fleet-wide verdict by the
+caller (``actions/solvers.py``), which is sound for every gang.
+
 Both read the same dense per-prefix pools (scatter-add of the release
 rows, running sum over the prefix axis).  The counted form could be had
 from the touched nodes alone without ever materialising them; the pools
@@ -154,20 +164,30 @@ def corrected_count(quotient, req, total):
     return jnp.maximum(c, 0.0)
 
 
+def stack_count(totals, room, req):
+    """The whole pods of request ``req`` [R] that stack into each node:
+    ``totals`` one array of free amounts a resource, ``room`` the pod
+    room, of any shapes that broadcast.  The one count of "whole pods
+    stacked node by node, pod room" of the prescreen's forms: a run's
+    capacity, and a node's accommodation under ``domain_holds``."""
+    count = jnp.floor(room)
+    # R unrolled, a plane a resource (feasibility_caps_row's way): the
+    # compiler keeps N minor in the pool, and 3 would waste the lanes.
+    for res, total in enumerate(totals):
+        rq = req[res]
+        safe = jnp.where(rq > 0, rq, 1.0)
+        fits = corrected_count(jnp.floor(total / safe), safe, total)
+        count = jnp.where(rq > 0, jnp.minimum(count, fits), count)
+    return count
+
+
 def run_capacity(rel_planes, node_idle, hard, room, req):
     """[K,N]: the pods of request ``req`` that each node takes under each
     prefix's pool, ``rel_planes`` a [K,N] plane a resource; ``hard`` [N]
     and ``room`` [N] or [K,N] bound it."""
-    count = jnp.where(hard, jnp.floor(room), 0.0)    # broadcasts to [K,N]
-    # R unrolled, a [K,N] plane a resource (feasibility_caps_row's way):
-    # the compiler keeps N minor in the pool, and 3 would waste the lanes.
-    for res, plane in enumerate(rel_planes):
-        rq = req[res]
-        safe = jnp.where(rq > 0, rq, 1.0)
-        total = node_idle[None, :, res] + plane
-        fits = corrected_count(jnp.floor(total / safe), safe, total)
-        count = jnp.where(rq > 0, jnp.minimum(count, fits), count)
-    return count
+    totals = tuple(node_idle[None, :, res] + plane
+                   for res, plane in enumerate(rel_planes))
+    return jnp.where(hard, stack_count(totals, room, req), 0.0)
 
 
 def _seats(capacity, need):
@@ -264,9 +284,103 @@ def group_prefixes(prefix_rel, node_allocatable, node_idle, node_labels,
                                     run_size[last])
 
 
+def domain_verdicts(node_allocatable, node_idle, node_releasing,
+                    node_labels, node_taints, node_room, release_step,
+                    release_node, release_vec, task_req, task_job,
+                    task_selector, task_tolerations, task_node_mask, *,
+                    slot_node, domain_ok, num_prefixes: int,
+                    num_domains: int, gpu_strategy: int, cpu_strategy: int):
+    """[2,K] bool for a gang with a REQUIRED topology level.
+
+    The nodes come by domain: ``slot_node`` [D*S] int32 is the node of
+    each slot of a ``[D,S]`` table, a domain a row in ascending node index
+    (``ops/topology.py`` ``domain_slots``), ``N`` where the row is padding;
+    ``domain_ok`` [D] bool the domains the job may use (all but where it
+    is pinned to those that hold its running pods).  The pools are built
+    over the slots, so a domain is a row of them and no segment op runs
+    over ``[K,N]``; a release on a node of no domain drops out.
+
+    Row 0: for some one domain, ``TopologySession.subset_nodes``' rule
+    holds (``domain_holds`` of ``ops/topology.py`` on the domain's free
+    sums and its nodes' ``stack_count``: the host's own two functions) AND
+    the gang fits the domain's nodes by the form its rows choose: counted,
+    or grouped (each run landed by score AMONG THE DOMAIN'S NODES, as the
+    exact kernel does under the domain's node mask).  The forms are the
+    fleet-wide ones,
+    vmapped over the domain axis: a domain is a small fleet.  A padding
+    slot has no pod room, a padding domain no slot with any, so neither
+    holds a gang.  Ties in a run's landing go to the lower slot, which is
+    the lower node index inside a domain.
+
+    Row 1: the same rule read with the fleet as its one domain (the root
+    level's): what a level-blind capacity check passes.  A prefix true
+    there and false in row 0 is one the domain axis pruned."""
+    from .topology import domain_holds
+
+    n, r = node_releasing.shape
+    d = num_domains
+    s = slot_node.shape[0] // d
+    dtype = node_idle.dtype
+    real = task_job == 0
+
+    def by_domain(rows, fill):
+        out = jnp.take(rows, slot_node, axis=0, mode="fill",
+                       fill_value=fill)
+        return out.reshape((d, s) + rows.shape[1:])
+
+    allocatable = by_domain(node_allocatable, 0)
+    idle = by_domain(node_idle, 0)
+    labels = by_domain(node_labels, -1)
+    taints = by_domain(node_taints, -1)
+    room = by_domain(node_room, 0)
+    node_slot = jnp.full(n, d * s, jnp.int32).at[slot_node].set(
+        jnp.arange(d * s, dtype=jnp.int32), mode="drop")
+    delta = jnp.zeros((num_prefixes, d * s, r), dtype)
+    delta = delta.at[release_step, node_slot[release_node]].add(
+        release_vec.astype(dtype), mode="drop")
+    pools = (by_domain(node_releasing, 0).reshape(d * s, r)[None]
+             + jnp.cumsum(delta, axis=0)).reshape(num_prefixes, d, s, r)
+    mask = None if task_node_mask is None else jnp.take(
+        task_node_mask, slot_node, axis=1, mode="fill",
+        fill_value=False).reshape(-1, d, s)
+    rows = (task_req, task_job, task_selector, task_tolerations)
+    mask_axis = None if mask is None else 1
+
+    def counted():
+        return jax.vmap(count_prefixes,
+                        in_axes=(1, 0, 0, 0, 0) + (None,) * 4
+                        + (mask_axis,))(
+            pools, idle, labels, taints, room, *rows, mask)
+
+    def grouped():
+        fn = functools.partial(group_prefixes, gpu_strategy=gpu_strategy,
+                               cpu_strategy=cpu_strategy)
+        return jax.vmap(fn, in_axes=(1, 0, 0, 0, 0, 0) + (None,) * 4
+                        + (mask_axis,))(
+            pools, allocatable, idle, labels, taints, room, *rows, mask)
+
+    fits = jax.lax.cond(uniform_gang(*rows, task_node_mask), counted,
+                        grouped)                                 # [D,K]
+
+    gang = real.sum().astype(dtype)
+    reqs = jnp.where(real[:, None], task_req, 0.0)
+    totals = tuple(idle[None, :, :, res] + pools[..., res]
+                   for res in range(r))                          # [K,D,S]
+    stacked = jnp.clip(stack_count(totals, room[None], reqs.max(axis=0)),
+                       0.0, gang)
+    free = jnp.stack([t.sum(axis=-1) for t in totals], axis=-1)  # [K,D,R]
+    pods = stacked.sum(axis=-1)                                   # [K,D]
+    total_req = reqs.sum(axis=0)
+    holds = domain_holds(free, pods, total_req, gang)
+    seated = jnp.any(holds & fits.T & domain_ok[None, :], axis=1)
+    fleet = domain_holds(free.sum(axis=1), pods.sum(axis=1), total_req,
+                         gang)
+    return jnp.stack([(gang > 0) & seated, (gang > 0) & fleet])
+
+
 @functools.partial(jax.jit,
                    static_argnames=("num_prefixes", "gpu_strategy",
-                                    "cpu_strategy"))
+                                    "cpu_strategy", "num_domains"))
 def batch_prefix_feasibility(node_allocatable, node_idle, node_releasing,
                              node_labels, node_taints, node_room,
                              release_step, release_node, release_vec,
@@ -274,7 +388,9 @@ def batch_prefix_feasibility(node_allocatable, node_idle, node_releasing,
                              task_tolerations, num_prefixes: int,
                              task_node_mask=None,
                              gpu_strategy: int = BINPACK,
-                             cpu_strategy: int = BINPACK) -> jnp.ndarray:
+                             cpu_strategy: int = BINPACK,
+                             slot_node=None, domain_ok=None,
+                             num_domains: int = 0) -> jnp.ndarray:
     """[num_prefixes] bool: can the pending job pipeline onto each
     prefix's released resources?
 
@@ -290,9 +406,21 @@ def batch_prefix_feasibility(node_allocatable, node_idle, node_releasing,
     ``task_node_mask`` [T,N] bool, all-true on the padding rows, is static
     (no eviction changes it): its rows tell pods apart as the other rows
     do, and a run's row bounds where the run may land.
+
+    With ``slot_node`` the gang carries a REQUIRED topology level and the
+    answer is [2, num_prefixes]: row 0, whether SOME ONE domain of the
+    level seats the gang with the prefix released; row 1, whether the
+    fleet as one domain holds it (``domain_verdicts``).
     """
     tasks = (task_req, task_job, task_selector, task_tolerations,
              task_node_mask)
+    if slot_node is not None:
+        return domain_verdicts(
+            node_allocatable, node_idle, node_releasing, node_labels,
+            node_taints, node_room, release_step, release_node,
+            release_vec, *tasks, slot_node=slot_node, domain_ok=domain_ok,
+            num_prefixes=num_prefixes, num_domains=num_domains,
+            gpu_strategy=gpu_strategy, cpu_strategy=cpu_strategy)
 
     def pools():
         n, r = node_releasing.shape

@@ -50,6 +50,9 @@ class TopologyTree:
     domain_names: dict = field(default_factory=dict)  # level -> [id->path]
     # Per level below the root: label-value path (a tuple) -> domain id.
     domain_ids: dict = field(default_factory=dict)
+    # level -> ``domain_slots`` of it: a memo of a pure function of
+    # ``node_domain``, the one thing a shared tree still gains.
+    slots: dict = field(default_factory=dict)
 
     def num_domains(self, level: str) -> int:
         return len(self.domain_names.get(level, []))
@@ -150,6 +153,48 @@ def session_trees(ssn) -> tuple[dict, int | None]:
     return trees, None
 
 
+def domain_holds(free, pods, total_req, gang_size):
+    """[..., D] bool: does a domain hold the gang?  THE rule, for the
+    host's ``subset_nodes`` (numpy, one state) and the device's prescreen
+    (``ops/scenario_batch.py`` ``domain_verdicts``, a state a prefix)
+    alike: the gang's whole request fits the domain's idle + releasing
+    sums ``free`` [..., D, R], and ``gang_size`` of its largest pod stack
+    into the domain's nodes, ``pods`` [..., D] the sum over them of the
+    whole pods each takes (capped at the gang): ``domain_aggregates``'
+    quotient on the host's one state, the prescreen's ``stack_count`` on
+    a prefix's, which is that quotient set right where a 32-bit division
+    rounds across a whole number (ROADMAP D12).  Necessary for a
+    placement inside the domain, not sufficient: the kernel decides."""
+    return (pods >= gang_size) & (total_req <= free + 1e-9).all(axis=-1)
+
+
+def domain_slots(seg: np.ndarray, n_pad: int):
+    """``(slot_node [D_pad * S_pad] int32, D_pad)`` of one level: its
+    domains as the rows of a table, a row the nodes of a domain in
+    ascending node index, padded with ``n_pad`` (no node): the columns to
+    a power of two, the rows to one up to 8 and to a multiple of 8 beyond
+    (a prefix's pool holds a row a domain: 1,536 racks padded to 2,048
+    would be a third more fleet to write and read).  The layout the
+    device's domain form reads the fleet in (a domain a contiguous row,
+    so no segment op over [K,N])."""
+    from .allocate_grouped import _next_pow2
+    member = np.flatnonzero(seg >= 0)
+    if member.size == 0:
+        return np.full(1, n_pad, np.int32), 1
+    doms = seg[member]
+    order = np.argsort(doms, kind="stable")
+    member, doms = member[order], doms[order]
+    sizes = np.bincount(doms)
+    d = len(sizes)
+    d_pad = _next_pow2(d) if d <= 8 else -(-d // 8) * 8
+    s_pad = _next_pow2(int(sizes.max()))
+    column = np.arange(member.size) - np.repeat(
+        np.cumsum(sizes) - sizes, sizes)
+    slot_node = np.full(d_pad * s_pad, n_pad, np.int32)
+    slot_node[doms * s_pad + column] = member
+    return slot_node, d_pad
+
+
 @functools.partial(jax.jit, static_argnames=("num_domains",))
 def domain_aggregates(node_free, node_room, seg, max_pod_req, gang_size,
                       num_domains: int):
@@ -225,6 +270,53 @@ class TopologySession:
                 break
         return out
 
+    def _pinned_domains(self, job, tree, required, podset=None):
+        """The domains of the required level that already host running
+        pods of the podset(s) being allocated
+        (getRelevantDomainsWithAllocatedPods takes the podSets under
+        allocation, not the whole job), or None where nothing pins."""
+        if not required or required not in tree.node_domain:
+            return None
+        pods = (podset.pods.values() if podset is not None
+                else job.pods.values())
+        active_nodes = {t.node_name for t in pods
+                        if t.is_active_allocated() and t.node_name}
+        if not active_nodes:
+            return None
+        ssn, seg_req = self.ssn, tree.node_domain[required]
+        return {int(seg_req[ssn.node_index(node)])
+                for node in active_nodes
+                if ssn.node_index(node) >= 0
+                and seg_req[ssn.node_index(node)] >= 0}
+
+    def required_domains(self, job):
+        """What the scenario prescreen needs of a job's REQUIRED level:
+        ``(level, slot_node, domain_ok [D_pad] bool, domains, preferred)``
+        (``domain_slots``; ``domain_ok`` false on the padding rows and,
+        for a job pinned by its running pods, on every other domain), or
+        None where the job requires no level of a tree the session has.
+        The job-level constraint only: a podset's own is not asked
+        here."""
+        constraint = self._job_constraint(job)
+        if constraint is None:
+            return None
+        tree, required, preferred = constraint
+        seg = tree.node_domain.get(required) if required else None
+        if seg is None or required == ROOT_LEVEL:
+            return None
+        n_pad = self.ssn.node_idle.shape[0]
+        if required not in tree.slots:
+            tree.slots[required] = domain_slots(seg, n_pad)
+        slot_node, d_pad = tree.slots[required]
+        domains = tree.num_domains(required)
+        domain_ok = np.zeros(d_pad, bool)
+        pinned = self._pinned_domains(job, tree, required)
+        if pinned is None:
+            domain_ok[:domains] = True
+        else:
+            domain_ok[sorted(pinned)] = True
+        return required, slot_node, domain_ok, domains, bool(preferred)
+
     # -- the SubsetNodes extension point -----------------------------------
     def subset_nodes(self, job, tasks, podset=None):
         constraint = self._job_constraint(job, podset)
@@ -243,22 +335,9 @@ class TopologySession:
         node_free = (ssn.node_idle + ssn.node_releasing)[:n]
         node_room = ssn.node_room[:n]
 
-        # Pin to domains already hosting running pods of the podset(s)
-        # being allocated (getRelevantDomainsWithAllocatedPods takes the
-        # podSets under allocation, not the whole job) when required is set.
-        pinned_domains = None
-        if required and required in tree.node_domain:
-            pods = (podset.pods.values() if podset is not None
-                    else job.pods.values())
-            active_nodes = {t.node_name for t in pods
-                            if t.is_active_allocated() and t.node_name}
-            if active_nodes:
-                seg_req = tree.node_domain[required]
-                pinned_domains = {
-                    int(seg_req[ssn.node_index(node)])
-                    for node in active_nodes
-                    if ssn.node_index(node) >= 0
-                    and seg_req[ssn.node_index(node)] >= 0}
+        # Pin to domains already hosting the job's running pods when
+        # required is set.
+        pinned_domains = self._pinned_domains(job, tree, required, podset)
 
         candidates = []  # (level_rank, ratio, domain_name, mask)
         self._job_node_scores.pop(job.uid, None)
@@ -275,14 +354,11 @@ class TopologySession:
                 jnp.asarray(seg), jnp.asarray(max_pod_req),
                 float(gang_size), d)
             free = np.asarray(free)
-            pods = np.asarray(pods)
-            for dom in range(d):
+            holds = domain_holds(free, np.asarray(pods), total_req,
+                                 gang_size)
+            for dom in np.flatnonzero(holds).tolist():
                 if pinned_domains is not None and level == required \
                         and dom not in pinned_domains:
-                    continue
-                if pods[dom] < gang_size:
-                    continue
-                if np.any(total_req > free[dom] + 1e-9):
                     continue
                 ratio = _pack_ratio(total_req, free[dom])
                 mask = np.zeros(n_pad, bool)

@@ -30,6 +30,10 @@ class TopologyPlugin(Plugin):
             TRACER.stamp(f"plugin:{self.name}", tree="reused",
                          rows_checked=checked)
         ssn.subset_nodes_fns.append(self._topo.subset_nodes)
+        # The same level's domains for the scenario prescreen, whose
+        # verdict applies subset_nodes' rule a prefix (ops/topology.py
+        # ``domain_holds``).
+        ssn.required_domain_fns.append(self._topo.required_domains)
         ssn.extra_score_fns.append(self.extra_scores)
         # Rank-aware gang placement (ops/rankplace.py): reorder an
         # interchangeable chunk's placements so consecutive MPI ranks

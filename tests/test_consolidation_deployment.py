@@ -178,7 +178,7 @@ def test_the_span_tree_under_the_consolidation_action(driven):
         "prefixes": cut["victims"], "steps": scored,
         "rows": 2 * cut["victims"],
         "t_pad": gang, "form": "grouped", "mask": "none",
-        "strategy": "binpack", "runs": 2,
+        "strategy": "binpack", "runs": 2, "level": "none", "domains": 0,
         "feasible": scored - (gang - 2), "first_feasible": gang - 2}
     dispatch = only(children(trace, prescreen),
                     "dispatch:scenario_prescreen")

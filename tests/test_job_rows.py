@@ -290,8 +290,7 @@ class TestSessionOperandForms:
         before = form_count("row", "none")
         out = ssn.propose_placements_multi(chunks, pipeline_only=False)
         assert form_count("row", "none") == before + 1
-        for (job, _tasks), node in zip(chunks, ("n05", "n11")):
-            prop = out[job.uid]
+        for prop, node in zip(out, ("n05", "n11")):
             assert prop.success
             assert {n for _t, n, _p in prop.placements} == {node}
 
@@ -334,7 +333,7 @@ class TestSessionOperandForms:
                                              pipeline_only=False)
         assert form_count(form, "dense" if hard_mask else "none") \
             == before + 2
-        assert single.success and multi[job.uid] == single
+        assert single.success and multi == [single]
         if form != "none" or hard_mask:
             ssn.extra_score_fns[:] = []
             ssn.hard_node_mask_fns[:] = []
@@ -370,8 +369,8 @@ class TestSessionOperandForms:
         out = ssn.propose_placements_multi(
             [(gang, list(gang.pods.values())),
              (other, list(other.pods.values()))], pipeline_only=False)
-        assert [n for _t, n, _p in out[gang.uid].placements] == ["n04"] * 2
-        assert [n for _t, n, _p in out[other.uid].placements] \
+        assert [n for _t, n, _p in out[0].placements] == ["n04"] * 2
+        assert [n for _t, n, _p in out[1].placements] \
             == ["n07", "n08"]
 
     @pytest.mark.parametrize("form", ("row", "per_task"))

@@ -161,7 +161,8 @@ def test_the_span_tree_under_the_reclaim_action(driven):
     assert prescreen.attrs == {
         "prefixes": scored, "steps": scored, "rows": prescreen.attrs["rows"],
         "t_pad": prescreen.attrs["t_pad"], "form": "counted",
-        "mask": "none", "strategy": "binpack",
+        "mask": "none", "strategy": "binpack", "level": "none",
+        "domains": 0,
         "feasible": scored - (steps - 2), "first_feasible": steps - 2}
     assert prescreen.attrs["rows"] >= 2 * scored
     assert prescreen.attrs["t_pad"] >= gang
