@@ -16,7 +16,7 @@ import numpy as np
 
 from . import resources as rs
 from .node_info import NodeInfo
-from .pod_status import PodStatus, is_active_allocated
+from .pod_status import PodStatus
 from .podgroup_info import PodGroupInfo
 from .queue_info import QueueInfo
 
@@ -196,11 +196,13 @@ class ClusterInfo:
         self._queue_aggregates = None
 
     def _aggregates(self) -> "QueueAggregates":
-        """The cycle's ONE pod walk for the per-leaf-queue sums — at
-        100k-node scale the walk itself dominates, so whoever needs them
-        (snapshot.pack, the proportion plugin's roll-up) must not pay it
-        again.  Memoized until the next snapshot build or the next
-        Statement mutation (which calls invalidate_aggregates)."""
+        """The per-leaf-queue sums, made once for whoever needs them
+        (snapshot.pack, the proportion plugin's roll-up).  Memoized until
+        the next snapshot build or the next Statement mutation (which
+        calls invalidate_aggregates); made again they cost a pass over
+        what the PodGroups keep (``PodGroupInfo.queue_counts``) and a pod
+        walk only inside those that changed, or one over every pod where
+        the sums have to be taken in turn."""
         cached = getattr(self, "_queue_aggregates", None)
         if cached is None:
             cached = self._queue_aggregates = (
@@ -243,8 +245,10 @@ class ClusterInfo:
 
     def _aggregates_by_count(self) -> "QueueAggregates | None":
         """The same sums where they are exact in any order: the pods of a
-        gang share a handful of requirement objects, so the walk counts
-        pods per object and adds ``count * vector`` once.  That equals the
+        fleet share a handful of requirement objects, so each PodGroup
+        keeps how many of its pods carry which (``queue_counts``), the
+        counts are added up by leaf, object and ``is_preemptible()``, and
+        ``count * vector`` is added once for each.  That equals the
         additions in turn to the bit only while every vector is made of
         non-negative whole multiples of a power of two and every total
         stays under 2**53 of them, column by column (``EXACT_BELOW``: 2**53
@@ -257,42 +261,39 @@ class ClusterInfo:
         PodGroup's property, so the same count), how many additions a
         pod-by-pod roll-up would have made at each leaf, and each leaf's
         unit, by which the plugin proves its ancestors' totals."""
-        pending = PodStatus.PENDING
-        allocated = {qid: rs.zeros() for qid in self.queues}
-        requested = {qid: rs.zeros() for qid in self.queues}
-        non_preemptible = {qid: rs.zeros() for qid in self.queues}
-        adds = dict.fromkeys(self.queues, 0)
-        asked: dict = {}      # (leaf, id(requirements)) -> their vector
+        queues = self.queues
+        # (leaf, id(requirements), guaranteed) -> [them, active, pending]
+        counted: dict = {}
         for pg in self.podgroups.values():
             qid = pg.queue_id
-            if qid not in allocated:
+            if qid not in queues:
                 continue
-            counts: dict = {}     # id(requirements) -> [them, active, pending]
-            for t in pg.pods.values():
-                status = t.status
-                if is_active_allocated(status):
-                    slot = 1
-                elif status == pending:
-                    slot = 2
-                else:
-                    continue
-                req = t.res_req
-                entry = counts.get(id(req))
-                if entry is None:
-                    entry = counts[id(req)] = [req, 0, 0]
-                entry[slot] += 1
+            kept = pg.queue_counts()
             guaranteed = not pg.is_preemptible()
-            for req, active, waiting in counts.values():
-                vec = req.to_vec()
-                if req.gpu_memory_bytes > 0.0 or (vec < 0.0).any() \
-                        or (vec != np.floor(vec)).any():
-                    return None
-                asked[qid, id(req)] = vec
-                allocated[qid] += active * vec
-                requested[qid] += (active + waiting) * vec
-                if guaranteed:
-                    non_preemptible[qid] += active * vec
-                adds[qid] += (3 if guaranteed else 2) * active + waiting
+            for i in range(0, len(kept), 3):
+                key = (qid, id(kept[i]), guaranteed)
+                entry = counted.get(key)
+                if entry is None:
+                    counted[key] = [kept[i], kept[i + 1], kept[i + 2]]
+                else:
+                    entry[1] += kept[i + 1]
+                    entry[2] += kept[i + 2]
+        allocated = {qid: rs.zeros() for qid in queues}
+        requested = {qid: rs.zeros() for qid in queues}
+        non_preemptible = {qid: rs.zeros() for qid in queues}
+        adds = dict.fromkeys(queues, 0)
+        asked: dict = {}      # (leaf, id(requirements)) -> their vector
+        for (qid, _, guaranteed), (req, active, waiting) in counted.items():
+            vec = req.to_vec()
+            if req.gpu_memory_bytes > 0.0 or (vec < 0.0).any() \
+                    or (vec != np.floor(vec)).any():
+                return None
+            asked[qid, id(req)] = vec
+            allocated[qid] += active * vec
+            requested[qid] += (active + waiting) * vec
+            if guaranteed:
+                non_preemptible[qid] += active * vec
+            adds[qid] += (3 if guaranteed else 2) * active + waiting
         # No queue asks more than all the leaves together: while that is
         # under 2**53 the unit 1 of whole numbers proves every total, and
         # the requests' own is looked for only past it.

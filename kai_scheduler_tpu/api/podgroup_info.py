@@ -14,10 +14,12 @@ from __future__ import annotations
 
 import hashlib
 from dataclasses import dataclass, field
+from itertools import chain
 from typing import Callable, Iterable, Optional
 
 import numpy as np
 
+from ..utils.metrics import METRICS
 from . import resources as rs
 from .pod_info import DEFAULT_SUBGROUP, PodInfo
 from .pod_status import PodStatus, is_active_allocated, is_alive
@@ -122,6 +124,10 @@ class PodGroupInfo:
         # so it must not rescan the pod dict each time at 1M-pod scale.
         self._pending_count = 0
         self._releasing_count = 0
+        # What the pods add up to for their queue (``queue_counts``),
+        # kept from one session to the next and dropped with the caches
+        # above: a pod that arrives or changes status goes through them.
+        self._queue_counts: Optional[tuple] = None
 
     # -- structure ---------------------------------------------------------
     def set_pod_sets(self, pod_sets: Iterable[PodSet],
@@ -163,6 +169,38 @@ class PodGroupInfo:
         self._tasks_to_allocate = None
         self._signature = None
         self._init_resource = None
+        self._queue_counts = None
+
+    def queue_counts(self) -> tuple:
+        """``(requirements, active, pending, ...)``, three entries for
+        each requirements object that an active-allocated or a pending pod
+        of this PodGroup carries, in the order the pods first show it:
+        how many of its pods are active-allocated and how many pending.
+        What ``ClusterInfo._aggregates_by_count`` sums; counted from the
+        pods where nothing is kept, which
+        ``queue_aggregate_pod_visits_total`` counts."""
+        kept = self._queue_counts
+        if kept is not None:
+            return kept
+        counts: dict = {}     # id(requirements) -> [them, active, pending]
+        pending = PodStatus.PENDING
+        for t in self.pods.values():
+            status = t.status
+            if is_active_allocated(status):
+                slot = 1
+            elif status == pending:
+                slot = 2
+            else:
+                continue
+            req = t.res_req
+            entry = counts.get(id(req))
+            if entry is None:
+                entry = counts[id(req)] = [req, 0, 0]
+            entry[slot] += 1
+        METRICS.inc("queue_aggregate_pod_visits_total", len(self.pods))
+        kept = self._queue_counts = tuple(
+            chain.from_iterable(counts.values()))
+        return kept
 
     # -- aggregate state ---------------------------------------------------
     def num_active_used(self) -> int:

@@ -315,6 +315,9 @@ class Session:
         METRICS.inc("scenario_prescreen_domain_calls_total", 0)
         METRICS.inc("scenario_prescreen_domain_pruned_total", 0)
         METRICS.inc("scenario_prescreen_pool_cells_total", 0)
+        # The pods counted anew for the queue sums
+        # (``PodGroupInfo.queue_counts``).
+        METRICS.inc("queue_aggregate_pod_visits_total", 0)
         # Sessions are scheduler-thread-owned end to end: statements
         # mutate mirrors on the cycle path only (commit I/O ships OUT of
         # the session to the executor; it never writes back in).

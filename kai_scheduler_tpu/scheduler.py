@@ -71,14 +71,23 @@ class Scheduler:
         escaped: BaseException | None = None
         try:
             with TRACER.span("snapshot", kind="snapshot") as snap_sp:
+                visits0 = METRICS.counters.get(
+                    "queue_aggregate_pod_visits_total", 0)
                 cluster = self.cluster_provider()
                 usage = (self.usage_provider()
                          if self.usage_provider else None)
                 ssn = Session(cluster, self.config, self.cache,
                               queue_usage=usage,
                               host_arena=self.host_arena)
+                # ``aggregate_pod_visits``: the pods this span counted
+                # anew for the queue sums; the PodGroups that stand as
+                # they stood keep theirs (``PodGroupInfo.queue_counts``).
                 snap_sp.set(nodes=len(cluster.nodes),
-                            podgroups=len(cluster.podgroups))
+                            podgroups=len(cluster.podgroups),
+                            aggregate_pod_visits=int(
+                                METRICS.counters[
+                                    "queue_aggregate_pod_visits_total"]
+                                - visits0))
                 cache_stats = getattr(cluster, "cache_stats", None)
                 if cache_stats:
                     # Incremental ClusterInfo verdict: how many objects

@@ -344,3 +344,210 @@ def test_queue_aggregates_count_past_2_53_bytes_in_whole_gi(bytes_asked):
     in_turn = ci._aggregates_in_turn()
     _same_bits(counted[0], in_turn[0])
     _same_bits(counted[1], in_turn[1])
+
+
+# -- the counts a PodGroup keeps (PodGroupInfo.queue_counts) ----------------
+
+def _held_to_a_walk(ci):
+    """What the cluster answers now, against a walk over every pod: a
+    fresh ClusterInfo over ``clone()``d objects, which have nothing kept
+    and one requirements object a pod, and the additions in turn."""
+    got, rollup = ci.queue_aggregates(), ci.queue_rollup()
+    want = ci.clone().queue_rollup()
+    assert rollup is not None and want is not None
+    _same_bits(got[0], rollup.allocated)
+    _same_bits(got[1], rollup.requested)
+    for field in ("allocated", "requested", "non_preemptible", "unit"):
+        _same_bits(getattr(rollup, field), getattr(want, field))
+    assert rollup.adds == want.adds
+    in_turn = ci._aggregates_in_turn()
+    _same_bits(got[0], in_turn[0])
+    _same_bits(got[1], in_turn[1])
+
+
+class _Churn:
+    """A client's and a session's moves on one persistent cluster: every
+    way a pod's status, a PodGroup's pods, its queue or its guarantee
+    change between two reads of the queue sums."""
+
+    QUEUES = ("qa", "qb", "qc", "lost")
+
+    def __init__(self, seed):
+        self.rng = np.random.default_rng(seed)
+        nodes = {f"n{i}": mknode(f"n{i}", cpu="512", mem="4096Gi", gpu=64)
+                 for i in range(6)}
+        queues = {q: QueueInfo(q, quota=QueueQuota.from_spec())
+                  for q in self.QUEUES[:3]}
+        self.shapes = [ResourceRequirements.from_spec(
+            f"{int(self.rng.integers(1, 20)) * 100}m",
+            f"{int(self.rng.integers(1, 8))}Gi", int(self.rng.integers(0, 2)))
+            for _ in range(3)]
+        self.seq = 0
+        self.ci = ClusterInfo(nodes, {}, queues)
+        for _ in range(5):
+            self.arrive(running=True)
+
+    def pick(self, items):
+        items = list(items)
+        return items[int(self.rng.integers(len(items)))] if items else None
+
+    def pod(self, **kw):
+        self.seq += 1
+        return PodInfo(uid=f"p{self.seq}", name=f"p{self.seq}",
+                       res_req=self.pick(self.shapes[:2] if self.seq % 3
+                                         else self.shapes), **kw)
+
+    # -- the client's moves -------------------------------------------------
+    def arrive(self, running=False):
+        self.seq += 1
+        pg = PodGroupInfo(f"pg{self.seq}", f"pg{self.seq}",
+                          queue_id=self.pick(self.QUEUES),
+                          preemptible=bool(self.rng.integers(2)))
+        for _ in range(int(self.rng.integers(1, 7))):
+            if running:
+                task = self.pod(status=PodStatus.RUNNING,
+                                node_name=self.pick(self.ci.nodes))
+                self.ci.nodes[task.node_name].add_task(task)
+            else:
+                task = self.pod()
+            pg.add_task(task)
+        self.ci.podgroups[pg.uid] = pg
+        self.ci.invalidate_aggregates()
+
+    def leave(self):
+        pg = self.pick(self.ci.podgroups.values())
+        if pg is None:
+            return
+        for task in pg.pods.values():
+            if task.node_name:
+                self.ci.nodes[task.node_name].remove_task(task)
+        del self.ci.podgroups[pg.uid]
+        self.ci.invalidate_aggregates()
+
+    def one_more_pod(self):
+        pg = self.pick(self.ci.podgroups.values())
+        if pg is not None:
+            pg.add_task(self.pod())
+            self.ci.invalidate_aggregates()
+
+    def gate(self):
+        unplaced = [(pg, t) for pg in self.ci.podgroups.values()
+                    for t in pg.pods.values() if not t.node_name]
+        if unplaced:
+            pg, task = self.pick(unplaced)
+            pg.update_task_status(task, self.pick(
+                (PodStatus.PENDING, PodStatus.GATED, PodStatus.FAILED)))
+            self.ci.invalidate_aggregates()
+
+    def requeue(self):
+        pg = self.pick(self.ci.podgroups.values())
+        if pg is not None:
+            pg.queue_id = self.pick(self.QUEUES)
+            self.ci.invalidate_aggregates()
+
+    def guarantee(self):
+        pg = self.pick(self.ci.podgroups.values())
+        if pg is not None:
+            pg.preemptible = not pg.preemptible
+            self.ci.invalidate_aggregates()
+
+    def invalidate(self):
+        self.ci.invalidate_aggregates()
+
+    # -- a session's moves --------------------------------------------------
+    def statement(self):
+        """A statement's allocations, pipelines and evictions, read
+        between them, then left standing, undone in part or undone."""
+        from kai_scheduler_tpu.framework.conf import SchedulerConfig
+        from kai_scheduler_tpu.framework.session import (InMemoryCache,
+                                                         Session)
+        ssn = Session(self.ci, SchedulerConfig(), InMemoryCache())
+        stmt = ssn.statement()
+        marks = [stmt.checkpoint()]
+        for _ in range(int(self.rng.integers(1, 6))):
+            tasks = [t for pg in self.ci.podgroups.values()
+                     for t in pg.pods.values()]
+            pending = [t for t in tasks if t.status == PodStatus.PENDING]
+            placed = [t for t in tasks if t.node_name
+                      and t.status in (PodStatus.RUNNING,
+                                       PodStatus.ALLOCATED)]
+            move = self.pick(("allocate", "pipeline", "evict"))
+            if move == "evict" and placed:
+                stmt.evict(self.pick(placed))
+            elif move != "evict" and pending:
+                getattr(stmt, move)(self.pick(pending),
+                                    self.pick(self.ci.nodes))
+            marks.append(stmt.checkpoint())
+            _held_to_a_walk(self.ci)
+        ending = self.pick(("stands", "part", "undone"))
+        if ending != "stands":
+            stmt.rollback(0 if ending == "undone" else self.pick(marks))
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_kept_queue_counts_equal_a_walk_after_any_moves(seed):
+    from kai_scheduler_tpu.utils.metrics import METRICS
+    churn = _Churn(seed)
+    _held_to_a_walk(churn.ci)
+    moves = ("arrive", "leave", "one_more_pod", "gate", "requeue",
+             "guarantee", "invalidate", "statement", "statement")
+    visits0 = METRICS.counters["queue_aggregate_pod_visits_total"]
+    pods = 0
+    for _ in range(40):
+        getattr(churn, churn.pick(moves))()
+        _held_to_a_walk(churn.ci)
+        pods += sum(len(pg.pods) for pg in churn.ci.podgroups.values())
+    # The clones are counted every time; the cluster itself far less than
+    # a walk a read would have.
+    visits = METRICS.counters["queue_aggregate_pod_visits_total"] - visits0
+    assert visits < 2 * pods
+
+
+@pytest.mark.parametrize("spoiler", [
+    ResourceRequirements.from_spec("1", "1Gi", 0, gpu_fraction=0.3),
+    ResourceRequirements.from_spec("1", "1Gi", 0, gpu_memory="4Gi"),
+    ResourceRequirements.from_spec("0.0005", "1Gi", 0),
+    ResourceRequirements(base=np.array([1000.0, 2.0 ** 53 - 1.0, 0.0])),
+], ids=["fraction", "gpu_memory", "half_a_millicore", "past_2_53"])
+def test_a_pod_the_count_cannot_take_ends_what_was_kept(spoiler):
+    ci = _aggregate_cluster(6)
+    assert ci.queue_rollup() is not None
+    kept = {uid: pg._queue_counts for uid, pg in ci.podgroups.items()
+            if pg.queue_id in ci.queues}
+    assert kept and None not in kept.values()
+    ci.podgroups["pg0"].add_task(PodInfo(
+        uid="spoiler", name="spoiler", status=PodStatus.RUNNING,
+        res_req=spoiler))
+    ci.invalidate_aggregates()
+    assert ci.queue_rollup() is None
+    got, want = ci.queue_aggregates(), ci._aggregates_in_turn()
+    _same_bits(got[0], want[0])
+    _same_bits(got[1], want[1])
+    # The spoiler gone, the others' counts are still theirs.
+    ci.podgroups["pg0"].update_task_status(
+        ci.podgroups["pg0"].pods["spoiler"], PodStatus.SUCCEEDED)
+    ci.invalidate_aggregates()
+    assert ci.queue_rollup() is not None
+    assert all(ci.podgroups[uid]._queue_counts is kept[uid]
+               for uid in kept if uid != "pg0")
+    _held_to_a_walk(ci)
+
+
+def test_queue_counts_are_per_requirements_object_and_dropped_by_the_door():
+    pg = PodGroupInfo("pg", "pg")
+    small, large = (ResourceRequirements.from_spec("1", "1Gi", g)
+                    for g in (0, 1))
+    tasks = [mktask(f"t{k}", status=s) for k, s in enumerate(
+        (PodStatus.RUNNING, PodStatus.PENDING, PodStatus.PENDING,
+         PodStatus.GATED, PodStatus.SUCCEEDED))]
+    for k, task in enumerate(tasks):
+        task.res_req = large if k == 2 else small
+        pg.add_task(task)
+    assert pg.queue_counts() == (small, 1, 1, large, 0, 1)
+    assert pg.queue_counts() is pg.queue_counts()
+    pg.update_task_status(tasks[3], PodStatus.PENDING)
+    assert pg._queue_counts is None
+    assert pg.queue_counts() == (small, 1, 2, large, 0, 1)
+    pg.add_task(mktask("t9", status=PodStatus.BOUND))
+    assert pg._queue_counts is None and len(pg.queue_counts()) == 9
+    assert pg.clone()._queue_counts is None

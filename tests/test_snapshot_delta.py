@@ -710,7 +710,8 @@ def _pad_changes(loop):
 def _mostly_dirty(loop):
     pg = loop.arrive(10, gpu=2)   # ten nodes of twelve
     for k, task in enumerate(pg.pods.values()):
-        task.node_name, task.status = f"n{k:02d}", _running()
+        task.node_name = f"n{k:02d}"
+        pg.update_task_status(task, _running())
         loop.cluster.nodes[task.node_name].add_task(task)
 
 
@@ -788,3 +789,155 @@ def test_host_arena_engages_once_and_says_so_on_the_snapshot_span():
         assert METRICS.gauges["snapshot_delta_ratio"] \
             == pytest.approx(len(touched) / 12)
     assert METRICS.counters["arena_full_rebuild_total"] - rebuilds0 == 1
+
+
+# -- what a PodGroup keeps of its pods from one session to the next --------
+
+def _visits():
+    return METRICS.counters["queue_aggregate_pod_visits_total"]
+
+
+def _snapshot_span():
+    from kai_scheduler_tpu.utils.tracing import TRACER
+    (span,) = [s for s in TRACER.get_trace().spans if s.name == "snapshot"]
+    return span.attrs
+
+
+@pytest.mark.parametrize("nodes", [64, 12])
+def test_a_session_counts_the_pods_of_the_podgroups_that_changed(nodes):
+    """``queue_aggregate_pod_visits_total``: a session after a cluster is
+    built counts every pod once; the next counts the pods of the PodGroups
+    that changed in between, none where nothing did; and the ``snapshot``
+    span says how many it counted (``aggregate_pod_visits``)."""
+    loop = BareLoop(_bare_spec(nodes=nodes, busy=range(0, nodes, 3)))
+    base = len(loop.cluster.podgroups["base"].pods)
+    first = loop.arrive(3)
+    loop.cycle()                  # binds the gang, the client runs it
+    assert _snapshot_span()["aggregate_pod_visits"] == base + 3
+    assert {t.status.name for t in first.pods.values()} == {"RUNNING"}
+    v0 = _visits()
+    loop.cycle()                  # nothing pending: the gang's new status
+    assert _visits() - v0 == 3
+    assert _snapshot_span()["aggregate_pod_visits"] == 3
+    v0 = _visits()
+    loop.cycle()                  # nothing changed
+    assert _visits() - v0 == 0
+    assert _snapshot_span()["aggregate_pod_visits"] == 0
+    second = loop.arrive(4)
+    loop.complete(first)          # a PodGroup that left counts for nothing
+    loop.cycle()
+    assert _snapshot_span()["aggregate_pod_visits"] == 4
+    v0 = _visits()
+    loop.cluster.podgroups["base"].queue_id = "q1"    # read, not kept
+    loop.cluster.invalidate_aggregates()
+    loop.cycle()
+    assert _visits() - v0 == len(second.pods)
+    assert loop.sessions[-1].snapshot.queue_allocated[0].sum() == 0.0
+
+
+def _plain_vocabulary_walk(cluster):
+    """``vocabulary_signature`` as it is defined, pod by pod."""
+    pods, carriers, keys = [], [], set()
+    for pg in cluster.podgroups.values():
+        for t in pg.pods.values():
+            if t.node_selector or t.tolerations:
+                pods.append((t.uid, tuple(t.node_selector.items()),
+                             tuple(sorted(t.tolerations))))
+                keys.update(t.node_selector)
+            if (t.affinity_terms or t.anti_affinity_terms
+                    or t.preferred_affinity_terms
+                    or t.preferred_anti_affinity_terms):
+                carriers.append(t)
+    nodes = [(name, tuple((k, v) for k, v in node.labels.items()
+                          if k in keys), tuple(node.taints))
+             for name, node in cluster.nodes.items()
+             if node.taints or (keys and node.labels)]
+    return (pods, nodes), carriers
+
+
+@pytest.mark.parametrize("spec", [None, _AT_REST],
+                         ids=["bare", "selected_and_tainted"])
+def test_vocabulary_signature_is_read_off_the_pods_every_time(spec):
+    """A selector-bearing gang arrives and leaves (the ``pools98k``
+    shape) between packs, and a pod gains a toleration and a term in
+    place: the signature is the plain walk's each time, so nothing of it
+    may be kept on a PodGroup (``tests/test_podaffinity_gate.py`` holds
+    the carriers to the same; ROADMAP S11d)."""
+    from kai_scheduler_tpu.api import AffinityTerm
+    from kai_scheduler_tpu.api.snapshot import vocabulary_signature
+    loop = BareLoop(spec)
+
+    def held():
+        got = vocabulary_signature(loop.cluster)
+        assert got == _plain_vocabulary_walk(loop.cluster)
+        return got
+
+    at_rest = held()
+    loop.cycle()
+    gang = loop.arrive(2, node_selector={"zone": "z1"},
+                       tolerations={"dedicated"})
+    arrived = held()
+    assert arrived != at_rest and len(arrived[0][0]) == len(at_rest[0][0]) + 2
+    assert loop.cycle().pack_stats["reason"] == "vocab-change"
+    loop.complete(gang)
+    assert held() == at_rest
+    assert loop.cycle().pack_stats["reason"] == "vocab-change"
+    loop.cycle()
+    # In place, through no door of the PodGroup's.
+    pod = next(iter(loop.cluster.podgroups["base"].pods.values()))
+    pod.tolerations = set(pod.tolerations) | {"spot"}
+    pod.anti_affinity_terms = [AffinityTerm({"app": "web"}, "zone")]
+    signature, carriers = held()
+    assert signature != at_rest[0] and carriers == [pod]
+    assert loop.cycle().pack_stats["reason"] == "vocab-change"
+    assert loop.sessions[-1].term_carriers == [pod]
+
+
+def test_the_benchmark_reads_the_pods_a_cycle_counted():
+    """``benchmark/layer_metrics/aggregate_pod_visits.json`` as the harness
+    reads it: the counter's movement over ``run_once``, 0.0 where nothing
+    changed and not nothing; a program without the counter, as the parent
+    is, leaves the metric out."""
+    import json
+    from benchmark.harness import readers
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as fh:
+        bench = json.load(fh)
+    # Asked by name: later PRs append their metrics after this one.
+    (entry,) = [m for m in bench["per_layer"]
+                if m["name"] == "aggregate_pod_visits"]
+    with open(os.path.join(root, "benchmark", "layer_metrics",
+                           entry["name"] + ".json")) as fh:
+        doc = json.load(fh)
+    assert entry["workloads"][:7] == [w["name"]
+                                      for w in bench["workloads"][:7]]
+    assert doc["reader"] == {"kind": "counter_delta",
+                             "counter": "queue_aggregate_pod_visits_total"}
+    assert {k: v for k, v in doc.items() if k != "reader"} \
+        == {k: v for k, v in entry.items() if k != "workloads"}
+    assert (entry["unit"], entry["better"], entry["source"], entry["layer"],
+            entry["moves"]) == ("pods/cycle", "lower", "program_counter",
+                                "snapshot and pack", "cycle_ms")
+    wanted = readers.counters_wanted([doc])
+
+    class Rec:
+        counters = {}
+        spans = []
+
+    assert readers.read_all([doc], {"records": [Rec]}) == {}
+    METRICS.reset()
+    loop = BareLoop()
+    loop.arrive(3)
+    reads = []
+    for _ in range(3):
+        before = {c: METRICS.counters.get(c, 0.0) for c in wanted}
+        loop.cycle()
+        Rec.counters = {c: METRICS.counters[c] - before[c]
+                        for c in wanted if c in METRICS.counters}
+        reads.append(readers.read_all([doc], {"records": [Rec]}))
+    unit = "pods/cycle"
+    assert reads[1:] == [{"aggregate_pod_visits": {"value": 3.0,
+                                                   "unit": unit}},
+                         {"aggregate_pod_visits": {"value": 0.0,
+                                                   "unit": unit}}]
+    assert reads[0]["aggregate_pod_visits"]["value"] >= 4 + 3
