@@ -13,6 +13,7 @@ import numpy as np
 
 from ..api import resources as rs
 from ..api.podgroup_info import PodGroupInfo
+from ..utils.metrics import METRICS
 from ..utils.tracing import TRACER
 from .solvers import fractional_headroom, solve_job
 from .utils import INFINITE, JobsOrderByQueues
@@ -105,10 +106,14 @@ def collect_consolidation_victims(ssn, job: PodGroupInfo, tasks
     evicts a prefix of this list: a job that shares its nodes frees no
     seat by leaving, and ahead of one that does it is moved for nothing."""
     victims, rows_victim, rows_node, rows_vec = [], [], [], []
+    # What the pass walked: every PodGroup is asked, and the running pods
+    # of those past the gate are read off their pods.
+    pod_visits = 0
     for pg in ssn.cluster.podgroups.values():
         if pg.uid == job.uid or not pg.is_preemptible() \
                 or pg.queue_id not in ssn.cluster.queues:
             continue
+        pod_visits += len(pg.pods)
         running = [t for t in pg.pods.values() if t.is_active_allocated()]
         if not running:
             continue
@@ -119,6 +124,10 @@ def collect_consolidation_victims(ssn, job: PodGroupInfo, tasks
                 rows_node.append(idx)
                 rows_vec.append(t.res_req.to_vec(mig_as_gpu=False))
         victims.append(pg)
+    METRICS.inc("fleet_walk_pod_visits_total", pod_visits,
+                walk="victim_survey")
+    TRACER.stamp("consolidation:victims",
+                 podgroups=len(ssn.cluster.podgroups), pod_visits=pod_visits)
     seats = _seats_a_task(ssn, tasks, len(victims), rows_victim, rows_node,
                           rows_vec)
     order = sorted(range(len(victims)), key=lambda i: (

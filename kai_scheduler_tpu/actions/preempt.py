@@ -153,9 +153,18 @@ def survey_preempt_victims(ssn) -> dict:
     priority, newest); per-preemptor filtering happens at use site
     (preempt.go:126-155)."""
     survey: dict[str, list] = {}
+    # What the pass walked: every PodGroup is asked, and
+    # ``num_active_allocated()`` reads the pods of the preemptible ones.
+    pod_visits = 0
     for pg in ssn.cluster.podgroups.values():
-        if pg.is_preemptible() and pg.num_active_allocated() > 0:
-            survey.setdefault(pg.queue_id, []).append(pg)
+        if pg.is_preemptible():
+            pod_visits += len(pg.pods)
+            if pg.num_active_allocated() > 0:
+                survey.setdefault(pg.queue_id, []).append(pg)
+    METRICS.inc("fleet_walk_pod_visits_total", pod_visits,
+                walk="victim_survey")
+    TRACER.stamp("preempt:survey", podgroups=len(ssn.cluster.podgroups),
+                 pod_visits=pod_visits)
     for victims in survey.values():
         victims.sort(key=lambda pg: (pg.priority, -pg.creation_ts))
     return survey

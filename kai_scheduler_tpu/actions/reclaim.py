@@ -126,9 +126,20 @@ class VictimStream:
 
     def __init__(self, ssn):
         queues = ssn.cluster.queues
-        victims = [pg for pg in ssn.cluster.podgroups.values()
-                   if pg.queue_id in queues and pg.is_preemptible()
-                   and pg.num_active_allocated() > 0]
+        # What the pass walked: every PodGroup is asked, and
+        # ``num_active_allocated()`` reads the pods of those it is asked
+        # of.  The order's keys walk the victims' pods again inside the
+        # plugins that give them (``ordering._below_min``): not counted.
+        victims, pod_visits = [], 0
+        for pg in ssn.cluster.podgroups.values():
+            if pg.queue_id in queues and pg.is_preemptible():
+                pod_visits += len(pg.pods)
+                if pg.num_active_allocated() > 0:
+                    victims.append(pg)
+        METRICS.inc("fleet_walk_pod_visits_total", pod_visits,
+                    walk="victim_survey")
+        TRACER.stamp("reclaim:survey", podgroups=len(ssn.cluster.podgroups),
+                     pod_visits=pod_visits)
         self.surveyed = len(victims)
         self._order = JobsOrderByQueues(ssn, victims, victim_mode=True)
         self.read: list[PodGroupInfo] = []

@@ -8,13 +8,22 @@ the pool and the gang can be rescheduled whole later.
 
 from __future__ import annotations
 
+from ..utils.metrics import METRICS
+from ..utils.tracing import TRACER
+
 
 class StaleGangEvictionAction:
     name = "stalegangeviction"
 
     def execute(self, ssn) -> None:
         now = ssn.cluster.now
-        for job in list(ssn.cluster.podgroups.values()):
+        jobs = list(ssn.cluster.podgroups.values())
+        # ``is_stale()`` reads its answer off the pods of every PodGroup
+        # it is asked of: what this pass walked, on the counter and on
+        # the action's span (scheduler.py opens it).
+        pod_visits = 0
+        for job in jobs:
+            pod_visits += len(job.pods)
             if not job.is_stale():
                 continue
             grace = job.staleness_grace_seconds
@@ -32,3 +41,7 @@ class StaleGangEvictionAction:
                 "StaleGangEvicted",
                 f"gang {job.namespace}/{job.name} below minAvailable for "
                 f">{grace}s; evicting {len(stmt.ops)} pods")
+        METRICS.inc("fleet_walk_pod_visits_total", pod_visits,
+                    walk="stale_gangs")
+        TRACER.stamp(f"action:{self.name}", podgroups=len(jobs),
+                     pod_visits=pod_visits)

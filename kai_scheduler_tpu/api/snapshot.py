@@ -19,6 +19,8 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from ..utils.metrics import METRICS
+from ..utils.tracing import TRACER
 from . import resources as rs
 from .cluster_info import ClusterInfo
 from .pod_info import PodInfo
@@ -152,7 +154,9 @@ def survey_pods(cluster: ClusterInfo) -> tuple[list, list]:
     order: what ``Session.term_carriers`` hands the pod-affinity gate so
     that it asks and does not list every running pod."""
     pods, carriers = [], []
+    pod_visits = 0
     for pg in cluster.podgroups.values():
+        pod_visits += len(pg.pods)
         for t in pg.pods.values():
             if t.node_selector or t.tolerations:
                 pods.append((t.uid, tuple(t.node_selector.items()),
@@ -161,6 +165,7 @@ def survey_pods(cluster: ClusterInfo) -> tuple[list, list]:
                     or t.preferred_affinity_terms
                     or t.preferred_anti_affinity_terms):
                 carriers.append(t)
+    METRICS.inc("fleet_walk_pod_visits_total", pod_visits, walk="pod_survey")
     return pods, carriers
 
 
@@ -280,7 +285,8 @@ def _pack_queue_arrays(cluster: ClusterInfo,
     q_alloc = np.zeros((q, rs.NUM_RES))
     q_req = np.zeros((q, rs.NUM_RES))
     q_usage = np.zeros((q, rs.NUM_RES))
-    allocated, requested = cluster.queue_aggregates()
+    with TRACER.span("snapshot:aggregates", kind="snapshot_part"):
+        allocated, requested = cluster.queue_aggregates()
     for qid, i in q_index.items():
         info = cluster.queues[qid]
         q_deserved[i] = info.quota.deserved
@@ -550,8 +556,6 @@ def fragmentation_stats(snap: SnapshotTensors,
     ``fragmentation_stats_skipped_total``) rather than risking a multi-second
     numpy pass inside the cycle.
     """
-    from ..utils.metrics import METRICS
-
     idle = snap.node_idle
     n_nodes, n_res = idle.shape
     names = _frag_resource_names(n_res)

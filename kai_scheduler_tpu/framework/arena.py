@@ -376,18 +376,25 @@ class HostArena:
              ) -> tuple[SnapshotTensors, dict]:
         """Pack ``cluster`` for one Session: the baseline patched where
         ``_reason_and_rows`` allows it, ``api.snapshot.pack`` otherwise;
-        bit-identical to the latter either way.  Opens no span: the
-        verdict rides on the caller's ``snapshot`` span as attributes
-        (``Session.pack_stats``; docs/OBSERVABILITY.md)."""
+        bit-identical to the latter either way.  Its three steps are
+        parts of the caller's ``snapshot`` span (``snapshot:survey``,
+        ``snapshot:stamps``, ``snapshot:pack``); the verdict rides on that
+        span as attributes (``Session.pack_stats``) and what of it says
+        which pack this was on ``snapshot:pack`` too
+        (docs/OBSERVABILITY.md)."""
         t0 = time.perf_counter()
-        vocab, self.term_carriers = vocabulary_signature(cluster)
-        reason, rows = self._reason_and_rows(cluster, pad_nodes_to, vocab)
+        with TRACER.span("snapshot:survey", kind="snapshot_part"):
+            vocab, self.term_carriers = vocabulary_signature(cluster)
+        with TRACER.span("snapshot:stamps", kind="snapshot_part"):
+            reason, rows = self._reason_and_rows(cluster, pad_nodes_to,
+                                                 vocab)
         dirty = () if rows is None else [cluster.node_order[i] for i in rows]
         # Task, job and queue arrays are rebuilt: podgroups, pod statuses
         # and queues are plain fields that no stamp covers.
-        snap, rows, reason = _delta_or_full(
-            cluster, self._prev, reason, dirty, queue_usage, pad_nodes_to,
-            reuse_tasks=False)
+        with TRACER.span("snapshot:pack", kind="snapshot_part") as pack_sp:
+            snap, rows, reason = _delta_or_full(
+                cluster, self._prev, reason, dirty, queue_usage,
+                pad_nodes_to, reuse_tasks=False)
         if rows is None:
             self.generation += 1
             self.products = {}
@@ -398,6 +405,8 @@ class HostArena:
         self.patched_rows = rows
         self._versions = None
         self.last_pack = _verdict(cluster, rows, reason, self.generation, t0)
+        pack_sp.set(**{k: self.last_pack[k]
+                       for k in ("full_rebuild", "reason", "changed_rows")})
         return snap, self.last_pack
 
     def carried(self, snap: SnapshotTensors) -> tuple:

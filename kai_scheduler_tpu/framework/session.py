@@ -318,6 +318,20 @@ class Session:
         # The pods counted anew for the queue sums
         # (``PodGroupInfo.queue_counts``).
         METRICS.inc("queue_aggregate_pod_visits_total", 0)
+        # The pods each pass over the whole fleet read its answers off:
+        # every PodGroup asked whether it is a stale gang
+        # (actions/stalegangeviction.py), the pass that keeps the running
+        # preemptible PodGroups for the cycle's first reclaimer,
+        # consolidator or preemptor, and ``survey_pods`` at every pack.
+        # ``len(pg.pods)`` once a pass for a PodGroup a per-pod method ran
+        # on, 0 for one answered from something kept; one increment a
+        # pass, never one a PodGroup (docs/OBSERVABILITY.md).
+        for walk in ("stale_gangs", "victim_survey", "pod_survey"):
+            METRICS.inc("fleet_walk_pod_visits_total", 0, walk=walk)
+        # Whether the topology trees outlived the session before
+        # (plugins/topology.py): a session that reuses builds 0, not none.
+        METRICS.inc("topology_tree_built_total", 0)
+        METRICS.inc("topology_tree_reused_total", 0)
         # Sessions are scheduler-thread-owned end to end: statements
         # mutate mirrors on the cycle path only (commit I/O ships OUT of
         # the session to the executor; it never writes back in).

@@ -73,9 +73,10 @@ class Scheduler:
             with TRACER.span("snapshot", kind="snapshot") as snap_sp:
                 visits0 = METRICS.counters.get(
                     "queue_aggregate_pod_visits_total", 0)
-                cluster = self.cluster_provider()
-                usage = (self.usage_provider()
-                         if self.usage_provider else None)
+                with TRACER.span("snapshot:provider", kind="snapshot_part"):
+                    cluster = self.cluster_provider()
+                    usage = (self.usage_provider()
+                             if self.usage_provider else None)
                 ssn = Session(cluster, self.config, self.cache,
                               queue_usage=usage,
                               host_arena=self.host_arena)
@@ -100,7 +101,9 @@ class Scheduler:
                     # cycle trace: /debug/trace shows per-cycle pack
                     # behavior next to the span that paid for it.
                     snap_sp.set(**ssn.pack_stats)
-                frag = fragmentation_stats(ssn.snapshot)
+                with TRACER.span("snapshot:fragmentation",
+                                 kind="snapshot_part"):
+                    frag = fragmentation_stats(ssn.snapshot)
                 if frag is not None:
                     # Fragmentation gauges ride the snapshot span AND the
                     # metrics registry so bench fleet rows and /metrics both

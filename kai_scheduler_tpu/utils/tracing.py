@@ -76,6 +76,12 @@ collection and its pause by generation, and a collection of the oldest
 generation that lands on a thread with a live cycle is a ``gc:full`` span
 under that thread's innermost open span.  ``end_cycle`` folds the counts
 into ``METRICS``; the callback itself takes no lock (``Tracer._on_gc``).
+
+What the recorder kept: beside the collector's, ``end_cycle`` folds into
+``METRICS`` how many spans closed in the cycle (``trace_spans_total``) and
+how many of them the trace had no room for (``trace_spans_dropped_total``):
+a reader of ``CycleTrace.spans`` that sees the second move knows its sums
+are short, where ``name_totals`` still holds every span.
 """
 
 from __future__ import annotations
@@ -309,9 +315,12 @@ class _HandOff:
 # Span kinds that get no ``cycle_span_<kind>_latency_ms`` histogram: each
 # mixes unlike intervals under one kind (``seam`` is a 5 s staging beside
 # a 1 ms launch), so a quantile over it says nothing.  ``span_names`` in
-# /debug/cycles carries their totals by name.
+# /debug/cycles carries their totals by name.  ``snapshot_part``: the parts
+# of the ``snapshot`` span (``snapshot:<part>``), whose own histogram keeps
+# holding the whole span alone.
 _NO_HISTOGRAM_KINDS = frozenset({"allocate", "topology", "propose", "seam",
-                                 "reclaim", "solver", "consolidation"})
+                                 "reclaim", "solver", "consolidation",
+                                 "snapshot_part"})
 
 
 def _trace_annotation():
@@ -499,6 +508,9 @@ class Tracer:
         if TRACER._on_gc not in gc.callbacks:
             # Once a process: a process that opens no cycle never pays.
             gc.callbacks.append(TRACER._on_gc)
+        # What end_cycle counts of the recorder, at 0 before the first.
+        METRICS.inc("trace_spans_total", 0)
+        METRICS.inc("trace_spans_dropped_total", 0)
         trace_id = f"t{next(self._ids):06d}"
         trace = CycleTrace(trace_id, cycle, self.max_spans_per_trace)
         self._annotation = _trace_annotation()
@@ -558,6 +570,11 @@ class Tracer:
             if sp.kind not in _NO_HISTOGRAM_KINDS:
                 METRICS.observe(f"cycle_span_{sp.kind}_latency_ms",
                                 sp.duration_s * 1e3)
+        # What the recorder kept of the cycle: every span that closed in
+        # it, and those of them that found no room.  Read before the ring
+        # has the trace: a span attached afterwards is in neither.
+        closed = sum(n for n, _secs in trace.name_totals.values())
+        dropped = trace.dropped_spans
         with self._lock:
             gc_moved = self._gc_unfolded()
             self._ring.append(trace)
@@ -574,6 +591,8 @@ class Tracer:
         for gen, (collections, pause_s) in enumerate(gc_moved):
             METRICS.inc("gc_collections_total", collections, generation=gen)
             METRICS.inc("gc_pause_seconds_total", pause_s, generation=gen)
+        METRICS.inc("trace_spans_total", closed)
+        METRICS.inc("trace_spans_dropped_total", dropped)
         self._maybe_dump(trace)
         return trace
 

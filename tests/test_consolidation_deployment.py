@@ -155,7 +155,14 @@ def test_the_span_tree_under_the_consolidation_action(driven):
     victims = only(children(trace, job), "consolidation:victims")
     # Every fragment job of the fleet, of which the solver takes its cap.
     frag = sum(1 for j in driven.client.jobs.values() if j.preemptible)
-    assert victims.attrs == {"victims": frag}
+    # And what the pass walked to find them (PR 55): every PodGroup asked
+    # (the book's jobs and the gangs alive), the two pods of each fragment
+    # job read.
+    client = driven.client
+    asked = len(client.jobs) + len(client.pending) + len(client.running)
+    assert victims.attrs == {"victims": frag, "pod_visits": 2 * frag,
+                             "podgroups": asked}
+    assert asked == len(client.cluster.podgroups) > frag
     assert job.attrs["victims"] == frag > cut["victims"]
     solve = only(children(trace, job), "solve:job")
     assert solve.kind == "solver"

@@ -303,7 +303,9 @@ def test_the_preemptor_passes_over_its_peers_and_other_queues():
     assert moved[SOLVED] == 1 and moved[UNSOLVED] == 0
     (survey,) = [s for s in trace.spans if s.name == "preempt:survey"]
     assert survey.kind == "preempt"
-    assert survey.attrs == {"queues": 2, "victims": 5}
+    # The five victims' two pods each were read, of six PodGroups asked.
+    assert survey.attrs == {"queues": 2, "victims": 5, "podgroups": 6,
+                            "pod_visits": 10}
 
 
 def test_a_preemptor_with_peers_alone_takes_nothing():

@@ -621,6 +621,85 @@ class TestNameTotals:
         assert totals[CycleTrace.OTHER_NAMES][0] == extra + 1
 
 
+def recorder_counters():
+    return (METRICS.counters["trace_spans_total"],
+            METRICS.counters["trace_spans_dropped_total"])
+
+
+class TestWhatTheRecorderKept:
+    """``trace_spans_total`` / ``trace_spans_dropped_total``: once a cycle
+    ``end_cycle`` folds into ``METRICS`` how many spans closed and how
+    many of them the trace had no room for, so that a reader of
+    ``CycleTrace.spans`` (the benchmark's ``span_sum``) can tell a trace
+    that is whole from one whose sums are short."""
+
+    @pytest.mark.parametrize("spans,dropped", [(5, 0), (7, 0), (8, 1),
+                                               (40, 33)],
+                             ids=["under", "at-its-room", "one-over",
+                                  "far-over"])
+    def test_the_counters_are_the_traces_own_numbers(self, spans, dropped):
+        tracer = Tracer(capacity=2, max_spans_per_trace=8)
+        tracer.begin_cycle(1)
+        recorded0, dropped0 = recorder_counters()
+        for i in range(spans):
+            with tracer.span(f"s{i % 3}", kind="kernel"):
+                pass
+        trace = tracer.end_cycle()
+        assert trace.dropped_spans == dropped
+        assert len(trace.spans) == spans + 1 - dropped
+        recorded = sum(n for n, _s in trace.name_totals.values())
+        assert recorded == spans + 1      # and the root
+        assert recorder_counters() == (recorded0 + recorded,
+                                       dropped0 + dropped)
+
+    def test_both_are_there_at_zero_from_the_first_begin_cycle(self):
+        METRICS.reset()
+        tracer = Tracer(capacity=2)
+        assert "trace_spans_total" not in METRICS.counters
+        tracer.begin_cycle(1)
+        assert recorder_counters() == (0, 0)
+        tracer.end_cycle()
+        assert recorder_counters() == (1, 0)
+
+    def test_they_move_once_a_cycle_not_once_a_span(self, monkeypatch):
+        calls = []
+        real = METRICS.inc
+
+        def inc(name, value=1.0, **labels):
+            if name.startswith("trace_spans_") \
+                    and name != "trace_spans_revoked_total":
+                calls.append((name, value))
+            return real(name, value, **labels)
+        monkeypatch.setattr(METRICS, "inc", inc)
+        tracer = Tracer(capacity=2, max_spans_per_trace=8)
+        tracer.begin_cycle(1)
+        for _ in range(20):
+            with tracer.span("s", kind="kernel"):
+                pass
+        tracer.end_cycle()
+        assert calls == [("trace_spans_total", 0),
+                         ("trace_spans_dropped_total", 0),
+                         ("trace_spans_total", 21),
+                         ("trace_spans_dropped_total", 13)]
+
+    def test_a_span_attached_after_the_cycle_is_in_neither(self):
+        tracer = Tracer(capacity=2, max_spans_per_trace=8)
+        trace_id = tracer.begin_cycle(1)
+        tracer.end_cycle()
+        before = recorder_counters()
+        assert tracer.attach_async_span(trace_id, "commit:wave", "commit",
+                                        0.001)
+        assert recorder_counters() == before
+
+    def test_a_scheduler_cycle_counts_every_span_it_recorded(self):
+        sched = Scheduler(small_cluster, SchedulerConfig())
+        METRICS.reset()
+        sched.run_once()
+        trace = TRACER.get_trace()
+        assert trace.dropped_spans == 0
+        assert recorder_counters() == (len(trace.spans), 0)
+
+
 class TestProfilerClock:
     @staticmethod
     def profiled_cycle(sched, out_dir):
