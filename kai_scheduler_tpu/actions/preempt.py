@@ -154,11 +154,12 @@ def survey_preempt_victims(ssn) -> dict:
     (preempt.go:126-155)."""
     survey: dict[str, list] = {}
     # What the pass walked: every PodGroup is asked, and
-    # ``num_active_allocated()`` reads the pods of the preemptible ones.
+    # ``num_active_allocated()`` reads the pods of the preemptible ones
+    # whose statuses changed since they were last counted.
     pod_visits = 0
     for pg in ssn.cluster.podgroups.values():
         if pg.is_preemptible():
-            pod_visits += len(pg.pods)
+            pod_visits += pg.uncounted_pods()
             if pg.num_active_allocated() > 0:
                 survey.setdefault(pg.queue_id, []).append(pg)
     METRICS.inc("fleet_walk_pod_visits_total", pod_visits,

@@ -128,12 +128,13 @@ class VictimStream:
         queues = ssn.cluster.queues
         # What the pass walked: every PodGroup is asked, and
         # ``num_active_allocated()`` reads the pods of those it is asked
-        # of.  The order's keys walk the victims' pods again inside the
-        # plugins that give them (``ordering._below_min``): not counted.
+        # of whose statuses changed since they were last counted.  The
+        # order's keys (``ordering._below_min``) read the same kept
+        # counts.
         victims, pod_visits = [], 0
         for pg in ssn.cluster.podgroups.values():
             if pg.queue_id in queues and pg.is_preemptible():
-                pod_visits += len(pg.pods)
+                pod_visits += pg.uncounted_pods()
                 if pg.num_active_allocated() > 0:
                     victims.append(pg)
         METRICS.inc("fleet_walk_pod_visits_total", pod_visits,

@@ -18,12 +18,13 @@ class StaleGangEvictionAction:
     def execute(self, ssn) -> None:
         now = ssn.cluster.now
         jobs = list(ssn.cluster.podgroups.values())
-        # ``is_stale()`` reads its answer off the pods of every PodGroup
-        # it is asked of: what this pass walked, on the counter and on
-        # the action's span (scheduler.py opens it).
+        # ``is_stale()`` reads its answer off what the PodGroup keeps of
+        # its pods' statuses, and off its pods where a status changed
+        # since: what this pass walked, on the counter and on the
+        # action's span (scheduler.py opens it).
         pod_visits = 0
         for job in jobs:
-            pod_visits += len(job.pods)
+            pod_visits += job.uncounted_pods()
             if not job.is_stale():
                 continue
             grace = job.staleness_grace_seconds

@@ -22,7 +22,12 @@ import numpy as np
 from ..utils.metrics import METRICS
 from . import resources as rs
 from .pod_info import DEFAULT_SUBGROUP, PodInfo
-from .pod_status import PodStatus, is_active_allocated, is_alive
+from .pod_status import (_ACTIVE_ALLOCATED, _ACTIVE_USED, _ALIVE, PodStatus,
+                         is_alive)
+
+_PENDING = PodStatus.PENDING.value
+_PIPELINED = PodStatus.PIPELINED.value
+_SUCCEEDED = PodStatus.SUCCEEDED.value
 
 
 class PodSet:
@@ -124,10 +129,13 @@ class PodGroupInfo:
         # so it must not rescan the pod dict each time at 1M-pod scale.
         self._pending_count = 0
         self._releasing_count = 0
-        # What the pods add up to for their queue (``queue_counts``),
-        # kept from one session to the next and dropped with the caches
-        # above: a pod that arrives or changes status goes through them.
+        # What the pods add up to, kept from one session to the next and
+        # dropped with the caches above (a pod that arrives or changes
+        # status goes through them): for their queue (``queue_counts``)
+        # and by status (``_census``).  One walk fills both
+        # (``_count_pods``); None where nobody has asked since.
         self._queue_counts: Optional[tuple] = None
+        self._census: Optional[tuple] = None
 
     # -- structure ---------------------------------------------------------
     def set_pod_sets(self, pod_sets: Iterable[PodSet],
@@ -136,6 +144,7 @@ class PodGroupInfo:
         self.subgroup_nodes = {sg.name: sg for sg in subgroup_nodes}
         for task in self.pods.values():
             self._index_task(task)
+        self.invalidate_caches()
 
     def _index_task(self, task: PodInfo) -> None:
         ps = self.pod_sets.get(task.subgroup)
@@ -170,6 +179,7 @@ class PodGroupInfo:
         self._signature = None
         self._init_resource = None
         self._queue_counts = None
+        self._census = None
 
     def queue_counts(self) -> tuple:
         """``(requirements, active, pending, ...)``, three entries for
@@ -179,69 +189,109 @@ class PodGroupInfo:
         What ``ClusterInfo._aggregates_by_count`` sums; counted from the
         pods where nothing is kept, which
         ``queue_aggregate_pod_visits_total`` counts."""
-        kept = self._queue_counts
-        if kept is not None:
-            return kept
-        counts: dict = {}     # id(requirements) -> [them, active, pending]
-        pending = PodStatus.PENDING
-        for t in self.pods.values():
-            status = t.status
-            if is_active_allocated(status):
-                slot = 1
-            elif status == pending:
-                slot = 2
-            else:
-                continue
-            req = t.res_req
-            entry = counts.get(id(req))
-            if entry is None:
-                entry = counts[id(req)] = [req, 0, 0]
-            entry[slot] += 1
-        METRICS.inc("queue_aggregate_pod_visits_total", len(self.pods))
-        kept = self._queue_counts = tuple(
-            chain.from_iterable(counts.values()))
-        return kept
+        if self._queue_counts is None:
+            METRICS.inc("queue_aggregate_pod_visits_total", len(self.pods))
+            self._count_pods()
+        return self._queue_counts
 
-    # -- aggregate state ---------------------------------------------------
+    def uncounted_pods(self) -> int:
+        """The pods that the next question about this PodGroup's counts
+        will walk: all of them where nothing is kept, none where it is.
+        What a pass over the fleet adds to its
+        ``fleet_walk_pod_visits_total`` before it asks."""
+        return len(self.pods) if self._census is None else 0
+
+    def _count_pods(self) -> tuple:
+        """One walk of the pods, pod set by pod set, for both kept
+        things; returns the census: ``(used, allocated, succeeded,
+        sets)``, how many pods are active-used, active-allocated and
+        SUCCEEDED, and for each pod set, in ``pod_sets``' order, ``(used,
+        allocated, alive, pipelined)``.  Counts and never verdicts:
+        ``min_available`` and the grace period are read when asked, so an
+        edited spec is never answered from before the edit.  Every pod is
+        in one pod set (``_index_task``), so the sets' sums are the
+        PodGroup's."""
+        by_req: dict = {}     # id(requirements) -> [them, active, pending]
+        sets = []
+        used = allocated = succeeded = 0
+        for ps in self.pod_sets.values():
+            ps_used = ps_allocated = ps_alive = ps_pipelined = 0
+            for t in ps.pods.values():
+                status = t.status._value_
+                if status & _ACTIVE_ALLOCATED:
+                    # Active-allocated is active-used and alive as well.
+                    ps_allocated += 1
+                    if status == _PIPELINED:
+                        ps_pipelined += 1
+                    slot = 1
+                elif status == _PENDING:
+                    ps_alive += 1
+                    slot = 2
+                else:
+                    if status & _ACTIVE_USED:
+                        ps_used += 1
+                    elif status & _ALIVE:
+                        ps_alive += 1
+                    elif status == _SUCCEEDED:
+                        succeeded += 1
+                    continue
+                req = t.res_req
+                entry = by_req.get(id(req))
+                if entry is None:
+                    entry = by_req[id(req)] = [req, 0, 0]
+                entry[slot] += 1
+            ps_used += ps_allocated
+            ps_alive += ps_allocated
+            used += ps_used
+            allocated += ps_allocated
+            sets.append((ps_used, ps_allocated, ps_alive, ps_pipelined))
+        self._queue_counts = tuple(chain.from_iterable(by_req.values()))
+        census = self._census = (used, allocated, succeeded, tuple(sets))
+        return census
+
+    # -- aggregate state: read off the census ------------------------------
     def num_active_used(self) -> int:
-        return sum(1 for t in self.pods.values() if t.is_active_used())
+        return (self._census or self._count_pods())[0]
 
     def num_active_allocated(self) -> int:
-        return sum(1 for t in self.pods.values() if t.is_active_allocated())
+        return (self._census or self._count_pods())[1]
 
     def pending_tasks(self) -> list[PodInfo]:
         return [t for t in self.pods.values() if t.status == PodStatus.PENDING]
 
     def is_gang_satisfied(self) -> bool:
-        return all(ps.is_gang_satisfied() for ps in self.pod_sets.values())
+        sets = (self._census or self._count_pods())[3]
+        for ps, counts in zip(self.pod_sets.values(), sets):
+            if counts[0] < ps.min_available:
+                return False
+        return True
 
     def is_ready_for_scheduling(self) -> bool:
-        return all(ps.is_ready_for_scheduling() for ps in self.pod_sets.values())
+        sets = (self._census or self._count_pods())[3]
+        for ps, counts in zip(self.pod_sets.values(), sets):
+            if counts[2] < ps.min_available:
+                return False
+        return True
 
     def is_elastic(self) -> bool:
         return any(ps.is_elastic() for ps in self.pod_sets.values())
 
     def is_stale(self) -> bool:
         """Partially-running gang below minAvailable (job_info.go:417)."""
-        if any(t.status == PodStatus.SUCCEEDED for t in self.pods.values()):
-            return False
-        if self.num_active_used() == 0:
+        used, _, succeeded, _ = self._census or self._count_pods()
+        if succeeded or used == 0:
             return False
         return not self.is_gang_satisfied()
 
     def should_pipeline(self) -> bool:
         """If any podset has a pipelined task and too few allocated for the
         gang, the whole job's new placements must pipeline (job_info.go:443)."""
-        for ps in self.pod_sets.values():
-            has_pipelined = any(t.status == PodStatus.PIPELINED
-                                for t in ps.pods.values())
-            # Pipelined members don't count toward the allocated quorum
-            # (the reference's if/elif excludes them, job_info.go:448-455).
-            active_allocated = sum(
-                1 for t in ps.pods.values()
-                if t.status != PodStatus.PIPELINED
-                and is_active_allocated(t.status))
-            if has_pipelined and active_allocated < ps.min_available:
+        sets = (self._census or self._count_pods())[3]
+        # Pipelined members don't count toward the allocated quorum
+        # (the reference's if/elif excludes them, job_info.go:448-455).
+        for ps, (_, allocated, _, pipelined) in zip(self.pod_sets.values(),
+                                                    sets):
+            if pipelined and allocated - pipelined < ps.min_available:
                 return True
         return False
 

@@ -323,9 +323,10 @@ class Session:
         # (actions/stalegangeviction.py), the pass that keeps the running
         # preemptible PodGroups for the cycle's first reclaimer,
         # consolidator or preemptor, and ``survey_pods`` at every pack.
-        # ``len(pg.pods)`` once a pass for a PodGroup a per-pod method ran
-        # on, 0 for one answered from something kept; one increment a
-        # pass, never one a PodGroup (docs/OBSERVABILITY.md).
+        # ``len(pg.pods)`` once a pass for a PodGroup whose pods were
+        # walked, 0 for one answered from what it keeps
+        # (``PodGroupInfo.uncounted_pods``); one increment a pass, never
+        # one a PodGroup (docs/OBSERVABILITY.md).
         for walk in ("stale_gangs", "victim_survey", "pod_survey"):
             METRICS.inc("fleet_walk_pod_visits_total", 0, walk=walk)
         # Whether the topology trees outlived the session before

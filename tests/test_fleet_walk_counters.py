@@ -5,7 +5,11 @@ parts, and the benchmark's files read both (PR 55).
     PodGroups it read off their pods, exactly, by one increment a pass; a
     walk that does not run leaves its series at 0 and present; the survey
     spans and the stale-gang action's span carry the count beside the
-    PodGroups the pass asked.
+    PodGroups the pass asked.  Since PR 56 a PodGroup keeps its pods'
+    status census (``tests/test_pod_census.py``): the stale-gang pass and
+    the reclaim and preempt surveys read every pod of a fleet nobody has
+    counted, none of a fleet that stands as it was counted, and the pods
+    of the PodGroups that changed in between.
 (b) the ``snapshot:*`` parts nest under ``snapshot``, are disjoint but for
     ``snapshot:aggregates`` under ``snapshot:pack``, say on ``snapshot:pack``
     which pack it was, and open no histogram of their own.
@@ -26,6 +30,7 @@ from kai_scheduler_tpu.actions.consolidation import \
     collect_consolidation_victims
 from kai_scheduler_tpu.actions.preempt import survey_preempt_victims
 from kai_scheduler_tpu.actions.reclaim import VictimStream
+from kai_scheduler_tpu.api import PodStatus
 from kai_scheduler_tpu.api.snapshot import survey_pods
 from kai_scheduler_tpu.framework.conf import SchedulerConfig
 from kai_scheduler_tpu.scheduler import Scheduler
@@ -57,7 +62,8 @@ JOBS = {
     "claim": ("b0", False, 0, 2),       # the pending gang
 }
 EVERY_POD = sum(run + wait for _q, _p, run, wait in JOBS.values())
-# The pods of the PodGroups each pass reads off their pods.
+# The pods of the PodGroups each pass reads off their pods, in a fleet
+# nobody has counted.
 READ_BY = {
     "stale_gangs": EVERY_POD,
     "pod_survey": EVERY_POD,
@@ -86,6 +92,19 @@ def fleet() -> dict:
             "tasks": [{"gpu": 1, "status": "RUNNING", "node": node}] * run
             + [{"gpu": 1}] * wait}
     return spec
+
+
+# The passes that read what a PodGroup keeps of its pods' statuses; the
+# two others read every pod every time (``consolidation:victims`` wants
+# every running pod's node and request, ``survey_pods`` its constraints).
+READ_KEPT = ("preempt", "reclaim", "stale_gangs")
+
+
+def forget(ssn) -> None:
+    """A fleet nobody has counted: opening the session counted the
+    PodGroups of its queues for the queue sums."""
+    for pg in ssn.cluster.podgroups.values():
+        pg.invalidate_caches()
 
 
 def walk_of(ssn, which: str) -> None:
@@ -127,6 +146,7 @@ def incs(monkeypatch):
 @pytest.mark.parametrize("which", sorted(WALK))
 def test_a_pass_counts_the_pods_it_read_by_one_increment(which, incs):
     ssn = build_session(fleet())
+    forget(ssn)
     before = {w: read(w) for w in FLEET_WALKS}
     del incs[:]
     walk_of(ssn, which)
@@ -137,13 +157,36 @@ def test_a_pass_counts_the_pods_it_read_by_one_increment(which, incs):
         assert read(w) - before[w] == (READ_BY[which] if w == walk else 0), w
 
 
-def test_a_second_pass_counts_again():
-    """Nothing is kept yet: every pass reads every pod it read before."""
+@pytest.mark.parametrize("which", sorted(WALK))
+def test_a_second_pass_counts_what_changed(which, incs):
+    """The first pass over a fleet nobody counted reads every pod; the
+    next over the fleet as it stands reads none, and still says so by one
+    increment; a pod that changes status in between has its PodGroup read
+    again, and no other."""
     ssn = build_session(fleet())
-    visits0 = read("stale_gangs")
-    for n in (1, 2, 3):
-        walk_of(ssn, "stale_gangs")
-        assert read("stale_gangs") == visits0 + n * EVERY_POD
+    forget(ssn)
+    walk = WALK[which]
+    kept = which in READ_KEPT
+    visits0 = read(walk)
+    walk_of(ssn, which)
+    assert read(walk) == visits0 + READ_BY[which]
+    del incs[:]
+    walk_of(ssn, which)
+    again = 0 if kept else READ_BY[which]
+    assert incs == [(walk, again)]
+    victim = ssn.cluster.podgroups["victim-b"]
+    waiting = next(t for t in victim.pods.values() if not t.node_name)
+    victim.update_task_status(waiting, PodStatus.GATED)
+    del incs[:]
+    walk_of(ssn, which)
+    assert incs == [(walk, len(victim.pods) if kept else READ_BY[which])]
+    # A PodGroup no pass reads counts for nothing, changed or not.
+    fixed = ssn.cluster.podgroups["fixed"]
+    fixed.invalidate_caches()
+    del incs[:]
+    walk_of(ssn, which)
+    assert incs == [(walk, {"stale_gangs": len(fixed.pods)}.get(
+        which, again))]
 
 
 @pytest.mark.parametrize("walk", FLEET_WALKS)
@@ -164,6 +207,7 @@ SPANS = dict(zip(("reclaim", "consolidation", "preempt"), SURVEY_SPANS),
 @pytest.mark.parametrize("which", sorted(SPANS))
 def test_the_open_span_carries_what_the_pass_counted(which):
     ssn = build_session(fleet())
+    forget(ssn)
     TRACER.begin_cycle(1)
     try:
         with TRACER.span("above", kind="action") as above, \
@@ -176,9 +220,21 @@ def test_the_open_span_carries_what_the_pass_counted(which):
     assert "pod_visits" not in above.attrs
     # Outside a cycle, or under no span of that name, the pass counts and
     # stamps nothing.
+    forget(ssn)
     before = read(WALK[which])
     walk_of(ssn, which)
     assert read(WALK[which]) - before == READ_BY[which]
+    if which in READ_KEPT:
+        # A pass that read nothing off the pods says 0, and not nothing.
+        TRACER.begin_cycle(2)
+        try:
+            with TRACER.span(SPANS[which], kind="action") as sp:
+                walk_of(ssn, which)
+        finally:
+            TRACER.end_cycle()
+        assert (sp.attrs["podgroups"], sp.attrs["pod_visits"]) \
+            == (len(JOBS), 0)
+        assert read(WALK[which]) - before == READ_BY[which]
 
 
 # -- one Scheduler over one cluster: the cycle's own spans --------------------
@@ -211,24 +267,87 @@ def named(spans, name: str) -> list:
 
 def test_a_cycle_says_what_its_passes_walked():
     loop = Loop()
-    before = {w: METRICS.counters.get(series(w), 0) for w in FLEET_WALKS}
+    for cycle in (1, 2, 3):
+        before = {w: METRICS.counters.get(series(w), 0) for w in FLEET_WALKS}
+        _ssn, spans = loop.cycle()
+        moved = {w: read(w) - before[w] for w in FLEET_WALKS}
+        (stale,) = named(spans, "action:stalegangeviction")
+        assert (stale.attrs["podgroups"], stale.attrs["pod_visits"]) \
+            == (len(JOBS), moved["stale_gangs"])
+        # Every survey of the cycle is on the counter, and each asked
+        # every PodGroup.  The reclaimer in ``b0`` is under its share: one
+        # reclaim survey, by the first action that needed one.
+        surveys = [s for s in spans if s.name in SURVEY_SPANS]
+        assert len(named(surveys, "reclaim:survey")) == 1
+        assert all(s.attrs["podgroups"] == len(JOBS) for s in surveys)
+        assert moved["victim_survey"] \
+            == sum(s.attrs["pod_visits"] for s in surveys)
+        assert moved["pod_survey"] == EVERY_POD
+        # The queue sums and the pending jobs' readiness counted the
+        # PodGroups of the cluster's queues before any pass asked: a pass
+        # reads what a statement of this cycle changed before it, and in
+        # the first cycle the PodGroup whose queue is gone, which the
+        # preempt survey is the first to ask.
+        orphan = len(loop.cluster.podgroups["orphan"].pods)
+        assert moved["victim_survey"] == (orphan if cycle == 1 else 0)
+        assert moved["stale_gangs"] <= EVERY_POD - orphan
+        if cycle == 3:
+            # Nothing is pending any more and nothing changed.
+            assert moved["stale_gangs"] == 0
+
+
+# -- what the stale-gang pass decides, cold and warm ---------------------------
+def gangs(now: float) -> dict:
+    """Four gangs of three, minimum three: one below its minimum for 70 s
+    at ``now`` = 1020 (grace 60 s), one for 30 s, one with a SUCCEEDED
+    pod, one whole."""
+    def job(started, *statuses):
+        return {"queue": "q", "min_available": 3, "last_start_ts": started,
+                "tasks": [{"gpu": 1, "status": s,
+                           **({"node": "n0"} if s == "RUNNING" else {})}
+                          for s in statuses]}
+    return {"now": now, "nodes": {"n0": {"gpu": 16}}, "queues": {"q": {}},
+            "jobs": {"broken": job(950.0, "RUNNING", "RUNNING", "FAILED"),
+                     "young": job(990.0, "RUNNING", "RUNNING", "FAILED"),
+                     "done": job(100.0, "RUNNING", "SUCCEEDED", "FAILED"),
+                     "whole": job(100.0, "RUNNING", "RUNNING", "RUNNING")}}
+
+
+@pytest.mark.parametrize("census", ["cold", "warm", "warm-then-failed"])
+def test_a_gang_below_minimum_past_its_grace_is_evicted_whole(census):
+    """On the first cycle, where every PodGroup is counted from its pods,
+    and on a second where the counts are kept and only the clock moved (a
+    kept verdict would say "inside its grace" for ever) or a pod failed
+    through the door."""
+    if census == "cold":
+        loop = Loop(gangs(1020.0))
+    else:
+        loop = Loop(gangs(1000.0))
+        loop.cycle()
+        assert loop.sched.cache.evicted == []
+        assert all(pg.uncounted_pods() == 0
+                   for pg in loop.cluster.podgroups.values())
+        loop.cluster.now = 1020.0
+    want = {"broken-0", "broken-1"}
+    if census == "warm-then-failed":
+        whole = loop.cluster.podgroups["whole"]
+        whole.update_task_status(whole.pods["whole-2"], PodStatus.FAILED)
+        loop.cluster.invalidate_aggregates()      # the snapshot recounts it
+        want |= {"whole-0", "whole-1"}
+    before = read("stale_gangs")
     _ssn, spans = loop.cycle()
+    assert set(loop.sched.cache.evicted) == want
+    assert len(loop.sched.cache.evicted) == len(want)
     (stale,) = named(spans, "action:stalegangeviction")
-    assert (stale.attrs["podgroups"], stale.attrs["pod_visits"]) \
-        == (len(JOBS), EVERY_POD)
-    # The reclaimer in ``b0`` is under its share: one survey, by the first
-    # action that needed one.
-    (survey,) = named(spans, "reclaim:survey")
-    assert survey.attrs["podgroups"] == len(JOBS)
-    assert survey.attrs["pod_visits"] >= READ_BY["reclaim"]
-    moved = {w: read(w) - before[w] for w in FLEET_WALKS}
-    assert moved["stale_gangs"] == moved["pod_survey"] == EVERY_POD
-    # Every survey of the cycle is on the counter, and each asked every
-    # PodGroup.
-    surveys = [s for s in spans if s.name in SURVEY_SPANS]
-    assert all(s.attrs["podgroups"] == len(JOBS) for s in surveys)
-    assert moved["victim_survey"] \
-        == sum(s.attrs["pod_visits"] for s in surveys) > 0
+    assert stale.attrs["pod_visits"] == read("stale_gangs") - before == 0
+    statuses = {uid: {t.status.name for t in pg.pods.values()}
+                for uid, pg in loop.cluster.podgroups.items()}
+    assert statuses["broken"] == {"RELEASING", "FAILED"}
+    assert statuses["young"] == {"RUNNING", "FAILED"}
+    assert statuses["done"] == {"RUNNING", "SUCCEEDED", "FAILED"}
+    # The evictions went through the door: the next question counts them.
+    assert loop.cluster.podgroups["broken"].uncounted_pods() == 3
+    assert not loop.cluster.podgroups["broken"].is_gang_satisfied()
 
 
 # -- (b) the parts of ``snapshot`` --------------------------------------------
@@ -429,9 +548,11 @@ def test_the_readers_return_what_the_cycle_walked_and_kept():
     rec = Rec(loop.cycle, wanted)
     got = read_all(names, [rec])
     assert set(got) == set(names)
-    assert got["stale_gang_pod_visits"] == EVERY_POD
+    # The first cycle: the snapshot counted the PodGroups of the cluster's
+    # queues before a pass asked, and ``orphan`` was the preempt survey's.
+    assert got["stale_gang_pod_visits"] == 0.0
     assert got["pod_survey_visits"] == EVERY_POD
-    assert got["victim_survey_pod_visits"] >= READ_BY["reclaim"]
+    assert got["victim_survey_pod_visits"] == 4.0
     trace = TRACER.get_trace()
     assert got["spans_recorded"] == len(trace.spans) \
         == sum(n for n, _s in trace.name_totals.values())
@@ -494,9 +615,12 @@ def test_a_survey_that_did_not_run_reads_zero():
     wanted = readers.counters_wanted([metric(n)[1] for n in names])
     METRICS.reset()
     loop = Loop(spec)
+    # No queue sum reads ``orphan`` and no survey ran: the stale-gang pass
+    # is the first to ask it, and the last, while it stands as it is.
     assert read_all(names, [Rec(loop.cycle, wanted)]) == {
-        "victim_survey_pod_visits": 0.0,
-        "stale_gang_pod_visits": 3 + 2 + 2 + 4}
+        "victim_survey_pod_visits": 0.0, "stale_gang_pod_visits": 4}
+    assert read_all(names, [Rec(loop.cycle, wanted)]) == {
+        "victim_survey_pod_visits": 0.0, "stale_gang_pod_visits": 0.0}
 
 
 @pytest.mark.parametrize("name", sorted(METRIC_FILES))

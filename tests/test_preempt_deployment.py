@@ -295,17 +295,26 @@ def preempt_cycle(ssn):
     return trace, moved
 
 
-def test_the_preemptor_passes_over_its_peers_and_other_queues():
+@pytest.mark.parametrize("counted", [True, False],
+                         ids=["counted", "uncounted"])
+def test_the_preemptor_passes_over_its_peers_and_other_queues(counted):
     ssn = build_session(team_spec())
+    if not counted:
+        for pg in ssn.cluster.podgroups.values():
+            pg.invalidate_caches()
     trace, moved = preempt_cycle(ssn)
     evicted = {uid.rsplit("-", 1)[0] for uid in ssn.cache.evicted}
     assert evicted == {"trainer2"}           # the newest of lower priority
     assert moved[SOLVED] == 1 and moved[UNSOLVED] == 0
     (survey,) = [s for s in trace.spans if s.name == "preempt:survey"]
     assert survey.kind == "preempt"
-    # The five victims' two pods each were read, of six PodGroups asked.
+    # Six PodGroups asked, and none read off its pods where the
+    # session's opening counted them for the queue sums: a PodGroup keeps
+    # what its pods add up to (``tests/test_pod_census.py``).  Where
+    # nobody has, the five victims' two pods each are read, and the same
+    # victims come of it.
     assert survey.attrs == {"queues": 2, "victims": 5, "podgroups": 6,
-                            "pod_visits": 10}
+                            "pod_visits": 0 if counted else 10}
 
 
 def test_a_preemptor_with_peers_alone_takes_nothing():

@@ -17,7 +17,11 @@ change):
 (d) two reclaimers a cycle, the first committing;
 (e) ``JobsOrderByQueues`` built in bulk pops what one built by pushes pops,
     on random queue trees;
-(f) the registered reclaim filters judge each victim alone, in order.
+(f) the registered reclaim filters judge each victim alone, in order;
+(g) since PR 56 the pass and the order's elastic key read what a PodGroup
+    keeps of its pods' statuses: the stream over kept counts, over counts
+    a statement dropped and over none is one order, and the oracle's pass
+    walks the pods itself.
 """
 
 import heapq
@@ -106,8 +110,8 @@ def parent_survey(ssn) -> list:
     """``survey_reclaim_victims`` as the parent had it: the pass, the
     order and the whole of its drain."""
     victims = [pg for pg in ssn.cluster.podgroups.values()
-               if pg.queue_id in ssn.cluster.queues
-               and pg.is_preemptible() and pg.num_active_allocated() > 0]
+               if pg.queue_id in ssn.cluster.queues and pg.is_preemptible()
+               and any(t.is_active_allocated() for t in pg.pods.values())]
     return drained(PushedOrder(ssn, victims, victim_mode=True))
 
 
@@ -212,6 +216,56 @@ def test_the_stream_read_to_its_end_is_the_parents_drain(seed, shape, mode):
         stream = VictimStream(ssn)
         stream._read_to(n)
         assert uids(stream.read) == uids(want[:n])
+
+
+def survey_visits() -> float:
+    return METRICS.counters[
+        'fleet_walk_pod_visits_total{walk="victim_survey"}']
+
+
+@pytest.mark.parametrize("shape", SHAPES)
+@pytest.mark.parametrize("seed", (4, 5))
+def test_the_stream_over_kept_counts_is_the_stream_over_none(seed, shape):
+    """The pass and the order's elastic key read what a PodGroup keeps of
+    its pods' statuses (PR 56).  The order is the same over a fleet whose
+    counts are all kept, over one where a statement took pods from some
+    victims since (gangs pushed below their minimum, jobs taken whole),
+    and over one nobody has counted; and ``fleet_walk_pod_visits_total``
+    moves by the pods of the PodGroups the pass found uncounted."""
+    ssn = session(fleet(shape, seed), "keys")
+    asked = [pg for pg in ssn.cluster.podgroups.values()
+             if pg.queue_id in ssn.cluster.queues and pg.is_preemptible()]
+    # The session's opening counted them for the queue sums.
+    assert all(pg.uncounted_pods() == 0 for pg in asked)
+    before = survey_visits()
+    first = uids(survey_reclaim_victims(ssn))
+    assert survey_visits() == before
+    assert first == uids(parent_survey(ssn))
+    # A statement evicts a pod of every third victim and every pod of
+    # every seventh, and stands.
+    stmt = ssn.statement()
+    touched = {}
+    for k, uid in enumerate(first):
+        pg = ssn.cluster.podgroups[uid]
+        if k % 7 == 0 or k % 3 == 0:
+            for task in list(pg.pods.values())[:None if k % 7 == 0 else 1]:
+                stmt.evict(task)
+            touched[uid] = len(pg.pods)
+    taken = {uid for uid in touched if not any(
+        t.is_active_allocated()
+        for t in ssn.cluster.podgroups[uid].pods.values())}
+    assert taken and taken < set(touched)
+    before = survey_visits()
+    warm = uids(survey_reclaim_victims(ssn))
+    assert survey_visits() - before == sum(touched.values())
+    assert set(warm) == set(first) - taken and warm != first[:len(warm)]
+    want = uids(parent_survey(ssn))
+    for pg in ssn.cluster.podgroups.values():
+        pg.invalidate_caches()
+    before = survey_visits()
+    cold = uids(survey_reclaim_victims(ssn))
+    assert survey_visits() - before == sum(len(pg.pods) for pg in asked)
+    assert warm == cold == want
 
 
 def test_a_leaf_in_key_mode_is_the_descending_sort_of_its_keys():
